@@ -1,7 +1,7 @@
 """Autoscale A/B + selfcheck: sim-twin closed-loop scaling, gated exactly.
 
 ``run_autoscale_ab`` produces the committed ``AUTOSCALE_AB.json`` record
-(scripts/autoscale_ab.sh; tpuwatch stage ``ab_autoscale``): the SAME
+(scripts/autoscale_ab.sh): the SAME
 seed and open-loop "dur:rate" schedule driven against two arms —
 
 - **autoscale**: the closed-loop controller armed (controller.py), the
@@ -435,7 +435,7 @@ def run_autoscale_ab(seed: int = 20260807, fast: bool = False,
 
 
 def selfcheck(seed: int = 20260807) -> dict:
-    """One-JSON-line selfcheck (tpuwatch-style): a fast flash-crowd run
+    """One-JSON-line selfcheck: a fast flash-crowd run
     with the autoscaler armed must scale up, lose nothing, resolve every
     unknown exactly once, and have every event doctor-attributed."""
     workdir = tempfile.mkdtemp(prefix="autoscale_self_")

@@ -53,7 +53,7 @@ async def get_estimated_range_size_bytes(tr, begin: bytes, end: bytes) -> int:
     Sums each covered shard's byte stats, with the same replica failover
     the read path uses (Database.first_of_team): a dead or lagging/fenced
     replica is demoted and the next team member answers, instead of the
-    whole estimate failing on the primary tag alone (ADVICE.md r5)."""
+    whole estimate failing on the primary tag alone (r5 review finding)."""
     db = tr.db
     await db.refresh_client_info()
     # Estimate at the transaction's read version: shard_stats waits for

@@ -15,7 +15,7 @@ Pieces:
   machine-readable divergence report (status JSON ``workload.consistency``,
   trace events per divergence).
 - ``python -m foundationdb_tpu.consistency`` — self-contained audit of a
-  replicated SimCluster under load; one JSON line (the CI/tpuwatch stage).
+  replicated SimCluster under load; one JSON line (the CI stage).
 - ``cli consistencycheck`` — the same walk against a deployed cluster.
 """
 

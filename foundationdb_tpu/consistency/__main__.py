@@ -5,7 +5,7 @@
 Boots a 3-storage / 2-replica cluster with data distribution on, commits a
 randomized write load, runs the full ConsistencyChecker walk, and prints
 ONE JSON line (the report). Exit 0 iff the audit came back consistent —
-the CI / tpuwatch heal-window stage contract.
+the CI stage contract.
 """
 
 from __future__ import annotations

@@ -34,8 +34,8 @@ Verification is EXACT, never liveness-only:
   the client-observed blackout (first post-fault commit ack).
 
 `python -m foundationdb_tpu.loadgen.chaos [--fast] [--seed N]` prints the
-one-JSON-line CHAOS record (scripts/chaos_run.sh → CHAOS.json; tpuwatch
-stage `chaos` runs --fast: one kill-restart cycle per role class). The
+one-JSON-line CHAOS record (scripts/chaos_run.sh → CHAOS.json; --fast
+runs one kill-restart cycle per role class). The
 seed reproduces the fault schedule and workload shape exactly; real-world
 interleaving is of course not deterministic — which is the point.
 """
@@ -745,8 +745,8 @@ def main(argv: "list[str] | None" = None) -> int:
         description="Deployed-cluster chaos battery -> one JSON line")
     ap.add_argument("--seed", type=int, default=20260804)
     ap.add_argument("--fast", action="store_true",
-                    help="one kill-restart cycle per role class only "
-                         "(tpuwatch chaos stage); default adds "
+                    help="one kill-restart cycle per role class only; "
+                         "default adds "
                          "partition-then-heal + SIGSTOP freeze")
     ap.add_argument("--rate", type=float, default=80.0,
                     help="open-loop offered load, txns/sec")

@@ -143,8 +143,11 @@ class _Proc:
 class SocketCluster:
     """Context manager around one deployed cluster's OS processes."""
 
-    BOOT_DEADLINE_S = 180.0
-    READY_DEADLINE_S = 60.0  # per-process restart readiness
+    # `ready` means compiled, and a resolver on the chip compiles cold when
+    # the persistent cache is: 58 s from launch to `ready` on a TPU v5e
+    # (PR 21's chip run; ~50 s of it compiles). Both hold three of those.
+    BOOT_DEADLINE_S = 300.0
+    READY_DEADLINE_S = 180.0  # per-process restart readiness
 
     def __init__(self, workdir: str, proxies: int = 2, tlogs: int = 1,
                  storages: int = 1, resolvers: int = 1,
@@ -176,7 +179,7 @@ class SocketCluster:
         self.spec_path = os.path.join(workdir, "cluster.json")
         with open(self.spec_path, "w") as f:
             json.dump(self.spec, f)
-        self.env = dict(os.environ, JAX_PLATFORMS="cpu", **(env or {}))
+        self._env = dict(os.environ, **(env or {}))
         self.procs: list[_Proc] = []
         self.relays: dict[str, "object"] = {}  # name -> TcpRelay
         self._relay_roles = tuple(relay_roles)
@@ -224,6 +227,25 @@ class SocketCluster:
             argv += ["--bind", f"{p.bind[0]}:{p.bind[1]}"]
         return argv
 
+    def _env_for(self, p: _Proc) -> dict:
+        """One environment per role. A chip belongs to one process, so
+        only a resolver whose spec says engine "tpu" may reach one: it
+        inherits the caller's environment as it stands (a caller that
+        wants that resolver on the CPU backend says JAX_PLATFORMS=cpu
+        itself, and the role refuses to boot on a CPU it was not told
+        about). With several such resolvers on this host each is bound to
+        the chip of its own index — libtpu's TPU_VISIBLE_CHIPS, with the
+        process bounds that make one chip a whole topology — and one
+        whose chip does not exist fails its boot, which fails start().
+        Every other role, and nothing else, is pinned to the CPU."""
+        if p.role != "resolver" or self.spec.get("engine") != "tpu":
+            return dict(self._env, JAX_PLATFORMS="cpu")
+        if len(self.spec["resolver"]) == 1:
+            return self._env
+        return dict(self._env, TPU_VISIBLE_CHIPS=str(p.index),
+                    TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                    TPU_PROCESS_BOUNDS="1,1,1")
+
     # -- lifecycle --------------------------------------------------------
 
     def _launch(self, p: _Proc) -> None:
@@ -238,7 +260,7 @@ class SocketCluster:
                         if os.path.exists(p.log_path) else 0)
         log_f = open(p.log_path, "ab")
         p.popen = subprocess.Popen(
-            self._argv(p), cwd=REPO, env=self.env,
+            self._argv(p), cwd=REPO, env=self._env_for(p),
             stdout=log_f, stderr=subprocess.STDOUT,
             # Own session = own process group: the leak check can see a
             # crashed role's surviving children, teardown can reap them.
@@ -255,7 +277,9 @@ class SocketCluster:
         try:
             with open(p.log_path, "rb") as f:
                 f.seek(p.log_offset)
-                return b"ready" in f.read()
+                # A line of its own: libtpu and JAX log to the same file.
+                return any(line.startswith(b"ready ")
+                           for line in f.read().splitlines())
         except OSError:
             return False
 

@@ -1462,13 +1462,12 @@ class TPUConflictSet:
         the fused pack exists to feed (the resident path already replaced
         _pack_dict with the mirror; serial stays the honest A/B baseline).
         FDB_TPU_NATIVE_WINDOW_PACK=0 forces the numpy packer for parity
-        tests; a stale prebuilt .so without the symbol degrades silently."""
+        tests."""
         if self._nat_win is None:
             self._nat_win = (
                 self.spec
                 and not self.resident
                 and os.environ.get("FDB_TPU_NATIVE_WINDOW_PACK", "1") != "0"
-                and hasattr(_keypack_lib(), "kp_pack_window")
             )
         return self._nat_win
 
@@ -2112,6 +2111,13 @@ class TPUConflictSet:
         return isinstance(self._hist_core, ck.HistState)
 
     @property
+    def _advance_hist_fn(self):
+        """The window-history engine's GC-only entry point."""
+        return (ck._advance_hist_res_jit
+                if isinstance(self.state, ck.ResState)
+                else ck._advance_hist_jit)
+
+    @property
     def overflowed(self) -> bool:
         st = self._hist_core
         if self._is_hist:
@@ -2183,10 +2189,7 @@ class TPUConflictSet:
         cv = np.int32(self._rel(commit_version))
         oldest = np.int32(self._rel(self.oldest_version))
         if self._is_hist:
-            fn = (ck._advance_hist_res_jit
-                  if isinstance(self.state, ck.ResState)
-                  else ck._advance_hist_jit)
-            _, self.state = fn(self.state, cv, oldest)
+            _, self.state = self._advance_hist_fn(self.state, cv, oldest)
             return
         if self._empty_dev_batch is None:
             # The packed dictionary build is real host work (np.unique over
@@ -2196,6 +2199,71 @@ class TPUConflictSet:
         self.state = self._resolve_fn(
             self.state, self._empty_dev_batch, cv, oldest
         )[-1]
+
+    def warm_up(self) -> dict[str, float]:
+        """Compile the entry points a serving resolver dispatches, before
+        the first request: resolve, the conflicting-keys report, the
+        GC-only advance, the version rebase and (resident engines) the
+        full dictionary repack, each run once at this engine's shapes on
+        an all-masked batch at relative version 0 — which paints nothing,
+        moves no floor and remaps every rank to itself, so the state that
+        comes out equals the state that went in. Host version bookkeeping
+        is not touched. Returns seconds per entry point (compile plus one
+        execution; set-up information, not a measurement).
+
+        Not covered, and compiled on first use: the scan-window program
+        (each window depth is its own program; its callers warm the depths
+        they use), the two-phase wave-exchange entry points and the
+        tiered evict."""
+        import jax
+
+        zero = np.int32(0)
+        bt = self._empty_batch()
+        if self.resident:
+            # Assembled directly, not through _pack_resident: a warm-up is
+            # no dispatch, and the mirror's counters should not say so.
+            flat, dims = self._flat_endpoints(bt)
+            empty = self._ranks_to_batch(
+                bt, np.full(len(flat), INT32_MAX, np.int32), dims, flat[:0])
+        else:
+            empty = self._dev_batch(bt)
+        steps: dict[str, Callable] = {
+            "resolve": lambda: self._resolve_fn(
+                self.state, empty, zero, zero)[-1],
+        }
+        if getattr(self, "_resolve_report_fn", None) is not None:
+            steps["resolve_report"] = lambda: self._resolve_report_fn(
+                self.state, empty, zero, zero)[-1]
+        if self._is_hist:
+            steps["advance"] = lambda: self._advance_hist_fn(
+                self.state, zero, zero)[-1]
+        steps["rebase"] = lambda: self._rebase_fn(self.state, zero)
+        if self.resident:
+            mir = self._mirror
+            dict_dev = np.full((mir.capacity + 1, mir.rows.shape[1]),
+                               INT32_MAX, np.int32)
+            dict_dev[: mir.n] = mir.rows
+            identity = np.arange(mir.capacity + 1, dtype=np.int32)
+            steps["repack"] = lambda: self._repack_fn(
+                self.state, dict_dev, np.int32(mir.n), identity)
+        seconds: dict[str, float] = {}
+        for name, step in steps.items():
+            t0 = _perf_counter()
+            self.state = jax.block_until_ready(step())
+            seconds[name] = round(_perf_counter() - t0, 3)
+        return seconds
+
+    def device_info(self) -> dict:
+        """Where this engine's state lives, read off the arrays
+        themselves: platform, device kind and how many devices hold it."""
+        import jax
+
+        from foundationdb_tpu.utils import describe_devices
+
+        return describe_devices(sorted(
+            {d for leaf in jax.tree.leaves(self.state)
+             for d in leaf.devices()},
+            key=lambda d: d.id))
 
     # -- internals ----------------------------------------------------------
 
@@ -2357,14 +2425,13 @@ def _keypack_lib():
         ]
         lib.kp_count_txns.restype = i64
         lib.kp_count_txns.argtypes = [u8p, i64, i64]
-        if hasattr(lib, "kp_pack_window"):  # absent only in a stale .so
-            lib.kp_pack_window.restype = i64
-            lib.kp_pack_window.argtypes = [
-                u8p, i64, i64, ctypes.c_int, ctypes.c_int, ctypes.c_int,
-                ctypes.c_int, ctypes.c_int, ctypes.c_int, i64,
-                i32p, i32p, u8p, i32p, i32p, u8p, i32p, u8p,
-                i32p, i32p, i32p, i32p, i32p,
-            ]
+        lib.kp_pack_window.restype = i64
+        lib.kp_pack_window.argtypes = [
+            u8p, i64, i64, ctypes.c_int, ctypes.c_int, ctypes.c_int,
+            ctypes.c_int, ctypes.c_int, ctypes.c_int, i64,
+            i32p, i32p, u8p, i32p, i32p, u8p, i32p, u8p,
+            i32p, i32p, i32p, i32p, i32p,
+        ]
         _KP_LIB = lib
     return _KP_LIB
 

@@ -14,7 +14,7 @@ Six pieces, one subsystem:
   on-disk ring of metric snapshots with first-class event annotations
   on the same timeline (ratekeeper limiting transitions, recovery
   stages, resolver-queue crossings, admission engage/release, chaos
-  fault/heal windows, reshard/repack events, scrape gaps).
+  fault-to-heal spans, reshard/repack events, scrape gaps).
 - ``slo``: rolling-baseline anomaly detection + SLO burn tracking
   (commit p99 / goodput / unknown-result rate) computed incrementally
   from the ring, with warm-up / insufficient-sample honesty flags —

@@ -10,17 +10,16 @@
     python -m foundationdb_tpu.obs --doctor-gate     # DOCTOR.json gate
     python -m foundationdb_tpu.obs --bench-history   # perf trajectory
 
-The selfcheck (scrape + span reconciliation on a short sim run) is wired
-as the `obs` stage of scripts/tpuwatch_r05.sh; the A/B is
-scripts/obs_ab.sh -> OBS_AB.json. `--poll` is the deployed-cluster
+The selfcheck is a scrape + span reconciliation on a short sim run; the
+A/B is scripts/obs_ab.sh -> OBS_AB.json. `--poll` is the deployed-cluster
 time-series scraper (plain snapshots + scrape_gap records); `--record`
 is the full flight recorder over a deployed cluster — bounded on-disk
 ring with derived annotations and SLO tracking. `--doctor` runs the
 incident doctor over an existing ring; `--doctor-gate` runs the seeded
 mini-chaos with the recorder armed and gates the per-fault attribution
-(scripts/doctor_run.sh -> DOCTOR.json, tpuwatch `doctor` stage).
+(scripts/doctor_run.sh -> DOCTOR.json).
 `--bench-history` folds the committed BENCH_*/\\*_AB artifacts into the
-time-ordered regression table (tpuwatch line).
+time-ordered regression table.
 """
 
 from __future__ import annotations

@@ -25,7 +25,7 @@ window (chaos_fault → matching chaos_heal annotation, grace-padded)
 must contain an annotation of its EXPECTED class — a kill/partition/
 pause that the cluster survived shows up as a recovery. ``run_doctor_
 gate()`` runs the seeded mini-chaos script with the recorder armed and
-gates exactly that, as one JSON line (tpuwatch ``doctor`` stage).
+gates exactly that, as one JSON line.
 
 Surfaces: ``cli doctor RING.jsonl``, ``python -m foundationdb_tpu.obs
 --doctor RING.jsonl`` and ``--doctor-gate``.
@@ -475,7 +475,7 @@ def attribute_faults(records: list[dict],
 
 def run_doctor_gate(seed: int = 20260804, rate: float = 60.0,
                     workdir: "str | None" = None) -> dict:
-    """tpuwatch ``doctor`` stage: seeded mini-chaos (loadgen/chaos.py
+    """The doctor gate: seeded mini-chaos (loadgen/chaos.py
     --fast equivalent) with the flight recorder armed, then the doctor
     over the resulting ring — one JSON line gating EXACTLY:
 

@@ -9,8 +9,7 @@ last by name). Drift check: for artifacts sharing a metric across
 rounds, the latest/previous ratio is computed ONLY between records both
 marked ``valid`` — a ``valid:false`` record (CPU fallback, failed gate,
 harness error) appears in the table with its reasons but is REFUSED as
-a ratio endpoint, never silently averaged in. Wired as a tpuwatch line
-so every future round gets the drift check for free.
+a ratio endpoint, never silently averaged in.
 """
 
 from __future__ import annotations
@@ -104,7 +103,7 @@ def bench_history(root: str = ".",
     paths = sorted(
         set(glob.glob(os.path.join(root, "BENCH_*.json")))
         | set(glob.glob(os.path.join(root, "*_AB.json"))))
-    # The tpuwatch stage writes THIS tool's output as BENCH_HISTORY_*.json
+    # THIS tool's output may be kept as BENCH_HISTORY_*.json
     # in the same root — folding a previous trajectory record in as a
     # bench row would make every table self-referential.
     paths = [p for p in paths

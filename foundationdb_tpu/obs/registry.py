@@ -49,6 +49,7 @@ DOCUMENTED_COUNTERS = (
     "commit_proxy.wave_exchanges",
     "resolver.txns_rejected_fail_safe",
     "resolver.overflow_events",
+    "resolver.resolve_failures",
     # Speculative pipelined resolve (FDB_TPU_SPEC_RESOLVE): exported
     # unconditionally (zeros on serial engines) so dashboards can alert
     # on the mis-speculation rate (repaired/dispatched) without a flag

@@ -84,8 +84,8 @@ def _popcount32(x: jax.Array) -> jax.Array:
 # over block maxima) instead of the sparse table's log2(N) passes —
 # measured 3.5x cheaper for the build+query shape on CPU-XLA; queries pay
 # one [Nq, G] row gather for the same-block case. sparse_table remains
-# for small/top-level tables and for the on-chip A/B in
-# scripts/tpu_diag.py (the TPU may rank the designs differently).
+# for small/top-level tables and for an on-chip A/B that has not run
+# (the TPU may rank the designs differently).
 # ---------------------------------------------------------------------------
 
 RMQ_BLOCK = 256

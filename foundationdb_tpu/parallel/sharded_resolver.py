@@ -44,16 +44,7 @@ from foundationdb_tpu.models.conflict_set import (
     _u64_unique_sorted,
 )
 
-# jax renamed/moved shard_map across releases (jax.shard_map with
-# check_vma= vs jax.experimental.shard_map with check_rep=); resolve once
-# so the engine builds on either.
-try:
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_KW = {"check_rep": False}
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 AXIS = "resolvers"
 
@@ -540,7 +531,6 @@ class ShardedConflictSet(TPUConflictSet):
             mesh=self.mesh,
             in_specs=(state_specs, batch_specs, P(), P(), P(AXIS), P(AXIS)),
             out_specs=out_specs,
-            **_SHARD_MAP_KW,
         )
         jitted = jax.jit(body, donate_argnums=(0,))
         resolve = lambda s, bt, cv, old: jitted(  # noqa: E731
@@ -573,7 +563,6 @@ class ShardedConflictSet(TPUConflictSet):
                 mesh=self.mesh,
                 in_specs=(state_specs, P()),
                 out_specs=state_specs,
-                **_SHARD_MAP_KW,
             ),
             donate_argnums=(0,),
         )
@@ -642,7 +631,6 @@ class ShardedConflictSet(TPUConflictSet):
             mesh=self.mesh,
             in_specs=(state_specs, batch_specs, P(), P()),
             out_specs=out_specs,
-            **_SHARD_MAP_KW,
         )
         resolve = jax.jit(body, donate_argnums=(0,))
         self._resolve_fn = self._strip_exchange(resolve) if wave else resolve
@@ -651,7 +639,6 @@ class ShardedConflictSet(TPUConflictSet):
             mesh=self.mesh,
             in_specs=(state_specs, batch_specs, P(), P()),
             out_specs=out_specs,
-            **_SHARD_MAP_KW,
         )
         resolve_many = jax.jit(many_body, donate_argnums=(0,))
         self._resolve_many_fn = (

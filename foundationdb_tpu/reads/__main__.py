@@ -6,7 +6,7 @@
 The selfcheck is a fast all-parity pass — batched point/range reads vs
 the sequential oracle on host AND device arms, watch fire-set parity
 across arms 0/1/device, plus a small end-to-end get_multi through a
-storage server — wired as the `reads` stage of scripts/tpuwatch_r05.sh.
+storage server.
 The A/B (scripts/reads_ab.sh -> READS_AB.json) additionally measures the
 batched-vs-per-key-actor throughput gates and watch-sweep scaling; see
 reads/bench.py.

@@ -245,7 +245,7 @@ class GrvProxy:
         entirely: with no generations there is nothing to fence against,
         so the check is vacuous and the fan-out is pure per-batch latency
         in the common read path; a recovery lock is still observed via
-        the normal commit/read paths (ADVICE.md r5)."""
+        the normal commit/read paths (r5 review finding)."""
         if not self.tlogs or not self.epoch:
             return
         tasks = [

@@ -95,6 +95,11 @@ class Resolver:
         self._unsafe_until: int | None = None  # version; set on true overflow
         self.overflow_events = 0
         self.txns_rejected_fail_safe = 0
+        # Batches whose engine call raised: the RPC fails, the proxy
+        # answers its clients commit_unknown_result, and the role keeps
+        # serving (_dispatch_group's failure contract) — so a broken
+        # engine shows here, not as a dead process.
+        self.resolve_failures = 0
         # Per-range conflict-loss sketch for THIS resolver's key shard:
         # every rejected txn's losing read ranges are recorded (decayed),
         # exported via get_metrics and aggregated at the commit proxy
@@ -344,6 +349,7 @@ class Resolver:
         try:
             reply = self._apply_entry(version, txns, pend, graph)
         except BaseException as e:  # noqa: BLE001 — fail the RPC waiter
+            self.resolve_failures += 1
             self._replies[version] = e
             self._trim_replies()
             self._pending.pop(version, None)
@@ -515,6 +521,7 @@ class Resolver:
         entry.reply.send(reply)
 
     def _fail_entry(self, entry: _QueuedBatch, e: BaseException) -> None:
+        self.resolve_failures += 1
         self._replies[entry.version] = e
         self._trim_replies()
         self._pending.pop(entry.version, None)
@@ -853,6 +860,11 @@ class Resolver:
             or self._unsafe_until is not None,
             "overflow_events": self.overflow_events,
             "txns_rejected_fail_safe": self.txns_rejected_fail_safe,
+            "resolve_failures": self.resolve_failures,
+            # Where the engine's state lives, read off its arrays (None
+            # for the host engines: oracle, C++ skiplist).
+            "device": (self.cs.device_info()
+                       if hasattr(self.cs, "device_info") else None),
             # Wave-commit attribution (reorder-don't-abort engines; both
             # zero under sequential-order resolution) + the exact conflict
             # count they are judged against.

@@ -527,7 +527,7 @@ class TLog:
         Only atomic while nothing pushes — true in that window: chains
         are resumed but no proxy generation is recruited yet.
 
-        GATED (ADVICE.md r5 — the precondition used to be docstring-only):
+        GATED (r5 review finding — the precondition used to be docstring-only):
         with a system token configured, only a matching token may read;
         otherwise the caller must either hold the lock-equivalent (tlog
         locked — recover_entries' own precondition) or present a

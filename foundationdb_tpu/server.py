@@ -257,7 +257,9 @@ DEPLOYED_WAVE_BATCH_LIMIT = 512
 
 def make_conflict_set(engine: str, n_resolvers: int = 1):
     """Resolver engine: 'tpu' is the production kernel; 'cpu' (C++ skiplist)
-    keeps a cluster deployable on hosts with no accelerator.
+    keeps a cluster deployable on hosts with no accelerator. 'tpu' refuses
+    to build on any other platform (utils.require_tpu): JAX's own fallback
+    to the CPU is silent, and a resolver that took it would look deployed.
 
     ``n_resolvers`` is the DEPLOYMENT's resolver role count (the spec's
     resolver list), not this process's: wave commit (FDB_TPU_WAVE_COMMIT=1)
@@ -280,6 +282,13 @@ def make_conflict_set(engine: str, n_resolvers: int = 1):
             wave_global_capable=engine in ("tpu", "oracle"),
         )
     if engine == "tpu":
+        from foundationdb_tpu.utils import (
+            enable_compilation_cache,
+            require_tpu,
+        )
+
+        enable_compilation_cache()
+        require_tpu("a resolver with engine 'tpu'")
         from foundationdb_tpu.models.conflict_set import TPUConflictSet
 
         return TPUConflictSet(wave_commit=wave)
@@ -292,6 +301,36 @@ def make_conflict_set(engine: str, n_resolvers: int = 1):
 
         return OracleConflictSet(wave_commit=wave)
     raise ValueError(f"unknown engine {engine!r}")
+
+
+def make_engine(spec: dict, name: str):
+    """The spec's conflict engine for resolver process `name`. An engine
+    with compiled entry points is warmed up before it is handed over
+    (TPUConflictSet.warm_up), and what its arrays sit on is printed to the
+    role's log; Resolver.get_metrics()["device"] serves the same."""
+    engine = spec.get("engine", "cpu")
+    cs = make_conflict_set(engine, len(spec["resolver"]))
+    if hasattr(cs, "warm_up"):
+        warm = cs.warm_up()
+        dev = cs.device_info()
+        print(f"device {name} engine={engine} platform={dev['platform']} "
+              f"device_kind={dev['device_kind']!r} count={dev['count']} "
+              f"warm_up_s={json.dumps(warm)}", flush=True)
+    return cs
+
+
+def make_resolver(loop, spec: dict, name: str, init_version: int = 0):
+    """The resolver role for the static boot and for each recruited
+    generation alike. It is built before the process prints `ready`, or
+    against jit caches a managed worker filled before it did, so `ready`
+    means compiled: no commit pays a compile, and none blocks the role's
+    event loop past the commit proxies' wedge timeout."""
+    from foundationdb_tpu.runtime.resolver import Resolver
+
+    return Resolver(loop, make_engine(spec, name),
+                    init_version=init_version,
+                    admission_filter=_make_admission_filter(),
+                    **_resolver_knobs(spec))
 
 
 class ReadRouter:
@@ -641,17 +680,10 @@ class Worker:
 
     @rpc
     async def recruit_resolver(self, epoch: int, start_version: int) -> int:
-        from foundationdb_tpu.runtime.resolver import Resolver
-
-        engine = self.spec.get("engine", "cpu")
         self.t.serve(
             "resolver",
-            Resolver(self.loop,
-                     make_conflict_set(engine,
-                                       len(self.spec["resolver"])),
-                     init_version=start_version,
-                     admission_filter=_make_admission_filter(),
-                     **_resolver_knobs(self.spec)),
+            make_resolver(self.loop, self.spec, f"resolver{self.index}",
+                          init_version=start_version),
         )
         self.epoch = epoch
         return start_version
@@ -1674,6 +1706,11 @@ def build_role(loop: RealLoop, t: NetTransport, spec: dict, role: str,
         return loop.spawn(boot_controller(), name="controller.boot")
     if managed and role in ("sequencer", "resolver", "tlog",
                             "satellite_tlog"):
+        if role == "resolver" and spec.get("engine") == "tpu":
+            # Every generation's recruit_resolver builds a fresh engine of
+            # the same shapes; compiling them once here, before `ready`,
+            # keeps the compile out of the recovery's recruit RPC.
+            make_engine(spec, f"resolver{index}")
         t.serve("worker", Worker(loop, t, spec, role, index, data_dir))
         return None
     if role == "satellite_tlog":
@@ -1754,14 +1791,7 @@ def build_role(loop: RealLoop, t: NetTransport, spec: dict, role: str,
 
         return loop.spawn(boot_sequencer(), name="sequencer.boot")
     elif role == "resolver":
-        from foundationdb_tpu.runtime.resolver import Resolver
-
-        engine = spec.get("engine", "cpu")
-        t.serve("resolver",
-                Resolver(loop, make_conflict_set(engine,
-                                                 len(spec["resolver"])),
-                         admission_filter=_make_admission_filter(),
-                         **_resolver_knobs(spec)))
+        t.serve("resolver", make_resolver(loop, spec, f"resolver{index}"))
     elif role == "tlog":
         from foundationdb_tpu.runtime.tlog import TLog
 
@@ -1833,7 +1863,7 @@ def build_role(loop: RealLoop, t: NetTransport, spec: dict, role: str,
         # GrvProxy skips the per-batch confirm_epoch fan-out at epoch 0 —
         # the fence check is vacuous there and the tlog round trip is
         # pure latency in the common read path; lock detection rides the
-        # normal commit/read paths instead (ADVICE.md r5).
+        # normal commit/read paths instead (r5 review finding).
         grv = GrvProxy(loop, seq_ep, rk_ep, tlog_eps=eps("tlog"))
         router = ReadRouter(storage_map, eps("storage"), loop=loop)
         t.serve("commit_proxy", proxy)

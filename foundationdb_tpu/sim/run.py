@@ -21,7 +21,7 @@ Two spec kinds share the loop:
         --seeds 1 --seed-base 1234 --buggify --clog 0.7   # replay one
     python -m foundationdb_tpu.sim.run --campaigns fast   # CI stage:
         # fast campaign battery, ONE summary JSON line last on stdout,
-        # exit 0 iff all green (tpuwatch/heal-window contract)
+        # exit 0 iff all green
 
 Each (spec-file, seed) runs in a fresh process (seeds fan out over
 --jobs workers); --buggify arms the in-role BUGGIFY sites, --clog adds
@@ -277,8 +277,8 @@ def main(argv: list[str] | None = None) -> int:
     else:
         print("all green", flush=True)
     if args.campaigns:
-        # ONE summary line, LAST on stdout — the tpuwatch `have` helper
-        # judges the artifact by its final JSON line.
+        # ONE summary line, LAST on stdout — a caller judges the
+        # artifact by its final JSON line.
         print(json.dumps({
             "metric": "nemesis_campaigns",
             "mode": args.campaigns,

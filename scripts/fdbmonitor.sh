@@ -26,6 +26,10 @@ DIR="${1:?usage: fdbmonitor.sh CLUSTER_DIR}"
 SPEC="$DIR/cluster.json"
 [ -f "$SPEC" ] || { echo "no $SPEC" >&2; exit 1; }
 rm -f "$DIR/stop"
+# A chip belongs to one process: with "engine": "tpu" in the spec only the
+# resolver runs without the JAX_PLATFORMS=cpu pin (as start_cluster.sh).
+ENGINE=$(python -c 'import json, sys
+print(json.load(open(sys.argv[1])).get("engine", "cpu"))' "$SPEC")
 
 supervise() { # role index
   local role=$1 idx=$2
@@ -35,7 +39,11 @@ supervise() { # role index
       mkdir -p "$DIR/data/$role$idx"
       data_args=(--data-dir "$DIR/data/$role$idx")
     fi
-    JAX_PLATFORMS=cpu python -m foundationdb_tpu.server \
+    local pin=(env JAX_PLATFORMS=cpu)
+    if [ "$role" = resolver ] && [ "$ENGINE" = tpu ]; then
+      pin=(env)
+    fi
+    "${pin[@]}" python -m foundationdb_tpu.server \
       --cluster "$SPEC" --role "$role" --index "$idx" \
       --trace-dir "$DIR/traces" "${data_args[@]}" \
       >> "$DIR/$role$idx.log" 2>&1 || true

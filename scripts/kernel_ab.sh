@@ -2,11 +2,10 @@
 # Packed-vs-unpacked kernel A/B: the same bench stream through
 # FDB_TPU_PACKED=1 and =0, one line of bytes/throughput delta at the end.
 #
-# Runs on whatever backend is reachable: standalone it allows the CPU
-# fallback (FDB_TPU_ALLOW_CPU=1 default — the delta is a real, if
-# hardware-different, measurement of the packed formats); the tpuwatch
-# autopilot invokes it with FDB_TPU_ALLOW_CPU=0 during a TPU heal window
-# so both sides bench the real chip.
+# Runs on the device JAX finds, and bench.py names it in each record.
+# Without a chip bench.py exits non-zero: say JAX_PLATFORMS=cpu to take the
+# delta on the CPU backend on purpose (a real, if hardware-different,
+# measurement; `valid` is then false).
 #
 #   TXNS=65536 MODE=ycsb OUT=KERNEL_AB.json scripts/kernel_ab.sh
 set -u
@@ -15,17 +14,8 @@ TXNS=${TXNS:-65536}
 MODE=${MODE:-ycsb}
 OUT=${OUT:-KERNEL_AB.json}
 LOG=${LOG:-kernel_ab.log}
-# The inherited deadline covers BOTH sides of the A/B; python/JAX startup
-# and compile time land OUTSIDE each bench's internal deadline, so leave
-# explicit headroom before halving or the outer timeout kills side B.
-DEADLINE=${FDB_TPU_BENCH_DEADLINE_S:-1800}
-PER_RUN=$(((DEADLINE - 120) / 2))
-[ "$PER_RUN" -lt 120 ] && PER_RUN=120
-
 run() {  # run PACKED_FLAG OUTFILE
   env FDB_TPU_PACKED="$1" \
-      FDB_TPU_ALLOW_CPU="${FDB_TPU_ALLOW_CPU:-1}" \
-      FDB_TPU_BENCH_DEADLINE_S="$PER_RUN" \
       python bench.py --mode "$MODE" --txns "$TXNS" > "$2" 2>> "$LOG"
 }
 

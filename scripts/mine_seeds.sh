@@ -2,10 +2,8 @@
 # Continuous fresh-seed mining (TestHarness soak analogue), in chunks.
 #
 # Runs the full spec battery at ever-increasing seed bases, alternating
-# normal buggify with aggressive mode. Pauses between chunks while
-# /tmp/tpu_window_open exists (the tpuwatch autopilot owns the host
-# during a heal window — a loaded host would skew the bench's in-run CPU
-# baseline). Appends one line per chunk to CAMPAIGN_r05_mine_auto.txt;
+# normal buggify with aggressive mode. Appends one line per chunk to
+# CAMPAIGN_r05_mine_auto.txt;
 # full per-chunk logs land in /tmp/mine_chunk_<base>.log and any FAILURE
 # output is copied into the summary so a found bug survives /tmp.
 set -u
@@ -18,7 +16,6 @@ say() { echo "$(date +%H:%M:%S) $*" >> "$OUT"; }
 say "miner armed: base=$BASE chunk=$CHUNK jobs=5"
 i=0
 while true; do
-  while [ -e /tmp/tpu_window_open ]; do sleep 60; done
   base=$((BASE + i * CHUNK))
   if [ $((i % 2)) -eq 0 ]; then flags="--buggify --clog 0.05"; else flags="--buggify-aggressive --clog 0.05"; fi
   log=/tmp/mine_chunk_$base.log

@@ -49,8 +49,7 @@ if [ $rc -ne 0 ] || [ ! -s "$SCRATCH/rec.json" ]; then
   exit 1
 fi
 tail -n 1 "$SCRATCH/rec.json" > "$OUT"
-# Human summary to stderr; the LAST stdout line is the full record (the
-# tpuwatch stage captures stdout and checks its final line).
+# Human summary to stderr; the LAST stdout line is the full record.
 python - "$OUT" >&2 <<'PYEOF'
 import json, sys
 r = json.load(open(sys.argv[1]))

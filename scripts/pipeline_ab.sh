@@ -17,6 +17,11 @@
 # every record. Honesty flags (valid / cpu_fallback / p99_quotable)
 # ride along exactly like the other A/B artifacts.
 #
+# Runs on the device JAX finds, and bench.py names it in each record.
+# Without a chip bench.py exits non-zero: say JAX_PLATFORMS=cpu to take the
+# delta on the CPU backend on purpose (a real, if hardware-different,
+# measurement; `valid` is then false).
+#
 #   TXNS=262144 OUT=PIPELINE_AB.json scripts/pipeline_ab.sh
 set -u
 cd "$(dirname "$0")/.."
@@ -28,14 +33,9 @@ TXNS=${TXNS:-1048576}
 WINDOW=${WINDOW:-8}
 OUT=${OUT:-PIPELINE_AB.json}
 LOG=${LOG:-pipeline_ab.log}
-DEADLINE=${FDB_TPU_BENCH_DEADLINE_S:-1800}
-PER_RUN=$(((DEADLINE - 120) / 4))
-[ "$PER_RUN" -lt 120 ] && PER_RUN=120
 
 run() {  # run SPEC_FLAG THETA OUTFILE
   env FDB_TPU_SPEC_RESOLVE="$1" \
-      FDB_TPU_ALLOW_CPU="${FDB_TPU_ALLOW_CPU:-1}" \
-      FDB_TPU_BENCH_DEADLINE_S="$PER_RUN" \
       python bench.py --mode ycsb --theta "$2" --txns "$TXNS" \
       --window "$WINDOW" --no-adaptive > "$3" 2>> "$LOG"
 }

@@ -11,6 +11,11 @@
 # on the same seeds. Honesty flags (valid / cpu_fallback / p99_quotable)
 # ride along exactly like the other A/B artifacts.
 #
+# Runs on the device JAX finds, and bench.py names it in each record.
+# Without a chip bench.py exits non-zero: say JAX_PLATFORMS=cpu to take the
+# delta on the CPU backend on purpose (a real, if hardware-different,
+# measurement; `valid` is then false).
+#
 #   TXNS=262144 MODE=ycsb OUT=RESIDENT_AB.json scripts/resident_ab.sh
 set -u
 cd "$(dirname "$0")/.."
@@ -20,14 +25,9 @@ TXNS=${TXNS:-1048576}
 MODE=${MODE:-ycsb}
 OUT=${OUT:-RESIDENT_AB.json}
 LOG=${LOG:-resident_ab.log}
-DEADLINE=${FDB_TPU_BENCH_DEADLINE_S:-1800}
-PER_RUN=$(((DEADLINE - 120) / 2))
-[ "$PER_RUN" -lt 120 ] && PER_RUN=120
 
 run() {  # run RESIDENT_FLAG OUTFILE
   env FDB_TPU_RESIDENT="$1" \
-      FDB_TPU_ALLOW_CPU="${FDB_TPU_ALLOW_CPU:-1}" \
-      FDB_TPU_BENCH_DEADLINE_S="$PER_RUN" \
       python bench.py --mode "$MODE" --txns "$TXNS" --no-adaptive \
       > "$2" 2>> "$LOG"
 }

@@ -7,10 +7,10 @@
 # JSON comparison with the p99 cut and throughput ratio; acceptance is
 # p99_cut_x >= 5 at equal-or-better throughput (kept_up + ratio).
 #
-# Runs on whatever backend is reachable: standalone it allows the CPU
-# fallback (the latency shape of fixed-vs-adaptive dispatch is real on any
-# backend); the tpuwatch autopilot invokes it with FDB_TPU_ALLOW_CPU=0
-# during a TPU heal window so both sides bench the real chip.
+# Runs on the device JAX finds, and bench.py names it in each record.
+# Without a chip bench.py exits non-zero: say JAX_PLATFORMS=cpu to take the
+# delta on the CPU backend on purpose (a real, if hardware-different,
+# measurement; `valid` is then false).
 #
 #   TXNS=262144 MODE=ycsb WINDOW=32 BUDGET_MS=250 OUT=SCHED_AB.json \
 #     scripts/sched_ab.sh
@@ -23,11 +23,8 @@ BUDGET_MS=${BUDGET_MS:-250}
 MAXWIN=${MAXWIN:-8}
 OUT=${OUT:-SCHED_AB.json}
 LOG=${LOG:-sched_ab.log}
-DEADLINE=${FDB_TPU_BENCH_DEADLINE_S:-1800}
 
-env FDB_TPU_ALLOW_CPU="${FDB_TPU_ALLOW_CPU:-1}" \
-    FDB_TPU_BENCH_DEADLINE_S="$DEADLINE" \
-    python bench.py --mode "$MODE" --txns "$TXNS" --window "$WINDOW" \
+python bench.py --mode "$MODE" --txns "$TXNS" --window "$WINDOW" \
         --latency-budget-ms "$BUDGET_MS" --adaptive-max-window "$MAXWIN" \
         > /tmp/_sched_ab.json 2>> "$LOG"
 rc=$?

@@ -21,9 +21,14 @@
 #     whole-dictionary ship the pre-tiering engine pays at that same
 #     watermark crossing: demotion_events * full_repack_ship_bytes)
 #
-# Honesty flags ride along exactly like the other A/B artifacts: on a
-# CPU-fallback host `valid` is false with the reason, but the parity and
+# Honesty flags ride along exactly like the other A/B artifacts: on the
+# CPU backend `valid` is false with the reason, but the parity and
 # zero-repack gates still bind (PIPELINE_AB / OPENLOOP_AB precedent).
+#
+# Runs on the device JAX finds, and bench.py names it in each record.
+# Without a chip bench.py exits non-zero: say JAX_PLATFORMS=cpu to take the
+# delta on the CPU backend on purpose (a real, if hardware-different,
+# measurement; `valid` is then false).
 #
 # Sizing (see bench.py gen_workload's shifting-hotspot geometry): batch
 # 512 keeps the MVCC window (WINDOW=64 versions = 64 batches) well
@@ -40,9 +45,6 @@ KEYS=${KEYS:-$((HOT * 100))}
 BATCH=${TIERED_BATCH:-512}
 OUT=${OUT:-TIERED_AB.json}
 LOG=${LOG:-tiered_ab.log}
-DEADLINE=${FDB_TPU_BENCH_DEADLINE_S:-1800}
-PER_RUN=$(((DEADLINE - 120) / 4))
-[ "$PER_RUN" -lt 120 ] && PER_RUN=120
 
 run() {  # run HOT_CAPACITY OUTFILE [extra bench args...]
   local hot="$1" out="$2"; shift 2
@@ -50,8 +52,6 @@ run() {  # run HOT_CAPACITY OUTFILE [extra bench args...]
       FDB_TPU_DICT_CAPACITY="$HOT" \
       FDB_TPU_DICT_DELTA=$((HOT / 2)) \
       FDB_TPU_DICT_DEMOTE_BATCH=2048 \
-      FDB_TPU_ALLOW_CPU="${FDB_TPU_ALLOW_CPU:-1}" \
-      FDB_TPU_BENCH_DEADLINE_S="$PER_RUN" \
       python bench.py --mode ycsb --batch "$BATCH" --txns "$TXNS" \
       --keys "$KEYS" --no-adaptive --smoke "$@" \
       > "$out" 2>> "$LOG"

@@ -32,8 +32,8 @@ SEED=${SEED:-20260803}
 OUT=${OUT:-WAVE_AB.json}
 LOG=${LOG:-wave_ab.log}
 
-# Per-invocation scratch dir: concurrent runs (tpuwatch stage + a manual
-# invocation) must not overwrite each other's arm files mid-merge.
+# Per-invocation scratch dir: concurrent runs must not overwrite each
+# other's arm files mid-merge.
 SCRATCH=$(mktemp -d /tmp/_wave_ab.XXXXXX)
 trap 'rm -rf "$SCRATCH"' EXIT
 for target in hottest coldest; do

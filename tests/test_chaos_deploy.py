@@ -336,8 +336,8 @@ class TestDeployedChaosMini:
     """One seeded chaos cycle against a live open-loop workload: a tlog
     SIGKILL + restart and a relay black-hole partition + heal, gated on
     the exact ledger (zero acked loss, exactly-once), consistency, and
-    a matched MTTR entry. The full 4-role-class battery runs as the
-    tpuwatch `chaos` stage / scripts/chaos_run.sh (CHAOS.json)."""
+    a matched MTTR entry. The full 4-role-class battery is
+    scripts/chaos_run.sh (CHAOS.json)."""
 
     def test_chaos_cycle_exact_ledger(self, tmp_path):
         from foundationdb_tpu.loadgen.chaos import ChaosEvent, run_chaos

@@ -17,12 +17,9 @@ Coverage the ISSUE demands:
   multi-resolver refusal;
 - env-flag validation satellite: unknown FDB_TPU_* values raise at
   import with the accepted list (subprocess), including the new
-  FDB_TPU_WAVE_COMMIT;
-- the compile-cache guard satellite (utils/cache_guard): known-bad pin
-  verdict, memoization, and the enable_compilation_cache gate.
+  FDB_TPU_WAVE_COMMIT.
 """
 
-import json
 import os
 import subprocess
 import sys
@@ -649,163 +646,3 @@ def test_wave_env_default_parity(extra):
     )
     assert r.returncode == 0, f"{extra}: {r.stderr[-2000:]}"
     assert r.stdout.strip().splitlines()[-1] == "WAVE-MATRIX-OK"
-
-
-# ---------------------------------------------------------------------------
-# Compile-cache guard satellite (utils/cache_guard)
-# ---------------------------------------------------------------------------
-
-
-class TestCacheGuard:
-    def test_known_bad_pin_short_circuits_without_probe(self, tmp_path,
-                                                        monkeypatch):
-        from foundationdb_tpu.utils import cache_guard
-
-        monkeypatch.setattr(cache_guard, "_jaxlib_version", lambda: "0.4.36")
-        monkeypatch.setattr(
-            cache_guard, "_run_guard",
-            lambda d: pytest.fail("known-bad pin must not spawn a guard"),
-        )
-        assert cache_guard.cpu_cache_safe(str(tmp_path)) is False
-        v = json.loads((tmp_path / cache_guard.VERDICT_FILE).read_text())
-        assert v == {"jaxlib": "0.4.36", "probed": False, "safe": False,
-                     "detail": v["detail"]}
-        # memoized: second call reads the file, still no guard spawn
-        assert cache_guard.cpu_cache_safe(str(tmp_path)) is False
-
-    def test_upgraded_jaxlib_probes_once_and_memoizes(self, tmp_path,
-                                                      monkeypatch):
-        from foundationdb_tpu.utils import cache_guard
-
-        calls = []
-        monkeypatch.setattr(cache_guard, "_jaxlib_version", lambda: "9.9.9")
-        monkeypatch.setattr(
-            cache_guard, "_run_guard",
-            lambda d: (calls.append(d) or ("ok", "clean")),
-        )
-        assert cache_guard.cpu_cache_safe(str(tmp_path)) is True
-        # populate + RELOAD_RUNS warm reloads
-        assert len(calls) == 1 + cache_guard.RELOAD_RUNS
-        assert cache_guard.cpu_cache_safe(str(tmp_path)) is True
-        assert len(calls) == 1 + cache_guard.RELOAD_RUNS  # memoized
-
-    def test_stale_verdict_from_other_jaxlib_is_ignored(self, tmp_path,
-                                                        monkeypatch):
-        from foundationdb_tpu.utils import cache_guard
-
-        cache_guard.write_verdict(
-            str(tmp_path), {"jaxlib": "0.0.1", "safe": True})
-        monkeypatch.setattr(cache_guard, "_jaxlib_version", lambda: "0.4.36")
-        assert cache_guard.read_verdict(str(tmp_path)) is None
-        assert cache_guard.cpu_cache_safe(str(tmp_path)) is False
-
-    def test_crashing_guard_marks_unsafe(self, tmp_path, monkeypatch):
-        from foundationdb_tpu.utils import cache_guard
-
-        seq = iter([("ok", "clean"), ("crash", "guard exited -11: boom")])
-        monkeypatch.setattr(cache_guard, "_jaxlib_version", lambda: "9.9.9")
-        monkeypatch.setattr(cache_guard, "_run_guard", lambda d: next(seq))
-        v = cache_guard.probe(str(tmp_path))
-        assert v["safe"] is False and "-11" in v["detail"]
-        assert cache_guard.cpu_cache_safe(str(tmp_path)) is False
-
-    def test_transient_guard_failure_is_not_memoized(self, tmp_path,
-                                                     monkeypatch):
-        """A plain nonzero guard exit (machine trouble, not the crash
-        signature) answers unsafe NOW but writes no verdict — the next
-        process re-probes instead of inheriting a poisoned 'unsafe'."""
-        from foundationdb_tpu.utils import cache_guard
-
-        monkeypatch.setattr(cache_guard, "_jaxlib_version", lambda: "9.9.9")
-        monkeypatch.setattr(
-            cache_guard, "_run_guard",
-            lambda d: ("error", "guard exited 1: No module named jax"),
-        )
-        v = cache_guard.probe(str(tmp_path))
-        assert v["safe"] is False and v["transient"] is True
-        assert not (tmp_path / cache_guard.VERDICT_FILE).exists()
-        # …and a later clean probe still lands the safe verdict.
-        monkeypatch.setattr(cache_guard, "_run_guard",
-                            lambda d: ("ok", "clean"))
-        assert cache_guard.cpu_cache_safe(str(tmp_path)) is True
-
-    def test_timeout_memoizes_only_when_warm(self, tmp_path, monkeypatch):
-        """A COLD populate never deserializes — its timeout is machine
-        slowness and must stay unmemoized; a WARM timeout after a clean
-        cold run is the documented hang mode and memoizes unsafe."""
-        from foundationdb_tpu.utils import cache_guard
-
-        monkeypatch.setattr(cache_guard, "_jaxlib_version", lambda: "9.9.9")
-        monkeypatch.setattr(cache_guard, "_run_guard",
-                            lambda d: ("timeout", "guard hung (timeout)"))
-        v = cache_guard.probe(str(tmp_path))
-        assert v["safe"] is False and v.get("transient") is True
-        assert not (tmp_path / cache_guard.VERDICT_FILE).exists()
-        seq = iter([("ok", "clean"), ("timeout", "guard hung (timeout)")])
-        monkeypatch.setattr(cache_guard, "_run_guard", lambda d: next(seq))
-        v = cache_guard.probe(str(tmp_path))
-        assert v["safe"] is False and "transient" not in v
-        assert cache_guard.read_verdict(str(tmp_path))["safe"] is False
-
-    def test_nonblocking_path_kicks_one_background_probe(self, tmp_path,
-                                                         monkeypatch):
-        """probe_missing=False must never probe inline: it reports unsafe,
-        kicks ONE detached prober (lockfile-deduped), and defers to any
-        verdict already on file."""
-        from foundationdb_tpu.utils import cache_guard
-
-        monkeypatch.setattr(cache_guard, "_jaxlib_version", lambda: "9.9.9")
-        spawns = []
-        monkeypatch.setattr(cache_guard.subprocess, "Popen",
-                            lambda *a, **k: spawns.append(a))
-        assert cache_guard.cpu_cache_safe(str(tmp_path),
-                                          probe_missing=False) is False
-        assert len(spawns) == 1
-        # Lock held by the (pretend-live) prober: kicks dedupe.
-        assert cache_guard.kick_background_probe(str(tmp_path)) is False
-        assert len(spawns) == 1
-        # A STALE lock (dead prober) is reclaimed and re-kicked.
-        lock = tmp_path / (cache_guard.VERDICT_FILE + ".probing")
-        os.utime(lock, (1, 1))
-        assert cache_guard.kick_background_probe(str(tmp_path)) is True
-        assert len(spawns) == 2
-        # A landed verdict beats kicking, even with the lock gone.
-        lock.unlink()
-        cache_guard.write_verdict(
-            str(tmp_path), {"jaxlib": "9.9.9", "probed": True, "safe": True})
-        assert cache_guard.kick_background_probe(str(tmp_path)) is False
-        assert len(spawns) == 2
-        assert cache_guard.cpu_cache_safe(str(tmp_path),
-                                          probe_missing=False) is True
-
-    def test_enable_compilation_cache_gates_on_verdict(self, tmp_path,
-                                                       monkeypatch):
-        import jax
-
-        from foundationdb_tpu.utils import cache_guard
-        from foundationdb_tpu.utils import enable_compilation_cache
-
-        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-        monkeypatch.delenv("FDB_TPU_CPU_CACHE", raising=False)
-        before = jax.config.jax_compilation_cache_dir
-        try:
-            # Unsafe verdict (the real container state): config untouched.
-            monkeypatch.setattr(
-                cache_guard, "cpu_cache_safe", lambda d, **kw: False)
-            enable_compilation_cache(str(tmp_path / "a"))
-            assert jax.config.jax_compilation_cache_dir == before
-            # Safe verdict: cache dir set.
-            monkeypatch.setattr(
-                cache_guard, "cpu_cache_safe", lambda d, **kw: True)
-            enable_compilation_cache(str(tmp_path / "b"))
-            assert jax.config.jax_compilation_cache_dir == str(tmp_path / "b")
-            # Forced off beats a safe verdict.
-            monkeypatch.setenv("FDB_TPU_CPU_CACHE", "0")
-            enable_compilation_cache(str(tmp_path / "c"))
-            assert jax.config.jax_compilation_cache_dir == str(tmp_path / "b")
-            # Typo'd knob fails fast (same rule as the kernel env flags).
-            monkeypatch.setenv("FDB_TPU_CPU_CACHE", "yes")
-            with pytest.raises(ValueError, match="accepted values: 0, 1"):
-                enable_compilation_cache(str(tmp_path / "d"))
-        finally:
-            jax.config.update("jax_compilation_cache_dir", before)

@@ -367,7 +367,7 @@ class TestChecker:
 
 
 def test_selfcheck_main_green(capsys):
-    """python -m foundationdb_tpu.consistency: the CI/tpuwatch stage —
+    """python -m foundationdb_tpu.consistency: the CI stage —
     one JSON line, exit 0 on a consistent audit."""
     import json
 

@@ -626,7 +626,7 @@ class TestBenchHistory:
         assert row["valid"] is True
 
     def test_own_output_artifact_is_never_ingested(self, tmp_path):
-        """The tpuwatch stage writes this tool's record as
+        """This tool's record may be kept as
         BENCH_HISTORY_*.json in the same root — the next run must not
         fold it in as a self-referential bench row."""
         from foundationdb_tpu.obs.history import bench_history

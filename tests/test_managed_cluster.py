@@ -64,7 +64,7 @@ def managed(tmp_path_factory):
         d = tmp / "data" / f"{role}{i}"
         d.mkdir(parents=True, exist_ok=True)
         # stderr to a FILE, not the pipe: supervise/controller chatter over
-        # a long heal window would fill an unread 64KB pipe and block the
+        # a long recovery would fill an unread 64KB pipe and block the
         # server's event loop mid-test. stdout stays piped for the single
         # "ready" line.
         errlog = open(tmp / f"{role}{i}.err.log", "ab")

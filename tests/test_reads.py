@@ -682,7 +682,7 @@ class TestDoctorReadAttribution:
 
 
 # ---------------------------------------------------------------------------
-# The selfcheck surface (tpuwatch `reads` stage)
+# The selfcheck surface
 # ---------------------------------------------------------------------------
 
 
