@@ -609,179 +609,6 @@ def run_tpu_adaptive(
 
 
 # ---------------------------------------------------------------------------
-# Per-phase profiling (--profile): attribute one warm batch's device cost
-# ---------------------------------------------------------------------------
-
-
-def profile_phases(capacity, blob, txn_ends, warm_batches: int = 8,
-                   mode: ModeConfig = MODES["ycsb"]) -> dict:
-    """Per-phase device timings (ms). Returned as a dict so the round
-    artifact carries the attribution (VERDICT r3 item 1: commit the phase
-    breakdown, don't just log it)."""
-    import jax
-
-    from foundationdb_tpu.models import conflict_kernel as ck
-    from foundationdb_tpu.models.conflict_set import TPUConflictSet
-
-    B = mode.batch
-    timings: dict = {}
-    if (len(txn_ends) - 1) // B < 2:
-        log("[profile] skipped: need >= 2 batches of txns to profile")
-        return timings
-    warm_batches = max(0, min(warm_batches, (len(txn_ends) - 1) // B - 1))
-    cs = TPUConflictSet(
-        capacity=capacity, batch_size=B, max_read_ranges=mode.n_reads,
-        max_write_ranges=mode.n_writes, max_key_bytes=KEY_BYTES,
-        window_versions=WINDOW,
-    )
-    for b in range(warm_batches):  # populate real history
-        lo, hi = int(txn_ends[b * B]), int(txn_ends[(b + 1) * B])
-        cs.resolve_wire_async(blob[lo:hi], b + 1, count=B, as_array=True)()
-    lo, hi = int(txn_ends[warm_batches * B]), int(txn_ends[(warm_batches + 1) * B])
-    batch, _ = cs._pack_wire(np.asarray(blob[lo:hi]), 0, B)
-    batch = cs._dev_batch(batch)  # PackedBatch under FDB_TPU_PACKED
-    packed = ck._PACKED
-    state = cs.state
-    cv = np.int32(warm_batches + 1)
-    oldest = np.int32(max(0, warm_batches + 1 - WINDOW))
-
-    def timeit(label, fn, *args):
-        fn(*args)  # compile
-        n, t0 = 5, time.perf_counter()
-        for _ in range(n):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        ms = (time.perf_counter() - t0) / n * 1000
-        timings[label] = round(ms, 3)
-        log(f"[profile] {label}: {ms:.3f} ms")
-        return out
-
-    timings["packed"] = packed
-    timings["resident"] = isinstance(state, ck.ResState)
-    # HOST-PACK attribution (the fix for phase_sum_vs_full: the packer's
-    # host time was invisible to the phase breakdown while dominating the
-    # wall clock). Timed on the RAW wire-packed batch; under the resident
-    # engine this is the mirror delta extraction (steady-state: all keys
-    # hit), under the packed baseline the full np.unique dedup+sort.
-    raw_batch, _ = cs._pack_wire(np.asarray(blob[lo:hi]), 0, B)
-
-    def host_pack():
-        return cs._dev_batch(raw_batch)
-
-    t0 = time.perf_counter()
-    n_hp = 5
-    for _ in range(n_hp):
-        out_hp = host_pack()
-    timings["host_pack"] = round(
-        (time.perf_counter() - t0) / n_hp * 1000, 3
-    )
-    log(f"[profile] host_pack: {timings['host_pack']:.3f} ms")
-
-    if isinstance(state, ck.ResState):
-        # Resident engine: rank-space phases + the device-merge component
-        # (dictionary delta insert + rank rebase) timed on a COLD pack of
-        # the same batch from a fresh mirror — the warm engine's delta is
-        # empty by design (that absence IS the resident win; the cold
-        # merge bounds what a miss-heavy dispatch would pay).
-        timings["history_design"] = ck._HIST_DESIGN
-        cold = TPUConflictSet(
-            capacity=capacity, batch_size=B, max_read_ranges=mode.n_reads,
-            max_write_ranges=mode.n_writes, max_key_bytes=KEY_BYTES,
-            window_versions=WINDOW,
-        )
-        cold_rb = cold._dev_batch(raw_batch)
-        timeit("device_merge_cold", ck._phase_dict_insert_res_jit,
-               state, cold_rb.delta_keys)
-        rb = out_hp
-        timeit("device_merge_empty", ck._phase_dict_insert_res_jit,
-               state, rb.delta_keys)
-        hist = timeit("history_check", ck._phase_history_res_jit,
-                      state, rb.ranks)
-        ranks_live = timeit("endpoint_ranks", ck._phase_ranks_packed_jit,
-                            rb.ranks)
-        hc = cs._hist_core
-        too_old_st = hc.delta if isinstance(hc, ck.HistState) else hc
-        floor, too_old = ck.too_old_mask_packed(too_old_st, rb.ranks, oldest)
-        base = (np.asarray(rb.ranks.txn_mask) & ~np.asarray(too_old)
-                & ~np.asarray(hist))
-        acc = timeit("block_accept_fused", ck._phase_accept_jit, base,
-                     *ranks_live)
-        timeit("paint_compact", ck._phase_paint_res_jit, state, rb.ranks,
-               acc, cv, oldest)
-        if isinstance(hc, ck.HistState):
-            timeit("merge_amortized", ck._phase_merge_hist_res_jit,
-                   state, oldest)
-        full = jax.jit(ck.resolve_batch_res)  # non-donating twin
-        timeit("full_resolve", full, state, rb, cv, oldest)
-        phase_sum = sum(
-            v for k, v in timings.items()
-            if k in ("history_check", "endpoint_ranks",
-                     "block_accept_fused", "paint_compact",
-                     "device_merge_empty")
-        )
-        timings["phase_sum_vs_full"] = round(
-            phase_sum / timings["full_resolve"], 2
-        ) if timings.get("full_resolve") else None
-        timings["unattributed_ms"] = round(
-            max(0.0, timings["full_resolve"] - phase_sum), 3
-        )
-        return timings
-    if isinstance(state, ck.HistState):
-        # Window-history engine: base RMQ rides a prebuilt table; the
-        # per-batch history cost is the delta table + queries, paint
-        # touches only the delta, and the amortized merge is timed
-        # separately (it runs once per ~Cd/(2BQ_live) batches).
-        timings["history_design"] = "window"
-        hist_fn = (ck._phase_history_hist_packed_jit if packed
-                   else ck._phase_history_hist_jit)
-        ranks_fn = ck._phase_ranks_packed_jit if packed else ck._phase_ranks_jit
-        paint_fn = (ck._phase_paint_hist_packed_jit if packed
-                    else ck._phase_paint_hist_jit)
-        too_old_fn = ck.too_old_mask_packed if packed else ck.too_old_mask
-        hist = timeit("history_check", hist_fn, state, batch)
-        ranks_live = timeit("endpoint_ranks", ranks_fn, batch)
-        floor, too_old = too_old_fn(state.delta, batch, oldest)
-        base = np.asarray(batch.txn_mask) & ~np.asarray(too_old) & ~np.asarray(hist)
-        acc = timeit("block_accept_fused", ck._phase_accept_jit, base, *ranks_live)
-        timeit("paint_compact", paint_fn, state, batch, acc, cv, oldest)
-        timeit("merge_amortized", ck._phase_merge_hist_jit, state, oldest)
-        full = jax.jit(ck.resolve_batch_hist_packed if packed
-                       else ck.resolve_batch_hist)  # non-donating twin
-        timeit("full_resolve", full, state, batch, cv, oldest)
-        phase_sum = sum(
-            v for k, v in timings.items()
-            if k not in ("full_resolve", "merge_amortized", "history_design",
-                         "packed", "resident", "host_pack")
-        )
-    else:
-        hist_fn = (ck._phase_history_packed_jit if packed
-                   else ck._phase_history_jit)
-        ranks_fn = ck._phase_ranks_packed_jit if packed else ck._phase_ranks_jit
-        paint_fn = (ck._phase_paint_packed_jit if packed
-                    else ck._phase_paint_jit)
-        too_old_fn = ck.too_old_mask_packed if packed else ck.too_old_mask
-        hist = timeit("history_check", hist_fn, state, batch)
-        ranks_live = timeit("endpoint_ranks", ranks_fn, batch)
-        floor, too_old = too_old_fn(state, batch, oldest)
-        base = np.asarray(batch.txn_mask) & ~np.asarray(too_old) & ~np.asarray(hist)
-        acc = timeit("block_accept_fused", ck._phase_accept_jit, base, *ranks_live)
-        timeit("paint_compact", paint_fn, state, batch, acc, cv, oldest)
-        full = jax.jit(ck.resolve_batch_packed if packed
-                       else ck.resolve_batch)  # non-donating twin
-        timeit("full_resolve", full, state, batch, cv, oldest)
-        phase_sum = sum(v for k, v in timings.items()
-                        if k not in ("full_resolve", "packed", "resident",
-                                     "host_pack"))
-    timings["phase_sum_vs_full"] = round(
-        phase_sum / timings["full_resolve"], 2
-    ) if timings.get("full_resolve") else None
-    timings["unattributed_ms"] = round(
-        max(0.0, timings.get("full_resolve", 0.0) - phase_sum), 3
-    )
-    return timings
-
-
-# ---------------------------------------------------------------------------
 # CPU baseline path
 # ---------------------------------------------------------------------------
 
@@ -1278,7 +1105,7 @@ def _adaptive_vs_windowed(adaptive_rec, windowed_rate, windowed_lat) -> "dict | 
 def run_config(
     name: str, mode: ModeConfig, n_txns: int, n_keys: int, seed: int,
     capacity: int, device: dict, repeats: int = 3, n_resolvers: int = 1,
-    window: int = 32, profile: bool = False, smoke: bool = False,
+    window: int = 32, smoke: bool = False,
     latency_budget_ms: float = 250.0, adaptive_max_window: int = 8,
     adaptive: bool = True, shifting_hotspot: bool = False,
 ) -> dict:
@@ -1363,19 +1190,6 @@ def run_config(
         except Exception as e:  # noqa: BLE001 — recorded; exit is non-zero
             log(f"[tpu] {name}: adaptive dispatch failed: {e}")
             adaptive_rec = {"error": str(e)[:300]}
-    # Phase attribution must land in EVERY headline record: a failure or
-    # a skip is recorded as such, never as null.
-    if profile:
-        try:
-            phase_profile = profile_phases(capacity, blob, txn_ends, mode=mode)
-            if not phase_profile:
-                phase_profile = {"skipped": "needs >= 2 batches of txns"}
-        except Exception as e:  # noqa: BLE001
-            log(f"[profile] {name} failed: {e}")
-            phase_profile = {"error": str(e)[:300]}
-    else:
-        phase_profile = {"skipped": "smoke run" if smoke
-                         else "profiling disabled for this config"}
     if tpu_conf != cpu_conf:
         log(f"[warn] {name}: verdict divergence: tpu={tpu_conf} "
             f"cpu={cpu_conf} ({abs(tpu_conf - cpu_conf) / n_txns:.2%})")
@@ -1435,7 +1249,6 @@ def run_config(
         "workload": "shifting_hotspot" if shifting_hotspot else "zipf",
         "shard_occupancy": occupancy or None,
         "overflowed": overflowed,
-        "phase_profile_ms": phase_profile,
         "roofline": (
             roofline_estimate(
                 mode, capacity, device["device_kind"], n_shards=n_resolvers,
@@ -1463,9 +1276,6 @@ def main() -> None:
     ap.add_argument("--keys", type=int, default=1 << 16)
     ap.add_argument("--capacity", type=int, default=1 << 18)
     ap.add_argument("--seed", type=int, default=20260729)
-    ap.add_argument("--profile", action="store_true",
-                    help="also run the per-phase profiler on the sweep "
-                         "configs (the headline config is always profiled)")
     ap.add_argument("--mode", choices=sorted(MODES), default=None,
                     help="run ONLY this config (default: ycsb headline plus "
                          "reduced-size mako/tpcc/4-resolver sweeps)")
@@ -1495,7 +1305,7 @@ def main() -> None:
                          "dictionary A/B's workload knob")
     ap.add_argument("--smoke", action="store_true",
                     help="minimal validity run: one repeat, no latency "
-                         "probe / profiler / adaptive pass / sweeps")
+                         "probe / adaptive pass / sweeps")
     ap.add_argument("--repair-sim", action="store_true",
                     help="run the transaction-repair goodput harness "
                          "(deterministic sim, oracle-verified; no TPU) "
@@ -1675,15 +1485,12 @@ def main() -> None:
     }
     try:
         # Headline config: full-size run (ycsb unless --mode overrides).
-        # The per-phase profiler runs UNCONDITIONALLY on the headline (it
-        # costs a few extra compiles on an already-warm cache) so every
-        # record carries byte/phase attribution.
         head = run_config(
             args.mode or "ycsb", headline_mode, args.txns, args.keys,
             args.seed, args.capacity, device,
             repeats=1 if args.smoke else (3 if on_tpu else 2),
             n_resolvers=args.resolvers, window=args.window,
-            profile=not args.smoke, smoke=args.smoke,
+            smoke=args.smoke,
             latency_budget_ms=args.latency_budget_ms,
             adaptive_max_window=args.adaptive_max_window,
             adaptive=not args.no_adaptive,
@@ -1752,7 +1559,6 @@ def main() -> None:
                         cname, cmode, sweep_txns, args.keys, args.seed + 1,
                         args.capacity, device, repeats=1,
                         n_resolvers=nres, window=args.window,
-                        profile=nres == 1,
                         latency_budget_ms=args.latency_budget_ms,
                         adaptive_max_window=args.adaptive_max_window,
                         adaptive=not args.no_adaptive,

@@ -21,6 +21,16 @@ completely different shape:
   versions, expired segments) are compacted out — the analogue of the
   reference skiplist's insert + version-window GC.
 
+Phases carry ``jax.named_scope`` names, so a profiler trace of the REAL
+program says which phase an operation belongs to (its ``op_name`` path):
+``dict_insert`` / ``dict_evict`` / ``dict_remap`` (resident dictionary
+upkeep: apply_delta, apply_evict, apply_dict_remap), ``hist_merge``
+(_maybe_merge, advance_hist), ``history_probe`` (too-old mask + reads vs
+history), ``endpoint_ranks``, ``accept`` (block scan or wave schedule),
+``paint_compact`` and ``verdicts``. The names sit on the shared helpers,
+so every entry point (flat / hist, raw / packed / resident, wave) speaks
+one vocabulary. Metadata only: the compiled program does not change.
+
 Everything is static-shape; hosts pad batches (see conflict_set.TPUConflictSet).
 Versions on device are int32, relative to a host-held base (the MVCC window
 is ~5-7M versions, far inside int32; the host rebases periodically).
@@ -216,6 +226,7 @@ def init_state(capacity: int, width: int, min_key) -> ConflictState:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("history_probe")
 def _history_conflict_ranges(
     state: ConflictState, batch: BatchTensors
 ) -> jax.Array:
@@ -362,6 +373,7 @@ def _overlap_rows(
     return m
 
 
+@jax.named_scope("endpoint_ranks")
 def endpoint_ranks_live(batch: BatchTensors) -> tuple[jax.Array, ...]:
     """(rb, re, read_live, wb, we, write_live): endpoint ranks plus the
     liveness masks (slot populated AND range non-empty in rank space) —
@@ -736,6 +748,7 @@ def _cycle_victim(p, undet, undetp):
     return victim
 
 
+@jax.named_scope("accept")
 def wave_pred_matrix(
     base: jax.Array, ranks: tuple[jax.Array, ...]
 ) -> jax.Array:
@@ -831,6 +844,7 @@ def _wave_level_packed(base: jax.Array, p: jax.Array) -> jax.Array:
     return level
 
 
+@jax.named_scope("accept")
 def wave_level_from_graph(
     cand: jax.Array, p: jax.Array
 ) -> tuple[jax.Array, jax.Array]:
@@ -866,6 +880,7 @@ def _wave_commit_accept(
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("paint_compact")
 def _paint_and_compact(
     state: ConflictState,
     batch: BatchTensors,
@@ -1053,6 +1068,7 @@ def clip_batch(batch: BatchTensors, lo: jax.Array, hi: jax.Array) -> BatchTensor
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("history_probe")
 def too_old_mask(
     state: ConflictState, batch: BatchTensors, new_oldest: jax.Array
 ) -> tuple[jax.Array, jax.Array]:
@@ -1069,6 +1085,7 @@ def too_old_mask(
     return floor, too_old
 
 
+@jax.named_scope("verdicts")
 def assemble_verdicts(
     too_old: jax.Array, txn_mask: jax.Array, accepted: jax.Array
 ) -> jax.Array:
@@ -1079,6 +1096,7 @@ def assemble_verdicts(
     )
 
 
+@jax.named_scope("verdicts")
 def loser_range_mask(
     hist_mask: jax.Array,
     ranks: tuple[jax.Array, ...],
@@ -1097,6 +1115,7 @@ def loser_range_mask(
     return (hist_mask | intra) & (verdicts == V_CONFLICT)[:, None]
 
 
+@jax.named_scope("accept")
 def _accept_or_schedule(base, ranks, wave: bool):
     """Shared acceptance dispatch: sequential-order block scan (wave=False)
     or the wave-commit schedule (wave=True — levels ride along)."""
@@ -1289,6 +1308,7 @@ def _merge_delta(base: ConflictState, delta: ConflictState,
     )
 
 
+@jax.named_scope("hist_merge")
 def _maybe_merge(hist: HistState, demand: jax.Array,
                  floor: jax.Array) -> HistState:
     """Fold delta into base when `demand` more boundary slots wouldn't
@@ -1315,6 +1335,7 @@ def _maybe_merge(hist: HistState, demand: jax.Array,
     return jax.lax.cond(need, do_merge, lambda h: h, hist)
 
 
+@jax.named_scope("history_probe")
 def _history_conflict_ranges_hist(base: ConflictState, base_st: jax.Array,
                                   delta: ConflictState,
                                   batch: BatchTensors) -> jax.Array:
@@ -1403,6 +1424,7 @@ def resolve_many_hist(
     return (*stacked, hist)
 
 
+@jax.named_scope("hist_merge")
 def advance_hist(hist: HistState, commit_version: jax.Array,
                  new_oldest: jax.Array) -> HistState:
     """GC-only step for the hist engine: advance the floor AND force a
@@ -1424,6 +1446,7 @@ def advance_hist(hist: HistState, commit_version: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("history_probe")
 def too_old_mask_packed(
     state: ConflictState, pb: PackedBatch, new_oldest: jax.Array
 ) -> tuple[jax.Array, jax.Array]:
@@ -1434,6 +1457,7 @@ def too_old_mask_packed(
     return floor, too_old
 
 
+@jax.named_scope("endpoint_ranks")
 def endpoint_ranks_live_packed(pb: PackedBatch) -> tuple[jax.Array, ...]:
     """endpoint_ranks_live without the device sort: the host packer
     already emitted rank-space intervals (order-isomorphic with exact tie
@@ -1444,6 +1468,7 @@ def endpoint_ranks_live_packed(pb: PackedBatch) -> tuple[jax.Array, ...]:
             pb.write_begin, pb.write_end, write_live)
 
 
+@jax.named_scope("history_probe")
 def _dict_history_search(
     state_keys: jax.Array, dict_keys: jax.Array
 ) -> tuple[jax.Array, jax.Array]:
@@ -1457,6 +1482,7 @@ def _dict_history_search(
     return rs, ls
 
 
+@jax.named_scope("history_probe")
 def _history_conflict_ranges_packed(
     state: ConflictState, pb: PackedBatch,
     rs: jax.Array | None = None, ls: jax.Array | None = None,
@@ -1488,6 +1514,7 @@ def _history_conflicts_packed(state: ConflictState, pb: PackedBatch) -> jax.Arra
     return jnp.any(_history_conflict_ranges_packed(state, pb), axis=1)
 
 
+@jax.named_scope("paint_compact")
 def _paint_and_compact_packed(
     state: ConflictState,
     pb: PackedBatch,
@@ -1536,6 +1563,7 @@ def _paint_and_compact_packed(
     )
 
 
+@jax.named_scope("verdicts")
 def pack_loser_mask(losers: jax.Array) -> jax.Array:
     """bool [B, R] -> uint32 [B] bitset (bit c = coalesced read slot c
     lost) when R <= 32 — an 8x cut of the report path's device→host
@@ -1596,6 +1624,7 @@ def resolve_many_packed(
     return (*stacked, state)
 
 
+@jax.named_scope("history_probe")
 def _history_conflict_ranges_hist_packed(
     base: ConflictState, base_st: jax.Array, delta: ConflictState,
     pb: PackedBatch,
@@ -2005,6 +2034,7 @@ def _shift_hist(hist, shift):
     return hist._replace(keys=_shift_rank_rows(hist.keys, shift))
 
 
+@jax.named_scope("dict_insert")
 def apply_delta(res: ResState, delta_keys: jax.Array) -> ResState:
     """Fold this dispatch's key delta into the resident state: insert the
     new keys into the dictionary and rank-rebase the history + shard
@@ -2055,6 +2085,7 @@ def _dict_evict(dict_keys, n_keys, evict_ranks):
     return out, new_n, shift
 
 
+@jax.named_scope("dict_evict")
 def apply_evict(res: ResState, evict_ranks: jax.Array) -> ResState:
     """Fold a demotion delta into the resident state: remove the evicted
     ranks from the dictionary and rank-rebase the history + shard bounds
@@ -2076,6 +2107,7 @@ def apply_evict(res: ResState, evict_ranks: jax.Array) -> ResState:
     return jax.lax.cond(any_ev, do, lambda r: r, res)
 
 
+@jax.named_scope("dict_remap")
 def apply_dict_remap(res: ResState, new_dict, new_n, remap) -> ResState:
     """Full-repack tail: swap in the host-rebuilt dictionary and remap
     every device-held rank through ``remap`` (old rank -> new rank; exact
@@ -2135,6 +2167,7 @@ def _rank_probe(keys: jax.Array, q: jax.Array, side: str) -> jax.Array:
     return searchsorted_words(keys, q[..., None], side=side)
 
 
+@jax.named_scope("history_probe")
 def _history_conflict_ranges_res(state: ConflictState, rbk: RankBatch) -> jax.Array:
     """_history_conflict_ranges over the rank-space history: per-slot
     probes (the host already deduped the rank space; a probe step gathers
@@ -2160,6 +2193,7 @@ def _history_conflicts_res(state: ConflictState, rbk: RankBatch) -> jax.Array:
     return jnp.any(_history_conflict_ranges_res(state, rbk), axis=1)
 
 
+@jax.named_scope("history_probe")
 def _history_conflict_ranges_hist_res(
     base: ConflictState, base_st: jax.Array, delta: ConflictState,
     rbk: RankBatch,
@@ -2195,6 +2229,7 @@ def _history_conflicts_hist_res(hist: HistState, rbk: RankBatch) -> jax.Array:
     )
 
 
+@jax.named_scope("paint_compact")
 def _paint_and_compact_res(
     state: ConflictState,
     rbk: RankBatch,
@@ -2245,11 +2280,12 @@ def _resolve_core_res(hist, rbk: RankBatch, commit_version, new_oldest,
     two_level = isinstance(hist, HistState)
     if two_level:
         floor, too_old = too_old_mask_packed(hist.delta, rbk, new_oldest)
-        demand = 2 * jnp.sum(
-            (rbk.write_mask & (rbk.write_begin < rbk.write_end)).astype(
-                jnp.int32
+        with jax.named_scope("hist_merge"):
+            demand = 2 * jnp.sum(
+                (rbk.write_mask & (rbk.write_begin < rbk.write_end)).astype(
+                    jnp.int32
+                )
             )
-        )
         hist = _maybe_merge(hist, demand, floor)
         base_h, base_st, delta = hist
         hist_mask = _history_conflict_ranges_hist_res(
@@ -2258,8 +2294,9 @@ def _resolve_core_res(hist, rbk: RankBatch, commit_version, new_oldest,
     else:
         floor, too_old = too_old_mask_packed(hist, rbk, new_oldest)
         hist_mask = _history_conflict_ranges_res(hist, rbk)
-    hist_conflict = jnp.any(hist_mask, axis=1)
-    base = rbk.txn_mask & ~too_old & ~hist_conflict
+    with jax.named_scope("history_probe"):
+        hist_conflict = jnp.any(hist_mask, axis=1)
+        base = rbk.txn_mask & ~too_old & ~hist_conflict
     ranks = endpoint_ranks_live_packed(rbk)
     accepted, levels = _accept_or_schedule(base, ranks, wave)
     verdicts = assemble_verdicts(too_old, rbk.txn_mask, accepted)
@@ -2562,114 +2599,6 @@ _wave_apply_hist_packed_jit = jax.jit(
 )
 _wave_apply_res_jit = jax.jit(wave_apply_res, donate_argnums=(0,))
 _wave_apply_hist_res_jit = _wave_apply_res_jit
-
-
-# ---------------------------------------------------------------------------
-# Per-phase entry points (bench --profile): each phase compiled alone so the
-# host can time it with block_until_ready and attribute the batch cost.
-# ---------------------------------------------------------------------------
-
-
-@jax.jit
-def _phase_history_jit(state, batch):
-    return _history_conflicts(state, batch)
-
-
-@jax.jit
-def _phase_ranks_jit(batch):
-    return endpoint_ranks_live(batch)
-
-
-@jax.jit
-def _phase_accept_jit(base, rb, re_, read_live, wb, we, write_live):
-    return _block_accept_fused(base, rb, re_, read_live, wb, we, write_live)
-
-
-@jax.jit  # state NOT donated: profiling replays phases on the same state
-def _phase_paint_jit(state, batch, accepted, commit_version, new_oldest):
-    return _paint_and_compact(state, batch, accepted, commit_version, new_oldest)
-
-
-@jax.jit
-def _phase_history_hist_jit(hist, batch):
-    return _history_conflicts_hist(hist.base, hist.base_st, hist.delta, batch)
-
-
-@jax.jit
-def _phase_paint_hist_jit(hist, batch, accepted, commit_version, new_oldest):
-    return _paint_and_compact(hist.delta, batch, accepted, commit_version,
-                              new_oldest)
-
-
-@jax.jit
-def _phase_merge_hist_jit(hist, new_oldest):
-    """The amortized cost: one delta→base fold + base table rebuild."""
-    nb = _merge_delta(hist.base, hist.delta, new_oldest)
-    return nb, sparse_table(nb.versions)
-
-
-@jax.jit
-def _phase_history_packed_jit(state, pb):
-    return _history_conflicts_packed(state, pb)
-
-
-@jax.jit
-def _phase_ranks_packed_jit(pb):
-    """Near-zero by design: the endpoint sort moved into the host packer
-    (the deduped dictionary) — timed anyway so the phase breakdown stays
-    shape-compatible across the A/B."""
-    return endpoint_ranks_live_packed(pb)
-
-
-@jax.jit
-def _phase_history_hist_packed_jit(hist, pb):
-    return _history_conflicts_hist_packed(hist, pb)
-
-
-@jax.jit  # state NOT donated: profiling replays phases on the same state
-def _phase_paint_packed_jit(state, pb, accepted, commit_version, new_oldest):
-    return _paint_and_compact_packed(state, pb, accepted, commit_version,
-                                     new_oldest)
-
-
-@jax.jit
-def _phase_paint_hist_packed_jit(hist, pb, accepted, commit_version,
-                                 new_oldest):
-    return _paint_and_compact_packed(hist.delta, pb, accepted,
-                                     commit_version, new_oldest)
-
-
-@jax.jit
-def _phase_dict_insert_res_jit(res, delta_keys):
-    """The resident path's DEVICE-MERGE component (the on-device half of
-    what the per-dispatch repack used to do monolithically): one delta
-    insert + rank rebase. Its host counterpart — the mirror delta
-    extraction — is timed host-side by the profiler as host_pack."""
-    return apply_delta(res, delta_keys)
-
-
-@jax.jit
-def _phase_history_res_jit(res, rbk):
-    hist = res.hist
-    if isinstance(hist, HistState):
-        return _history_conflicts_hist_res(hist, rbk)
-    return _history_conflicts_res(hist, rbk)
-
-
-@jax.jit  # state NOT donated: profiling replays phases on the same state
-def _phase_paint_res_jit(res, rbk, accepted, commit_version, new_oldest):
-    hist = res.hist
-    st = hist.delta if isinstance(hist, HistState) else hist
-    return _paint_and_compact_res(st, rbk, accepted, commit_version,
-                                  new_oldest)
-
-
-@jax.jit
-def _phase_merge_hist_res_jit(res, new_oldest):
-    """The amortized two-level fold, rank-space edition."""
-    hist = res.hist
-    nb = _merge_delta(hist.base, hist.delta, new_oldest)
-    return nb, sparse_table(nb.versions)
 
 
 # ---------------------------------------------------------------------------

@@ -15,6 +15,7 @@ absolute↔relative version mapping with periodic device rebase.
 from __future__ import annotations
 
 import ctypes
+import functools
 import os
 import struct
 import threading
@@ -27,9 +28,50 @@ import numpy as np
 from foundationdb_tpu.core.keypack import INT32_MAX, KeyCodec, row_sort_keys
 from foundationdb_tpu.core.types import KeyRange, TxnConflictInfo, Verdict
 from foundationdb_tpu.models import conflict_kernel as ck
+from foundationdb_tpu.obs.span import stage_timer
 
 DEFAULT_WINDOW_VERSIONS = 5_000_000  # ~5s at 1M versions/sec, reference MVCC window
 _REBASE_THRESHOLD = 1 << 30
+
+def _staged(stage: str):
+    """Method decorator: the call is stage `stage` of the dispatch in hand
+    (obs/span.py stage_timer: seconds into ``self.last_stage_s``, a
+    TraceAnnotation tagged with the commit version being resolved)."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def timed(self, *args, **kwargs):
+            with stage_timer(self.last_stage_s, stage, self._last_commit):
+                return fn(self, *args, **kwargs)
+        return timed
+    return deco
+
+
+# Programs this PROCESS built, and the seconds that took: monotonic since
+# count_compiles() was first called; read as window differences, so that a
+# compile inside a measured window can be told from a stall.
+_COMPILE_STATS = {"compiles": 0, "compile_s": 0.0}
+_compile_listener_on = False
+
+
+def count_compiles() -> None:
+    """Register, once a process, a jax.monitoring duration listener on
+    ``/jax/core/compile/backend_compile_duration`` — the event JAX records
+    around every program it compiles or loads from the persistent cache
+    (jax._src.dispatch.BACKEND_COMPILE_EVENT). Called by warm_up, i.e. by
+    the process that owns the engine; counts every program of the
+    process, not this engine's alone."""
+    global _compile_listener_on
+    if _compile_listener_on:
+        return
+    _compile_listener_on = True
+    import jax.monitoring
+
+    def on_duration(event: str, duration_secs: float, **_kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            _COMPILE_STATS["compiles"] += 1
+            _COMPILE_STATS["compile_s"] += duration_secs
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
 
 
 # ---------------------------------------------------------------------------
@@ -238,6 +280,14 @@ class _ResidentMirror:
             "delta_new_keys": 0,
             "evictions": 0,
             "full_repacks": 0,
+            # Why a full repack was decided (the arms of _pack_resident's
+            # need_repack, first that holds; their sum is full_repacks
+            # when no tiered fallback fires) and the seconds spent inside
+            # _repack_and_rank. Monotonic; read as window differences.
+            "repacks_delta_overflow": 0,
+            "repacks_dict_full": 0,
+            "repacks_frag_due": 0,
+            "repack_s": 0.0,
             "repack_stalls": 0,
             # Tiered-dictionary economics (zero when tiering is off):
             "demotions": 0,        # keys moved hot -> cold via _dict_evict
@@ -668,6 +718,12 @@ class TPUConflictSet:
         # [k, count] levels, one independent wave schedule per scanned
         # batch (batches already serialize by commit version).
         self.last_wave_window: np.ndarray | None = None
+        # Stage seconds of the dispatch in hand (obs/span.py ENGINE_STAGES;
+        # filled through stage_timer, chunks accumulate). The resolver's
+        # span sink hands in a fresh dict before a batch and reads it
+        # after; with no reader it is a handful of floats that keep
+        # summing. Never enters kernel state.
+        self.last_stage_s: dict[str, float] = {}
         self._empty_dev_batch = None  # advance()'s constant batch, packed lazily
         # Admission subsystem (attach_admission_filter): a RecentWritesFilter
         # fed from each dispatch's ACCEPTED write sets using the endpoint
@@ -771,10 +827,14 @@ class TPUConflictSet:
         static size is the endpoint count + 1, with the last row always
         +inf (paint parks masked slots there); ranks are exact order
         isomorphisms (equal keys share a rank)."""
+        with stage_timer(self.last_stage_s, "dict_rank", self._last_commit):
+            return self._pack_dict_rows(bt)
+
+    def _pack_dict_rows(self, bt: ck.BatchTensors) -> ck.PackedBatch:
         rb = np.asarray(bt.read_begin)
         if rb.ndim == 4:  # [k, B, R, W] window path: pack per scan step
             parts = [
-                self._pack_dict(
+                self._pack_dict_rows(
                     ck.BatchTensors(*(np.asarray(x)[i] for x in bt))
                 )
                 for i in range(rb.shape[0])
@@ -879,6 +939,7 @@ class TPUConflictSet:
         fps = u64_cols_fingerprint(qu[sect])
         self._adm_stash = (fps.reshape(b, q), (~is_pad[sect]).reshape(b, q))
 
+    @_staged("dict_rank")
     def _pack_resident(self, bt: ck.BatchTensors, defer_repack: bool = False):
         """Rank-space pack against the resident mirror: classify every
         endpoint as hit (already resident) or miss, emit the sorted-unique
@@ -890,7 +951,10 @@ class TPUConflictSet:
         forces a FULL REPACK, which needs exact device liveness: inline on
         the dispatching thread, or — on the threaded window path
         (``defer_repack``) — deferred to dispatch_window via _RepackPlan
-        with the mirror gate held so later packs wait for the new mirror."""
+        with the mirror gate held so later packs wait for the new mirror.
+
+        Stage ``dict_rank`` (obs/span.py): the whole of this minus any
+        inline repack or demotion, which are ``dict_repack``."""
         mir = self._mirror
         mir.gate.wait()
         flat, dims = self._flat_endpoints(bt)
@@ -922,18 +986,22 @@ class TPUConflictSet:
             new_rows = np.zeros((0, dims[-1]), np.int32)
         m = len(new_u64)
         cv = self._last_commit
-        need_repack = (
-            m > self.dict_delta_slots
-            or (not self.tiered and mir.n + m > mir.capacity)
-            or mir.frag_due(self.oldest_version)
+        cause = (
+            "repacks_delta_overflow" if m > self.dict_delta_slots
+            else "repacks_dict_full"
+            if not self.tiered and mir.n + m > mir.capacity
+            else "repacks_frag_due" if mir.frag_due(self.oldest_version)
+            else None
         )
-        if need_repack:
+        if cause is not None:
+            mir.stats[cause] += 1
             if defer_repack:
                 mir.gate.clear()
                 mir.stats["repack_stalls"] += 1
                 return _RepackPlan(bt, qu, is_pad, new_u64, new_rows, dims, cv)
             return self._repack_and_rank(
-                _RepackPlan(bt, qu, is_pad, new_u64, new_rows, dims, cv)
+                _RepackPlan(bt, qu, is_pad, new_u64, new_rows, dims, cv),
+                inside="dict_rank",
             )
         if self.tiered and mir.n + m > self._demote_watermark:
             if defer_repack:
@@ -943,13 +1011,16 @@ class TPUConflictSet:
                 mir.gate.clear()
                 mir.stats["demotion_stalls"] += 1
                 return _DemotePlan(bt, qu, is_pad, new_u64, new_rows, dims, cv)
-            self._demote_now(m, protect=(qu, is_pad))
+            with stage_timer(self.last_stage_s, "dict_repack", cv,
+                             inside="dict_rank"):
+                self._demote_now(m, protect=(qu, is_pad))
             if mir.n + m > mir.capacity:
                 # Demotion could not free enough room (victims all
                 # pinned, device-live or recent): the honest full-repack
                 # fallback — the thrash pathology obs/doctor flags.
                 return self._repack_and_rank(
-                    _RepackPlan(bt, qu, is_pad, new_u64, new_rows, dims, cv)
+                    _RepackPlan(bt, qu, is_pad, new_u64, new_rows, dims, cv),
+                    inside="dict_rank",
                 )
         with mir.lock:
             mir.touch(ids[hot_hit], cv)
@@ -998,12 +1069,24 @@ class TPUConflictSet:
         live = np.unique(ranks[ranks != INT32_MAX])
         return live[(live >= 0) & (live < self._mirror.n)]
 
-    def _repack_and_rank(self, plan: _RepackPlan) -> ck.ResidentBatch:
+    def _repack_and_rank(self, plan: _RepackPlan,
+                         inside: "str | None" = None) -> ck.ResidentBatch:
         """Full dictionary repack: rebuild the dictionary from {live
         history ranks} ∪ {pinned} ∪ {this dispatch's keys} ∪ the most
         recently used survivors (oldest-last-used evicted first), ship it
         whole, and remap every device-held rank. The rare fallback the
-        per-delta path buys its way out of; also the cold-start path."""
+        per-delta path buys its way out of; also the cold-start path.
+
+        Stage ``dict_repack`` (carved out of ``inside`` when called from
+        within another stage); its seconds also sum into the mirror's
+        ``repack_s``."""
+        with stage_timer(self.last_stage_s, "dict_repack", plan.cv,
+                         inside=inside) as timed:
+            out = self._repack_and_rank_now(plan)
+        self._mirror.stats["repack_s"] += timed.seconds
+        return out
+
+    def _repack_and_rank_now(self, plan: _RepackPlan) -> ck.ResidentBatch:
         mir = self._mirror
         with mir.lock:
             try:
@@ -1163,8 +1246,9 @@ class TPUConflictSet:
         itself escalates to a full repack if demotion could not free
         enough room."""
         try:
-            self._demote_now(len(plan.new_u64),
-                             protect=(plan.qu, plan.is_pad))
+            with stage_timer(self.last_stage_s, "dict_repack", plan.cv):
+                self._demote_now(len(plan.new_u64),
+                                 protect=(plan.qu, plan.is_pad))
         finally:
             self._mirror.gate.set()
         return self._pack_resident(plan.bt)
@@ -1175,7 +1259,7 @@ class TPUConflictSet:
         keys/dispatch, delta hit rate, evictions, forced full repacks."""
         if self._mirror is None:
             return None
-        s = dict(self._mirror.stats)
+        s = dict(self._mirror.stats, **_COMPILE_STATS)
         d = max(1, s["dispatches"])
         e = max(1, s["endpoints"])
         s.update(
@@ -1241,7 +1325,10 @@ class TPUConflictSet:
                 # Pack BEFORE reading self.state: a resident-dictionary
                 # repack inside the packer replaces (and donates) it.
                 dev = self._dev_batch(batch)
-                out = self._resolve_report_fn(self.state, dev, cv, oldest)
+                with stage_timer(self.last_stage_s, "engine_enqueue",
+                                 commit_version):
+                    out = self._resolve_report_fn(self.state, dev, cv,
+                                                  oldest)
                 verdicts, levels, losers, self.state = (
                     out if self.wave_commit else (out[0], None, *out[1:])
                 )
@@ -1253,7 +1340,9 @@ class TPUConflictSet:
             else:
                 batch = self._pack(chunk)
                 dev = self._dev_batch(batch)  # may repack: order matters
-                out = self._resolve_fn(self.state, dev, cv, oldest)
+                with stage_timer(self.last_stage_s, "engine_enqueue",
+                                 commit_version):
+                    out = self._resolve_fn(self.state, dev, cv, oldest)
                 verdicts, levels, self.state = (
                     out if self.wave_commit else (out[0], None, out[1])
                 )
@@ -1312,7 +1401,9 @@ class TPUConflictSet:
             n = min(remaining, self.batch_size)
             batch, offset = self._pack_wire(buf, offset, n)
             dev = self._dev_batch(batch)  # may repack: order matters
-            out = self._resolve_fn(self.state, dev, cv, oldest)
+            with stage_timer(self.last_stage_s, "engine_enqueue",
+                             commit_version):
+                out = self._resolve_fn(self.state, dev, cv, oldest)
             verdicts, levels, self.state = (
                 out if self.wave_commit else (out[0], None, out[1])
             )
@@ -1545,9 +1636,11 @@ class TPUConflictSet:
             # Deferred tiered demotion: same exactness argument, but the
             # device traffic is an evict rank vector, not a dictionary.
             batch = self._demote_and_rank(batch)
-        out = self._resolve_many_fn(
-            self.state, batch, prepared.cvs_rel, prepared.olds_rel
-        )
+        with stage_timer(self.last_stage_s, "engine_enqueue",
+                         self._last_commit):
+            out = self._resolve_many_fn(
+                self.state, batch, prepared.cvs_rel, prepared.olds_rel
+            )
         verdicts, levels, self.state = (
             out if self.wave_commit else (out[0], None, out[1])
         )
@@ -2039,6 +2132,17 @@ class TPUConflictSet:
                 self.admission_filter.advance(cv)
 
     def _collect(self, pending: list[tuple]) -> list[Verdict]:
+        with stage_timer(self.last_stage_s, "verdict_wait",
+                         self._last_commit):
+            # The first blocking read: chunks execute in order, so the
+            # last chunk's verdicts are ready when every chunk's are.
+            if pending:
+                np.asarray(pending[-1][0])
+        with stage_timer(self.last_stage_s, "resolve_post",
+                         self._last_commit):
+            return self._decode(pending)
+
+    def _decode(self, pending: list[tuple]) -> list[Verdict]:
         out: list[Verdict] = []
         self.last_conflicting = {}
         self._collect_waves(pending)
@@ -2217,6 +2321,7 @@ class TPUConflictSet:
         tiered evict."""
         import jax
 
+        count_compiles()
         zero = np.int32(0)
         bt = self._empty_batch()
         if self.resident:
@@ -2315,10 +2420,12 @@ class TPUConflictSet:
             txn_mask=np.zeros((*lead, b), bool),
         )
 
+    @_staged("host_pack")
     def _pack_wire(
         self, buf: np.ndarray, offset: int, count: int
     ) -> tuple[ck.BatchTensors, int]:
-        """One C pass: wire bytes [offset..] → padded batch tensors."""
+        """One C pass: wire bytes [offset..] → padded batch tensors.
+        Stage ``host_pack``, like _pack."""
         bt = self._empty_batch()
         lib = _keypack_lib()
         new_off = lib.kp_pack_batch(
@@ -2333,11 +2440,13 @@ class TPUConflictSet:
             raise ValueError("malformed resolver wire batch")
         return bt, int(new_off)
 
+    @_staged("host_pack")
     def _pack(self, txns: list[TxnConflictInfo], collect_reads: bool = False):
-        # Host-pack stage stamp (obs subsystem): wall seconds of the last
-        # host-side pack, read by the resolver's span sink right after a
-        # resolve — a stored float, never entering kernel state.
-        _t_pack0 = _perf_counter()
+        """Keys -> row tensors. Stage ``host_pack`` (obs/span.py): its
+        wall seconds ACCUMULATE in ``last_stage_s`` across the chunks of
+        a capacity-chunked resolve; the reader — the resolver's span
+        sink — hands in a fresh record per dispatched batch, so the sum
+        is per batch."""
         bt = self._empty_batch()
         read_begin, read_end, read_mask = bt.read_begin, bt.read_end, bt.read_mask
         write_begin, write_end, write_mask = bt.write_begin, bt.write_end, bt.write_mask
@@ -2376,12 +2485,6 @@ class TPUConflictSet:
             write_end[w_rows, w_cols] = we
             write_mask[w_rows, w_cols] = True
 
-        # ACCUMULATE across chunks (a capacity-chunked resolve packs once
-        # per chunk; the reader — the resolver's span sink — clears the
-        # stamp to None per dispatched batch, so the sum is per-batch).
-        self.last_host_pack_s = (
-            (getattr(self, "last_host_pack_s", None) or 0.0)
-            + (_perf_counter() - _t_pack0))
         if collect_reads:
             return bt, reads_per_txn
         return bt

@@ -66,6 +66,7 @@ from foundationdb_tpu.obs.selfcheck import (
     run_selfcheck,
 )
 from foundationdb_tpu.obs.span import (
+    ENGINE_STAGES,
     READ_STAGES,
     SUB_STAGES,
     TXN_STAGES,
@@ -81,6 +82,7 @@ __all__ = [
     "ANNOTATION_CLASSES",
     "CHAOS_DOCUMENTED_COUNTERS",
     "DOCUMENTED_COUNTERS",
+    "ENGINE_STAGES",
     "FlightRecorder",
     "MetricsPoller",
     "MetricsRegistry",

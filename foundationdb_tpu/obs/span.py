@@ -57,12 +57,35 @@ reconciliation identity):
                   (request + reply transport legs)
 
     grv_proxy_queue   GRV proxy: request arrival -> batch admit
+    rpc_decode        transport: ``wire.loads`` of one request frame
+                      (NetTransport._on_frame; n = 1 per frame, only
+                      while a sink is on)
     coalesce_queue    resolver: chain admission -> dispatch group start
-    host_pack         resolver: engine host-side pack (engines that
-                      publish ``last_host_pack_s``)
-    device_dispatch   resolver: modeled dispatch cost + engine execution
-                      (under the global wave protocol: both phases'
-                      engine work, edges + level/paint)
+    host_pack         engine: keys -> row tensors (_pack / _pack_wire)
+    device_dispatch   resolver: the UMBRELLA over one batch's engine
+                      bracket — wall time of the whole synchronous
+                      engine path (plus the modeled dispatch cost in
+                      sim) minus host_pack. Despite the name it is host
+                      AND device time: the stages below are its
+                      interior. (Under the global wave protocol: both
+                      phases' engine work, edges + level/paint.)
+    dict_rank         engine: endpoints -> u64, mirror probe, delta
+                      build, insert_new, ranks (_pack_resident without
+                      its repack; _pack_dict)
+    dict_repack       engine: the full dictionary repack / tiered
+                      demotion, its device liveness sync included
+    engine_enqueue    engine: the jitted resolve call until it returns
+                      — argument transfer (H2D) and enqueue; does not
+                      wait for the device
+    verdict_wait      engine: the first blocking read of the verdicts —
+                      the rest of the device's execution plus D2H
+    headroom_sync     resolver: cs.headroom() + cs.overflowed after the
+                      verdicts — a second device round trip per batch
+    resolve_post      engine + resolver: verdict list, loser ranges,
+                      admission feed (_collect after the wait), then
+                      hot ranges, filter feed, counters (_finish_entry)
+    engine_unattributed  resolver: the bracket minus every stage above;
+                      recorded, never dropped
     wave_exchange     resolver: global wave commit only — phase-1 reply
                       to phase-2 arrival (the proxy's OR-reduce of the
                       shards' edge bitsets plus both network legs), the
@@ -78,11 +101,32 @@ reconciliation identity):
                       rollback/repair re-resolves (interior of
                       device_dispatch, the phase-B half)
     tlog_fsync        tlog: chain-ordered push -> durable ack
+
+The engine identity (``ENGINE_STAGES``), per batch on the serial path and
+by ARITHMETIC like the txn identity above:
+
+    host_pack + device_dispatch ==
+        host_pack + dict_rank + dict_repack + engine_enqueue
+        + verdict_wait + headroom_sync + resolve_post
+        + engine_unattributed
+
+The engine fills one per-dispatch record of stage seconds
+(``TPUConflictSet.last_stage_s``, through ``stage_timer`` below); the
+resolver hands it a fresh record before a batch, adds its own stages,
+and ticks every stage with the batch's commit version after it, so the
+spans of one batch share an identifier. ``stage_timer`` also enters a
+``jax.profiler.TraceAnnotation("fdb:<stage>", version=...)``: while a
+profiler trace runs, the stages are host events on the profiler's own
+clock, next to the device operations. The interior stages are wall-clock
+attribution of synchronous work, which a sim loop's virtual clock cannot
+see (it reads 0 inside one task step): they are recorded on wall-time
+loops only, and sim keeps ``device_dispatch`` = the modeled cost.
 """
 
 from __future__ import annotations
 
 import os
+import sys
 import time
 from collections import deque
 
@@ -106,14 +150,35 @@ TXN_STAGES = (
 #: the identity — they live within grv_wait / resolve_wait / tlog_durable).
 SUB_STAGES = (
     "grv_proxy_queue",
+    "rpc_decode",
     "coalesce_queue",
     "host_pack",
     "device_dispatch",
+    "dict_rank",
+    "dict_repack",
+    "engine_enqueue",
+    "verdict_wait",
+    "headroom_sync",
+    "resolve_post",
+    "engine_unattributed",
     "wave_exchange",
     "wave_level",
     "spec_resolve",
     "reconcile",
     "tlog_fsync",
+)
+
+#: The interior of one batch's engine bracket on the serial path: the
+#: engine identity is  host_pack + device_dispatch == sum(ENGINE_STAGES)
+#: + engine_unattributed.
+ENGINE_STAGES = (
+    "host_pack",
+    "dict_rank",
+    "dict_repack",
+    "engine_enqueue",
+    "verdict_wait",
+    "headroom_sync",
+    "resolve_post",
 )
 
 #: Read-plane batch-level stages (foundationdb_tpu/reads/): stamped via
@@ -501,6 +566,54 @@ def span_sink(loop) -> "SpanSink | None":
     when tracing is off."""
     s = getattr(loop, "span_sink", None)
     return s if s is not None and s.enabled else None
+
+
+class stage_timer:
+    """``with stage_timer(record, "dict_rank", version):`` — one stage of
+    one dispatch, on both clocks: its seconds are ADDED to
+    ``record[stage]`` (a plain dict; chunks of one batch accumulate; None
+    = time nothing), and the block runs inside a
+    ``jax.profiler.TraceAnnotation("fdb:<stage>", version=...)``, a host
+    event on the profiler's timeline while a trace runs and a flag check
+    while none does. ``inside``: the stage this one is nested in, whose
+    seconds it is carved out of, so the record stays a partition.
+    ``.seconds`` holds the elapsed time after the block.
+
+    The annotation class is taken from ``sys.modules`` — this module is
+    imported by the client and by harnesses that must never load JAX;
+    where JAX is not loaded this is a plain timer. THE one place that
+    calls TraceAnnotation."""
+
+    __slots__ = ("record", "stage", "version", "inside", "seconds",
+                 "_t0", "_ann")
+
+    def __init__(self, record: "dict | None", stage: str,
+                 version: "int | None" = None, inside: "str | None" = None):
+        self.record = record
+        self.stage = stage
+        self.version = version
+        self.inside = inside
+        self.seconds = 0.0
+        self._ann = None
+
+    def __enter__(self) -> "stage_timer":
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            self._ann = jax.profiler.TraceAnnotation(
+                "fdb:" + self.stage, version=int(self.version or 0))
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = dt = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        rec = self.record
+        if rec is not None:
+            rec[self.stage] = rec.get(self.stage, 0.0) + dt
+            if self.inside is not None:
+                rec[self.inside] = rec.get(self.inside, 0.0) - dt
 
 
 def stage_clock(loop):

@@ -34,6 +34,7 @@ import time
 _SOFT_ERRNOS = (errno.EAGAIN, errno.EINPROGRESS, errno.ENOTCONN, errno.EALREADY)
 
 from foundationdb_tpu.core.errors import FdbError, TransactionTooLarge
+from foundationdb_tpu.obs.span import span_sink, stage_timer
 from foundationdb_tpu.runtime import wire
 from foundationdb_tpu.runtime.flow import (
     BrokenPromise, Future, Loop, Promise, rpc,
@@ -602,7 +603,17 @@ class NetTransport:
     # -- dispatch ---------------------------------------------------------
 
     def _on_frame(self, conn: _Conn, frame: bytes) -> None:
-        kind, msg_id, *rest = wire.loads(frame)
+        sink = span_sink(self.loop)
+        if sink is None:
+            kind, msg_id, *rest = wire.loads(frame)
+        else:
+            # Stage rpc_decode (obs/span.py): decoding one frame, serial
+            # with everything else this process does. Requests only: a
+            # reply's decode belongs to the caller's stages.
+            with stage_timer(None, "rpc_decode") as timed:
+                kind, msg_id, *rest = wire.loads(frame)
+            if kind == _REQ:
+                sink.stage_tick("rpc_decode", timed.seconds)
         if kind == _REQ:
             service, method, args = rest[:3]
             kwargs = rest[3] if len(rest) > 3 else None

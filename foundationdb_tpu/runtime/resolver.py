@@ -11,6 +11,7 @@ all exposing resolve(txns, commit_version, oldest_version) → verdicts.
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 
 from foundationdb_tpu.core.types import (
@@ -18,12 +19,20 @@ from foundationdb_tpu.core.types import (
     TxnConflictInfo,
     Verdict,
 )
-from foundationdb_tpu.obs.span import span_sink, stage_clock
+from foundationdb_tpu.obs.span import (
+    ENGINE_STAGES,
+    span_sink,
+    stage_clock,
+    stage_timer,
+)
 from foundationdb_tpu.repair.hotrange import HotRangeSketch
 from foundationdb_tpu.runtime.flow import Loop, Promise, rpc
 from foundationdb_tpu.runtime.sequencer import MVCC_WINDOW_VERSIONS
 from foundationdb_tpu.runtime.trace import Severity, trace
 from foundationdb_tpu.sched.resolver_queue import ResolveScheduler
+
+
+_NO_SPAN = contextlib.nullcontext()  # a stage with no sink: nothing timed
 
 
 @dataclass
@@ -122,6 +131,10 @@ class Resolver:
         # from the caches.
         self._wave_pending_role: dict[int, dict] = {}
         self._edge_replies: dict[int, tuple] = {}
+        # Stage seconds of the batch inside _serial_entry (obs/span.py
+        # ENGINE_STAGES): the engine fills its stages into this same dict,
+        # the role adds its own. None outside a traced serial batch.
+        self._stage_rec: dict | None = None
         self.wave_batches = 0  # windows resolved via the global protocol
 
     @rpc
@@ -460,9 +473,12 @@ class Resolver:
             # start per batch, txn-weighted so the histograms reconcile
             # against per-txn populations.
             t0 = self.loop.now
-            for entry in group:
-                sink.stage_tick("coalesce_queue", t0 - entry.t_enq,
-                                n=max(1, len(entry.txns)))
+            # The annotation marks the dispatch start on a profiler's
+            # timeline (the queueing itself is in the past by now).
+            with stage_timer(None, "coalesce_queue", group[0].version):
+                for entry in group:
+                    sink.stage_tick("coalesce_queue", t0 - entry.t_enq,
+                                    n=max(1, len(entry.txns)))
         if self.dispatch_cost_s:
             # Modeled device execution time for this window (sim-only;
             # see __init__) — spent BEFORE the verdicts resolve, like the
@@ -485,34 +501,66 @@ class Resolver:
         the sub-stages, cache + deliver the reply. Shared by the serial
         group loop and the speculative path's fallback (reporting batches,
         fail-safe, oversize windows the ring cannot take)."""
-        t_eng = clock() if sink is not None else 0.0
-        if sink is not None and hasattr(self.cs, "last_host_pack_s"):
-            # Clear the stamp so a batch that never packs (fail-safe
-            # rejection, overflow) can't re-record the PREVIOUS
-            # batch's pack time — fail-safe engages exactly under
+        rec = None
+        if sink is not None:
+            # A fresh record per batch, so a batch that never packs
+            # (fail-safe rejection, overflow) can't re-record the
+            # PREVIOUS batch's stages — fail-safe engages exactly under
             # overload, when the attribution is being read.
-            self.cs.last_host_pack_s = None
+            rec = self._stage_rec = {}
+            if hasattr(self.cs, "last_stage_s"):
+                self.cs.last_stage_s = rec
+        t_eng = clock() if sink is not None else 0.0
         try:
-            reply = self._resolve_entry(entry)
+            with (stage_timer(None, "device_dispatch", entry.version)
+                  if sink is not None else _NO_SPAN):
+                reply = self._resolve_entry(entry)
         except BaseException as e:  # noqa: BLE001 — fail the RPC waiter
             self._fail_entry(entry, e)
             return
+        finally:
+            self._stage_rec = None
         if sink is not None:
             n = max(1, len(entry.txns))
+            # Synchronous work has no virtual duration: only a wall-time
+            # loop attributes the bracket's interior, and only there do
+            # the per-batch spans carry real seconds (sim span records
+            # stay byte-identical under a seed).
+            wall = getattr(self.loop, "WALL_TIME", False)
+            version = entry.version if wall else None
             eng_s = (clock() - t_eng) + self.dispatch_cost_s
-            pack_s = getattr(self.cs, "last_host_pack_s", None)
+            pack_s = rec.get("host_pack")
             if pack_s is not None:
                 # DISJOINT attribution: the engine bracket above
                 # includes the synchronous host pack — carve it out
                 # so host_pack + device_dispatch sums to the
                 # interior, never above it.
-                sink.stage_tick("host_pack", pack_s, n=n)
+                sink.stage_tick("host_pack", pack_s, n=n, version=version)
                 eng_s = max(0.0, eng_s - pack_s)
-            # Engine execution (synchronous: perf-clocked on real
-            # loops, 0 virtual seconds in sim by construction) plus
-            # the modeled dispatch cost this batch's share paid.
-            sink.stage_tick("device_dispatch", eng_s, n=n)
+            # The UMBRELLA: the whole engine bracket minus host_pack
+            # (synchronous: perf-clocked on real loops, 0 virtual
+            # seconds in sim by construction) plus the modeled dispatch
+            # cost this batch's share paid.
+            sink.stage_tick("device_dispatch", eng_s, n=n, version=version)
+            if wall:
+                # Its interior, and the residue: the engine identity
+                # (obs/span.py), by arithmetic per batch.
+                for stage in ENGINE_STAGES[1:]:
+                    stage_s = rec.get(stage)
+                    if stage_s is not None:
+                        sink.stage_tick(stage, stage_s, n=n, version=version)
+                        eng_s -= stage_s
+                sink.stage_tick("engine_unattributed", max(0.0, eng_s),
+                                n=n, version=version)
         self._send_entry(entry, reply)
+
+    def _stage(self, name: str, version: int):
+        """A role-side stage of the traced serial batch in hand (seconds
+        into its record, annotation on the profiler's timeline); nothing
+        at all outside one."""
+        rec = self._stage_rec
+        return (stage_timer(rec, name, version) if rec is not None
+                else _NO_SPAN)
 
     def _send_entry(self, entry: _QueuedBatch, reply) -> None:
         self._replies[entry.version] = reply
@@ -568,8 +616,9 @@ class Resolver:
             if oldest is None:
                 oldest = max(0, version - MVCC_WINDOW_VERSIONS)
             t_eng = clock() if sink is not None else 0.0
-            if sink is not None and hasattr(self.cs, "last_host_pack_s"):
-                self.cs.last_host_pack_s = None
+            rec: dict = {}
+            if sink is not None and hasattr(self.cs, "last_stage_s"):
+                self.cs.last_stage_s = rec
             coll = None
             if not self._should_fail_safe(len(txns), version, oldest):
                 try:
@@ -588,7 +637,7 @@ class Resolver:
             if sink is not None:
                 n = max(1, len(txns))
                 eng_s = (clock() - t_eng) + self.dispatch_cost_s
-                pack_s = getattr(self.cs, "last_host_pack_s", None)
+                pack_s = rec.get("host_pack")
                 if pack_s is not None:
                     sink.stage_tick("host_pack", pack_s, n=n)
                     eng_s = max(0.0, eng_s - pack_s)
@@ -691,7 +740,9 @@ class Resolver:
                 # for a rejected batch would skew the attribution
                 # counters below and invite a caller to reorder it.
                 wave = None
-        return self._finish_entry(version, txns, verdicts, fail_safe, wave)
+        with self._stage("resolve_post", version):
+            return self._finish_entry(version, txns, verdicts, fail_safe,
+                                      wave)
 
     def _finish_entry(
         self, version: int, txns: list, verdicts: list[Verdict],
@@ -819,8 +870,11 @@ class Resolver:
         after it until the MVCC floor passes this version."""
         if not hasattr(self.cs, "headroom"):
             return False
-        self._headroom = self.cs.headroom()
-        if not self.cs.overflowed:
+        with self._stage("headroom_sync", version):
+            # Two device reads after the verdicts: a second round trip.
+            self._headroom = self.cs.headroom()
+            overflowed = self.cs.overflowed
+        if not overflowed:
             return False
         self.overflow_events += 1
         self._unsafe_until = version
@@ -917,6 +971,23 @@ class Resolver:
                     self.cs, "reshard_moved_shards", 0),
                 "full_repacks": self._engine_dict_stat("full_repacks"),
                 "evictions": self._engine_dict_stat("evictions"),
+                # WHY the dictionary repacked (the arms of the engine's
+                # need_repack; they sum to full_repacks when no tiered
+                # fallback fires) and the seconds it spent doing so; the
+                # delta's size per dispatch; and the programs the process
+                # compiled, so a compile inside a window is not read as
+                # a stall. Monotonic since boot: read as differences.
+                "repacks_delta_overflow": self._engine_dict_stat(
+                    "repacks_delta_overflow"),
+                "repacks_dict_full": self._engine_dict_stat(
+                    "repacks_dict_full"),
+                "repacks_frag_due": self._engine_dict_stat(
+                    "repacks_frag_due"),
+                "repack_s": self._engine_dict_fstat("repack_s"),
+                "delta_new_keys": self._engine_dict_stat("delta_new_keys"),
+                "dispatches": self._engine_dict_stat("dispatches"),
+                "compiles": self._engine_dict_stat("compiles"),
+                "compile_s": self._engine_dict_fstat("compile_s"),
                 # Tiered-dictionary economics (all zero when tiering is
                 # off — FDB_TPU_DICT_HOT_CAPACITY unset — or the engine
                 # is not resident): obs/doctor's dict_thrash detector

@@ -1,0 +1,338 @@
+"""The resolver's engine bracket, stage by stage (obs/span.py ENGINE_STAGES):
+the per-batch identity on a wall-time loop, every stage sampled, nothing
+recorded with no sink, the counters that say why the dictionary repacked or
+the process compiled, the kernel's named scopes in the lowered program, and
+the rule that obs/span.py never loads JAX by itself.
+"""
+
+import re
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+from foundationdb_tpu.core.types import KeyRange, TxnConflictInfo
+from foundationdb_tpu.models import conflict_kernel as ck
+from foundationdb_tpu.models import conflict_set as cset
+from foundationdb_tpu.models.conflict_set import TPUConflictSet
+from foundationdb_tpu.obs.span import (
+    ENGINE_STAGES,
+    SUB_STAGES,
+    SpanSink,
+    stage_timer,
+)
+from foundationdb_tpu.runtime.flow import Loop
+from foundationdb_tpu.runtime.net import NetTransport, RealLoop, rpc
+from foundationdb_tpu.runtime.resolver import Resolver
+
+INTERIOR = ENGINE_STAGES[1:] + ("engine_unattributed",)
+BATCH = 128
+STEP = 1000  # versions a batch
+ENGINE_COUNTERS = (
+    "repacks_delta_overflow", "repacks_dict_full", "repacks_frag_due",
+    "repack_s", "delta_new_keys", "dispatches", "compiles", "compile_s")
+
+
+def point_txns(keys, read_version):
+    return [TxnConflictInfo(read_version=read_version,
+                            read_ranges=[KeyRange(k, k + b"\x00")],
+                            write_ranges=[KeyRange(k, k + b"\x00")])
+            for k in keys]
+
+
+def small_engine(**kw):
+    # A dictionary of 2,048 keys takes 256 new endpoint keys a batch: it
+    # fills, and repacks (cause: dict_full), within the 16 batches.
+    args = dict(capacity=1 << 13, dict_capacity=1 << 11, batch_size=BATCH)
+    args.update(kw)
+    return TPUConflictSet(**args)
+
+
+def drive(loop, resolver, n_batches, seed=7, history_batches=3):
+    """`n_batches` of BATCH never-seen keys through `resolver`, the MVCC
+    floor `history_batches` behind: old keys die, so a repack finds room."""
+    rng = np.random.default_rng(seed)
+
+    async def main():
+        prev = 0
+        for b in range(n_batches):
+            v = (b + 1) * STEP
+            keys = [b"k%09d" % x for x in rng.integers(0, 1 << 30, BATCH)]
+            await resolver.resolve(prev, v, point_txns(keys, max(0, v - STEP)),
+                                   max(0, v - history_batches * STEP))
+            prev = v
+        return await resolver.get_metrics()
+
+    return loop.run(main(), timeout=300)
+
+
+def spans_by_version(sink):
+    out: dict = {}
+    for s in sink.spans:
+        if s.get("version") is not None:
+            stage = out.setdefault(s["version"], {})
+            stage[s["name"]] = stage.get(s["name"], 0.0) + s["dur"]
+    return out
+
+
+@pytest.fixture(scope="module")
+def traced():
+    """16 batches through a Resolver on the resident engine, on a
+    wall-time loop with a sink that records every tick."""
+    loop = RealLoop()
+    sink = SpanSink(loop, sample_every=1)
+    cs = small_engine()
+    cs.warm_up()
+    resolver = Resolver(loop, cs)
+    metrics = drive(loop, resolver, 16)
+    return sink, metrics, cs
+
+
+class TestEngineIdentity:
+    def test_identity_holds_per_batch(self, traced):
+        sink, _m, _cs = traced
+        batches = spans_by_version(sink)
+        assert len(batches) == 16
+        for version, d in batches.items():
+            bracket = d["host_pack"] + d["device_dispatch"]
+            parts = sum(d.get(s, 0.0) for s in ENGINE_STAGES) \
+                + d["engine_unattributed"]
+            assert abs(bracket - parts) < 1e-6, (version, d)
+
+    def test_unattributed_is_small(self, traced):
+        """Under 5 % of the bracket in the median batch: on a loaded
+        runner one preempted batch can hold more than every other
+        batch's residue together, so the sum would test the scheduler."""
+        sink, _m, _cs = traced
+        shares = sorted(
+            d["engine_unattributed"] / (d["host_pack"] + d["device_dispatch"])
+            for d in spans_by_version(sink).values())
+        assert 0.0 <= shares[0] and shares[len(shares) // 2] < 0.05, shares
+
+    @pytest.mark.parametrize("stage", INTERIOR)
+    def test_every_stage_has_a_sample(self, traced, stage):
+        sink, _m, _cs = traced
+        assert stage in SUB_STAGES
+        assert stage in sink.stage_hists, sorted(sink.stage_hists)
+        assert sink.stage_hists[stage].count > 0
+
+    def test_repack_seconds_are_the_dict_repack_stage(self, traced):
+        sink, metrics, _cs = traced
+        eng = metrics["engine"]
+        assert eng["full_repacks"] >= 1
+        by_stage = sum(d.get("dict_repack", 0.0)
+                       for d in spans_by_version(sink).values())
+        assert eng["repack_s"] == pytest.approx(by_stage, rel=1e-3, abs=1e-6)
+
+    @pytest.mark.parametrize("name", ENGINE_COUNTERS)
+    def test_get_metrics_carries_the_counter(self, traced, name):
+        _sink, metrics, _cs = traced
+        assert name in metrics["engine"]
+        assert metrics["engine"][name] >= 0
+        if name in ("dispatches", "delta_new_keys", "compiles"):
+            assert metrics["engine"][name] > 0
+
+    def test_nothing_recorded_with_no_sink(self):
+        loop = RealLoop()
+        cs = small_engine()
+        resolver = Resolver(loop, cs)
+        drive(loop, resolver, 3)
+        assert not hasattr(loop, "span_sink")
+        assert resolver._stage_rec is None
+        # The engine's own always-on record holds engine stages only.
+        assert "headroom_sync" not in cs.last_stage_s
+        assert cs.last_stage_s["engine_enqueue"] > 0.0
+
+    def test_sim_loop_keeps_the_interior_out(self):
+        """Synchronous work is 0 virtual seconds: a sim loop records the
+        umbrella (and host_pack), never wall-clock interior stages, so
+        span records stay seed-deterministic."""
+        loop = Loop(seed=3)
+        sink = SpanSink(loop, sample_every=1)
+        resolver = Resolver(loop, small_engine())
+        drive(loop, resolver, 3)
+        assert "device_dispatch" in sink.stage_hists
+        assert not set(INTERIOR) & set(sink.stage_hists)
+        assert all(s.get("version") is None for s in sink.spans)
+
+
+# -- why the dictionary repacked ---------------------------------------------
+
+
+def resolve_new_keys(cs, version, n_keys, oldest=0, tag=b"a"):
+    keys = [tag + b"%08d.%06d" % (version, i) for i in range(n_keys)]
+    cs.resolve(point_txns(keys, max(0, version - 1)), version, oldest)
+
+
+def repack_causes(cs):
+    st = cs.dict_stats
+    return {k: st[k] for k in ("repacks_delta_overflow", "repacks_dict_full",
+                               "repacks_frag_due", "full_repacks")}
+
+
+class TestRepackCauses:
+    def test_delta_overflow(self):
+        # 64 point transactions bring 128 new endpoint keys: over the 64
+        # delta slots, far under the dictionary's capacity.
+        cs = TPUConflictSet(capacity=1 << 12, dict_capacity=1 << 12,
+                            dict_delta_slots=64, batch_size=64)
+        resolve_new_keys(cs, 1000, 64)
+        assert repack_causes(cs) == {
+            "repacks_delta_overflow": 1, "repacks_dict_full": 0,
+            "repacks_frag_due": 0, "full_repacks": 1}
+
+    def test_dict_full(self):
+        cs = TPUConflictSet(capacity=1 << 12, dict_capacity=256,
+                            dict_delta_slots=128, batch_size=32)
+        for b in range(1, 4):  # 64 new keys a batch; live history: 1 batch
+            resolve_new_keys(cs, b * 1000, 32, oldest=(b - 1) * 1000)
+        assert repack_causes(cs) == {"repacks_delta_overflow": 0,
+                                     "repacks_dict_full": 0,
+                                     "repacks_frag_due": 0, "full_repacks": 0}
+        resolve_new_keys(cs, 4000, 32, oldest=3000)  # 193 + 64 > 256
+        assert repack_causes(cs) == {
+            "repacks_delta_overflow": 0, "repacks_dict_full": 1,
+            "repacks_frag_due": 0, "full_repacks": 1}
+
+    def test_frag_due(self):
+        cs = TPUConflictSet(capacity=1 << 12, dict_capacity=256,
+                            dict_delta_slots=128, batch_size=32)
+        for b in range(1, 4):  # 193 keys: over half of 256, all in use
+            resolve_new_keys(cs, b * 1000, 32, oldest=0)
+        assert cs.dict_stats["full_repacks"] == 0
+        # The floor passes every key's last use: mostly full AND mostly
+        # stale, with room for this batch's 2 new keys.
+        resolve_new_keys(cs, 9000, 1, oldest=8000)
+        assert repack_causes(cs) == {
+            "repacks_delta_overflow": 0, "repacks_dict_full": 0,
+            "repacks_frag_due": 1, "full_repacks": 1}
+
+
+def test_compiles_rise_on_a_first_call_and_not_on_the_next():
+    import jax
+    import jax.numpy as jnp
+
+    cset.count_compiles()
+    fn = jax.jit(lambda x: x * 3 + 1)
+    x = jnp.arange(37, dtype=jnp.int32)  # a shape nothing else uses
+    before = dict(cset._COMPILE_STATS)
+    fn(x).block_until_ready()
+    first = dict(cset._COMPILE_STATS)
+    fn(x).block_until_ready()
+    second = dict(cset._COMPILE_STATS)
+    assert first["compiles"] == before["compiles"] + 1
+    assert first["compile_s"] > before["compile_s"]
+    assert second == first
+
+
+# -- the kernel's phases by name ---------------------------------------------
+
+
+def lowered_scopes(jitted, *args) -> set:
+    text = jitted.lower(*args).as_text(debug_info=True)
+    return {part for path in re.findall(r'loc\("(jit\([^"]*)"', text)
+            for part in path.split("/")}
+
+
+@pytest.fixture(scope="module")
+def resident_args():
+    from foundationdb_tpu.core.keypack import INT32_MAX
+
+    cs = TPUConflictSet(capacity=1 << 10, dict_capacity=1 << 10,
+                        batch_size=16)
+    bt = cs._empty_batch()
+    flat, dims = cs._flat_endpoints(bt)
+    empty = cs._ranks_to_batch(
+        bt, np.full(len(flat), INT32_MAX, np.int32), dims, flat[:0])
+    return cs, empty
+
+
+@pytest.mark.parametrize("scope", [
+    "dict_insert", "hist_merge", "history_probe", "endpoint_ranks",
+    "accept", "paint_compact", "verdicts"])
+def test_resolve_program_names_its_phases(resident_args, scope):
+    cs, empty = resident_args
+    zero = np.int32(0)
+    assert scope in lowered_scopes(ck._resolve_res_jit, cs.state, empty,
+                                   zero, zero)
+
+
+@pytest.mark.parametrize("scope,entry", [("dict_evict", "_evict_res_jit"),
+                                         ("dict_remap", "_repack_res_jit")])
+def test_dictionary_upkeep_programs_name_their_phase(resident_args, scope,
+                                                     entry):
+    cs, _empty = resident_args
+    mir = cs._mirror
+    if entry == "_evict_res_jit":
+        args = (cs.state, np.full(8, np.iinfo(np.int32).max, np.int32))
+    else:
+        args = (cs.state,
+                np.zeros((mir.capacity + 1, mir.rows.shape[1]), np.int32),
+                np.int32(mir.n), np.arange(mir.capacity + 1, dtype=np.int32))
+    assert scope in lowered_scopes(getattr(ck, entry), *args)
+
+
+# -- the helper, and the processes that must never load JAX ------------------
+
+
+def test_stage_timer_accumulates_and_carves_out_nested_stages():
+    rec: dict = {}
+    with stage_timer(rec, "outer", 7):
+        with stage_timer(rec, "inner", 7, inside="outer") as t:
+            pass
+    with stage_timer(rec, "outer", 7) as again:
+        pass
+    assert rec["inner"] == t.seconds
+    assert rec["outer"] >= again.seconds
+    assert rec["outer"] >= 0.0
+    with stage_timer(None, "timed_only") as t2:  # no record: a plain timer
+        pass
+    assert t2.seconds >= 0.0
+
+
+def test_client_and_span_module_do_not_load_jax():
+    code = (
+        "import sys\n"
+        "import foundationdb_tpu.client\n"
+        "from foundationdb_tpu.obs.span import stage_timer\n"
+        "rec = {}\n"
+        "with stage_timer(rec, 'dict_rank', 5):\n"
+        "    pass\n"
+        "assert rec['dict_rank'] >= 0.0\n"
+        "assert 'jax' not in sys.modules, 'jax was loaded'\n"
+        "print('clean')\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == "clean"
+
+
+class Echo:
+    @rpc
+    async def echo(self, x):
+        return x
+
+
+@pytest.mark.parametrize("with_sink", [True, False])
+def test_rpc_decode_is_ticked_per_request_frame_only_with_a_sink(with_sink):
+    loop = RealLoop()
+    sink = SpanSink(loop, sample_every=1) if with_sink else None
+    server, client = NetTransport(loop), NetTransport(loop)
+    server.serve("echo", Echo())
+    ep = client.endpoint(server.addr, "echo")
+
+    async def main():
+        for i in range(5):
+            assert await ep.echo([i, b"x" * 64]) == [i, b"x" * 64]
+
+    try:
+        loop.run(main(), timeout=30)
+    finally:
+        server.close()
+        client.close()
+    if with_sink:
+        # Five request frames; the five replies are not requests.
+        assert sink.stage_hists["rpc_decode"].count == 5
+    else:
+        assert not hasattr(loop, "span_sink")

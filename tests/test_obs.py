@@ -161,7 +161,8 @@ class TestSimClusterTracing:
         txns = [TxnConflictInfo(read_version=0,
                                 read_ranges=[KeyRange(b"a", b"b")],
                                 write_ranges=[KeyRange(b"a", b"b")])]
-        cs.last_host_pack_s = 0.005  # stale stamp from a previous batch
+        # a stale record from a previous batch
+        cs.last_stage_s = {"host_pack": 0.005}
         loop.run(r.resolve(0, 10, txns), timeout=60)
         assert "host_pack" not in sink.stage_hists  # cleared, not reused
 
