@@ -1981,29 +1981,57 @@ def _dict_insert(dict_keys, n_keys, delta_keys):
 
     Returns (new_dict_keys, new_n_keys, shift) where shift[r] = how many
     inserted keys precede old rank r — the rank-rebase table: an existing
-    rank r becomes r + shift[r]. Same scatter-free merge-path construction
-    as _paint_tail; the host guarantees fit (n_keys + m <= capacity), and
-    real delta rows are disjoint from resident keys by construction."""
+    rank r becomes r + shift[r]. The host guarantees fit (n_keys + m <=
+    capacity), and real delta rows are disjoint from resident keys by
+    construction.
+
+    Costs what the delta costs: the only search is the delta's M queries
+    into the dictionary; the tables over the dictionary's rows are a
+    histogram and a prefix sum, and the rows move by streaming shifts —
+    nothing searches or gathers once per dictionary row."""
     d1, w = dict_keys.shape
     m_cap = delta_keys.shape[0]
-    # 'left' of dict rows into the delta: for a real dict key, the count
-    # of real delta keys strictly below it (delta +inf padding never
-    # counts); for dict +inf padding rows, exactly m — both correct.
-    shift = searchsorted_words_fp(delta_keys, dict_keys, side="left")
     # 'right' of delta rows into the dict: real delta keys (distinct from
     # every resident key) count the resident keys below; delta +inf rows
-    # count ALL d1 rows, pushing their merge position past the output
-    # window so only real rows ever land.
+    # count ALL d1 rows, which puts them outside the histogram's bins and
+    # their merge position past the output window, so only real rows land.
     cross = searchsorted_words_fp(dict_keys, delta_keys, side="right")
     pos_d = jnp.arange(m_cap, dtype=jnp.int32) + cross
-    idx = jnp.arange(d1, dtype=jnp.int32)
-    cnt_le = jnp.searchsorted(pos_d, idx, side="right").astype(jnp.int32)
-    k_new = jnp.maximum(cnt_le - 1, 0)
-    from_new = (cnt_le > 0) & (pos_d[k_new] == idx)
-    old_idx = jnp.clip(idx - cnt_le, 0, d1 - 1)
-    out = jnp.where(from_new[:, None], delta_keys[k_new], dict_keys[old_idx])
     m = jnp.sum(
         (~jnp.all(delta_keys == INT32_MAX, axis=-1)).astype(jnp.int32)
+    )
+    # A delta key is strictly below resident row r exactly when its
+    # cross <= r, so shift is the running count of a histogram of cross.
+    # The dict's +inf padding rows read exactly m.
+    shift = jnp.cumsum(
+        jnp.zeros(d1, jnp.int32).at[cross].add(
+            1, mode="drop", indices_are_sorted=True
+        )
+    )
+    # Old row r moves up to r + shift[r]. shift is nondecreasing, so the
+    # rows keep their order after every binary digit of it, high to low,
+    # and no two ever meet: each stage is one streaming pass that moves
+    # the rows whose remaining shift has that bit up by 2**b, the shift
+    # riding along as a last column. A vacated slot is zeroed so it stays
+    # put; those holes end exactly at the delta's merge positions pos_d.
+    # Only the bits of m run.
+    rows = jnp.concatenate([dict_keys, shift[:, None]], axis=1)
+
+    def stage(rows, k):
+        below = jnp.concatenate(
+            [jnp.zeros((k, w + 1), jnp.int32), rows[: d1 - k]]
+        )
+        arrives = (below[:, w:] & k) != 0
+        leaves = (rows[:, w:] & k) != 0
+        return jnp.where(arrives, below, jnp.where(leaves, 0, rows))
+
+    for b in reversed(range(min(m_cap, d1 - 1).bit_length())):
+        k = 1 << b
+        rows = jax.lax.cond(
+            m >= k, functools.partial(stage, k=k), lambda rows: rows, rows
+        )
+    out = rows[:, :w].at[pos_d].set(
+        delta_keys, mode="drop", indices_are_sorted=True, unique_indices=True
     )
     return out, n_keys + m, shift
 
@@ -2065,7 +2093,7 @@ def _dict_evict(dict_keys, n_keys, evict_ranks):
     host guarantees no evicted rank is referenced by device history or
     shard bounds (exact-liveness selection), so the off-by-one a demoted
     rank itself would take through the table is never observed. Same
-    scatter-free merge-path construction as _dict_insert: kept row j
+    scatter-free merge-path construction as _paint_tail: kept row j
     reads source j + t where t = |{i : e_i - i <= j}| (e_i - i is
     nondecreasing for strictly increasing e_i)."""
     d1, _w = dict_keys.shape

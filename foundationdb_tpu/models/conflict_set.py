@@ -278,6 +278,11 @@ class _ResidentMirror:
             "endpoint_hits": 0,
             "unique_keys": 0,
             "delta_new_keys": 0,
+            # Dispatches whose DEVICE delta carried no key (none was new,
+            # or a full repack took the new keys in with it): apply_delta's
+            # lax.cond skips the merge on exactly these, so the share of
+            # dispatches that pay for it is 1 - this / dispatches.
+            "delta_empty_dispatches": 0,
             "evictions": 0,
             "full_repacks": 0,
             # Why a full repack was decided (the arms of _pack_resident's
@@ -1052,6 +1057,7 @@ class TPUConflictSet:
             )
             st["unique_keys"] += m + uniq_found
             st["delta_new_keys"] += m
+            st["delta_empty_dispatches"] += int(m == 0)
         self._note_write_fps(qu, is_pad, dims)
         return self._ranks_to_batch(bt, ranks, dims, new_rows)
 
@@ -1158,6 +1164,7 @@ class TPUConflictSet:
                 st["endpoint_hits"] += int(found.sum())
                 st["unique_keys"] += m + int(np.unique(pos[found]).size)
                 st["delta_new_keys"] += m
+                st["delta_empty_dispatches"] += 1
 
                 # Ranks against the rebuilt mirror; the delta already rode
                 # in with the repack, so the device delta is empty.

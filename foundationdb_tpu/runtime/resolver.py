@@ -974,9 +974,12 @@ class Resolver:
                 # WHY the dictionary repacked (the arms of the engine's
                 # need_repack; they sum to full_repacks when no tiered
                 # fallback fires) and the seconds it spent doing so; the
-                # delta's size per dispatch; and the programs the process
-                # compiled, so a compile inside a window is not read as
-                # a stall. Monotonic since boot: read as differences.
+                # delta's size per dispatch, and the dispatches whose
+                # device delta was empty (no new key, or a repack took
+                # them in), on which the kernel skips dict_insert; and
+                # the programs the process compiled, so a compile inside
+                # a window is not read as a stall. Monotonic since boot:
+                # read as differences.
                 "repacks_delta_overflow": self._engine_dict_stat(
                     "repacks_delta_overflow"),
                 "repacks_dict_full": self._engine_dict_stat(
@@ -986,6 +989,8 @@ class Resolver:
                 "repack_s": self._engine_dict_fstat("repack_s"),
                 "delta_new_keys": self._engine_dict_stat("delta_new_keys"),
                 "dispatches": self._engine_dict_stat("dispatches"),
+                "delta_empty_dispatches": self._engine_dict_stat(
+                    "delta_empty_dispatches"),
                 "compiles": self._engine_dict_stat("compiles"),
                 "compile_s": self._engine_dict_fstat("compile_s"),
                 # Tiered-dictionary economics (all zero when tiering is

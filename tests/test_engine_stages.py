@@ -31,7 +31,8 @@ BATCH = 128
 STEP = 1000  # versions a batch
 ENGINE_COUNTERS = (
     "repacks_delta_overflow", "repacks_dict_full", "repacks_frag_due",
-    "repack_s", "delta_new_keys", "dispatches", "compiles", "compile_s")
+    "repack_s", "delta_new_keys", "dispatches", "delta_empty_dispatches",
+    "compiles", "compile_s")
 
 
 def point_txns(keys, read_version):
@@ -209,6 +210,29 @@ class TestRepackCauses:
             "repacks_frag_due": 1, "full_repacks": 1}
 
 
+def test_delta_empty_dispatches_counts_the_skipped_merges():
+    """A dispatch whose device delta is empty (every key resident, or the
+    new keys rode in with a full repack) skips dict_insert; one that ships
+    a new key pays for it."""
+    cs = TPUConflictSet(capacity=1 << 12, dict_capacity=1 << 12,
+                        dict_delta_slots=64, batch_size=64)
+
+    def counts():
+        st = cs.dict_stats
+        return (st["dispatches"], st["delta_empty_dispatches"],
+                st["full_repacks"])
+
+    keys = [b"k%04d" % i for i in range(8)]
+    cs.resolve(point_txns(keys, 999), 1000, 0)  # 16 new endpoints: a merge
+    assert counts() == (1, 0, 0)
+    cs.resolve(point_txns(keys, 1999), 2000, 0)  # the same keys: none new
+    assert counts() == (2, 1, 0)
+    resolve_new_keys(cs, 3000, 64)  # 128 new keys over 64 slots: a repack
+    assert counts() == (3, 2, 1)
+    resolve_new_keys(cs, 4000, 1)
+    assert counts() == (4, 2, 1)
+
+
 def test_compiles_rise_on_a_first_call_and_not_on_the_next():
     import jax
     import jax.numpy as jnp
@@ -271,6 +295,28 @@ def test_dictionary_upkeep_programs_name_their_phase(resident_args, scope,
                 np.zeros((mir.capacity + 1, mir.rows.shape[1]), np.int32),
                 np.int32(mir.n), np.arange(mir.capacity + 1, dtype=np.int32))
     assert scope in lowered_scopes(getattr(ck, entry), *args)
+
+
+def test_dict_insert_searches_and_gathers_by_the_delta_not_the_dictionary(
+        resident_args):
+    """The structural rule of the delta merge, read off the lowered
+    program: nothing indexes the dictionary row by row. 1,025 rows is the
+    dictionary's alone in this program (history 1,024, delta 512), so any
+    gather that takes 1,025 indices, and any loop that carries more than
+    the searched column at that length (a binary search's bounds), is a
+    per-dictionary-row search or row gather, whatever scope it sits in."""
+    cs, empty = resident_args
+    zero = np.int32(0)
+    d1 = cs.state.dict_keys.shape[0]
+    assert d1 not in (cs.capacity, empty.delta_keys.shape[0])
+    text = ck._resolve_res_jit.lower(cs.state, empty, zero, zero).as_text()
+    assert f"tensor<{d1}x" in text  # the dictionary is in the program
+    gathers = re.findall(
+        r'"stablehlo\.gather"[^\n]* : \(tensor<[^>]*>, tensor<(\d+)[x>]', text)
+    assert gathers and str(d1) not in gathers
+    loops = re.findall(r"stablehlo\.while\([^\n]*\) : ([^\n]*)", text)
+    assert loops
+    assert max(carry.count(f"tensor<{d1}xi32>") for carry in loops) <= 1
 
 
 # -- the helper, and the processes that must never load JAX ------------------
