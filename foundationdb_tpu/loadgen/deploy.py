@@ -90,8 +90,12 @@ def build_spec(proxies: int = 2, tlogs: int = 1, storages: int = 1,
                resolvers: int = 1, ratekeeper: bool = True,
                engine: str = "cpu", extra: "dict | None" = None,
                managed: bool = False,
-               ports: "list[int] | None" = None) -> dict:
+               ports: "list[int] | None" = None,
+               resolver_splits: "list[bytes] | None" = None) -> dict:
     """A cluster spec dict with fresh localhost ports (server.py shape).
+    ``resolver_splits``: the resolvers - 1 keys at which the resolvers'
+    ranges part (server.resolver_shard_map; hex in the spec); None leaves
+    the split to KeyShardMap.uniform, which goes by first byte.
     ``managed=True`` adds a controller process — chain-role failures then
     heal with a generation change instead of needing a full bounce.
     ``ports``: pre-allocated port list (callers that need MORE ports —
@@ -111,6 +115,8 @@ def build_spec(proxies: int = 2, tlogs: int = 1, storages: int = 1,
     }
     if managed:
         spec["controller"] = [f"127.0.0.1:{next(ports)}"]
+    if resolver_splits is not None:
+        spec["resolver_splits"] = [k.hex() for k in resolver_splits]
     if extra:
         spec.update(extra)
     return spec
@@ -156,7 +162,8 @@ class SocketCluster:
                  env: "dict | None" = None,
                  managed: bool = False,
                  data_dirs: bool = False,
-                 relay_roles: tuple = ()):
+                 relay_roles: tuple = (),
+                 resolver_splits: "list[bytes] | None" = None):
         os.makedirs(workdir, exist_ok=True)
         self.workdir = workdir
         self.managed = managed
@@ -175,7 +182,8 @@ class SocketCluster:
         self._bind_ports = iter(ports[n_spec:])
         self.spec = build_spec(proxies, tlogs, storages, resolvers,
                                ratekeeper, engine, spec_extra, managed,
-                               ports=ports[:n_spec])
+                               ports=ports[:n_spec],
+                               resolver_splits=resolver_splits)
         self.spec_path = os.path.join(workdir, "cluster.json")
         with open(self.spec_path, "w") as f:
             json.dump(self.spec, f)

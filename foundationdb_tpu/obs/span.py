@@ -57,6 +57,10 @@ reconciliation identity):
                   (request + reply transport legs)
 
     grv_proxy_queue   GRV proxy: request arrival -> batch admit
+    resolve_straggle  proxy: a batch's last resolver reply minus its
+                      first — what the fan-out to several resolvers
+                      adds to resolve_wait (0 with one resolver);
+                      recorded in the proxy's own sink, txn-weighted
     rpc_decode        transport: ``wire.loads`` of one request frame
                       (NetTransport._on_frame; n = 1 per frame, only
                       while a sink is on)
@@ -150,6 +154,7 @@ TXN_STAGES = (
 #: the identity — they live within grv_wait / resolve_wait / tlog_durable).
 SUB_STAGES = (
     "grv_proxy_queue",
+    "resolve_straggle",
     "rpc_decode",
     "coalesce_queue",
     "host_pack",

@@ -721,17 +721,28 @@ class CommitProxy:
             return await self._resolve_wave_global(
                 per_resolver, prev_version, version
             )
+        replied_at: list[float] = []
+
+        async def ask(r, txns):
+            reply = await self._with_retry(
+                lambda: r.resolve(prev_version, version, txns)
+            )
+            replied_at.append(self.loop.now)
+            return reply
+
         replies = await all_of(
             [
-                self.loop.spawn(
-                    self._with_retry(
-                        lambda r=r, txns=txns: r.resolve(prev_version, version, txns)
-                    ),
-                    name=f"resolve@{version}",
-                )
+                self.loop.spawn(ask(r, txns), name=f"resolve@{version}")
                 for r, txns in zip(self.resolvers, per_resolver)
             ]
         )
+        sink = span_sink(self.loop)
+        if sink is not None:
+            # The batch waited for the slowest resolver: how far behind
+            # the first reply the last one landed (0 with one resolver).
+            sink.stage_tick("resolve_straggle",
+                            max(replied_at) - min(replied_at),
+                            n=len(batch), version=version)
         combined: list[Verdict] = []
         conflicting: dict[int, list[tuple[bytes, bytes]]] = {}
         # Any shard in fail-safe taints the whole batch's conflict stats:

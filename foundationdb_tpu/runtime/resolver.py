@@ -79,6 +79,12 @@ class Resolver:
         self.sched.attach(self._dispatch_group)
         self.batches_resolved = 0
         self.txns_resolved = 0
+        # What this resolver was SENT: conflict ranges (read and write,
+        # after the proxy's clip to this resolver's shard) and the txns
+        # that brought it any. With several resolvers every one gets
+        # every batch, so only these say whether the key split is even.
+        self.ranges_received = 0
+        self.txns_with_ranges = 0
         # Wave-commit accounting (engines publishing last_wave, i.e. the
         # reorder-don't-abort kernel/oracle): txns committed at a
         # non-zero wave serialized AFTER at least one same-window
@@ -381,6 +387,15 @@ class Resolver:
         self._advance_chain(version)
         return reply
 
+    def _count_resolved(self, txns: list[TxnConflictInfo]) -> None:
+        self.batches_resolved += 1
+        self.txns_resolved += len(txns)
+        for t in txns:
+            n = len(t.read_ranges) + len(t.write_ranges)
+            if n:
+                self.ranges_received += n
+                self.txns_with_ranges += 1
+
     def _advance_chain(self, version: int) -> None:
         self._version = version
         w = self._waiters.pop(version, None)
@@ -449,8 +464,7 @@ class Resolver:
                 1 for lv in wave if lv == WAVE_LEVEL_CYCLE
             )
             self.wave_batches += 1
-        self.batches_resolved += 1
-        self.txns_resolved += len(txns)
+        self._count_resolved(txns)
         return (verdicts, conflicting, fail_safe, wave)
 
     async def _dispatch_group(self, group: list[_QueuedBatch]) -> None:
@@ -804,8 +818,7 @@ class Resolver:
             self.txns_cycle_aborted += sum(
                 1 for lv in wave if lv == WAVE_LEVEL_CYCLE
             )
-        self.batches_resolved += 1
-        self.txns_resolved += len(txns)
+        self._count_resolved(txns)
         return (verdicts, conflicting, fail_safe, wave)
 
     # -- history-capacity fail-safe -----------------------------------------
@@ -909,6 +922,8 @@ class Resolver:
         return {
             "batches_resolved": self.batches_resolved,
             "txns_resolved": self.txns_resolved,
+            "ranges_received": self.ranges_received,
+            "txns_with_ranges": self.txns_with_ranges,
             "version": self._version,
             "fail_safe_active": self._fail_safe_on
             or self._unsafe_until is not None,

@@ -29,9 +29,14 @@ failure/recovery testing; this is the deployment data plane):
   single-connection clients (the native C client) need only one address.
 - storage[i] has tag i and pulls from tlog[i % n_tlogs]; commit proxies
   push every batch to every tlog (replicated logs, as the sim does).
-- shard maps are derived deterministically from the spec
-  (KeyShardMap.uniform over the storage/resolver counts), so every process
-  and client agrees without a metadata service.
+- shard maps are derived deterministically from the spec, so every process
+  and client agrees without a metadata service: `storage_shard_map` is
+  KeyShardMap.uniform over the storage count, `resolver_shard_map` the
+  spec's own `resolver_splits` (N-1 sorted keys, hex) where it states
+  them and KeyShardMap.uniform over the resolver count where it does not.
+  `uniform` splits by FIRST BYTE (0x40, 0x80, 0xC0 for four): even for
+  keys spread over the byte range, and one resolver's work for a key set
+  that shares a prefix — such a deployment states its splits.
 
 Service names are unindexed ("sequencer", "tlog", ...): the address
 already identifies the instance. The ReadRouter is also served under the
@@ -48,7 +53,7 @@ import sys
 from foundationdb_tpu.runtime.flow import ActorCancelled, BrokenPromise, rpc
 from foundationdb_tpu.runtime.net import NetTransport, RealLoop
 from foundationdb_tpu.core.errors import FutureVersion
-from foundationdb_tpu.runtime.shardmap import KeyShardMap, ring_teams
+from foundationdb_tpu.runtime.shardmap import MAX_KEY, KeyShardMap, ring_teams
 
 ROLES = ("sequencer", "resolver", "tlog", "storage", "proxy", "ratekeeper",
          "controller", "satellite_tlog")
@@ -61,6 +66,7 @@ def load_spec(path: str) -> dict:
         if not spec.get(role):
             raise ValueError(f"cluster spec missing role {role!r}")
     _validate_regions(spec)
+    resolver_shard_map(spec)  # bad resolver_splits fail every boot
     # Resolve key-material paths against the cluster file's directory at
     # LOAD time (the one choke point every entry point — server, cli,
     # dr_tool, tests — goes through), so consumers never depend on cwd.
@@ -160,6 +166,50 @@ def storage_shard_map(spec: dict) -> "KeyShardMap":
     n = len(spec["storage"])
     return KeyShardMap.uniform(
         n, teams=ring_teams(n, int(spec.get("replicas", 1))))
+
+
+def resolver_shard_map(spec: dict,
+                       n_live: "int | None" = None) -> "KeyShardMap":
+    """THE deployed resolver map: resolver i checks the conflict ranges
+    that fall in shard i. One definition for every deployed consumer (the
+    static wiring, a managed worker's recruited proxy), as with
+    storage_shard_map: proxies that split differently would send a read
+    and the write it conflicts with to different resolvers.
+
+    Where the spec states `resolver_splits` — N-1 strictly ascending
+    keys, hex in the JSON, for its N resolvers — those are the bounds
+    (reference: the resolver key ranges the master keeps, and moves with
+    resolutionBalancing until the load is even; here the spec states
+    where they lie). Where it states none, KeyShardMap.uniform: by first
+    byte, which is even only for keys spread over the byte range. Fixed
+    for the life of a generation either way: a resolver's history resets
+    with the generation, so only then can a bound move without parting a
+    read from the writes it must be checked against.
+
+    ``n_live``: the resolvers of the generation being formed (managed
+    mode recruits the live ones). With fewer than the spec's N the
+    stated ranges are merged with their neighbours, evenly."""
+    n = len(spec["resolver"])
+    n_live = n if n_live is None else n_live
+    splits = spec.get("resolver_splits")
+    if splits is None:
+        return KeyShardMap.uniform(n_live)
+    try:
+        keys = [bytes.fromhex(s) for s in splits]
+    except (TypeError, ValueError):
+        raise ValueError(
+            f"resolver_splits must be hex strings, got {splits!r}") from None
+    if len(keys) != n - 1:
+        raise ValueError(
+            f"resolver_splits has {len(keys)} keys; {n} resolvers need "
+            f"{n - 1}")
+    if any(not a < b for a, b in zip([b""] + keys, keys + [MAX_KEY])):
+        raise ValueError(
+            "resolver_splits must be strictly ascending keys inside "
+            f"the keyspace, got {splits!r}")
+    if n_live != n:
+        keys = [keys[(j * n) // n_live - 1] for j in range(1, n_live)]
+    return KeyShardMap(keys, tags=list(range(len(keys) + 1)))
 
 
 def _system_token(spec: dict) -> str | None:
@@ -726,7 +776,7 @@ class Worker:
 
         proxy = CommitProxy(
             self.loop, seq_ep, resolver_eps,
-            KeyShardMap.uniform(len(resolver_eps)), tlog_eps,
+            resolver_shard_map(self.spec, len(resolver_eps)), tlog_eps,
             storage_map,
             controller_ep=controller_ep, epoch=epoch,
             authz=_make_authz(self.spec),
@@ -1688,7 +1738,7 @@ def build_role(loop: RealLoop, t: NetTransport, spec: dict, role: str,
     seq_addr = parse_addr(spec["sequencer"][0])
     n_storages = len(spec["storage"])
     n_tlogs = len(spec["tlog"])
-    resolver_map = KeyShardMap.uniform(len(spec["resolver"]))
+    resolver_map = resolver_shard_map(spec)
     storage_map = storage_shard_map(spec)
 
     def eps(role_name: str, service: str | None = None):
