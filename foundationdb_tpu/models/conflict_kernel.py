@@ -131,8 +131,9 @@ _WAVE_COMMIT = _env_choice("FDB_TPU_WAVE_COMMIT", "0", ("0", "1")) == "1"
 # repack baseline — scripts/resident_ab.sh A/Bs the two). Under resident
 # mode the endpoint-key dictionary AND the MVCC history PERSIST in device
 # memory across dispatches: the host ships only the DELTA of
-# never-before-seen endpoint keys per dispatch (merged on-device by
-# _dict_insert, with a rank-rebase that shifts existing history ranks
+# never-before-seen endpoint keys per dispatch, each with the rank its
+# mirror found for it (merged on-device by _dict_insert, which therefore
+# searches nothing, with a rank-rebase that shifts existing history ranks
 # past the inserted positions), and the history itself lives in RANK
 # SPACE — width-1 int32 rank rows instead of [C, W] key rows — so every
 # history probe, paint sort, and merge streams 1/W of the key bytes and
@@ -1923,10 +1924,14 @@ class RankBatch(NamedTuple):
 class ResidentBatch(NamedTuple):
     """A RankBatch plus its dictionary DELTA: the sorted never-before-seen
     endpoint keys of this dispatch, +inf padded to the engine's static
-    delta width. On the window path the ranks carry a leading [k] scan
-    axis while the delta does NOT — one merge serves the whole window."""
+    delta width, and beside each its ``cross`` rank — how many resident
+    keys sort below it — which the host mirror computes anyway to splice
+    the key into its own sorted view, so the device searches nothing. On
+    the window path the ranks carry a leading [k] scan axis while the
+    delta does NOT — one merge serves the whole window."""
 
     delta_keys: jax.Array  # int32 [M, W] sorted new keys, +inf padded
+    delta_cross: jax.Array  # int32 [M] resident keys below; D + 1 padded
     ranks: RankBatch
 
 
@@ -1976,8 +1981,15 @@ def init_res(
     )
 
 
-def _dict_insert(dict_keys, n_keys, delta_keys):
+def _dict_insert(dict_keys, n_keys, delta_keys, cross):
     """Merge M sorted-unique NEW keys into the resident dictionary.
+
+    ``cross`` [M] is each delta row's count of resident keys below it,
+    shipped by the host (conflict_set._ResidentMirror.insert_new): real
+    delta keys are distinct from every resident key, so 'left' and 'right'
+    agree; a +inf padding row carries D + 1 (all d1 rows), which puts it
+    outside the histogram's bins and its merge position past the output
+    window, so only real rows land.
 
     Returns (new_dict_keys, new_n_keys, shift) where shift[r] = how many
     inserted keys precede old rank r — the rank-rebase table: an existing
@@ -1985,21 +1997,14 @@ def _dict_insert(dict_keys, n_keys, delta_keys):
     capacity), and real delta rows are disjoint from resident keys by
     construction.
 
-    Costs what the delta costs: the only search is the delta's M queries
-    into the dictionary; the tables over the dictionary's rows are a
-    histogram and a prefix sum, and the rows move by streaming shifts —
-    nothing searches or gathers once per dictionary row."""
+    Costs what the delta costs and searches nothing: the tables over the
+    dictionary's rows are a histogram and a prefix sum, and the rows move
+    by streaming shifts — nothing searches or gathers once per dictionary
+    row, nor once per delta slot."""
     d1, w = dict_keys.shape
     m_cap = delta_keys.shape[0]
-    # 'right' of delta rows into the dict: real delta keys (distinct from
-    # every resident key) count the resident keys below; delta +inf rows
-    # count ALL d1 rows, which puts them outside the histogram's bins and
-    # their merge position past the output window, so only real rows land.
-    cross = searchsorted_words_fp(dict_keys, delta_keys, side="right")
     pos_d = jnp.arange(m_cap, dtype=jnp.int32) + cross
-    m = jnp.sum(
-        (~jnp.all(delta_keys == INT32_MAX, axis=-1)).astype(jnp.int32)
-    )
+    m = jnp.sum((cross < d1).astype(jnp.int32))
     # A delta key is strictly below resident row r exactly when its
     # cross <= r, so shift is the running count of a histogram of cross.
     # The dict's +inf padding rows read exactly m.
@@ -2063,15 +2068,19 @@ def _shift_hist(hist, shift):
 
 
 @jax.named_scope("dict_insert")
-def apply_delta(res: ResState, delta_keys: jax.Array) -> ResState:
+def apply_delta(res: ResState, delta_keys: jax.Array,
+                delta_cross: jax.Array) -> ResState:
     """Fold this dispatch's key delta into the resident state: insert the
-    new keys into the dictionary and rank-rebase the history + shard
-    bounds past the inserted positions. The empty-delta steady state (high
-    hit rate) skips the whole merge via lax.cond."""
-    any_new = jnp.any(~jnp.all(delta_keys == INT32_MAX, axis=-1))
+    new keys into the dictionary at the ranks the host shipped with them
+    (``delta_cross``, see ResidentBatch) and rank-rebase the history +
+    shard bounds past the inserted positions. The empty-delta steady state
+    (high hit rate; every row's cross the padding D + 1) skips the whole
+    merge via lax.cond."""
+    any_new = jnp.any(delta_cross < res.dict_keys.shape[0])
 
     def do(res):
-        nd, nn, shift = _dict_insert(res.dict_keys, res.n_keys, delta_keys)
+        nd, nn, shift = _dict_insert(res.dict_keys, res.n_keys, delta_keys,
+                                     delta_cross)
         return ResState(
             dict_keys=nd,
             n_keys=nn,
@@ -2349,7 +2358,7 @@ def resolve_batch_res(res: ResState, rb: ResidentBatch, commit_version,
     """resolve_batch over the resident state: delta merge + rank rebase,
     then the rank-space resolve core. Identical verdicts to the packed
     per-dispatch-dictionary path (oracle- and A/B-parity tested)."""
-    res = apply_delta(res, rb.delta_keys)
+    res = apply_delta(res, rb.delta_keys, rb.delta_cross)
     out = _resolve_core_res(res.hist, rb.ranks, commit_version, new_oldest,
                             report=report, wave=wave)
     return (*out[:-1], res._replace(hist=out[-1]))
@@ -2360,7 +2369,7 @@ def resolve_many_res(res: ResState, rb: ResidentBatch, commit_versions,
     """Window path: ONE delta merge + rank rebase for the whole window
     (the delta carries no scan axis), then a pure rank-space scan with no
     per-step dictionary work at all."""
-    res = apply_delta(res, rb.delta_keys)
+    res = apply_delta(res, rb.delta_keys, rb.delta_cross)
 
     def body(h, xs):
         rbk, cv, old = xs
@@ -2510,7 +2519,7 @@ def wave_edges_res(res: ResState, rb: ResidentBatch, new_oldest):
     packed ranks against the post-merge mirror), so the returned state
     carries the merged dictionary and the apply phase must not re-merge.
     History is still unpainted."""
-    res = apply_delta(res, rb.delta_keys)
+    res = apply_delta(res, rb.delta_keys, rb.delta_cross)
     hist = res.hist
     if isinstance(hist, HistState):
         _floor, too_old = too_old_mask_packed(hist.delta, rb.ranks, new_oldest)
@@ -2737,7 +2746,7 @@ def paint_batch_res(res: ResState, rb: ResidentBatch, accepted,
     """Resident edition: the dictionary delta re-applies exactly as the
     resolve body would (a rolled-back snapshot predates this window's
     insert, so the replayed merge reproduces the original rank space)."""
-    res = apply_delta(res, rb.delta_keys)
+    res = apply_delta(res, rb.delta_keys, rb.delta_cross)
     return res._replace(
         hist=_paint_core_res(res.hist, rb.ranks, accepted, commit_version,
                              new_oldest)
@@ -2745,7 +2754,7 @@ def paint_batch_res(res: ResState, rb: ResidentBatch, accepted,
 
 
 def paint_many_res(res, rb, accepted, commit_versions, new_oldests):
-    res = apply_delta(res, rb.delta_keys)
+    res = apply_delta(res, rb.delta_keys, rb.delta_cross)
 
     def body(h, xs):
         rbk, acc, cv, old = xs

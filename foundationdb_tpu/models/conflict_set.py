@@ -450,8 +450,13 @@ class _ResidentMirror:
         return self.last_used_by_id[self.id_at]
 
     def insert_new(self, new_u64, new_rows, cv: int,
-                   ids: "np.ndarray | None" = None) -> np.ndarray:
-        """Incremental sorted insert of delta keys; returns their ids.
+                   ids: "np.ndarray | None" = None):
+        """Incremental sorted insert of delta keys; returns (their ids,
+        ``ins``): ins[j] is the count of hot keys below new key j BEFORE
+        the insert — the splice point here, and the device merge's
+        ``cross`` rank (ck.ResidentBatch.delta_cross): the hot view is the
+        device's dictionary row for row, so the kernel takes it as shipped
+        and searches nothing.
 
         ``ids`` (tiered promotion path): per-row existing cold id, or -1
         for a genuinely new key. Cold keys re-enter the sorted view with
@@ -472,7 +477,7 @@ class _ResidentMirror:
             self.rank_of_id[self.id_at] = np.arange(len(self.id_at))
             self.hot_by_id[new_ids] = True
             self._tab_insert(new_ids)
-            return new_ids
+            return new_ids, ins
         alloc = np.flatnonzero(ids < 0)
         self._ensure_ids(self._n_ids + len(alloc))
         new_ids = np.asarray(ids, np.int64).copy()
@@ -486,7 +491,7 @@ class _ResidentMirror:
         self.hot_by_id[new_ids] = True
         self.stats["promotions"] += m - len(alloc)
         self._tab_insert(fresh)
-        return new_ids
+        return new_ids, ins
 
     def _tab_insert(self, ids: np.ndarray) -> None:
         """Vectorized linear-probing insert: same-batch slot races resolve
@@ -885,14 +890,25 @@ class TPUConflictSet:
         return flat, (lead, b, r, q, w)
 
     def _ranks_to_batch(self, bt: ck.BatchTensors, ranks: np.ndarray,
-                        dims, delta_rows: np.ndarray) -> ck.ResidentBatch:
+                        dims, delta=None) -> ck.ResidentBatch:
         """Reassemble flat endpoint ranks + a key delta into the device
-        ResidentBatch (delta padded to the engine's static slot count)."""
+        ResidentBatch. ``delta`` is (new key rows, their cross ranks as
+        _ResidentMirror.insert_new returned them), or None for an EMPTY
+        delta (a full repack, a warm-up). Both are padded to the engine's
+        static slot count: the rows with +inf, the ranks with
+        dict_capacity + 1, past every row of the device's dictionary, which
+        is how the kernel tells padding from a key."""
         lead, b, r, q, w = dims
         nl = int(np.prod(lead)) if lead else 1
         n_r, n_q = nl * b * r, nl * b * q
-        delta = np.full((self.dict_delta_slots, w), INT32_MAX, np.int32)
-        delta[: len(delta_rows)] = delta_rows
+        delta_keys = np.full((self.dict_delta_slots, w), INT32_MAX, np.int32)
+        delta_cross = np.full(
+            self.dict_delta_slots, self.dict_capacity + 1, np.int32
+        )
+        if delta is not None:
+            rows, cross = delta
+            delta_keys[: len(rows)] = rows
+            delta_cross[: len(cross)] = cross
         wb = ranks[2 * n_r : 2 * n_r + n_q].reshape(*lead, b, q)
         we = ranks[2 * n_r + n_q :].reshape(*lead, b, q)
         # The paint permutation, precomputed here (kernel RankBatch
@@ -907,7 +923,8 @@ class TPUConflictSet:
         )
         paint_src = np.argsort(paint, axis=-1).astype(np.int32)
         return ck.ResidentBatch(
-            delta_keys=delta,
+            delta_keys=delta_keys,
+            delta_cross=delta_cross,
             ranks=ck.RankBatch(
                 read_begin=ranks[:n_r].reshape(*lead, b, r),
                 read_end=ranks[n_r : 2 * n_r].reshape(*lead, b, r),
@@ -1027,6 +1044,7 @@ class TPUConflictSet:
                     _RepackPlan(bt, qu, is_pad, new_u64, new_rows, dims, cv),
                     inside="dict_rank",
                 )
+        delta = None
         with mir.lock:
             mir.touch(ids[hot_hit], cv)
             if m:
@@ -1036,13 +1054,14 @@ class TPUConflictSet:
                     # it to its existing cold id (promotion) or -1 (new).
                     row_ids = np.full(m, -1, np.int64)
                     row_ids[pos] = ids[mi]
-                    new_ids = mir.insert_new(new_u64, new_rows, cv,
-                                             ids=row_ids)
+                    new_ids, cross = mir.insert_new(new_u64, new_rows, cv,
+                                                    ids=row_ids)
                 else:
                     # Every miss is in the new set: its index there is its
                     # id.
-                    new_ids = mir.insert_new(new_u64, new_rows, cv)
+                    new_ids, cross = mir.insert_new(new_u64, new_rows, cv)
                 ids[mi] = new_ids[pos]
+                delta = (new_rows, cross)
             # Post-merge rank = current sorted position of the id.
             ranks = mir.rank_of_id[np.maximum(ids, 0)].astype(np.int32)
             ranks[is_pad | (ids < 0)] = INT32_MAX
@@ -1059,7 +1078,7 @@ class TPUConflictSet:
             st["delta_new_keys"] += m
             st["delta_empty_dispatches"] += int(m == 0)
         self._note_write_fps(qu, is_pad, dims)
-        return self._ranks_to_batch(bt, ranks, dims, new_rows)
+        return self._ranks_to_batch(bt, ranks, dims, delta)
 
     def _device_live_ranks(self) -> np.ndarray:
         """Exact dictionary liveness: every rank the device history still
@@ -1175,10 +1194,7 @@ class TPUConflictSet:
             finally:
                 mir.gate.set()
         self._note_write_fps(plan.qu, plan.is_pad, plan.dims)
-        return self._ranks_to_batch(
-            plan.bt, ranks, plan.dims,
-            np.zeros((0, plan.dims[-1]), np.int32),
-        )
+        return self._ranks_to_batch(plan.bt, ranks, plan.dims)
 
     def _demote_now(self, incoming: int, protect=None) -> int:
         """Demote cold hot-tier keys to the host cold store (dispatch
@@ -2336,7 +2352,7 @@ class TPUConflictSet:
             # no dispatch, and the mirror's counters should not say so.
             flat, dims = self._flat_endpoints(bt)
             empty = self._ranks_to_batch(
-                bt, np.full(len(flat), INT32_MAX, np.int32), dims, flat[:0])
+                bt, np.full(len(flat), INT32_MAX, np.int32), dims)
         else:
             empty = self._dev_batch(bt)
         steps: dict[str, Callable] = {

@@ -217,8 +217,9 @@ def _res_shard_step(hist, lo, hi, rbk, commit_version, new_oldest, wave):
 
 def _sharded_resolve_res(res, rb, commit_version, new_oldest, wave=False):
     """Resident mesh body: replicated dictionary-delta insert (every device
-    computes the identical merged dictionary), per-shard rank-rebase of
-    histories AND shard bounds, then the rank-space shard step."""
+    takes the same host-shipped ranks, rb.delta_cross, and computes the
+    identical merged dictionary), per-shard rank-rebase of histories AND
+    shard bounds, then the rank-space shard step."""
     local = ck.ResState(
         dict_keys=res.dict_keys,  # replicated (P())
         n_keys=res.n_keys,
@@ -226,7 +227,7 @@ def _sharded_resolve_res(res, rb, commit_version, new_oldest, wave=False):
         shard_lo=res.shard_lo,  # local [1] slice
         shard_hi=res.shard_hi,
     )
-    local = ck.apply_delta(local, rb.delta_keys)
+    local = ck.apply_delta(local, rb.delta_keys, rb.delta_cross)
     verdicts, levels, stats, new_hist = _res_shard_step(
         local.hist, local.shard_lo[0], local.shard_hi[0], rb.ranks,
         commit_version, new_oldest, wave,
@@ -248,7 +249,7 @@ def _sharded_resolve_res_many(res, rb, commit_versions, new_oldests,
         shard_lo=res.shard_lo,
         shard_hi=res.shard_hi,
     )
-    local = ck.apply_delta(local, rb.delta_keys)
+    local = ck.apply_delta(local, rb.delta_keys, rb.delta_cross)
     lo = local.shard_lo[0]
     hi = local.shard_hi[0]
 
@@ -621,6 +622,7 @@ class ShardedConflictSet(TPUConflictSet):
         )
         batch_specs = ck.ResidentBatch(
             delta_keys=P(),
+            delta_cross=P(),
             ranks=ck.RankBatch(*(P() for _ in ck.RankBatch._fields)),
         )
         wave = self.wave_commit

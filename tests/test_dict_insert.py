@@ -7,6 +7,12 @@ same table from a histogram and a prefix sum and moves the rows by streaming
 shifts. Every case must agree element for element: the merged dictionary,
 the live count and ``shift`` (the dictionary's ``+inf`` padding rows read
 exactly the delta's real count).
+
+The kernel searches nothing: each delta row arrives with its ``cross`` rank
+(resident keys below it; ``D + 1`` on a padding row). The search the kernel
+used to run for it, ``searchsorted_words_fp(dict_keys, delta_keys, "right")``,
+lives on here as the oracle of what the host ships
+(``_ResidentMirror.insert_new``), over the same cases.
 """
 
 import jax
@@ -15,6 +21,8 @@ import pytest
 
 from foundationdb_tpu.core.keypack import INT32_MAX
 from foundationdb_tpu.models import conflict_kernel as ck
+from foundationdb_tpu.models.conflict_set import _ResidentMirror, _rows_to_u64
+from foundationdb_tpu.ops.lex import searchsorted_words_fp
 
 D = 4096  # dictionary capacity: D + 1 rows, the last always +inf
 M = 1024  # delta slots
@@ -89,25 +97,72 @@ def reference(dict_keys, n, delta_keys, m):
     return padded(merged, d1, w), n + m, shift
 
 
+def reference_cross(dict_keys, n, delta_keys, m):
+    """Resident keys below each delta row; D + 1 on the padding rows."""
+    cross = np.full(len(delta_keys), len(dict_keys), np.int32)
+    cross[:m] = np.searchsorted(as_ints(dict_keys[:n]),
+                                as_ints(delta_keys[:m]), side="left")
+    return cross
+
+
+def case(w, count, placement):
+    m = COUNTS[count]
+    resident, delta = place(placement, m)
+    n = len(resident)
+    dict_keys = padded(encode(resident, w), D + 1, w)
+    delta_keys = padded(encode(delta, w), M, w)
+    return dict_keys, n, delta_keys, m
+
+
 insert_jit = jax.jit(ck._dict_insert)
+search_jit = jax.jit(lambda d, q: searchsorted_words_fp(d, q, side="right"))
 
 
 @pytest.mark.parametrize("placement", PLACEMENTS)
 @pytest.mark.parametrize("count", COUNTS)
 @pytest.mark.parametrize("w", [1, 3, 9])
 def test_dict_insert_equals_numpy_merge(w, count, placement):
-    m = COUNTS[count]
-    resident, delta = place(placement, m)
-    n = len(resident)
-    dict_keys = padded(encode(resident, w), D + 1, w)
-    delta_keys = padded(encode(delta, w), M, w)
+    dict_keys, n, delta_keys, m = case(w, count, placement)
     want_keys, want_n, want_shift = reference(dict_keys, n, delta_keys, m)
-    got_keys, got_n, got_shift = insert_jit(dict_keys, np.int32(n),
-                                            delta_keys)
+    got_keys, got_n, got_shift = insert_jit(
+        dict_keys, np.int32(n), delta_keys,
+        reference_cross(dict_keys, n, delta_keys, m))
     assert int(got_n) == want_n
     np.testing.assert_array_equal(np.asarray(got_shift), want_shift)
     assert (np.asarray(got_shift)[n:] == m).all()
     np.testing.assert_array_equal(np.asarray(got_keys), want_keys)
+
+
+@pytest.mark.parametrize("placement", PLACEMENTS)
+@pytest.mark.parametrize("count", COUNTS)
+@pytest.mark.parametrize("w", [1, 3, 9])
+def test_shipped_cross_equals_the_deleted_device_search(w, count, placement):
+    dict_keys, n, delta_keys, m = case(w, count, placement)
+    mirror = _ResidentMirror(dict_keys[:n], D, M, 0.5)
+    _ids, ins = mirror.insert_new(_rows_to_u64(delta_keys[:m]),
+                                  delta_keys[:m], 0)
+    shipped = np.full(M, D + 1, np.int32)  # _ranks_to_batch's padding
+    shipped[:m] = ins
+    np.testing.assert_array_equal(
+        shipped, np.asarray(search_jit(dict_keys, delta_keys)))
+    np.testing.assert_array_equal(
+        shipped, reference_cross(dict_keys, n, delta_keys, m))
+    # The mirror spliced the keys where it said it would.
+    np.testing.assert_array_equal(
+        mirror.rows, reference(dict_keys, n, delta_keys, m)[0][:n + m])
+
+
+def test_apply_delta_lowers_without_a_loop():
+    """A search on the device is a ``while`` (searchsorted_words_fp's column
+    cascades were ``_while.55`` and ``_while.61`` of the chip's traces); the
+    merge is a histogram, a prefix sum, conditional shifts and a scatter."""
+    w = 9
+    res = ck.init_res(encode([0], w), D, 256, delta_capacity=64)
+    text = jax.jit(ck.apply_delta).lower(
+        res, padded(encode([], w), M, w), np.full(M, D + 1, np.int32)
+    ).as_text()
+    assert "stablehlo.scatter" in text and "stablehlo.case" in text
+    assert "stablehlo.while" not in text
 
 
 def test_apply_delta_rebases_two_level_history_and_shard_bounds():
@@ -136,7 +191,9 @@ def test_apply_delta_rebases_two_level_history_and_shard_bounds():
         with_ranks(hist.base, base_ranks), hist.base_st,
         with_ranks(hist.delta, delta_ranks)))
 
-    out = jax.jit(ck.apply_delta)(res, delta_keys)
+    out = jax.jit(ck.apply_delta)(
+        res, delta_keys,
+        reference_cross(np.asarray(res.dict_keys), n, delta_keys, m))
 
     want_keys, want_n, shift = reference(
         np.asarray(res.dict_keys), n, delta_keys, m)

@@ -268,7 +268,7 @@ def resident_args():
     bt = cs._empty_batch()
     flat, dims = cs._flat_endpoints(bt)
     empty = cs._ranks_to_batch(
-        bt, np.full(len(flat), INT32_MAX, np.int32), dims, flat[:0])
+        bt, np.full(len(flat), INT32_MAX, np.int32), dims)
     return cs, empty
 
 
