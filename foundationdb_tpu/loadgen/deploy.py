@@ -74,15 +74,43 @@ def _group_has_running(pgid: int) -> bool:
     return False
 
 
+_PORT_LO = 10000  # above the well-known services, below any ephemeral range
+_PORT_STRETCH = 64  # consecutive pids start their walks this far apart
+_claimed: list[socket.socket] = []  # held until this process exits
+_walked = 0
+
+
 def free_ports(n: int) -> list[int]:
-    socks, ports = [], []
-    for _ in range(n):
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        socks.append(s)
-        ports.append(s.getsockname()[1])
-    for s in socks:
-        s.close()
+    """`n` localhost ports that nobody else is given before the caller's
+    roles bind them, or between a role's death and its restart. They lie
+    BELOW the kernel's ephemeral range, so neither a bind to port 0 nor an
+    outgoing connection can be handed one. Each is claimed for the rest of
+    this process's life under a name in the abstract unix namespace
+    (atomic across processes, released by the kernel when the process
+    ends), so no other caller of this function gets it either. And each
+    is probed by bind, so a port that a foreign program holds is passed
+    over. Every process starts its walk at a stretch of its own (by pid);
+    the claim, not the stretch, is what keeps two walks apart."""
+    global _walked
+    with open("/proc/sys/net/ipv4/ip_local_port_range") as f:
+        span = int(f.read().split()[0]) - _PORT_LO
+    ports: list[int] = []
+    while len(ports) < n:
+        if _walked >= span:
+            raise RuntimeError(
+                f"no free port left in {_PORT_LO}..{_PORT_LO + span - 1}")
+        port = _PORT_LO + (os.getpid() * _PORT_STRETCH + _walked) % span
+        _walked += 1
+        claim = socket.socket(socket.AF_UNIX)
+        try:
+            claim.bind(f"\0foundationdb_tpu.port.{port}")
+            with socket.socket() as probe:
+                probe.bind(("127.0.0.1", port))
+        except OSError:
+            claim.close()
+            continue
+        _claimed.append(claim)
+        ports.append(port)
     return ports
 
 
@@ -90,20 +118,16 @@ def build_spec(proxies: int = 2, tlogs: int = 1, storages: int = 1,
                resolvers: int = 1, ratekeeper: bool = True,
                engine: str = "cpu", extra: "dict | None" = None,
                managed: bool = False,
-               ports: "list[int] | None" = None,
                resolver_splits: "list[bytes] | None" = None) -> dict:
     """A cluster spec dict with fresh localhost ports (server.py shape).
     ``resolver_splits``: the resolvers - 1 keys at which the resolvers'
     ranges part (server.resolver_shard_map; hex in the spec); None leaves
     the split to KeyShardMap.uniform, which goes by first byte.
     ``managed=True`` adds a controller process — chain-role failures then
-    heal with a generation change instead of needing a full bounce.
-    ``ports``: pre-allocated port list (callers that need MORE ports —
-    relay binds — must draw them all from one free_ports batch, or the
-    kernel can hand a just-released spec port back as a bind port)."""
+    heal with a generation change instead of needing a full bounce."""
     n = (1 + resolvers + tlogs + storages + proxies
          + (1 if ratekeeper else 0) + (1 if managed else 0))
-    ports = iter(ports if ports is not None else free_ports(n))
+    ports = iter(free_ports(n))
     spec = {
         "sequencer": [f"127.0.0.1:{next(ports)}"],
         "resolver": [f"127.0.0.1:{next(ports)}" for _ in range(resolvers)],
@@ -168,22 +192,12 @@ class SocketCluster:
         self.workdir = workdir
         self.managed = managed
         self.data_dirs = data_dirs
-        # ONE free_ports batch covers the spec AND the relayed roles'
-        # private bind ports: separate allocations release the spec
-        # ports before the bind ports are drawn, and the kernel may
-        # hand one straight back (flaky EADDRINUSE at boot).
-        counts = {"sequencer": 1, "resolver": resolvers, "tlog": tlogs,
-                  "storage": storages, "proxy": proxies,
-                  "ratekeeper": 1 if ratekeeper else 0,
-                  "controller": 1 if managed else 0}
-        n_spec = sum(counts.values())
-        n_bind = sum(counts.get(r, 0) for r in relay_roles)
-        ports = free_ports(n_spec + n_bind)
-        self._bind_ports = iter(ports[n_spec:])
         self.spec = build_spec(proxies, tlogs, storages, resolvers,
                                ratekeeper, engine, spec_extra, managed,
-                               ports=ports[:n_spec],
                                resolver_splits=resolver_splits)
+        # A relayed role binds a private port behind its relay's.
+        self._bind_ports = iter(free_ports(sum(
+            len(self.spec.get(r) or []) for r in relay_roles)))
         self.spec_path = os.path.join(workdir, "cluster.json")
         with open(self.spec_path, "w") as f:
             json.dump(self.spec, f)
@@ -204,8 +218,7 @@ class SocketCluster:
                 bind = None
                 if role in self._relay_roles:
                     # The spec's (advertised) port belongs to the RELAY;
-                    # the role binds a private port the relay forwards to
-                    # (allocated in __init__'s single free_ports batch).
+                    # the role binds a private port the relay forwards to.
                     bind = ("127.0.0.1", next(self._bind_ports))
                     self.relays[name] = TcpRelay(bind, host=addr[0],
                                                  port=addr[1])
@@ -219,7 +232,7 @@ class SocketCluster:
                     data_dir=data_dir,
                 ))
 
-    def _by_name(self, name: str) -> _Proc:
+    def proc(self, name: str) -> _Proc:
         for p in self.procs:
             if p.name == name:
                 return p
@@ -279,7 +292,7 @@ class SocketCluster:
 
     def role_ready(self, name: str) -> bool:
         """Has this process printed its readiness line since (re)launch?"""
-        p = self._by_name(name)
+        p = self.proc(name)
         if not p.alive():
             return False
         try:
@@ -291,19 +304,30 @@ class SocketCluster:
         except OSError:
             return False
 
+    def log_tail(self, name: str, n_bytes: int = 2000) -> str:
+        """The end of one role's log (all its generations share the file)."""
+        try:
+            with open(self.proc(name).log_path, "rb") as f:
+                f.seek(max(0, os.fstat(f.fileno()).st_size - n_bytes))
+                return f.read().decode(errors="replace")
+        except OSError as e:
+            return f"<no log: {e}>"
+
     def wait_ready(self, name: str,
                    timeout_s: "float | None" = None) -> None:
-        p = self._by_name(name)
+        p = self.proc(name)
         deadline = time.monotonic() + (timeout_s or self.READY_DEADLINE_S)
         while True:
             if self.role_ready(name):
                 return
             if p.popen is not None and p.popen.poll() is not None:
                 raise RuntimeError(
-                    f"{name} exited rc={p.popen.returncode} during boot "
-                    f"(see {p.log_path})")
+                    f"{name} exited rc={p.popen.returncode} during boot; "
+                    f"{p.log_path} ends:\n{self.log_tail(name)}")
             if time.monotonic() > deadline:
-                raise RuntimeError(f"timed out waiting for {name} ready")
+                raise RuntimeError(
+                    f"timed out waiting for {name} ready; "
+                    f"{p.log_path} ends:\n{self.log_tail(name)}")
             time.sleep(0.05)
 
     def start(self) -> "SocketCluster":
@@ -333,7 +357,7 @@ class SocketCluster:
         crashed-process leak check exists to catch (teardown's group
         kill is the mop-up, not the fault model). Returns the wall stamp
         of the kill (chaos MTTR anchors detection latency on it)."""
-        p = self._by_name(name)
+        p = self.proc(name)
         stamp = time.time()
         if p.alive():
             p.popen.send_signal(sig)
@@ -352,14 +376,14 @@ class SocketCluster:
         """SIGSTOP: the process stays alive but answers nothing — the
         failure detector's hardest case (no connection death, RPCs just
         hang; the controller's probe timeout is what notices)."""
-        p = self._by_name(name)
+        p = self.proc(name)
         if p.alive():
             p.popen.send_signal(signal.SIGSTOP)
             p.paused = True
         return time.time()
 
     def resume_role(self, name: str) -> None:
-        p = self._by_name(name)
+        p = self.proc(name)
         if p.alive() and p.paused:
             p.popen.send_signal(signal.SIGCONT)
         p.paused = False
@@ -370,7 +394,7 @@ class SocketCluster:
         restart-on-exit. The new process recovers its disk queue
         (TLog.from_disk) and the controller folds it into the next
         generation via the begin_epoch/tlog_adopt handshake."""
-        p = self._by_name(name)
+        p = self.proc(name)
         if p.alive():
             self.kill_role(name)
         p.restarts += 1
@@ -387,7 +411,7 @@ class SocketCluster:
         if relay is None:
             raise KeyError(
                 f"{name} has no relay — boot the cluster with "
-                f"relay_roles=({self._by_name(name).role!r},)")
+                f"relay_roles=({self.proc(name).role!r},)")
         relay.set_mode(mode, delay_s=delay_s)
         return time.time()
 
@@ -522,10 +546,10 @@ class SocketCluster:
         self.procs = []
         return {"exit_codes": codes, "killed": killed}
 
-    def kill(self) -> None:
+    def kill(self) -> dict:
         """Hard teardown (exception path): SIGKILL every process GROUP —
         orphaned children of crashed AND restarted-over roles included —
-        and reap."""
+        and reap. Returns the leak_report of what even that left."""
         for p in self.procs:
             if p.popen is None:
                 continue
@@ -546,8 +570,10 @@ class SocketCluster:
         for p in self.procs:
             if p.popen is not None:
                 p.popen.wait()
+        report = self.leak_report(dead_only=False)
         self._close_relays()
         self.procs = []
+        return report
 
     def _close_relays(self) -> None:
         for relay in self.relays.values():
@@ -597,5 +623,5 @@ class SocketCluster:
         """Admin endpoint of one role process (inject_fault/clear_faults/
         obs_snapshot), via its REAL address — reachable even when the
         role's relay is partitioned."""
-        p = self._by_name(name)
+        p = self.proc(name)
         return t.endpoint(p.bind or p.addr, "admin")

@@ -20,6 +20,7 @@ from foundationdb_tpu.core.errors import (
     CommitUnknownResult,
     DatabaseLocked,
     NotCommitted,
+    ProcessKilled,
     TransactionTooOld,
 )
 from foundationdb_tpu.core.mutations import (
@@ -32,7 +33,9 @@ from foundationdb_tpu.core.wavemesh import clip_ranges
 from foundationdb_tpu.obs.span import span_sink
 from foundationdb_tpu.repair.hotrange import HotRangeSketch
 from foundationdb_tpu.runtime.backup import BACKUP_TAG
-from foundationdb_tpu.runtime.flow import BrokenPromise, Loop, Promise, all_of, rpc
+from foundationdb_tpu.runtime.flow import (
+    ActorCancelled, BrokenPromise, Loop, Promise, all_of, rpc,
+)
 from foundationdb_tpu.runtime.shardmap import KeyShardMap
 from foundationdb_tpu.runtime.trace import Severity, trace
 from foundationdb_tpu.sched.lanes import LaneQueue
@@ -169,7 +172,8 @@ class CommitProxy:
             admission.hot_ranges = self.hot_ranges
         self._shaped: list[tuple[CommitRequest, Promise]] = []
         self._shaped_since = loop.now  # head-of-lane arrival (flush clock)
-        self._inflight: set[int] = set()  # batch versions being processed
+        # Batches being processed, by version (retire() answers them).
+        self._inflight: dict[int, list[tuple[CommitRequest, Promise]]] = {}
         # Batches popped from _queue but not yet in _inflight (awaiting
         # their commit version): quiesce() must see them or a batch could
         # vanish from both sets mid-await and slip past a DR switchover.
@@ -359,11 +363,20 @@ class CommitProxy:
                 for _req, p in batch:
                     p.fail(CommitUnknownResult("sequencer unreachable"))
                 continue
+            except ActorCancelled:
+                # Retired while waiting (a recovery recruits the next
+                # generation's proxy): this batch has left the queue that
+                # the recruiter fails, nothing of it was sent anywhere,
+                # and unanswered its clients would wait for ever over a
+                # healthy connection.
+                for _req, p in batch:
+                    p.fail(ProcessKilled("proxy retired: resubmit"))
+                raise
             finally:
                 self._admitting -= 1
             # Into _inflight HERE (not in the spawned task, which may not
             # have run yet when quiesce() samples).
-            self._inflight.add(version)
+            self._inflight[version] = batch
             self.loop.spawn(
                 self._process(batch, prev_version, version),
                 name=f"commit_batch@{version}",
@@ -495,12 +508,29 @@ class CommitProxy:
         watchdog = self.loop.spawn(
             self._wedge_watchdog(version), name=f"wedge_watchdog@{version}"
         )
-        self._inflight.add(version)
+        self._inflight[version] = batch
         try:
             await self._process_inner(batch, prev_version, version)
         finally:
-            self._inflight.discard(version)
+            self._inflight.pop(version, None)
             watchdog.cancel()
+
+    def retire(self, reason: str) -> None:
+        """Answer everything this proxy still holds. Its batch loop is
+        being cancelled (a recovery recruits the next generation's proxy,
+        or a stand-down) and nothing else ever will: each client would
+        wait for ever over a healthy connection. What is queued or parked
+        was sent nowhere: retryable. A batch in _process may have reached
+        the logs, and may wait on a resolver or tlog of the old generation
+        that never replies (parked behind a gap in its chain when it was
+        replaced): commit_unknown_result, which is the truth; whatever
+        that batch completes with later is dropped."""
+        for _req, p in self._queue.drain() + self._shaped:
+            p.fail(ProcessKilled(reason))
+        self._shaped = []
+        for batch in self._inflight.values():
+            for _req, p in batch:
+                p.fail(CommitUnknownResult(reason))
 
     @rpc
     async def quiesce(self) -> None:

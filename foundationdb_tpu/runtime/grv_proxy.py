@@ -16,8 +16,9 @@ from __future__ import annotations
 import itertools
 import os
 
+from foundationdb_tpu.core.errors import ProcessKilled
 from foundationdb_tpu.obs.span import span_sink
-from foundationdb_tpu.runtime.flow import Loop, Promise, rpc
+from foundationdb_tpu.runtime.flow import ActorCancelled, Loop, Promise, rpc
 
 #: Unique-per-process GRV poller ids (pid + counter: deterministic in the
 #: single-process sim, collision-free across deployed proxy processes).
@@ -230,6 +231,12 @@ class GrvProxy:
                 for p in batch:
                     p.fail(e)
                 continue
+            except ActorCancelled:
+                # Retired while waiting: these have left the queues that
+                # the recruiter fails (the commit proxy's twin case).
+                for p in batch:
+                    p.fail(ProcessKilled("proxy retired: ask again"))
+                raise
             self.grvs_served += len(batch)
             for p in batch:
                 p.send(version)

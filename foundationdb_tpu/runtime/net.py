@@ -182,8 +182,10 @@ class _Conn:
         # _events(), not EVENT_READ: _step_tls may already have queued the
         # ClientHello in wbuf (send hit EAGAIN on an in-flight connect) —
         # registering read-only here would drop write interest and the
-        # handshake would deadlock.
-        self.t.loop.register(sock, self._events(), self._on_ready)
+        # handshake would deadlock. Or its send found the dial refused
+        # (a loopback peer that does not listen yet) and closed us.
+        if not self.closed:
+            self.t.loop.register(sock, self._events(), self._on_ready)
 
     # -- IO -------------------------------------------------------------
 
@@ -490,6 +492,9 @@ class NetTransport:
             self._note_dial_failed(addr)
             raise
         conn = _Conn(self, sock, server_side=False)
+        if conn.closed:  # refused before the TLS hello could leave
+            self._note_dial_failed(addr)
+            raise BrokenPromise(f"connect to {addr} refused")
         conn.outbound_addr = addr
         self._conns[addr] = conn
         self._all_conns.add(conn)

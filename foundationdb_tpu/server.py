@@ -599,17 +599,15 @@ class Worker:
         return True
 
     def _fail_commit_queue(self, reason: str) -> None:
-        """Fail every queued commit promise retryably: the batch loop is
-        cancelled on retire/stand-down, so a parked commit would otherwise
-        hang its client forever over a healthy connection (the client's
-        on_error resubmits against the new generation)."""
-        from foundationdb_tpu.core.errors import ProcessKilled
-
+        """Answer every commit the outgoing proxy holds (CommitProxy.retire):
+        the batch loop is cancelled on retire/stand-down, so a parked
+        commit would otherwise hang its client forever over a healthy
+        connection (the client's on_error resubmits against the new
+        generation)."""
         cp = getattr(self, "_commit_proxy", None)
         if cp is None:
             return
-        for _req, p in cp._queue.drain():  # every lane (sched/lanes.py)
-            p.fail(ProcessKilled(reason))
+        cp.retire(reason)
         self._commit_proxy = None
 
     def _release_grv_lease(self) -> None:
@@ -639,7 +637,7 @@ class Worker:
         g = getattr(self, "_grv_proxy", None)
         if g is None:
             return
-        for q in (g._queue, g._batch_queue):
+        for q in (g._queue, g._batch_queue, g._system_queue):
             for p, _tags in q:
                 p.fail(ProcessKilled(reason))
             q.clear()
@@ -1920,7 +1918,27 @@ def build_role(loop: RealLoop, t: NetTransport, spec: dict, role: str,
         t.serve("grv_proxy", grv)
         t.serve("read_router", router)
         t.serve("storage0", router)  # C client default service name
-        _supervise(loop, f"proxy{index}.run", proxy.run)
+
+        async def run_once_linked():
+            # Static wiring has no recovery. A batch whose resolve or push
+            # fails because a peer does not listen YET (roles boot in any
+            # order, and the retry ladder is a third of a second) leaves a
+            # gap in the version chain that nothing ever fills: that peer
+            # waits for the lost version, every later batch waits behind
+            # it, and no commit is acknowledged again. So this proxy asks
+            # for no commit version until every chain peer has answered it
+            # once; clients' commits queue meanwhile.
+            for call in ([ep.get_metrics for ep in eps("resolver")]
+                         + [ep.get_version for ep in eps("tlog")]):
+                while True:
+                    try:
+                        await call()
+                        break
+                    except BrokenPromise:
+                        await loop.sleep(0.1)
+            await proxy.run()
+
+        _supervise(loop, f"proxy{index}.run", run_once_linked)
         _supervise(loop, f"grv{index}.run", grv.run)
     elif role == "ratekeeper":
         from foundationdb_tpu.runtime.ratekeeper import Ratekeeper

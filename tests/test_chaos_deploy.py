@@ -48,7 +48,7 @@ class TestCrashedProcessLeakCheck:
             # Simulate an orphan still holding the crashed role's port:
             # the check must flag it and shutdown must refuse to report
             # a clean teardown.
-            addr = cluster._by_name("storage0").addr
+            addr = cluster.proc("storage0").addr
             holder = socket.create_server(addr)
             rep = cluster.leak_report()
             assert [p["port"] for p in rep["ports_still_bound"]] == [addr[1]]
@@ -78,7 +78,7 @@ class TestCrashedProcessLeakCheck:
                                    ratekeeper=False)
         cluster.start()
         try:
-            pgid = cluster._by_name("proxy0").popen.pid
+            pgid = cluster.proc("proxy0").popen.pid
             cluster.kill_role("proxy0")  # kills the ROLE, not its group
             rep = cluster.leak_report()
             assert "proxy0" in rep["orphan_groups"], rep
@@ -87,7 +87,7 @@ class TestCrashedProcessLeakCheck:
             # group: the orphan lives in the OLD pgid, the new process
             # in a fresh one — the leak check chases both (review find).
             cluster.restart_role("proxy0")
-            assert cluster._by_name("proxy0").alive()
+            assert cluster.proc("proxy0").alive()
             rep = cluster.leak_report()
             assert "proxy0" in rep["orphan_groups"], rep
             with pytest.raises(RuntimeError, match="leaked"):
@@ -107,6 +107,41 @@ class TestCrashedProcessLeakCheck:
             time.sleep(0.05)
         else:
             raise AssertionError("orphan process group survived kill()")
+
+
+class TestLateListener:
+    """A statically wired cluster has no recovery, so a commit batch that
+    fails at boot because a chain peer does not listen yet would leave a
+    gap in the version chain for ever: every role `ready`, no commit ever
+    acknowledged (the one-in-twenty wedge of six test workers booting
+    clusters at once, PR 28). The proxies wait for their links instead."""
+
+    @pytest.mark.parametrize("late", ["tlog1", "resolver0"])
+    def test_commits_flow_once_a_late_chain_role_listens(
+            self, cluster_factory, late):
+        cluster = cluster_factory(start=False, tlogs=2, storages=2)
+        for p in cluster.procs:
+            if p.name != late:
+                cluster.restart_role(p.name, wait=False)
+        for p in cluster.procs:
+            if p.name != late:
+                cluster.wait_ready(p.name)
+        # Longer than the proxies' idle-batch interval plus their whole
+        # retry ladder: on the parent a batch has failed by now.
+        time.sleep(3.0)
+        cluster.restart_role(late)
+
+        loop, t, db = cluster.open_client()
+        try:
+            async def main():
+                tr = db.transaction()
+                tr.set(b"late/k", b"v")
+                await tr.commit()
+                return await db.transaction().get(b"late/k")
+
+            assert loop.run(main(), timeout=30) == b"v"
+        finally:
+            t.close()
 
 
 class TestBootFailureCleanup:

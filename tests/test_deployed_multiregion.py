@@ -10,28 +10,16 @@ loss — the satellites are the salvage source — and the healed primary
 must be able to take the database back symmetrically.
 """
 
-import json
 import os
-import signal
-import socket
 import subprocess
 import sys
 import time
 
 import pytest
 
+from foundationdb_tpu.loadgen.deploy import free_ports
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def free_ports(n: int) -> list[int]:
-    socks, ports = [], []
-    for _ in range(n):
-        s = socket.create_server(("127.0.0.1", 0))
-        socks.append(s)
-        ports.append(s.getsockname()[1])
-    for s in socks:
-        s.close()
-    return ports
 
 
 def run_cli(spec_path: str, cmds: str):
@@ -85,82 +73,43 @@ PRI = {"sequencer": [0], "resolver": [0], "tlog": [0, 1], "proxy": [0],
        "storage": [0]}
 REM = {"sequencer": [1], "resolver": [1], "tlog": [2, 3], "proxy": [1],
        "storage": [1]}
-ALL_ROLES = ("sequencer", "resolver", "tlog", "storage", "proxy",
-             "satellite_tlog")
 
 
 @pytest.fixture
-def multiregion(tmp_path_factory):
-    tmp = tmp_path_factory.mktemp("mregion")
-    ports = iter(free_ports(14))
-    spec = {
-        "controller": [f"127.0.0.1:{next(ports)}"],
-        "sequencer": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-        "resolver": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-        "tlog": [f"127.0.0.1:{next(ports)}" for _ in range(4)],
-        "storage": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-        "proxy": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-        "satellite_tlog": [f"127.0.0.1:{next(ports)}"],
-        "regions": {"pri": PRI, "rem": REM},
-        "engine": "cpu",
-    }
-    spec_path = tmp / "cluster.json"
-    spec_path.write_text(json.dumps(spec))
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    procs: dict[tuple, subprocess.Popen] = {}
+def multiregion(cluster_factory):
+    """Two regions (a sequencer, a resolver, two tlogs, a proxy and a
+    storage each), one satellite tlog and a controller, each process with
+    a data dir; yields (spec, spec path, cluster)."""
+    ports = iter(free_ports(13))
 
-    def launch(role, i):
-        d = tmp / "data" / f"{role}{i}"
-        d.mkdir(parents=True, exist_ok=True)
-        errlog = open(tmp / f"{role}{i}.err.log", "ab")
-        p = subprocess.Popen(
-            [sys.executable, "-m", "foundationdb_tpu.server",
-             "--cluster", str(spec_path), "--role", role,
-             "--index", str(i), "--data-dir", str(d)],
-            cwd=REPO, env=env, stdout=subprocess.PIPE,
-            stderr=errlog, text=True,
-        )
-        errlog.close()
-        procs[(role, i)] = p
-        return p
+    def addrs(n):
+        return [f"127.0.0.1:{next(ports)}" for _ in range(n)]
 
-    for role in ALL_ROLES:
-        for i in range(len(spec[role])):
-            launch(role, i)
-    launch("controller", 0)
-
-    try:
-        for p in procs.values():
-            line = p.stdout.readline()
-            assert "ready" in line, line
-        yield spec, str(spec_path), procs, launch
-    finally:
-        for p in procs.values():
-            if p.poll() is None:
-                p.send_signal(signal.SIGKILL)
-        for p in procs.values():
-            p.wait()
+    c = cluster_factory(managed=True, data_dirs=True, ratekeeper=False,
+                        spec_extra={
+                            "sequencer": addrs(2), "resolver": addrs(2),
+                            "tlog": addrs(4), "storage": addrs(2),
+                            "proxy": addrs(2), "satellite_tlog": addrs(1),
+                            "regions": {"pri": PRI, "rem": REM}})
+    return c.spec, c.spec_path, c
 
 
-def kill_region(procs, region: dict) -> None:
+def kill_region(cluster, region: dict) -> None:
     for role, idxs in region.items():
         for i in idxs:
-            p = procs[(role, i)]
-            if p.poll() is None:
-                p.send_signal(signal.SIGKILL)
-            p.wait()
+            cluster.kill_role(f"{role}{i}")
 
 
 class TestRegionFailover:
     def test_primary_region_loss_is_lossless(self, multiregion):
-        spec, spec_path, procs, launch = multiregion
+        spec, spec_path, cluster = multiregion
         cli_ok(spec_path, "writemode on; set mr/a v1; set mr/b v2")
         st = controller_status(spec)
         assert st.get("active_region") == "pri"
         assert st["generation"].get("satellite_tlog") == [0]
 
         # The ENTIRE primary region goes dark — chain roles AND storage.
-        kill_region(procs, PRI)
+        kill_region(cluster, PRI)
 
         st = wait_status(
             spec, lambda s: s.get("active_region") == "rem"
@@ -173,9 +122,9 @@ class TestRegionFailover:
         assert all(v in out.stdout for v in ("v1", "v2", "v3")), out.stdout
 
     def test_failback_after_heal(self, multiregion):
-        spec, spec_path, procs, launch = multiregion
+        spec, spec_path, cluster = multiregion
         cli_ok(spec_path, "writemode on; set fb/a v1")
-        kill_region(procs, PRI)
+        kill_region(cluster, PRI)
         wait_status(spec, lambda s: s.get("active_region") == "rem"
                     and not s["recovering"])
         cli_ok(spec_path, "writemode on; set fb/b v2")
@@ -184,8 +133,7 @@ class TestRegionFailover:
         # as standby (storage replica catches up from the rem chain).
         for role, idxs in PRI.items():
             for i in idxs:
-                launch(role, i)
-                assert "ready" in procs[(role, i)].stdout.readline()
+                cluster.restart_role(f"{role}{i}")
         wait_status(
             spec, lambda s: sorted(s["generation"].get("storage", []))
             == [0, 1] and not s["recovering"])
@@ -193,7 +141,7 @@ class TestRegionFailover:
 
         # Now the REM region dies: the database must move back to pri —
         # including commits that only ever existed in the rem generation.
-        kill_region(procs, REM)
+        kill_region(cluster, REM)
         wait_status(spec, lambda s: s.get("active_region") == "pri"
                     and not s["recovering"])
         out = cli_ok(spec_path,
@@ -249,7 +197,7 @@ class TestRegionPartition:
         acks after the lock can exist (the reference's epoch fencing via
         tlog locks) — and every write the client ever got an ack for
         must read back afterwards."""
-        spec, spec_path, procs, launch = multiregion
+        spec, spec_path, cluster = multiregion
         cli_ok(spec_path, "writemode on; set pp/a v1; set pp/b v2")
 
         partition_primary(
@@ -309,14 +257,12 @@ class TestNoFlipWithoutSalvage:
         wait. When the partition expires it locks the primary's own
         tlogs and heals IN region; the restarted satellite folds back
         into a later generation; every ack survives."""
-        spec, spec_path, procs, launch = multiregion
+        spec, spec_path, cluster = multiregion
         cli_ok(spec_path, "writemode on; set nf/a v1; set nf/b v2")
         st = controller_status(spec)
         assert st.get("active_region") == "pri"
 
-        p = procs[("satellite_tlog", 0)]
-        p.send_signal(signal.SIGKILL)
-        p.wait()
+        cluster.kill_role("satellite_tlog0")
         partition_primary(
             spec,
             [("controller", 0)]
@@ -339,8 +285,7 @@ class TestNoFlipWithoutSalvage:
 
         # Partition expires: the controller heals IN region from the
         # primary's own tlogs; the relaunched satellite rejoins.
-        launch("satellite_tlog", 0)
-        assert "ready" in procs[("satellite_tlog", 0)].stdout.readline()
+        cluster.restart_role("satellite_tlog0")
         wait_status(
             spec, lambda s: s.get("active_region") == "pri"
             and not s["recovering"]

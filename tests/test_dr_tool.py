@@ -4,59 +4,13 @@ replicate → pause → switch resumes from the progress key, drains, locks
 the source; the destination then serves every acked commit.
 """
 
-import json
 import os
-import signal
-import socket
 import subprocess
 import sys
 import time
 
-import pytest
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ENV = dict(os.environ, JAX_PLATFORMS="cpu")
-
-
-def free_ports(n: int) -> list[int]:
-    socks, ports = [], []
-    for _ in range(n):
-        s = socket.create_server(("127.0.0.1", 0))
-        socks.append(s)
-        ports.append(s.getsockname()[1])
-    for s in socks:
-        s.close()
-    return ports
-
-
-def mini_spec(ports) -> dict:
-    return {
-        "sequencer": [f"127.0.0.1:{next(ports)}"],
-        "resolver": [f"127.0.0.1:{next(ports)}"],
-        "tlog": [f"127.0.0.1:{next(ports)}"],
-        "storage": [f"127.0.0.1:{next(ports)}"],
-        "proxy": [f"127.0.0.1:{next(ports)}"],
-        "engine": "cpu",
-    }
-
-
-def boot(spec, spec_path, tmp, tag):
-    procs = []
-    for role, addrs in spec.items():
-        if role == "engine":
-            continue
-        for i in range(len(addrs)):
-            errlog = open(os.path.join(tmp, f"{tag}.{role}{i}.err"), "ab")
-            procs.append(subprocess.Popen(
-                [sys.executable, "-m", "foundationdb_tpu.server",
-                 "--cluster", spec_path, "--role", role, "--index", str(i)],
-                cwd=REPO, env=ENV, stdout=subprocess.PIPE, stderr=errlog,
-                text=True,
-            ))
-            errlog.close()
-    for p in procs:
-        assert "ready" in p.stdout.readline()
-    return procs
 
 
 def cli(spec_path, cmds, tries=30):
@@ -81,54 +35,39 @@ def dr(cmd, src, dst, *extra, timeout=180):
     )
 
 
-def test_deployed_dr_replicate_then_switch(tmp_path_factory):
-    tmp = str(tmp_path_factory.mktemp("drtool"))
-    ports = iter(free_ports(10))
-    src_spec, dst_spec = mini_spec(ports), mini_spec(ports)
-    src_path = os.path.join(tmp, "src.json")
-    dst_path = os.path.join(tmp, "dst.json")
-    with open(src_path, "w") as f:
-        json.dump(src_spec, f)
-    with open(dst_path, "w") as f:
-        json.dump(dst_spec, f)
+def test_deployed_dr_replicate_then_switch(cluster_factory):
+    # Two clusters, each one process a role and no ratekeeper.
+    src_path, dst_path = (
+        cluster_factory(proxies=1, ratekeeper=False).spec_path
+        for _ in range(2))
+    cli(src_path, "writemode on; set dr/a v1; set dr/b v2")
+    r = dr("replicate", src_path, dst_path, "--duration", "8")
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert "replicating" in r.stdout
 
-    procs = boot(src_spec, src_path, tmp, "src") + \
-        boot(dst_spec, dst_path, tmp, "dst")
-    try:
-        cli(src_path, "writemode on; set dr/a v1; set dr/b v2")
-        r = dr("replicate", src_path, dst_path, "--duration", "8")
-        assert r.returncode == 0, r.stdout + r.stderr
-        assert "replicating" in r.stdout
+    st = dr("status", src_path, dst_path)
+    assert st.returncode == 0 and "applied=" in st.stdout
 
-        st = dr("status", src_path, dst_path)
-        assert st.returncode == 0 and "applied=" in st.stdout
+    cli(src_path, "writemode on; set dr/c v3")  # lands post-pause
+    sw = dr("switch", src_path, dst_path)
+    assert sw.returncode == 0, sw.stdout + sw.stderr
+    assert "switched at version" in sw.stdout
+    # `switch` must have RESUMED (progress key found, tagging still
+    # on), not re-bootstrapped from scratch.
+    assert "resumed from 0" not in sw.stdout, sw.stdout
 
-        cli(src_path, "writemode on; set dr/c v3")  # lands post-pause
-        sw = dr("switch", src_path, dst_path)
-        assert sw.returncode == 0, sw.stdout + sw.stderr
-        assert "switched at version" in sw.stdout
-        # `switch` must have RESUMED (progress key found, tagging still
-        # on), not re-bootstrapped from scratch.
-        assert "resumed from 0" not in sw.stdout, sw.stdout
+    out = cli(dst_path, "getrange dr/ dr0")
+    assert all(v in out.stdout for v in ("v1", "v2", "v3")), out.stdout
 
-        out = cli(dst_path, "getrange dr/ dr0")
-        assert all(v in out.stdout for v in ("v1", "v2", "v3")), out.stdout
+    # Source is locked: plain writes fail.
+    bad = subprocess.run(
+        [sys.executable, "-m", "foundationdb_tpu.cli",
+         "--cluster", src_path, "--exec", "writemode on; set dr/x y"],
+        cwd=REPO, env=ENV, capture_output=True, text=True, timeout=60,
+    )
+    assert bad.returncode != 0 or "ERROR" in bad.stdout, bad.stdout
 
-        # Source is locked: plain writes fail.
-        bad = subprocess.run(
-            [sys.executable, "-m", "foundationdb_tpu.cli",
-             "--cluster", src_path, "--exec", "writemode on; set dr/x y"],
-            cwd=REPO, env=ENV, capture_output=True, text=True, timeout=60,
-        )
-        assert bad.returncode != 0 or "ERROR" in bad.stdout, bad.stdout
-
-        # abort unlocks the (old) source again.
-        ab = dr("abort", src_path, dst_path)
-        assert ab.returncode == 0, ab.stdout + ab.stderr
-        cli(src_path, "writemode on; set dr/y v4; get dr/y")
-    finally:
-        for p in procs:
-            if p.poll() is None:
-                p.send_signal(signal.SIGKILL)
-        for p in procs:
-            p.wait()
+    # abort unlocks the (old) source again.
+    ab = dr("abort", src_path, dst_path)
+    assert ab.returncode == 0, ab.stdout + ab.stderr
+    cli(src_path, "writemode on; set dr/y v4; get dr/y")

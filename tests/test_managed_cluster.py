@@ -9,10 +9,7 @@ produces in production (reference: fdbserver workers re-recruited by
 ClusterController.actor.cpp after reboot).
 """
 
-import json
 import os
-import signal
-import socket
 import subprocess
 import sys
 import time
@@ -20,17 +17,6 @@ import time
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def free_ports(n: int) -> list[int]:
-    socks, ports = [], []
-    for _ in range(n):
-        s = socket.create_server(("127.0.0.1", 0))
-        socks.append(s)
-        ports.append(s.getsockname()[1])
-    for s in socks:
-        s.close()
-    return ports
 
 
 def run_cli(spec_path: str, cmds: str):
@@ -43,60 +29,12 @@ def run_cli(spec_path: str, cmds: str):
 
 
 @pytest.fixture
-def managed(tmp_path_factory):
-    tmp = tmp_path_factory.mktemp("managed")
-    ports = iter(free_ports(10))
-    spec = {
-        "controller": [f"127.0.0.1:{next(ports)}"],
-        "sequencer": [f"127.0.0.1:{next(ports)}"],
-        "resolver": [f"127.0.0.1:{next(ports)}"],
-        "tlog": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-        "storage": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-        "proxy": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-        "engine": "cpu",
-    }
-    spec_path = tmp / "cluster.json"
-    spec_path.write_text(json.dumps(spec))
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    procs: dict[tuple, subprocess.Popen] = {}
-
-    def launch(role, i):
-        d = tmp / "data" / f"{role}{i}"
-        d.mkdir(parents=True, exist_ok=True)
-        # stderr to a FILE, not the pipe: supervise/controller chatter over
-        # a long recovery would fill an unread 64KB pipe and block the
-        # server's event loop mid-test. stdout stays piped for the single
-        # "ready" line.
-        errlog = open(tmp / f"{role}{i}.err.log", "ab")
-        p = subprocess.Popen(
-            [sys.executable, "-m", "foundationdb_tpu.server",
-             "--cluster", str(spec_path), "--role", role,
-             "--index", str(i), "--data-dir", str(d)],
-            cwd=REPO, env=env, stdout=subprocess.PIPE,
-            stderr=errlog, text=True,
-        )
-        errlog.close()  # child holds its own fd
-        procs[(role, i)] = p
-        return p
-
-    # Workers first, controller last (any order works — the controller's
-    # bootstrap retries — but this keeps boot fast).
-    for role in ("sequencer", "resolver", "tlog", "storage", "proxy"):
-        for i in range(len(spec[role])):
-            launch(role, i)
-    launch("controller", 0)
-
-    try:
-        for p in procs.values():
-            line = p.stdout.readline()
-            assert "ready" in line, line
-        yield spec, str(spec_path), procs, launch
-    finally:
-        for p in procs.values():
-            if p.poll() is None:
-                p.send_signal(signal.SIGKILL)
-        for p in procs.values():
-            p.wait()
+def managed(cluster_factory):
+    """A controller, 1 sequencer, 1 resolver, 2 tlogs, 2 storages, 2
+    proxies, each with a data dir; yields (spec, spec path, cluster)."""
+    c = cluster_factory(tlogs=2, storages=2, ratekeeper=False, managed=True,
+                        data_dirs=True)
+    return c.spec, c.spec_path, c
 
 
 def controller_status(spec: dict) -> dict:
@@ -125,20 +63,18 @@ def cli_ok(spec_path: str, cmds: str, tries: int = 45):
 
 class TestManagedHealing:
     def test_tlog_kill_heals_without_bounce(self, managed):
-        spec, spec_path, procs, launch = managed
+        spec, spec_path, cluster = managed
         cli_ok(spec_path, "writemode on; set mg/a v1; set mg/b v2")
 
         # kill -9 one tlog: the controller must form a new generation on
         # the survivors; commits resume; acked data still reads.
-        procs[("tlog", 1)].send_signal(signal.SIGKILL)
-        procs[("tlog", 1)].wait()
+        cluster.kill_role("tlog1")
         out = cli_ok(spec_path, "writemode on; set mg/c v3; getrange mg/ mg0")
         assert "v1" in out.stdout and "v2" in out.stdout and "v3" in out.stdout
 
         # Restart the killed tlog (what fdbmonitor does): the controller
         # folds it back in with another generation change; writes continue.
-        launch("tlog", 1)
-        assert "ready" in procs[("tlog", 1)].stdout.readline()
+        cluster.restart_role("tlog1")
         deadline = time.monotonic() + 90
         rejoined = False
         while time.monotonic() < deadline and not rejoined:
@@ -158,15 +94,13 @@ class TestManagedHealing:
         """Both tlogs die at once (rack loss): no live chain to lock, so
         the controller must fall back to the durable disk-resume path once
         the restarted workers all report fresh — not spin forever."""
-        spec, spec_path, procs, launch = managed
+        spec, spec_path, cluster = managed
         cli_ok(spec_path, "writemode on; set rk/a v1; set rk/b v2")
         time.sleep(1)
         for i in (0, 1):
-            procs[("tlog", i)].send_signal(signal.SIGKILL)
-            procs[("tlog", i)].wait()
+            cluster.kill_role(f"tlog{i}")
         for i in (0, 1):
-            launch("tlog", i)
-            assert "ready" in procs[("tlog", i)].stdout.readline()
+            cluster.restart_role(f"tlog{i}")
         out = cli_ok(spec_path, "getrange rk/ rk0", tries=90)
         assert "v1" in out.stdout and "v2" in out.stdout, out.stdout
         cli_ok(spec_path, "writemode on; set rk/c v3; get rk/c")
@@ -176,19 +110,12 @@ class TestManagedHealing:
         spec + data dirs — the controller's bootstrap resumes the tlog
         chains from disk (truncating the unacked suffix) and acked data
         reads back in a new epoch."""
-        spec, spec_path, procs, launch = managed
+        spec, spec_path, cluster = managed
         cli_ok(spec_path, "writemode on; set fb/a v1; set fb/b v2")
         time.sleep(2)  # let pulls/flushes settle a beat
-        for p in procs.values():
-            p.send_signal(signal.SIGKILL)
-        for p in procs.values():
-            p.wait()
-        for role in ("sequencer", "resolver", "tlog", "storage", "proxy"):
-            for i in range(len(spec[role])):
-                launch(role, i)
-        launch("controller", 0)
-        for key, p in procs.items():
-            assert "ready" in p.stdout.readline(), key
+        for p in cluster.procs:
+            cluster.kill_role(p.name)
+        cluster.start()
         out = cli_ok(spec_path, "getrange fb/ fb0")
         assert "v1" in out.stdout and "v2" in out.stdout
         cli_ok(spec_path, "writemode on; set fb/c v3; get fb/c")
@@ -196,16 +123,14 @@ class TestManagedHealing:
         assert st["epoch"] >= 2  # durable restart started a new generation
 
     def test_sequencer_kill_heals_after_restart(self, managed):
-        spec, spec_path, procs, launch = managed
+        spec, spec_path, cluster = managed
         cli_ok(spec_path, "writemode on; set sq/a v1")
 
-        procs[("sequencer", 0)].send_signal(signal.SIGKILL)
-        procs[("sequencer", 0)].wait()
+        cluster.kill_role("sequencer0")
         time.sleep(2)  # let the failure be observed
         # There is exactly one sequencer process in the spec; recovery
         # waits for its restart (fdbmonitor's job — emulated here).
-        launch("sequencer", 0)
-        assert "ready" in procs[("sequencer", 0)].stdout.readline()
+        cluster.restart_role("sequencer0")
 
         out = cli_ok(spec_path, "writemode on; set sq/b v2; getrange sq/ sq0")
         assert "v1" in out.stdout and "v2" in out.stdout
@@ -215,7 +140,7 @@ class TestManagedHealing:
         and a locked database must stay locked through recruitment —
         recruit_proxy with defaults silently dropped both (stream gap /
         stale-client commits after switchover)."""
-        spec, spec_path, procs, launch = managed
+        spec, spec_path, cluster = managed
 
         def proxy_rpc(method, *args):
             from foundationdb_tpu.runtime.net import NetTransport, RealLoop
@@ -239,8 +164,7 @@ class TestManagedHealing:
         time.sleep(3)  # > one heartbeat: the controller sweep caches flags
 
         epoch0 = controller_status(spec)["epoch"]
-        procs[("tlog", 1)].send_signal(signal.SIGKILL)
-        procs[("tlog", 1)].wait()
+        cluster.kill_role("tlog1")
         deadline = time.monotonic() + 90
         healed = False
         while time.monotonic() < deadline and not healed:
@@ -264,7 +188,7 @@ class TestManagedHealing:
         lock/unlock (1038 at the proxies), exclude/include of a chain
         process (generation membership via the controller), configure
         (chain-role counts), coordinators."""
-        spec, spec_path, procs, launch = managed
+        spec, spec_path, cluster = managed
         cli_ok(spec_path, "writemode on; set op/a v1")
 
         # lock: non-lock-aware writes fail; unlock: they work again.
@@ -339,7 +263,7 @@ class TestManagedHealing:
         serve path and reports a consistent JSON verdict."""
         import json as _json
 
-        spec, spec_path, procs, launch = managed
+        spec, spec_path, cluster = managed
         cli_ok(spec_path, "writemode on; set ck/a v1; set ck/b v2; set ck/c v3")
         out = cli_ok(spec_path, "consistencycheck")
         rep = _json.loads(out.stdout)
@@ -373,15 +297,14 @@ class TestDeployedChaos:
         surviving tlog: recovery cannot lock the chain until the fault
         expires — it must stall (not corrupt), then complete, with a
         client writing throughout and no acked write lost."""
-        spec, spec_path, procs, launch = managed
+        spec, spec_path, cluster = managed
         cli_ok(spec_path, "writemode on; set ch/a v1")
 
         host, port = spec["tlog"][0].rsplit(":", 1)
         out = admin_rpc(spec, "controller", 0, "inject_fault",
                         host, int(port), "drop", 0.05, 8.0)
         assert "drop" in out
-        procs[("tlog", 1)].send_signal(signal.SIGKILL)
-        procs[("tlog", 1)].wait()
+        cluster.kill_role("tlog1")
 
         # Writes keep retrying through the stalled heal and land once the
         # fault expires and recovery completes.
@@ -397,17 +320,14 @@ class TestDeployedChaos:
         controller is recruiting: recovery must retry until fdbmonitor
         (the test) brings the sequencer back, and every acked write
         survives the double failure."""
-        spec, spec_path, procs, launch = managed
+        spec, spec_path, cluster = managed
         cli_ok(spec_path, "writemode on; set sk/a v1; set sk/b v2")
 
-        procs[("tlog", 1)].send_signal(signal.SIGKILL)
-        procs[("tlog", 1)].wait()
+        cluster.kill_role("tlog1")
         time.sleep(1.5)  # sweep notices; recovery begins
-        procs[("sequencer", 0)].send_signal(signal.SIGKILL)
-        procs[("sequencer", 0)].wait()
+        cluster.kill_role("sequencer0")
         time.sleep(2)
-        launch("sequencer", 0)
-        assert "ready" in procs[("sequencer", 0)].stdout.readline()
+        cluster.restart_role("sequencer0")
 
         out = cli_ok(spec_path,
                      "writemode on; set sk/c v3; getrange sk/ sk0",
@@ -418,7 +338,7 @@ class TestDeployedChaos:
         """Delay-mode fault: a slow-but-alive proxy→tlog link (the hard
         case — no failure detector trips). Commits must still complete,
         just slower."""
-        spec, spec_path, procs, launch = managed
+        spec, spec_path, cluster = managed
         cli_ok(spec_path, "writemode on; set cl/a v1")
         host, port = spec["tlog"][0].rsplit(":", 1)
         for p in range(len(spec["proxy"])):
@@ -429,80 +349,35 @@ class TestDeployedChaos:
                      tries=60)
         assert "v1" in out.stdout and "v2" in out.stdout
 
-    def test_heal_with_replicated_storage(self, tmp_path_factory):
+    def test_heal_with_replicated_storage(self, cluster_factory):
         """Managed recruitment composes with `replicas: 2`: a tlog kill
         heals with a generation change, and a storage replica death
         afterwards costs availability nothing (team failover) — the
         recruitment path is replication-agnostic and this proves it."""
-        import json as _json
+        cluster = cluster_factory(
+            tlogs=2, storages=2, ratekeeper=False, managed=True,
+            data_dirs=True, spec_extra={"replicas": 2})
+        spec_path = cluster.spec_path
+        cli_ok(spec_path, "writemode on; set hr/a v1; set hr/b v2")
+        time.sleep(1.0)  # replicas pull their tag streams
 
-        tmp = tmp_path_factory.mktemp("managed_repl")
-        ports = iter(free_ports(10))
-        spec = {
-            "controller": [f"127.0.0.1:{next(ports)}"],
-            "sequencer": [f"127.0.0.1:{next(ports)}"],
-            "resolver": [f"127.0.0.1:{next(ports)}"],
-            "tlog": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-            "storage": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-            "proxy": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-            "engine": "cpu",
-            "replicas": 2,
-        }
-        spec_path = tmp / "cluster.json"
-        spec_path.write_text(_json.dumps(spec))
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        procs: dict = {}
+        # Replica parity on the deployed plane: consistencycheck walks
+        # both members of every 2-replica team via their own serve
+        # paths (scanner waits out pull lag rather than flagging it).
+        out = cli_ok(spec_path, "consistencycheck")
+        assert '"status": "consistent"' in out.stdout, out.stdout
+        assert '"replicas_compared": 4' in out.stdout, out.stdout
 
-        def launch(role, i):
-            d = tmp / "data" / f"{role}{i}"
-            d.mkdir(parents=True, exist_ok=True)
-            errlog = open(tmp / f"{role}{i}.err.log", "ab")
-            p = subprocess.Popen(
-                [sys.executable, "-m", "foundationdb_tpu.server",
-                 "--cluster", str(spec_path), "--role", role,
-                 "--index", str(i), "--data-dir", str(d)],
-                cwd=REPO, env=env, stdout=subprocess.PIPE,
-                stderr=errlog, text=True,
-            )
-            errlog.close()
-            procs[(role, i)] = p
-            return p
+        # Chain-role heal under replication.
+        cluster.kill_role("tlog1")
+        out = cli_ok(spec_path,
+                     "writemode on; set hr/c v3; getrange hr/ hr0",
+                     tries=90)
+        assert all(v in out.stdout for v in ("v1", "v2", "v3"))
 
-        for role in ("sequencer", "resolver", "tlog", "storage", "proxy"):
-            for i in range(len(spec[role])):
-                launch(role, i)
-        launch("controller", 0)
-        try:
-            for p in procs.values():
-                assert "ready" in p.stdout.readline()
-            cli_ok(str(spec_path), "writemode on; set hr/a v1; set hr/b v2")
-            time.sleep(1.0)  # replicas pull their tag streams
-
-            # Replica parity on the deployed plane: consistencycheck walks
-            # both members of every 2-replica team via their own serve
-            # paths (scanner waits out pull lag rather than flagging it).
-            out = cli_ok(str(spec_path), "consistencycheck")
-            assert '"status": "consistent"' in out.stdout, out.stdout
-            assert '"replicas_compared": 4' in out.stdout, out.stdout
-
-            # Chain-role heal under replication.
-            procs[("tlog", 1)].send_signal(signal.SIGKILL)
-            procs[("tlog", 1)].wait()
-            out = cli_ok(str(spec_path),
-                         "writemode on; set hr/c v3; getrange hr/ hr0",
-                         tries=90)
-            assert all(v in out.stdout for v in ("v1", "v2", "v3"))
-
-            # Now a storage replica dies: reads AND writes keep working.
-            procs[("storage", 1)].send_signal(signal.SIGKILL)
-            procs[("storage", 1)].wait()
-            out = cli_ok(str(spec_path),
-                         "writemode on; set hr/d v4; getrange hr/ hr0",
-                         tries=90)
-            assert all(v in out.stdout for v in ("v1", "v2", "v3", "v4"))
-        finally:
-            for p in procs.values():
-                if p.poll() is None:
-                    p.send_signal(signal.SIGKILL)
-            for p in procs.values():
-                p.wait()
+        # Now a storage replica dies: reads AND writes keep working.
+        cluster.kill_role("storage1")
+        out = cli_ok(spec_path,
+                     "writemode on; set hr/d v4; getrange hr/ hr0",
+                     tries=90)
+        assert all(v in out.stdout for v in ("v1", "v2", "v3", "v4"))

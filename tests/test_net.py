@@ -7,6 +7,7 @@ drives answer RPCs over real TCP, and a lost peer surfaces as
 BrokenPromise exactly like a sim kill_process.
 """
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -20,6 +21,8 @@ from foundationdb_tpu.runtime import wire
 from foundationdb_tpu.runtime.flow import BrokenPromise
 from foundationdb_tpu.runtime.net import MAX_FRAME, NetTransport, RealLoop, rpc
 from foundationdb_tpu.runtime.tlog import TLog
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class TestWireFormat:
@@ -239,7 +242,7 @@ class TestCrossProcess:
         proc = subprocess.Popen(
             [sys.executable, "-c", SERVER_SCRIPT],
             stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
-            cwd="/root/repo",
+            cwd=REPO,
         )
         try:
             port = int(proc.stdout.readline())
@@ -320,7 +323,7 @@ class TestCrossProcessPipeline:
         proc = subprocess.Popen(
             [sys.executable, "-c", PIPELINE_SERVER],
             stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
-            cwd="/root/repo",
+            cwd=REPO,
         )
         try:
             port = int(proc.stdout.readline())
@@ -376,7 +379,7 @@ class TestNativeCClient:
         proc = subprocess.Popen(
             [sys.executable, "-c", PIPELINE_SERVER],
             stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
-            cwd="/root/repo",
+            cwd=REPO,
         )
         try:
             port = int(proc.stdout.readline())
@@ -430,7 +433,7 @@ class TestNativeCClientPipelining:
         proc = subprocess.Popen(
             [sys.executable, "-c", PIPELINE_SERVER],
             stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
-            cwd="/root/repo",
+            cwd=REPO,
         )
         try:
             port = int(proc.stdout.readline())
@@ -741,3 +744,28 @@ class TestReconnectBackoff:
             server.close()
         finally:
             client.close()
+
+
+class TestTlsDialRefused:
+    """Over TLS the ClientHello is written as the connection object is
+    made; on loopback a peer that does not listen yet has refused by then.
+    That must fail the call as any dead link does (BrokenPromise, which
+    every retry loop catches), not unwind its caller with the selector's
+    ValueError for a closed socket (PR 28: `[storage0.run] actor failed:
+    ValueError: Invalid file descriptor: -1` at every TLS cluster's boot,
+    and a proxy's first push lost without one retry)."""
+
+    def test_a_refused_tls_dial_is_a_broken_promise(self, tmp_path):
+        from foundationdb_tpu.loadgen.deploy import free_ports
+        from tests.test_tls import make_ca_and_leaf
+
+        loop = RealLoop()
+        t = NetTransport(loop, tls=make_ca_and_leaf(str(tmp_path), "main"))
+        nobody = ("127.0.0.1", free_ports(1)[0])
+        try:
+            for _ in range(3):  # the dial backoff's refusals too
+                with pytest.raises(BrokenPromise):
+                    loop.run_until(t.endpoint(nobody, "tlog").get_version(),
+                                   timeout=10)
+        finally:
+            t.close()

@@ -455,3 +455,75 @@ class TestRecovery:
             return "ok"
 
         assert run(c, main()) == "ok"
+
+
+class TestRetiredProxy:
+    """A recovery retires a proxy by cancelling its batch loop and answering
+    what it holds. That was the QUEUE alone: a batch the loop had taken out
+    and was waiting on the sequencer for, and a batch in _process waiting
+    on a resolver or tlog of the old generation that never replies,
+    belonged to nobody, and their clients waited for ever over a healthy
+    connection (PR 28: test_sigkill_tlogs_mid_push_salvages_acked hung in
+    1 of 2 whole runs and 4 of ~370 runs under load, the cluster healthy
+    and idle behind it)."""
+
+    class Unanswering:
+        """A peer whose replies to `silent` never come."""
+
+        def __init__(self, *silent):
+            from foundationdb_tpu.runtime.flow import Promise
+
+            self.silent = silent
+            self.asked = Promise()
+            self._never = Promise()
+
+        def __getattr__(self, method):
+            def call(*_a, **_kw):
+                if method in self.silent:
+                    self.asked.send(None)
+                    return self._never.future
+                return self._answer({"get_commit_version": (0, 1)}[method])
+
+            return call
+
+        @staticmethod
+        async def _answer(value):
+            return value
+
+    @pytest.mark.parametrize("held", ["by_the_batcher", "in_process", "grv"])
+    def test_what_a_retired_proxy_holds_is_answered(self, held):
+        from foundationdb_tpu.core.errors import (
+            CommitUnknownResult, ProcessKilled)
+        from foundationdb_tpu.runtime.commit_proxy import (
+            CommitProxy, CommitRequest)
+        from foundationdb_tpu.runtime.flow import Loop
+        from foundationdb_tpu.runtime.grv_proxy import GrvProxy
+        from foundationdb_tpu.runtime.shardmap import KeyShardMap
+
+        loop = Loop(seed=0)
+        one = KeyShardMap.uniform(1)
+        if held == "grv":
+            peer = self.Unanswering("get_live_committed_version")
+            proxy = GrvProxy(loop, peer)
+            call, want = proxy.get_read_version(), ProcessKilled
+        else:
+            peer = self.Unanswering(
+                "get_commit_version" if held == "by_the_batcher"
+                else "resolve")
+            proxy = CommitProxy(loop, peer, [peer], one, [], one)
+            call = proxy.commit(CommitRequest(read_version=0))
+            want = (ProcessKilled if held == "by_the_batcher"
+                    else CommitUnknownResult)
+
+        async def main():
+            asked = loop.spawn(call, name="client")
+            batcher = loop.spawn(proxy.run(), name="batcher")
+            await peer.asked.future  # the request is out of the queue now
+            batcher.cancel()  # what Worker.recruit_proxy / stand_down do
+            if held != "grv":
+                proxy.retire("proxy retired by recovery")
+            with pytest.raises(want):
+                await asked
+            return "answered"
+
+        assert loop.run(main(), timeout=60) == "answered"

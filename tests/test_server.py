@@ -12,8 +12,6 @@ from __future__ import annotations
 
 import json
 import os
-import signal
-import socket
 import subprocess
 import sys
 import time
@@ -23,76 +21,11 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def free_ports(n: int) -> list[int]:
-    socks, ports = [], []
-    for _ in range(n):
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        socks.append(s)
-        ports.append(s.getsockname()[1])
-    for s in socks:
-        s.close()
-    return ports
-
-
 @pytest.fixture(scope="module")
-def cluster(tmp_path_factory):
-    """1 sequencer, 1 resolver, 2 tlogs, 2 storages, 2 proxies — each an
-    OS process; yields the spec path."""
-    tmp = tmp_path_factory.mktemp("cluster")
-    ports = iter(free_ports(9))
-    spec = {
-        "sequencer": [f"127.0.0.1:{next(ports)}"],
-        "resolver": [f"127.0.0.1:{next(ports)}"],
-        "tlog": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-        "storage": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-        "proxy": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-        "ratekeeper": [f"127.0.0.1:{next(ports)}"],
-        "engine": "cpu",
-    }
-    spec_path = tmp / "cluster.json"
-    spec_path.write_text(json.dumps(spec))
-
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    procs = []
-    try:
-        for role, addrs in spec.items():
-            if role in ("engine",):
-                continue
-            for i in range(len(addrs)):
-                procs.append(subprocess.Popen(
-                    [sys.executable, "-m", "foundationdb_tpu.server",
-                     "--cluster", str(spec_path), "--role", role,
-                     "--index", str(i)],
-                    cwd=REPO, env=env,
-                    stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                    text=True,
-                ))
-        # Readiness: every process prints "ready ..." once listening.
-        # Generous deadline: each boot imports jax (~seconds of CPU), and
-        # a loaded single-core runner boots the dozen processes serially
-        # — 30s flaked under a concurrent seed-mining batch. The select
-        # gate makes the deadline real: a bare readline() would block
-        # forever on a process wedged before its first line.
-        import select
-
-        deadline = time.monotonic() + 120
-        for p in procs:
-            while True:
-                remaining = deadline - time.monotonic()
-                assert remaining > 0, "cluster boot timed out"
-                readable, _, _ = select.select(
-                    [p.stdout], [], [], min(remaining, 5))
-                if readable:
-                    break
-            line = p.stdout.readline()
-            assert "ready" in line, line
-        yield str(spec_path)
-    finally:
-        for p in procs:
-            p.send_signal(signal.SIGKILL)
-        for p in procs:
-            p.wait()
+def cluster(module_cluster_factory):
+    """1 sequencer, 1 resolver, 2 tlogs, 2 storages, 2 proxies, a
+    ratekeeper — each an OS process; yields the spec path."""
+    return module_cluster_factory(tlogs=2, storages=2).spec_path
 
 
 def run_cli(spec_path: str, cmds: str) -> subprocess.CompletedProcess:
@@ -286,179 +219,76 @@ class TestBackupTool:
 
 
 class TestAdminKill:
-    def test_cli_kill_stops_process(self, tmp_path_factory):
+    def test_cli_kill_stops_process(self, cluster_factory):
         """fdbcli `kill` analogue: the admin shutdown RPC exits the target
         process cleanly (its supervisor decides on restart)."""
-        tmp = tmp_path_factory.mktemp("killtest")
-        port = free_ports(1)[0]
-        spec = {
-            "sequencer": [f"127.0.0.1:{port}"],
-            "resolver": ["127.0.0.1:1"], "tlog": ["127.0.0.1:1"],
-            "storage": ["127.0.0.1:1"], "proxy": ["127.0.0.1:1"],
-        }
-        spec_path = tmp / "cluster.json"
-        spec_path.write_text(json.dumps(spec))
-        p = subprocess.Popen(
-            [sys.executable, "-m", "foundationdb_tpu.server",
-             "--cluster", str(spec_path), "--role", "sequencer",
-             "--index", "0"],
+        c = cluster_factory(start=False, proxies=1, ratekeeper=False)
+        c.restart_role("sequencer0")  # the only role launched
+        out = subprocess.run(
+            [sys.executable, "-m", "foundationdb_tpu.cli",
+             "--cluster", c.spec_path, "--exec", "kill sequencer0"],
             cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
+            capture_output=True, text=True, timeout=60,
         )
-        try:
-            assert "ready" in p.stdout.readline()
-            out = subprocess.run(
-                [sys.executable, "-m", "foundationdb_tpu.cli",
-                 "--cluster", str(spec_path), "--exec", "kill sequencer0"],
-                cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
-                capture_output=True, text=True, timeout=60,
-            )
-            assert "shutting down" in out.stdout, out.stdout + out.stderr
-            assert p.wait(timeout=15) == 0  # clean exit
-        finally:
-            if p.poll() is None:
-                p.kill()
-                p.wait()
+        assert "shutting down" in out.stdout, out.stdout + out.stderr
+        assert c.proc("sequencer0").popen.wait(timeout=15) == 0  # clean exit
 
 
 class TestDurableDeployedRestart:
-    def test_full_bounce_preserves_acked_data(self, tmp_path_factory):
+    def test_full_bounce_preserves_acked_data(self, cluster_factory):
         """Deployed durable restart: write to a --data-dir cluster, kill
         every process, reboot the same spec+data — acked commits read
         back and new commits land (tlog from_disk + the sequencer's
         begin_epoch chain jump)."""
-        tmp = tmp_path_factory.mktemp("durable")
-        ports = iter(free_ports(9))
-        spec = {
-            "sequencer": [f"127.0.0.1:{next(ports)}"],
-            "resolver": [f"127.0.0.1:{next(ports)}"],
-            "tlog": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-            "storage": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-            "proxy": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-            "ratekeeper": [f"127.0.0.1:{next(ports)}"],
-            "engine": "cpu",
-        }
-        spec_path = tmp / "cluster.json"
-        spec_path.write_text(json.dumps(spec))
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-
-        def boot():
-            procs = []
-            for role, addrs in spec.items():
-                if role == "engine":
-                    continue
-                for i in range(len(addrs)):
-                    d = tmp / "data" / f"{role}{i}"
-                    d.mkdir(parents=True, exist_ok=True)
-                    procs.append(subprocess.Popen(
-                        [sys.executable, "-m", "foundationdb_tpu.server",
-                         "--cluster", str(spec_path), "--role", role,
-                         "--index", str(i), "--data-dir", str(d)],
-                        cwd=REPO, env=env, stdout=subprocess.PIPE,
-                        stderr=subprocess.STDOUT, text=True,
-                    ))
-            for p in procs:
-                assert "ready" in p.stdout.readline()
-            return procs
+        c = cluster_factory(tlogs=2, storages=2, data_dirs=True)
 
         def cli_ok(cmds, tries=30):
             for _ in range(tries):
-                r = run_cli(str(spec_path), cmds)
+                r = run_cli(c.spec_path, cmds)
                 if r.returncode == 0 and "ERROR" not in r.stdout:
                     return r
                 time.sleep(1)
             raise AssertionError(f"cli never succeeded: {r.stdout} {r.stderr}")
 
-        procs = boot()
-        try:
-            cli_ok("writemode on; set dur/a v1; set dur/b v2")
-            # Let tlog fsync/acks settle (acks are pre-reply, but give the
-            # pull/flush loops a beat so sqlite holds a prefix too).
-            time.sleep(2)
-        finally:
-            for p in procs:
-                p.send_signal(signal.SIGKILL)
-            for p in procs:
-                p.wait()
+        cli_ok("writemode on; set dur/a v1; set dur/b v2")
+        # Let tlog fsync/acks settle (acks are pre-reply, but give the
+        # pull/flush loops a beat so sqlite holds a prefix too).
+        time.sleep(2)
+        for p in c.procs:
+            c.kill_role(p.name)
 
-        procs = boot()
-        try:
-            out = cli_ok("getrange dur/ dur0")
-            assert "v1" in out.stdout and "v2" in out.stdout, out.stdout
-            cli_ok("writemode on; set dur/c v3; get dur/c")
-            out = cli_ok("getrange dur/ dur0")
-            assert "v3" in out.stdout
-        finally:
-            for p in procs:
-                p.send_signal(signal.SIGKILL)
-            for p in procs:
-                p.wait()
+        c.start()
+        out = cli_ok("getrange dur/ dur0")
+        assert "v1" in out.stdout and "v2" in out.stdout, out.stdout
+        cli_ok("writemode on; set dur/c v3; get dur/c")
+        out = cli_ok("getrange dur/ dur0")
+        assert "v3" in out.stdout
 
-    def test_mixed_tlog_state_refuses_boot(self, tmp_path_factory):
+    def test_mixed_tlog_state_refuses_boot(self, cluster_factory):
         """One tlog's disk queue lost while others recovered data: the
         sequencer must refuse to start (the fresh-chain fallback would
         false-ack new pushes on the recovered tlogs — silent data loss)
         rather than boot at version 0."""
-        tmp = tmp_path_factory.mktemp("mixed")
-        ports = iter(free_ports(7))
-        spec = {
-            "sequencer": [f"127.0.0.1:{next(ports)}"],
-            "resolver": [f"127.0.0.1:{next(ports)}"],
-            "tlog": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-            "storage": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-            "proxy": [f"127.0.0.1:{next(ports)}"],
-            "engine": "cpu",
-        }
-        spec_path = tmp / "cluster.json"
-        spec_path.write_text(json.dumps(spec))
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-
-        def launch(role, i):
-            d = tmp / "data" / f"{role}{i}"
-            d.mkdir(parents=True, exist_ok=True)
-            return subprocess.Popen(
-                [sys.executable, "-m", "foundationdb_tpu.server",
-                 "--cluster", str(spec_path), "--role", role,
-                 "--index", str(i), "--data-dir", str(d)],
-                cwd=REPO, env=env, stdout=subprocess.PIPE,
-                stderr=subprocess.STDOUT, text=True,
-            )
-
-        procs = []
-        for role, addrs in spec.items():
-            if role == "engine":
-                continue
-            for i in range(len(addrs)):
-                procs.append(launch(role, i))
-        try:
-            for p in procs:
-                assert "ready" in p.stdout.readline()
-            r = run_cli(str(spec_path), "writemode on; set mx/a v1")
-            assert r.returncode == 0 and "ERROR" not in r.stdout, r.stdout
-            time.sleep(1)
-        finally:
-            for p in procs:
-                p.send_signal(signal.SIGKILL)
-            for p in procs:
-                p.wait()
+        c = cluster_factory(proxies=1, tlogs=2, storages=2, ratekeeper=False,
+                            data_dirs=True)
+        r = run_cli(c.spec_path, "writemode on; set mx/a v1")
+        assert r.returncode == 0 and "ERROR" not in r.stdout, r.stdout
+        time.sleep(1)
+        for p in c.procs:
+            c.kill_role(p.name)
 
         # Blank one tlog's recovered state, reboot tlogs + the sequencer.
-        q = tmp / "data" / "tlog1" / "tlog1.q"
-        assert q.exists()
-        q.unlink()
-        tl0, tl1 = launch("tlog", 0), launch("tlog", 1)
-        seq = launch("sequencer", 0)
-        try:
-            assert "ready" in tl0.stdout.readline()
-            assert "ready" in tl1.stdout.readline()
-            out, _ = seq.communicate(timeout=120)
-            assert seq.returncode != 0, out
-            assert "mixed tlog recovery state" in out, out
-        finally:
-            for p in (tl0, tl1, seq):
-                if p.poll() is None:
-                    p.send_signal(signal.SIGKILL)
-                    p.wait()
+        q = os.path.join(c.proc("tlog1").data_dir, "tlog1.q")
+        assert os.path.exists(q)
+        os.unlink(q)
+        c.restart_role("tlog0")
+        c.restart_role("tlog1")
+        c.restart_role("sequencer0", wait=False)
+        seq = c.proc("sequencer0")
+        seq.popen.wait(timeout=120)
+        out = c.log_tail("sequencer0")
+        assert seq.popen.returncode != 0, out
+        assert "mixed tlog recovery state" in out, out
 
 
 class TestDeployedReplication:
@@ -468,84 +298,44 @@ class TestDeployedReplication:
     team failover — a deployed storage death no longer takes its shard
     offline."""
 
-    def test_reads_survive_replica_kill_and_catchup(self, tmp_path):
-        ports = iter(free_ports(9))
-        spec = {
-            "sequencer": [f"127.0.0.1:{next(ports)}"],
-            "resolver": [f"127.0.0.1:{next(ports)}"],
-            "tlog": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-            "storage": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-            "proxy": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-            "engine": "cpu",
-            "replicas": 2,
-        }
-        spec_path = tmp_path / "cluster.json"
-        spec_path.write_text(json.dumps(spec))
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        procs: dict = {}
+    def test_reads_survive_replica_kill_and_catchup(self, cluster_factory):
+        c = cluster_factory(tlogs=2, storages=2, ratekeeper=False,
+                            data_dirs=True, spec_extra={"replicas": 2})
+        spec_path = c.spec_path
 
-        def launch(role, i):
-            d = tmp_path / "data" / f"{role}{i}"
-            d.mkdir(parents=True, exist_ok=True)
-            p = subprocess.Popen(
-                [sys.executable, "-m", "foundationdb_tpu.server",
-                 "--cluster", str(spec_path), "--role", role,
-                 "--index", str(i), "--data-dir", str(d)],
-                cwd=REPO, env=env, stdout=subprocess.PIPE,
-                stderr=subprocess.DEVNULL, text=True,
-            )
-            procs[(role, i)] = p
-            return p
+        r = run_cli(spec_path,
+                    "writemode on; set rp/a v1; set rp/b v2; "
+                    "getrange rp/ rp0")
+        assert "v1" in r.stdout and "v2" in r.stdout, r.stdout
+        time.sleep(1.0)  # let replicas pull their tag streams
 
-        for role in ("sequencer", "resolver", "tlog", "storage", "proxy"):
-            for i in range(len(spec[role])):
-                launch(role, i)
-        try:
-            for p in procs.values():
-                assert "ready" in p.stdout.readline()
+        # Kill ONE replica: every key still reads (team failover) and
+        # writes continue (the dead tag just queues at the tlogs).
+        c.kill_role("storage1")
+        ok = None
+        for _ in range(30):
+            ok = run_cli(spec_path,
+                         "writemode on; set rp/c v3; getrange rp/ rp0")
+            if ok.returncode == 0 and all(
+                    v in ok.stdout for v in ("v1", "v2", "v3")):
+                break
+            time.sleep(1)
+        assert ok and all(v in ok.stdout for v in ("v1", "v2", "v3")), (
+            ok.stdout if ok else "never succeeded")
 
-            r = run_cli(str(spec_path),
-                        "writemode on; set rp/a v1; set rp/b v2; "
-                        "getrange rp/ rp0")
-            assert "v1" in r.stdout and "v2" in r.stdout, r.stdout
-            time.sleep(1.0)  # let replicas pull their tag streams
+        # Restart it: the tlog held its tag stream; it catches up.
+        c.restart_role("storage1")
+        time.sleep(2.0)
 
-            # Kill ONE replica: every key still reads (team failover) and
-            # writes continue (the dead tag just queues at the tlogs).
-            procs[("storage", 1)].send_signal(signal.SIGKILL)
-            procs[("storage", 1)].wait()
-            ok = None
-            for _ in range(30):
-                ok = run_cli(str(spec_path),
-                             "writemode on; set rp/c v3; getrange rp/ rp0")
-                if ok.returncode == 0 and all(
-                        v in ok.stdout for v in ("v1", "v2", "v3")):
-                    break
-                time.sleep(1)
-            assert ok and all(v in ok.stdout for v in ("v1", "v2", "v3")), (
-                ok.stdout if ok else "never succeeded")
-
-            # Restart it: the tlog held its tag stream; it catches up.
-            launch("storage", 1)
-            assert "ready" in procs[("storage", 1)].stdout.readline()
-            time.sleep(2.0)
-
-            # Now kill the OTHER replica: only the restarted one serves —
-            # proof it caught up on writes made while it was dead.
-            procs[("storage", 0)].send_signal(signal.SIGKILL)
-            procs[("storage", 0)].wait()
-            ok = None
-            for _ in range(30):
-                ok = run_cli(str(spec_path), "getrange rp/ rp0")
-                if ok.returncode == 0 and all(
-                        v in ok.stdout for v in ("v1", "v2", "v3")):
-                    break
-                time.sleep(1)
-            assert ok and all(v in ok.stdout for v in ("v1", "v2", "v3")), (
-                ok.stdout if ok else "never succeeded")
-        finally:
-            for p in procs.values():
-                if p.poll() is None:
-                    p.send_signal(signal.SIGKILL)
-            for p in procs.values():
-                p.wait()
+        # Now kill the OTHER replica: only the restarted one serves —
+        # proof it caught up on writes made while it was dead.
+        c.kill_role("storage0")
+        ok = None
+        for _ in range(30):
+            ok = run_cli(spec_path, "getrange rp/ rp0")
+            if ok.returncode == 0 and all(
+                    v in ok.stdout for v in ("v1", "v2", "v3")):
+                break
+            time.sleep(1)
+        assert ok and all(v in ok.stdout for v in ("v1", "v2", "v3")), (
+            ok.stdout if ok else "never succeeded")

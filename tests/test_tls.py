@@ -10,8 +10,6 @@ certificate from a DIFFERENT CA is rejected (mutual verification).
 import datetime
 import json
 import os
-import signal
-import socket
 import subprocess
 import sys
 import time
@@ -19,17 +17,6 @@ import time
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def free_ports(n: int) -> list[int]:
-    socks, ports = [], []
-    for _ in range(n):
-        s = socket.create_server(("127.0.0.1", 0))
-        socks.append(s)
-        ports.append(s.getsockname()[1])
-    for s in socks:
-        s.close()
-    return ports
 
 
 def make_ca_and_leaf(dirpath, prefix: str):
@@ -83,47 +70,15 @@ def make_ca_and_leaf(dirpath, prefix: str):
 
 
 @pytest.fixture
-def tls_cluster(tmp_path_factory):
-    tmp = tmp_path_factory.mktemp("tls")
-    certs = make_ca_and_leaf(str(tmp), "main")
-    ports = iter(free_ports(6))
-    spec = {
-        "sequencer": [f"127.0.0.1:{next(ports)}"],
-        "resolver": [f"127.0.0.1:{next(ports)}"],
-        "tlog": [f"127.0.0.1:{next(ports)}"],
-        "storage": [f"127.0.0.1:{next(ports)}" for _ in range(2)],
-        "proxy": [f"127.0.0.1:{next(ports)}"],
-        "engine": "cpu",
-        "tls": {"cert": certs["cert"], "key": certs["key"],
-                "ca": certs["ca"]},
-    }
-    spec_path = tmp / "cluster.json"
-    spec_path.write_text(json.dumps(spec))
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    procs = []
-    for role, addrs in spec.items():
-        if role in ("engine", "tls"):
-            continue
-        for i in range(len(addrs)):
-            errlog = open(tmp / f"{role}{i}.err.log", "ab")
-            procs.append(subprocess.Popen(
-                [sys.executable, "-m", "foundationdb_tpu.server",
-                 "--cluster", str(spec_path), "--role", role,
-                 "--index", str(i)],
-                cwd=REPO, env=env, stdout=subprocess.PIPE,
-                stderr=errlog, text=True,
-            ))
-            errlog.close()
-    try:
-        for p in procs:
-            assert "ready" in p.stdout.readline()
-        yield spec, str(spec_path), str(tmp)
-    finally:
-        for p in procs:
-            if p.poll() is None:
-                p.send_signal(signal.SIGKILL)
-        for p in procs:
-            p.wait()
+def tls_cluster(cluster_factory, tmp_path):
+    """A sequencer, a resolver, a tlog, two storages and a proxy that all
+    load one CA's leaf; yields (spec, spec path, the certificates' dir)."""
+    certs = make_ca_and_leaf(str(tmp_path), "main")
+    c = cluster_factory(proxies=1, storages=2, ratekeeper=False,
+                        spec_extra={"tls": {"cert": certs["cert"],
+                                            "key": certs["key"],
+                                            "ca": certs["ca"]}})
+    return c.spec, c.spec_path, str(tmp_path)
 
 
 def run_cli(spec_path: str, cmds: str):
