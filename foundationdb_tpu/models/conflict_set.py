@@ -198,6 +198,7 @@ class _RepackPlan(NamedTuple):
     new_rows: np.ndarray  # their int32 rows
     dims: tuple  # (lead, b, r, q, w)
     cv: int
+    cause: "str | None"  # the repacks_* counter that decided it
 
 
 class _DemotePlan(NamedTuple):
@@ -235,11 +236,15 @@ class _ResidentMirror:
     on every insert so id -> current rank stays exact as inserts shift
     the rank space."""
 
+    # frag_due's share of the capacity, as a divisor: a quarter. Half of a
+    # dictionary that is over half full is what a frag_due repack means to
+    # free, and what the dictionary has to grow by before the next.
+    FRAG_SHARE = 4
+
     def __init__(self, rows: np.ndarray, capacity: int, delta_slots: int,
-                 frag_threshold: float, tiered: bool = False):
+                 tiered: bool = False):
         self.capacity = int(capacity)
         self.delta_slots = int(delta_slots)
-        self.frag_threshold = float(frag_threshold)
         # Tiered mode (FDB_TPU_DICT_HOT_CAPACITY): the ID space is
         # promoted from "mirror" to authoritative COLD STORE. Ids of
         # demoted keys keep their tab entries, u64 rows and last-used
@@ -265,6 +270,14 @@ class _ResidentMirror:
         self.hot_by_id = np.zeros(self.capacity + 1, bool)
         self.reset(u64, rows, np.zeros(len(rows), np.int64),
                    np.ones(len(rows), bool))
+        # What frag_due decides from, as the last full repack left it
+        # (repacked()): the key count it ended with, the stale rows it had
+        # to keep (at the start the seed rows: pinned, never used), and
+        # whether a frag_due repack has freed nothing since a repack last
+        # freed a row.
+        self._n_repacked = self.n
+        self._stale_held = self.n
+        self._frag_barren = False
         self.lock = threading.RLock()
         # Deferred-repack handshake: cleared when a pack emits a
         # _RepackPlan, set again once the dispatch thread executes it —
@@ -515,21 +528,43 @@ class _ResidentMirror:
                 raise RuntimeError("resident hash table full")
 
     def frag_due(self, floor_version: int) -> bool:
-        """Opportunistic-repack trigger: the dictionary is mostly full AND
-        mostly stale (keys unused since the MVCC floor) — reclaim early
-        instead of stalling the pipeline on a forced overflow repack.
+        """Opportunistic-repack trigger: fire only when a repack would free
+        rows worth its cost, counted without a device sync. A full repack
+        keeps {device-live} ∪ {pinned} ∪ {the dispatch's keys} ∪ {keys used
+        at or after the MVCC floor} and drops every other key
+        (TPUConflictSet._repack_and_rank), so what it can free is the stale
+        keys, less those the last repack found device-live or pinned and
+        had to keep (floor advanced, history not yet merged: the mirror
+        cannot see when the device lets go of them). Fires when the
+        dictionary is over half full, over half of it is reclaimable by
+        that count, and it has grown by a quarter of its capacity
+        (1 / FRAG_SHARE) since the last full repack.
+
+        Invariant: a frag_due repack never runs twice without the
+        dictionary having grown by a quarter of its capacity in between,
+        and never frees 0 rows twice in a row: one that freed nothing
+        shuts the trigger until a repack (a forced one: dict_full,
+        delta_overflow) has freed a row again.
+
         Tiered engines reclaim stale keys through DEMOTION deltas instead
-        (stale == the demotion victim set), so the trigger is off there:
-        a stale-but-device-live key can't be reclaimed by a repack either,
-        and firing on it would repack repeatedly for zero freed rows."""
-        if self.tiered:
-            return False
-        if self.n <= self.capacity // 2:
+        (stale == the demotion victim set), so the trigger is off there."""
+        grown = self.n - self._n_repacked
+        if (self.tiered or self._frag_barren
+                or self.n <= self.capacity // 2
+                or grown < self.capacity // self.FRAG_SHARE):
             return False
         stale = int(
             (self.last_used_by_id[: self._n_ids] < floor_version).sum()
         )
-        return stale > self.frag_threshold * self.n
+        return 2 * (stale - self._stale_held) > self.n
+
+    def repacked(self, stale_held: int, freed: int, for_frag: bool) -> None:
+        """A full repack has rebuilt the sorted view (reset): record what
+        frag_due counts from. ``stale_held`` is the stale rows it kept
+        (device-live, pinned), ``freed`` the rows it dropped."""
+        self._n_repacked = self.n
+        self._stale_held = stale_held
+        self._frag_barren = (for_frag or self._frag_barren) and freed == 0
 
 
 class PreparedWindow(NamedTuple):
@@ -644,7 +679,6 @@ class TPUConflictSet:
                    max(1024, 2 * batch_size * (max_read_ranges
                                                + max_write_ranges)))
         )
-        self._dict_frag = float(os.environ.get("FDB_TPU_DICT_FRAG", "0.75"))
         # Two-tier dictionary (FDB_TPU_DICT_HOT_CAPACITY > 0, resident
         # engines only): the device dictionary becomes the HOT tier at
         # this capacity and the mirror's ID space the authoritative host
@@ -776,8 +810,7 @@ class TPUConflictSet:
         if self.resident:
             self._mirror = _ResidentMirror(
                 self.codec.min_key[None, :], self.dict_capacity,
-                self.dict_delta_slots, self._dict_frag,
-                tiered=self.tiered,
+                self.dict_delta_slots, tiered=self.tiered,
             )
             self.state = ck.init_res(
                 self._mirror.rows, self.dict_capacity, self.capacity,
@@ -1015,16 +1048,14 @@ class TPUConflictSet:
             else "repacks_frag_due" if mir.frag_due(self.oldest_version)
             else None
         )
+        plan = _RepackPlan(bt, qu, is_pad, new_u64, new_rows, dims, cv, cause)
         if cause is not None:
             mir.stats[cause] += 1
             if defer_repack:
                 mir.gate.clear()
                 mir.stats["repack_stalls"] += 1
-                return _RepackPlan(bt, qu, is_pad, new_u64, new_rows, dims, cv)
-            return self._repack_and_rank(
-                _RepackPlan(bt, qu, is_pad, new_u64, new_rows, dims, cv),
-                inside="dict_rank",
-            )
+                return plan
+            return self._repack_and_rank(plan, inside="dict_rank")
         if self.tiered and mir.n + m > self._demote_watermark:
             if defer_repack:
                 # Same deferral contract as _RepackPlan: victim selection
@@ -1040,10 +1071,7 @@ class TPUConflictSet:
                 # Demotion could not free enough room (victims all
                 # pinned, device-live or recent): the honest full-repack
                 # fallback — the thrash pathology obs/doctor flags.
-                return self._repack_and_rank(
-                    _RepackPlan(bt, qu, is_pad, new_u64, new_rows, dims, cv),
-                    inside="dict_rank",
-                )
+                return self._repack_and_rank(plan, inside="dict_rank")
         delta = None
         with mir.lock:
             mir.touch(ids[hot_hit], cv)
@@ -1097,10 +1125,17 @@ class TPUConflictSet:
     def _repack_and_rank(self, plan: _RepackPlan,
                          inside: "str | None" = None) -> ck.ResidentBatch:
         """Full dictionary repack: rebuild the dictionary from {live
-        history ranks} ∪ {pinned} ∪ {this dispatch's keys} ∪ the most
-        recently used survivors (oldest-last-used evicted first), ship it
-        whole, and remap every device-held rank. The rare fallback the
-        per-delta path buys its way out of; also the cold-start path.
+        history ranks} ∪ {pinned} ∪ {this dispatch's keys} ∪ {keys last
+        used at or after the MVCC floor}, ship it whole, and remap every
+        device-held rank. What is kept is what is useful, not what fits:
+        a key that is stale on the host and that the device history does
+        not reference is dropped, and comes back, if ever, as a delta
+        row. Raises ValueError when the live set itself does not fit. The
+        rare fallback the per-delta path buys its way out of; also the
+        cold-start path. What decides it: delta_overflow and dict_full by
+        force, frag_due by _ResidentMirror.frag_due's counts, which this
+        repack refreshes (never twice without the dictionary having grown
+        by a quarter of its capacity, never 0 rows freed twice in a row).
 
         Stage ``dict_repack`` (carved out of ``inside`` when called from
         within another stage); its seconds also sum into the mirror's
@@ -1137,16 +1172,21 @@ class TPUConflictSet:
                         " raise dict_capacity / FDB_TPU_DICT_CAPACITY or"
                         " run with FDB_TPU_RESIDENT=0"
                     )
-                # Fill remaining room newest-first, leaving delta headroom.
+                # Of the rest, only a key used at or after the MVCC floor
+                # stays: a stale one that nothing references comes back, if
+                # it ever does, as an ordinary delta row. Should more be in
+                # use than fits under the delta headroom, the newest stay.
                 used_sorted = mir.used_sorted()
-                target = max(mir.capacity - self.dict_delta_slots - m, must)
-                room = target - must
-                cand_idx = np.flatnonzero(~keep)
-                if room > 0 and cand_idx.size:
-                    by_age = cand_idx[
-                        np.argsort(used_sorted[cand_idx], kind="stable")
-                    ]
-                    keep[by_age[max(0, by_age.size - room):]] = True
+                floor = self.oldest_version
+                stale_held = int((used_sorted[keep] < floor).sum())
+                fresh = np.flatnonzero(~keep & (used_sorted >= floor))
+                room = max(
+                    mir.capacity - self.dict_delta_slots - m - must, 0
+                )
+                if fresh.size > room:
+                    by_age = np.argsort(used_sorted[fresh], kind="stable")
+                    fresh = fresh[by_age[fresh.size - room:]]
+                keep[fresh] = True
                 evicted = mir.n - int(keep.sum())
 
                 kept_u64 = mir.u64[keep]
@@ -1175,6 +1215,8 @@ class TPUConflictSet:
                     self.state, dict_dev, np.int32(n_new), remap
                 )
                 mir.reset(fin_u64, fin_rows, fin_used, fin_pin)
+                mir.repacked(stale_held, evicted,
+                             plan.cause == "repacks_frag_due")
                 st = mir.stats
                 st["full_repacks"] += 1
                 st["evictions"] += evicted

@@ -583,7 +583,7 @@ class ShardedConflictSet(TPUConflictSet):
         # self._lo rows are sorted unique (row 0 = packed b"").
         self._mirror = _ResidentMirror(
             self._lo, self.dict_capacity, self.dict_delta_slots,
-            self._dict_frag, tiered=self.tiered,
+            tiered=self.tiered,
         )
         self._dev_batch = lambda bt: self._pack_resident(bt)
         self._dev_batch_deferred = lambda bt: self._pack_resident(

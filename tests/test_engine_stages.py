@@ -208,6 +208,12 @@ class TestRepackCauses:
         assert repack_causes(cs) == {
             "repacks_delta_overflow": 0, "repacks_dict_full": 0,
             "repacks_frag_due": 1, "full_repacks": 1}
+        # A second batch after a frag_due repack repacks nothing: the
+        # dictionary, as stale as before, has grown by 2 keys since.
+        resolve_new_keys(cs, 10000, 1, oldest=8000)
+        assert repack_causes(cs) == {
+            "repacks_delta_overflow": 0, "repacks_dict_full": 0,
+            "repacks_frag_due": 1, "full_repacks": 1}
 
 
 def test_delta_empty_dispatches_counts_the_skipped_merges():
