@@ -62,7 +62,10 @@ class ModeConfig:
 MODES = {
     # YCSB-A hot-key contention: 2 reads + 50% single write, Zipf 0.99.
     "ycsb": ModeConfig(2, 1, 0.5, 0.99, BATCH),
-    # mako 90/10 op mix: 9 reads + 1 write every txn.
+    # mako 90/10 op mix: 9 reads + 1 write every txn, kernel only, on an
+    # engine built 9 slots wide. No cell: the benchmark's mako is
+    # `mako_share_g8ui` (BENCHMARK.json; benchmark/configs/
+    # mako_resolver_share.json), g8ui through the served role's 8 slots.
     "mako": ModeConfig(9, 1, 1.0, 0.99, 4096),
     # TPC-C new-order shape: wide txns (12 reads, 8 writes), uniform items.
     "tpcc": ModeConfig(12, 8, 1.0, 0.0, 2048),

@@ -182,6 +182,9 @@ class BatchTensors(NamedTuple):
     write_mask: jax.Array  # bool [B, Q]
     read_version: jax.Array  # int32 [B] (relative)
     txn_mask: jax.Array  # bool [B]
+    # bool [B], or None when every transaction of the batch fits one row:
+    # row i continues the transaction of row i - 1 (see txn_segments).
+    cont: jax.Array | None = None
 
 
 class PackedBatch(NamedTuple):
@@ -206,6 +209,7 @@ class PackedBatch(NamedTuple):
     write_mask: jax.Array  # bool [B, Q]
     read_version: jax.Array  # int32 [B] (relative)
     txn_mask: jax.Array  # bool [B]
+    cont: jax.Array | None = None  # as BatchTensors.cont
 
 
 def init_state(capacity: int, width: int, min_key) -> ConflictState:
@@ -513,6 +517,75 @@ def _block_accept_fused(
     )
 
 
+# ---------------------------------------------------------------------------
+# Transactions wider than a row (BatchTensors.cont)
+# ---------------------------------------------------------------------------
+#
+# The batch's static row holds R read and Q write ranges. A transaction with
+# more takes CONTINUATION rows right after its first (its head): the same
+# read version and mask, its further ranges in slot order; the host marks
+# them in ``cont`` (conflict_set._pack / native/keypack.cpp). Rows and
+# transactions then stop being the same index, and the reference's rule is
+# about transactions: one is accepted, and its writes painted, only if NONE
+# of its rows conflicts, in batch order, with the accepted writes of earlier
+# transactions of the batch counted. Everything below reduces rows to
+# transactions around the acceptance designs, which stay as they are: a
+# transaction is a candidate when every one of its rows is (txn_candidates);
+# the row-by-row overlap matrix is folded onto the head rows
+# (txn_fold_overlap: head(t) meets head(u) when any row of t meets any row
+# of u, and a continuation row meets nothing), so the block scan, the wave
+# and the wave-commit schedule see one row a transaction; the heads' answers
+# go back to every row (txn_spread), which is what the paint, the verdicts
+# and the loser report read. A batch with ``cont`` None never comes here:
+# it traces to the program it always had.
+
+
+class TxnSegments(NamedTuple):
+    head: jax.Array  # bool [B] — the row starts a transaction
+    start: jax.Array  # int32 [B] — the head row of the row's transaction
+    end: jax.Array  # int32 [B] — the last row of the row's transaction
+
+
+def txn_segments(cont: jax.Array) -> TxnSegments:
+    b = cont.shape[0]
+    idx = jnp.arange(b, dtype=jnp.int32)
+    head = ~cont
+    start = jax.lax.cummax(jnp.where(head, idx, 0))
+    last = jnp.concatenate([head[1:], jnp.ones((1,), jnp.bool_)])
+    end = jax.lax.cummin(jnp.where(last, idx, b - 1), reverse=True)
+    return TxnSegments(head, start, end)
+
+
+def _seg_any(m: jax.Array, seg: TxnSegments, axis: int) -> jax.Array:
+    """OR of `m` over each transaction's rows along `axis`, at every row of
+    the transaction: an inclusive prefix count, read at the two ends."""
+    c = jnp.cumsum(m.astype(jnp.int32), axis=axis)
+    at_start = jnp.take(c, seg.start, axis=axis) - jnp.take(
+        m, seg.start, axis=axis).astype(jnp.int32)
+    return jnp.take(c, seg.end, axis=axis) > at_start
+
+
+def txn_candidates(base: jax.Array, seg: TxnSegments) -> jax.Array:
+    """bool [B]: set on a head row whose transaction has every row in
+    `base`; a continuation row is never a candidate of its own."""
+    return seg.head & ~_seg_any(~base, seg, 0)
+
+
+def txn_spread(v: jax.Array, seg: TxnSegments) -> jax.Array:
+    """The head row's value on every row of its transaction."""
+    return v[seg.start]
+
+
+def txn_fold_overlap(m: jax.Array, seg: TxnSegments) -> jax.Array:
+    """[B, B] row-by-row overlaps -> the same relation between head rows:
+    entry (head(t), head(u)) is set when any row of t meets any row of u;
+    rows and columns of continuation rows are cleared. The diagonal block
+    of a transaction (its reads against its own writes) lands on its
+    head's diagonal, which every acceptance design ignores."""
+    m = _seg_any(m, seg, 1) & seg.head[None, :]
+    return _seg_any(m, seg, 0) & seg.head[:, None]
+
+
 def _seq_accept(base: jax.Array, m: jax.Array) -> jax.Array:
     """Exact sequential acceptance as a fixed G-step fori_loop.
 
@@ -674,10 +747,13 @@ def _seq_accept_packed(base: jax.Array, p: jax.Array) -> jax.Array:
 #: oracle and the runtime share them without importing device code.
 
 
-def _pred_matrix_packed(base, rb, re_, read_live, wb, we, write_live):
+def _pred_matrix_packed(base, rb, re_, read_live, wb, we, write_live,
+                        seg: "TxnSegments | None" = None):
     """uint32 [BP, BP/32] packed predecessor bitsets over rank intervals:
     bit i of row j ⇔ reads(i) ∩ writes(j) ≠ ∅ (txn i must serialize
-    before txn j), diagonal cleared, restricted to candidate txns.
+    before txn j), diagonal cleared, restricted to candidate txns. With
+    `seg` (a batch with continuation rows) the matrix is built whole and
+    folded onto the head rows before it is packed.
 
     Built [G, B]-blockwise with the same _overlap_rows primitive as the
     acceptance scan (writes of the block's txns as rows, everyone's reads
@@ -687,7 +763,10 @@ def _pred_matrix_packed(base, rb, re_, read_live, wb, we, write_live):
     bp = base.shape[0]
     g = min(_ACCEPT_BLOCK, bp)
     q = wb.shape[1]
-    if bp % g == 0 and bp > g:
+    if seg is not None:
+        p = pack_bits_u32(txn_fold_overlap(
+            _overlap_rows(wb, we, write_live, rb, re_, read_live), seg))
+    elif bp % g == 0 and bp > g:
         nblk = bp // g
         p = jax.lax.map(
             lambda x: pack_bits_u32(
@@ -751,7 +830,8 @@ def _cycle_victim(p, undet, undetp):
 
 @jax.named_scope("accept")
 def wave_pred_matrix(
-    base: jax.Array, ranks: tuple[jax.Array, ...]
+    base: jax.Array, ranks: tuple[jax.Array, ...],
+    cont: "jax.Array | None" = None,
 ) -> jax.Array:
     """uint32 [BP, BP/32] packed predecessor bitsets over (possibly
     shard-clipped) rank intervals, padded to BP = ceil32(B). The
@@ -772,7 +852,13 @@ def wave_pred_matrix(
         wb = jnp.pad(wb, ((0, pad), (0, 0)))
         we = jnp.pad(we, ((0, pad), (0, 0)))
         write_live = jnp.pad(write_live, ((0, pad), (0, 0)))
-    return _pred_matrix_packed(base, rb, re_, read_live, wb, we, write_live)
+        if cont is not None:
+            cont = jnp.pad(cont, (0, pad))
+    # `base` of a batch with continuation rows is txn_candidates' (heads
+    # only), so the candidate mask clears every continuation column.
+    seg = None if cont is None else txn_segments(cont)
+    return _pred_matrix_packed(base, rb, re_, read_live, wb, we, write_live,
+                               seg)
 
 
 def wave_occupied_tiles(p: jax.Array) -> jax.Array:
@@ -863,13 +949,14 @@ def wave_level_from_graph(
 
 
 def _wave_commit_accept(
-    base: jax.Array, ranks: tuple[jax.Array, ...]
+    base: jax.Array, ranks: tuple[jax.Array, ...],
+    cont: "jax.Array | None" = None,
 ) -> tuple[jax.Array, jax.Array]:
     """(accepted bool [B], level int32 [B]): schedule candidate txns into
     dependency-ordered commit waves; abort only true-cycle members. The
     single-shard composition of wave_pred_matrix + _wave_level_packed."""
     b = base.shape[0]
-    p = wave_pred_matrix(base, ranks)
+    p = wave_pred_matrix(base, ranks, cont)
     bp = p.shape[0]
     basep = jnp.pad(base, (0, bp - b)) if bp != b else base
     level = _wave_level_packed(basep, p)[:b]
@@ -1117,12 +1204,27 @@ def loser_range_mask(
 
 
 @jax.named_scope("accept")
-def _accept_or_schedule(base, ranks, wave: bool):
+def _accept_or_schedule(base, ranks, wave: bool, cont=None):
     """Shared acceptance dispatch: sequential-order block scan (wave=False)
-    or the wave-commit schedule (wave=True — levels ride along)."""
+    or the wave-commit schedule (wave=True — levels ride along). `cont`
+    (a batch with continuation rows, else None) reduces rows to
+    transactions around either: scope ``accept/txn_rows``."""
+    if cont is None:
+        if wave:
+            return _wave_commit_accept(base, ranks)
+        return _block_accept_fused(base, *ranks), None
+    with jax.named_scope("txn_rows"):
+        seg = txn_segments(cont)
+        cand = txn_candidates(base, seg)
     if wave:
-        return _wave_commit_accept(base, ranks)
-    return _block_accept_fused(base, *ranks), None
+        accepted, levels = _wave_commit_accept(cand, ranks, cont)
+    else:
+        with jax.named_scope("txn_rows"):
+            m = txn_fold_overlap(_overlap_rows(*ranks), seg)
+        accepted, levels = _block_accept(cand, m), None
+    with jax.named_scope("txn_rows"):
+        return txn_spread(accepted, seg), (
+            None if levels is None else txn_spread(levels, seg))
 
 
 def resolve_batch(
@@ -1150,7 +1252,7 @@ def resolve_batch(
     hist_conflict = jnp.any(hist_mask, axis=1)
     base = batch.txn_mask & ~too_old & ~hist_conflict
     ranks = endpoint_ranks_live(batch)
-    accepted, levels = _accept_or_schedule(base, ranks, wave)
+    accepted, levels = _accept_or_schedule(base, ranks, wave, batch.cont)
     verdicts = assemble_verdicts(too_old, batch.txn_mask, accepted)
     new_state = _paint_and_compact(state, batch, accepted, commit_version, floor)
     out = (verdicts, levels) if wave else (verdicts,)
@@ -1396,7 +1498,7 @@ def resolve_batch_hist(
     hist_conflict = jnp.any(hist_mask, axis=1)
     ok = batch.txn_mask & ~too_old & ~hist_conflict
     ranks = endpoint_ranks_live(batch)
-    accepted, levels = _accept_or_schedule(ok, ranks, wave)
+    accepted, levels = _accept_or_schedule(ok, ranks, wave, batch.cont)
     verdicts = assemble_verdicts(too_old, batch.txn_mask, accepted)
     delta = _paint_and_compact(delta, batch, accepted, commit_version, floor)
     new_hist = HistState(base_h, base_st, delta)
@@ -1595,7 +1697,7 @@ def resolve_batch_packed(
     hist_conflict = jnp.any(hist_mask, axis=1)
     base = pb.txn_mask & ~too_old & ~hist_conflict
     ranks = endpoint_ranks_live_packed(pb)
-    accepted, levels = _accept_or_schedule(base, ranks, wave)
+    accepted, levels = _accept_or_schedule(base, ranks, wave, pb.cont)
     verdicts = assemble_verdicts(too_old, pb.txn_mask, accepted)
     new_state = _paint_and_compact_packed(
         state, pb, accepted, commit_version, floor, rs
@@ -1688,7 +1790,7 @@ def resolve_batch_hist_packed(
     hist_conflict = jnp.any(hist_mask, axis=1)
     ok = pb.txn_mask & ~too_old & ~hist_conflict
     ranks = endpoint_ranks_live_packed(pb)
-    accepted, levels = _accept_or_schedule(ok, ranks, wave)
+    accepted, levels = _accept_or_schedule(ok, ranks, wave, pb.cont)
     verdicts = assemble_verdicts(too_old, pb.txn_mask, accepted)
     delta = _paint_and_compact_packed(
         delta, pb, accepted, commit_version, floor, rs_d
@@ -1919,6 +2021,7 @@ class RankBatch(NamedTuple):
     read_version: jax.Array  # int32 [B] (relative)
     txn_mask: jax.Array  # bool [B]
     paint_src: jax.Array  # int32 [2·B·Q] stable argsort of write endpoints
+    cont: jax.Array | None = None  # as BatchTensors.cont
 
 
 class ResidentBatch(NamedTuple):
@@ -2335,7 +2438,7 @@ def _resolve_core_res(hist, rbk: RankBatch, commit_version, new_oldest,
         hist_conflict = jnp.any(hist_mask, axis=1)
         base = rbk.txn_mask & ~too_old & ~hist_conflict
     ranks = endpoint_ranks_live_packed(rbk)
-    accepted, levels = _accept_or_schedule(base, ranks, wave)
+    accepted, levels = _accept_or_schedule(base, ranks, wave, rbk.cont)
     verdicts = assemble_verdicts(too_old, rbk.txn_mask, accepted)
     if two_level:
         delta = _paint_and_compact_res(
@@ -2473,14 +2576,34 @@ def _evict_res_jit(res, evict_ranks):
 # ---------------------------------------------------------------------------
 
 
+def _edge_pred(base, ranks, cont):
+    """Phase 1's predecessor bitsets, by ROW. With continuation rows a
+    transaction is a candidate only if every row is, and its rows' edges
+    are folded onto its head row; the engine then renumbers head rows as
+    transactions (conflict_set._heads_to_txns), since every shard sees
+    every transaction but lays it out in rows of its own."""
+    if cont is not None:
+        base = txn_candidates(base, txn_segments(cont))
+    return wave_pred_matrix(base, ranks, cont)
+
+
+def _accepted_rows(accepted, cont):
+    """Phase 2's answer by TRANSACTION (the exchange's index) -> by row:
+    every row of a transaction carries its verdict into the paint."""
+    if cont is None:
+        return accepted
+    return accepted[jnp.cumsum((~cont).astype(jnp.int32)) - 1]
+
+
 def wave_edges_batch(state: ConflictState, batch: BatchTensors, new_oldest):
     """(too_old [B], hist_conflict [B], pred uint32 [BP, BP/32]): the
     phase-1 body — gate verdicts for THIS shard's clipped view plus its
-    clipped predecessor matrix. Reads the history, never paints it."""
+    clipped predecessor matrix, all three by row (_edge_pred). Reads the
+    history, never paints it."""
     _floor, too_old = too_old_mask(state, batch, new_oldest)
     hist_conflict = _history_conflicts(state, batch)
     base = batch.txn_mask & ~too_old & ~hist_conflict
-    p = wave_pred_matrix(base, endpoint_ranks_live(batch))
+    p = _edge_pred(base, endpoint_ranks_live(batch), batch.cont)
     return too_old, hist_conflict, p
 
 
@@ -2494,7 +2617,7 @@ def wave_edges_batch_hist(hist: HistState, batch: BatchTensors, new_oldest):
         hist.base, hist.base_st, hist.delta, batch
     )
     base = batch.txn_mask & ~too_old & ~hist_conflict
-    p = wave_pred_matrix(base, endpoint_ranks_live(batch))
+    p = _edge_pred(base, endpoint_ranks_live(batch), batch.cont)
     return too_old, hist_conflict, p
 
 
@@ -2502,7 +2625,7 @@ def wave_edges_batch_packed(state: ConflictState, pb: PackedBatch, new_oldest):
     _floor, too_old = too_old_mask_packed(state, pb, new_oldest)
     hist_conflict = _history_conflicts_packed(state, pb)
     base = pb.txn_mask & ~too_old & ~hist_conflict
-    p = wave_pred_matrix(base, endpoint_ranks_live_packed(pb))
+    p = _edge_pred(base, endpoint_ranks_live_packed(pb), pb.cont)
     return too_old, hist_conflict, p
 
 
@@ -2510,7 +2633,7 @@ def wave_edges_batch_hist_packed(hist: HistState, pb: PackedBatch, new_oldest):
     _floor, too_old = too_old_mask_packed(hist.delta, pb, new_oldest)
     hist_conflict = _history_conflicts_hist_packed(hist, pb)
     base = pb.txn_mask & ~too_old & ~hist_conflict
-    p = wave_pred_matrix(base, endpoint_ranks_live_packed(pb))
+    p = _edge_pred(base, endpoint_ranks_live_packed(pb), pb.cont)
     return too_old, hist_conflict, p
 
 
@@ -2528,7 +2651,8 @@ def wave_edges_res(res: ResState, rb: ResidentBatch, new_oldest):
         _floor, too_old = too_old_mask_packed(hist, rb.ranks, new_oldest)
         hist_conflict = _history_conflicts_res(hist, rb.ranks)
     base = rb.ranks.txn_mask & ~too_old & ~hist_conflict
-    p = wave_pred_matrix(base, endpoint_ranks_live_packed(rb.ranks))
+    p = _edge_pred(base, endpoint_ranks_live_packed(rb.ranks),
+                   rb.ranks.cont)
     return too_old, hist_conflict, p, res
 
 
@@ -2539,9 +2663,12 @@ def wave_apply_batch(
     """(levels int32 [B], new_state): level the GLOBAL graph, paint the
     globally accepted writes. ``cand``/``p`` are the combined candidate
     mask and OR-reduced predecessor matrix — identical on every shard,
-    so the returned schedule is identical on every shard."""
+    so the returned schedule is identical on every shard. Graph and
+    levels go by transaction; only the paint goes by row
+    (_accepted_rows)."""
     floor = jnp.maximum(state.oldest, new_oldest)
     accepted, levels = wave_level_from_graph(cand, p)
+    accepted = _accepted_rows(accepted, batch.cont)
     new_state = _paint_and_compact(state, batch, accepted, commit_version,
                                    floor)
     return levels, new_state
@@ -2558,6 +2685,7 @@ def wave_apply_batch_hist(
     hist = _maybe_merge(hist, demand, floor)
     base_h, base_st, delta = hist
     accepted, levels = wave_level_from_graph(cand, p)
+    accepted = _accepted_rows(accepted, batch.cont)
     delta = _paint_and_compact(delta, batch, accepted, commit_version, floor)
     return levels, HistState(base_h, base_st, delta)
 
@@ -2568,6 +2696,7 @@ def wave_apply_batch_packed(
 ):
     floor = jnp.maximum(state.oldest, new_oldest)
     accepted, levels = wave_level_from_graph(cand, p)
+    accepted = _accepted_rows(accepted, pb.cont)
     new_state = _paint_and_compact_packed(
         state, pb, accepted, commit_version, floor
     )
@@ -2584,6 +2713,7 @@ def wave_apply_batch_hist_packed(
     hist = _maybe_merge(hist, demand, floor)
     base_h, base_st, delta = hist
     accepted, levels = wave_level_from_graph(cand, p)
+    accepted = _accepted_rows(accepted, pb.cont)
     delta = _paint_and_compact_packed(
         delta, pb, accepted, commit_version, floor
     )
@@ -2597,6 +2727,7 @@ def wave_apply_res(
     so this is pure rank-space level + paint."""
     hist = res.hist
     accepted, levels = wave_level_from_graph(cand, p)
+    accepted = _accepted_rows(accepted, rbk.cont)
     if isinstance(hist, HistState):
         floor = jnp.maximum(hist.delta.oldest, new_oldest)
         demand = 2 * jnp.sum(

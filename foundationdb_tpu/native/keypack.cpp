@@ -18,9 +18,16 @@
 // Key packing must match core/keypack.py KeyCodec bit-for-bit: big-endian
 // bytes into int32 words, XOR 0x80000000 bias, trailing length column;
 // overlong begins truncate down, overlong ends round up to the prefix
-// successor (all-0xff prefix -> +inf sentinel). Range-count overflow
-// coalesces exactly like models/conflict_set.py _coalesce: stable-sort by
-// begin, cover ceil(n/limit)-sized groups.
+// successor (all-0xff prefix -> +inf sentinel).
+//
+// Row layout must match models/conflict_set.py _pack bit-for-bit: a
+// transaction's non-empty ranges fill slots in wire order; one with more
+// than r_cap reads or q_cap writes runs on into CONTINUATION rows right
+// after its first (same read version, txn_mask set, `cont` set): range c
+// lands in row c / cap, slot c % cap, which in a row-major [B, cap, W]
+// tensor is simply slot c counted from the transaction's first row. No
+// range is ever widened, merged or dropped, and a transaction is never
+// split across two batches: the pass stops before one that does not fit.
 
 #include <algorithm>
 #include <cstdint>
@@ -79,35 +86,15 @@ void pack_key(const uint8_t* k, int len, int n_words, bool end_mode,
   out[n_words] = len;
 }
 
-// Emit up to `limit` slots for `ranges` into row-major [limit, W] tensors,
-// mirroring _coalesce: empties dropped; if still over limit, stable-sort by
-// begin and cover even groups (group begin, max group end).
-void emit_ranges(std::vector<RangeView>& live, int limit, int n_words,
+// Emit `live` into consecutive slots of row-major [*, cap, W] tensors,
+// starting at the transaction's first row: past slot cap - 1 they are the
+// slots of its continuation rows (the caller made sure the rows exist).
+void emit_ranges(const std::vector<RangeView>& live, int n_words,
                  int32_t* begin_out, int32_t* end_out, uint8_t* mask_out) {
   const int w = n_words + 1;
-  if (static_cast<int>(live.size()) <= limit) {
-    for (size_t c = 0; c < live.size(); ++c) {
-      pack_key(live[c].b, live[c].bl, n_words, false, begin_out + c * w);
-      pack_key(live[c].e, live[c].el, n_words, true, end_out + c * w);
-      mask_out[c] = 1;
-    }
-    return;
-  }
-  std::stable_sort(live.begin(), live.end(),
-                   [](const RangeView& x, const RangeView& y) {
-                     return bytecmp(x.b, x.bl, y.b, y.bl) < 0;
-                   });
-  const int n = static_cast<int>(live.size());
-  const int step = (n + limit - 1) / limit;
-  int c = 0;
-  for (int i = 0; i < n; i += step, ++c) {
-    const int hi = std::min(i + step, n);
-    const RangeView* best = &live[i];
-    for (int j = i + 1; j < hi; ++j)
-      if (bytecmp(live[j].e, live[j].el, best->e, best->el) > 0)
-        best = &live[j];
-    pack_key(live[i].b, live[i].bl, n_words, false, begin_out + c * w);
-    pack_key(best->e, best->el, n_words, true, end_out + c * w);
+  for (size_t c = 0; c < live.size(); ++c) {
+    pack_key(live[c].b, live[c].bl, n_words, false, begin_out + c * w);
+    pack_key(live[c].e, live[c].el, n_words, true, end_out + c * w);
     mask_out[c] = 1;
   }
 }
@@ -116,23 +103,32 @@ void emit_ranges(std::vector<RangeView>& live, int limit, int n_words,
 
 extern "C" {
 
-// Walks `count` transactions starting at byte `offset`; fills the padded
-// batch tensors (callers pass zero/INT32_MAX-prefilled arrays of shape
-// B x R x W / B x Q x W / B x R / B x Q / B). Returns the wire offset just
-// past the last consumed transaction, or -1 on malformed input / overrun.
+// Walks up to `count` transactions starting at byte `offset`; fills the
+// padded batch tensors (callers pass zero/INT32_MAX-prefilled arrays of
+// shape B x R x W / B x Q x W / B x R / B x Q / B). It stops early, before
+// a transaction whose rows no longer fit in b_cap. used[0] = transactions
+// taken, used[1] = rows filled; cont[row] = 1 on continuation rows. Returns
+// the wire offset just past the last transaction taken; -1 on malformed
+// input, overrun, or a transaction that b_cap rows cannot hold; -2 where a
+// transaction needs a continuation row and the caller takes none (`cont`
+// null: the window path, one row a transaction).
 int64_t kp_pack_batch(
     const uint8_t* wire, int64_t wire_len, int64_t offset, int count,
     int b_cap, int r_cap, int q_cap, int n_words, int64_t base_version,
     int32_t* read_begin, int32_t* read_end, uint8_t* read_mask,
     int32_t* write_begin, int32_t* write_end, uint8_t* write_mask,
-    int32_t* read_version, uint8_t* txn_mask) {
+    int32_t* read_version, uint8_t* txn_mask, uint8_t* cont,
+    int32_t* used) {
   const int w = n_words + 1;
-  if (count > b_cap) return -1;
+  if (count > b_cap || r_cap <= 0 || q_cap <= 0) return -1;
   // pack_key's truncation scratch is MAX_KEY_BYTES — a wider codec would
   // smash the stack on overlong wire keys. Reject the config, not the key.
   if (n_words <= 0 || 4 * n_words > MAX_KEY_BYTES) return -1;
   std::vector<RangeView> reads, writes;
-  for (int t = 0; t < count; ++t) {
+  int t = 0;
+  int64_t row = 0;
+  for (; t < count; ++t) {
+    const int64_t txn_offset = offset;
     if (offset + 16 > wire_len) return -1;
     int64_t rv;
     int32_t n_reads, n_writes;
@@ -169,12 +165,29 @@ int64_t kp_pack_batch(
     // silent wrap would turn a far-future reader into a recent one.
     const int64_t rel = rv - base_version;
     if (rel > 0x7fffffffLL) return -1;
-    txn_mask[t] = 1;
-    read_version[t] = static_cast<int32_t>(rel < -1 ? -1 : rel);
-    emit_ranges(reads, r_cap, n_words, read_begin + t * r_cap * w,
-                read_end + t * r_cap * w, read_mask + t * r_cap);
-    emit_ranges(writes, q_cap, n_words, write_begin + t * q_cap * w,
-                write_end + t * q_cap * w, write_mask + t * q_cap);
+    const int64_t need = std::max<int64_t>(
+        1, std::max((static_cast<int64_t>(reads.size()) + r_cap - 1) / r_cap,
+                    (static_cast<int64_t>(writes.size()) + q_cap - 1) / q_cap));
+    if (need > 1 && !cont) return -2;
+    if (need > b_cap) return -1;
+    if (row + need > b_cap) {  // the next batch's first transaction
+      offset = txn_offset;
+      break;
+    }
+    for (int64_t k = 0; k < need; ++k) {
+      txn_mask[row + k] = 1;
+      read_version[row + k] = static_cast<int32_t>(rel < -1 ? -1 : rel);
+      if (k) cont[row + k] = 1;
+    }
+    emit_ranges(reads, n_words, read_begin + row * r_cap * w,
+                read_end + row * r_cap * w, read_mask + row * r_cap);
+    emit_ranges(writes, n_words, write_begin + row * q_cap * w,
+                write_end + row * q_cap * w, write_mask + row * q_cap);
+    row += need;
+  }
+  if (used) {
+    used[0] = t;
+    used[1] = static_cast<int32_t>(row);
   }
   return offset;
 }
@@ -193,7 +206,8 @@ int64_t kp_pack_batch(
 // pack_rank_dictionary bit-for-bit: rows compare lexicographically by
 // SIGNED int32 words (the packing bias makes that equal to key byte order;
 // the trailing length column is a small non-negative int in both).
-// Returns the wire offset past the last batch, or -1 on malformed input.
+// Returns the wire offset past the last batch, -1 on malformed input, -2 on
+// a transaction with more ranges than a row has slots (kp_pack_batch).
 int64_t kp_pack_window(
     const uint8_t* wire, int64_t wire_len, int64_t offset, int k, int count,
     int b_cap, int r_cap, int q_cap, int n_words, int64_t base_version,
@@ -218,8 +232,9 @@ int64_t kp_pack_window(
                            q_cap, n_words, base_version, rb, re,
                            read_mask + i * nr, wb, we, write_mask + i * nq,
                            read_version + static_cast<int64_t>(i) * b_cap,
-                           txn_mask + static_cast<int64_t>(i) * b_cap);
-    if (offset < 0) return -1;
+                           txn_mask + static_cast<int64_t>(i) * b_cap,
+                           nullptr, nullptr);
+    if (offset < 0) return offset;
     // Flat dictionary-input row j, section order rb/re/wb/we (the order
     // _pack_dict concatenates — ranks scatter back by the same layout).
     auto row = [&](int64_t j) -> const int32_t* {

@@ -65,7 +65,13 @@ reconciliation identity):
                       (NetTransport._on_frame; n = 1 per frame, only
                       while a sink is on)
     coalesce_queue    resolver: chain admission -> dispatch group start
-    host_pack         engine: keys -> row tensors (_pack / _pack_wire)
+    host_pack         engine: keys -> row tensors (_pack / _pack_wire),
+                      and which transactions go into which dispatch
+    wide_layout       engine: INSIDE host_pack, only over a batch that
+                      holds a transaction with more ranges than a row has
+                      slots — counting each transaction's rows and
+                      splitting the batch into dispatches without
+                      breaking one (_chunks)
     device_dispatch   resolver: the UMBRELLA over one batch's engine
                       bracket — wall time of the whole synchronous
                       engine path (plus the modeled dispatch cost in
@@ -158,6 +164,7 @@ SUB_STAGES = (
     "rpc_decode",
     "coalesce_queue",
     "host_pack",
+    "wide_layout",
     "device_dispatch",
     "dict_rank",
     "dict_repack",

@@ -142,11 +142,11 @@ def _sharded_resolve(state, batch, commit_version, new_oldest, lo, hi,
         # resolve_edges/resolve_apply protocol runs through the commit
         # proxy (core/wavemesh), here fused into the device program.
         accepted, levels, stats = _wave_exchange_and_level(
-            base, ck.endpoint_ranks_live(local)
+            base, ck.endpoint_ranks_live(local), batch.cont
         )
     else:
         accepted, _ = ck._accept_or_schedule(
-            base, ck.endpoint_ranks_live(batch), False
+            base, ck.endpoint_ranks_live(batch), False, batch.cont
         )
     verdicts = ck.assemble_verdicts(too_old, batch.txn_mask, accepted)
 
@@ -157,20 +157,30 @@ def _sharded_resolve(state, batch, commit_version, new_oldest, lo, hi,
     return verdicts, new_state
 
 
-def _wave_exchange_and_level(base, clipped_ranks):
+def _wave_exchange_and_level(base, clipped_ranks, cont=None):
     """Shared mesh wave body (runs under shard_map): clipped per-shard
     predecessor tiles -> packed all_gather -> OR-reduce -> replicated
-    leveling. Returns (accepted [B], levels [B], stats int32 [2]) where
+    leveling. A batch with continuation rows (`cont`) exchanges the
+    graph of its head rows (conflict_kernel.txn_segments): every shard
+    holds the same row layout, so the OR is still the exact graph.
+    Returns (accepted [B], levels [B], stats int32 [2]) where
     stats = (occupied 32x32-bit tiles summed over shards, total tiles
     shipped by the dense all_gather) — the realized-graph exchange
     economics surfaced to the host for the roofline's
     ``exchange_bytes_per_batch`` term."""
-    p_local = ck.wave_pred_matrix(base, clipped_ranks)
+    seg = None
+    if cont is not None:
+        seg = ck.txn_segments(cont)
+        base = ck.txn_candidates(base, seg)
+    p_local = ck.wave_pred_matrix(base, clipped_ranks, cont)
     occ = ck.wave_occupied_tiles(p_local)
     gathered = jax.lax.all_gather(p_local, AXIS)  # [D, BP, BP/32]
     d = gathered.shape[0]
     p = functools.reduce(jnp.bitwise_or, [gathered[i] for i in range(d)])
     accepted, levels = ck.wave_level_from_graph(base, p)
+    if seg is not None:
+        accepted = ck.txn_spread(accepted, seg)
+        levels = ck.txn_spread(levels, seg)
     total = jnp.int32(d * (p.shape[0] // 32) * p.shape[1])
     stats = jnp.stack([jax.lax.psum(occ, AXIS), total])
     return accepted, levels, stats
@@ -202,11 +212,11 @@ def _res_shard_step(hist, lo, hi, rbk, commit_version, new_oldest, wave):
         # slice of every edge (clip_ranks is a two-sided clamp on shared
         # global ranks), so the OR across shards is the exact graph.
         accepted, levels, stats = _wave_exchange_and_level(
-            base, ck.endpoint_ranks_live_packed(local)
+            base, ck.endpoint_ranks_live_packed(local), rbk.cont
         )
     else:
         accepted, levels = ck._accept_or_schedule(
-            base, ck.endpoint_ranks_live_packed(rbk), False
+            base, ck.endpoint_ranks_live_packed(rbk), False, rbk.cont
         )
     verdicts = ck.assemble_verdicts(too_old, rbk.txn_mask, accepted)
     new_hist = ck._paint_and_compact_res(

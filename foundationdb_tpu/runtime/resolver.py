@@ -44,6 +44,9 @@ class _QueuedBatch:
     oldest_version: int | None
     reply: Promise
     t_enq: float = 0.0  # chain-admission time (obs coalesce_queue stage)
+    # (padded engine rows, wide transactions): Resolver._txn_rows, asked
+    # once when the batch's group is dispatched.
+    rows: tuple[int, int] = (0, 0)
 
 
 class Resolver:
@@ -85,6 +88,10 @@ class Resolver:
         # every batch, so only these say whether the key split is even.
         self.ranges_received = 0
         self.txns_with_ranges = 0
+        # Padded engine rows the resolved transactions took, and the
+        # transactions with more ranges than one row's slots (_txn_rows).
+        self.rows_dispatched = 0
+        self.wide_txns = 0
         # Wave-commit accounting (engines publishing last_wave, i.e. the
         # reorder-don't-abort kernel/oracle): txns committed at a
         # non-zero wave serialized AFTER at least one same-window
@@ -285,7 +292,17 @@ class Resolver:
         sink = span_sink(self.loop)
         clock = stage_clock(self.loop) if sink is not None else None
         t0 = clock() if sink is not None else 0.0
-        fail_safe = self._should_fail_safe(len(txns), version, oldest_version)
+        rows = self._txn_rows(txns)
+        # One exchange carries one schedule domain: a window whose
+        # transactions (a client's wide ones take several rows each) do
+        # not fit one engine dispatch is answered like a capacity event.
+        # The batch conflicts as a whole, nothing is painted on any shard
+        # and the chain advances at apply; raising here would park every
+        # successor on a version that never applies.
+        fail_safe = (
+            self._should_fail_safe(rows[0], version, oldest_version)
+            or rows[0] > getattr(self.cs, "batch_size", rows[0])
+        )
         if fail_safe:
             import numpy as np
 
@@ -304,6 +321,7 @@ class Resolver:
         self._wave_pending_role[version] = {
             "txns": txns,
             "oldest": oldest_version,
+            "rows": rows,
             "fail_safe": fail_safe,
             "t_edges_done": self.loop.now,
         }
@@ -387,7 +405,8 @@ class Resolver:
         self._advance_chain(version)
         return reply
 
-    def _count_resolved(self, txns: list[TxnConflictInfo]) -> None:
+    def _count_resolved(self, txns: list[TxnConflictInfo],
+                        rows: tuple[int, int]) -> None:
         self.batches_resolved += 1
         self.txns_resolved += len(txns)
         for t in txns:
@@ -395,6 +414,15 @@ class Resolver:
             if n:
                 self.ranges_received += n
                 self.txns_with_ranges += 1
+        self.rows_dispatched += rows[0]
+        self.wide_txns += rows[1]
+
+    def _txn_rows(self, txns: list[TxnConflictInfo]) -> tuple[int, int]:
+        """(padded rows the engine gives `txns`, how many of them are wider
+        than one row's slots): a transaction a row for engines with no
+        slots (oracle, C++ skiplist)."""
+        fn = getattr(self.cs, "txn_rows", None)
+        return fn(txns) if fn is not None else (len(txns), 0)
 
     def _advance_chain(self, version: int) -> None:
         self._version = version
@@ -464,7 +492,7 @@ class Resolver:
                 1 for lv in wave if lv == WAVE_LEVEL_CYCLE
             )
             self.wave_batches += 1
-        self._count_resolved(txns)
+        self._count_resolved(txns, pend["rows"])
         return (verdicts, conflicting, fail_safe, wave)
 
     async def _dispatch_group(self, group: list[_QueuedBatch]) -> None:
@@ -499,6 +527,8 @@ class Resolver:
             # real kernel's dispatch wall time.
             await self.loop.sleep(self.dispatch_cost_s * len(group))
         clock = stage_clock(self.loop) if sink is not None else None
+        for entry in group:
+            entry.rows = self._txn_rows(entry.txns)
         if getattr(self.cs, "spec", False):
             # Speculative pipelined resolve (FDB_TPU_SPEC_RESOLVE=1): the
             # engine's reconcile ring lets window N+1's resolve dispatch
@@ -551,6 +581,12 @@ class Resolver:
                 # interior, never above it.
                 sink.stage_tick("host_pack", pack_s, n=n, version=version)
                 eng_s = max(0.0, eng_s - pack_s)
+                layout_s = rec.get("wide_layout")
+                if layout_s is not None:
+                    # Inside host_pack, not beside it: what laying wide
+                    # transactions out in rows adds to the pack.
+                    sink.stage_tick("wide_layout", layout_s, n=n,
+                                    version=version)
             # The UMBRELLA: the whole engine bracket minus host_pack
             # (synchronous: perf-clocked on real loops, 0 virtual
             # seconds in sim by construction) plus the modeled dispatch
@@ -634,7 +670,7 @@ class Resolver:
             if sink is not None and hasattr(self.cs, "last_stage_s"):
                 self.cs.last_stage_s = rec
             coll = None
-            if not self._should_fail_safe(len(txns), version, oldest):
+            if not self._should_fail_safe(entry.rows[0], version, oldest):
                 try:
                     coll = self.cs.spec_resolve_async(txns, version, oldest)
                 except BaseException as e:  # noqa: BLE001
@@ -702,7 +738,7 @@ class Resolver:
             if coal is not None and hasattr(coal, "note_misspec"):
                 coal.note_misspec(self._spec_repaired() > rep0)
             reply = self._finish_entry(version, txns, verdicts, fail_safe,
-                                       wave)
+                                       wave, entry.rows)
             if sink is not None:
                 n = max(1, len(txns))
                 rec_s = clock() - t0
@@ -730,7 +766,8 @@ class Resolver:
         if oldest_version is None:
             oldest_version = max(0, version - MVCC_WINDOW_VERSIONS)
         wave: list[int] | None = None
-        fail_safe = self._should_fail_safe(len(txns), version, oldest_version)
+        fail_safe = self._should_fail_safe(
+            entry.rows[0], version, oldest_version)
         if fail_safe:
             # Conflict-everything: rejected txns paint nothing, so history
             # stops growing; advance() still slides the GC floor so expired
@@ -756,11 +793,11 @@ class Resolver:
                 wave = None
         with self._stage("resolve_post", version):
             return self._finish_entry(version, txns, verdicts, fail_safe,
-                                      wave)
+                                      wave, entry.rows)
 
     def _finish_entry(
         self, version: int, txns: list, verdicts: list[Verdict],
-        fail_safe: bool, wave: "list[int] | None",
+        fail_safe: bool, wave: "list[int] | None", rows: tuple[int, int],
     ) -> tuple[
         list[Verdict], dict[int, list[tuple[bytes, bytes]]], bool,
         "list[int] | None",
@@ -818,19 +855,20 @@ class Resolver:
             self.txns_cycle_aborted += sum(
                 1 for lv in wave if lv == WAVE_LEVEL_CYCLE
             )
-        self._count_resolved(txns)
+        self._count_resolved(txns, rows)
         return (verdicts, conflicting, fail_safe, wave)
 
     # -- history-capacity fail-safe -----------------------------------------
 
     def _should_fail_safe(
-        self, n_txns: int, version: int, oldest_version: int
+        self, n_rows: int, version: int, oldest_version: int
     ) -> bool:
         """True → this batch must be rejected wholesale (all CONFLICT).
 
         Two triggers:
-        - Proactive headroom check: resolving n_txns can add at most
-          ``cs.worst_case_growth(n_txns)`` boundary slots; if the cached
+        - Proactive headroom check: resolving n_rows padded rows (one a
+          transaction unless it is wide, _txn_rows) can add at most
+          ``cs.worst_case_growth(n_rows)`` boundary slots; if the cached
           headroom (refreshed after every engine touch, so no extra device
           sync here) can't absorb that, painting could truncate history.
         - Unsafe window after a true overflow (belt and braces — should be
@@ -851,7 +889,7 @@ class Resolver:
                 return True
         if self._headroom is None:
             self._headroom = self.cs.headroom()
-        needed = self.cs.worst_case_growth(n_txns)
+        needed = self.cs.worst_case_growth(n_rows)
         engaged = self._headroom < needed
         # Episode tracking with hysteresis: the per-batch decision above is
         # the correctness gate (an empty batch is always safe to resolve),
@@ -922,6 +960,11 @@ class Resolver:
         return {
             "batches_resolved": self.batches_resolved,
             "txns_resolved": self.txns_resolved,
+            # Padded engine rows those transactions took, and how many of
+            # them had more ranges than one row's slots (continuation
+            # rows; rows a transaction is a window difference of the two).
+            "rows_dispatched": self.rows_dispatched,
+            "wide_txns": self.wide_txns,
             "ranges_received": self.ranges_received,
             "txns_with_ranges": self.txns_with_ranges,
             "version": self._version,

@@ -121,22 +121,30 @@ def test_too_old_only_with_reads():
     assert got == [Verdict.TOO_OLD, Verdict.COMMITTED]
 
 
-def test_range_coalescing_is_conservative():
-    """Txns with more ranges than the padded width still resolve correctly
-    (may only over-conflict, never under-conflict — with disjoint keys the
-    covering ranges here stay disjoint so verdicts stay exact)."""
+def test_more_ranges_than_slots_are_judged_exactly():
+    """Four writes on a 2-slot engine take two rows, and every one of them
+    is painted as it was sent: nothing is widened. Every verdict is the
+    oracle's: a reader of each written key conflicts, and a reader of the
+    keys BETWEEN them, which a covering range would have swallowed,
+    commits."""
     cs = TPUConflictSet(capacity=256, batch_size=8, max_read_ranges=2,
                         max_write_ranges=2, max_key_bytes=8)
+    oracle = OracleConflictSet()
     pt = lambda k: KeyRange(k, k + b"\x00")
-    cs.resolve([TxnConflictInfo(5, [], [pt(b"a"), pt(b"c"), pt(b"e"), pt(b"g")])], 10)
-    got = cs.resolve(
-        [
-            TxnConflictInfo(5, [pt(b"e")], []),  # overlaps write@10
-            TxnConflictInfo(15, [pt(b"e")], []),
-        ],
-        20,
+    first = [TxnConflictInfo(5, [], [pt(b"a"), pt(b"c"), pt(b"e"), pt(b"g")])]
+    assert cs.resolve(first, 10) == oracle.resolve(first, 10)
+    readers = (
+        [TxnConflictInfo(5, [pt(k)], []) for k in (b"a", b"c", b"e", b"g")]
+        + [TxnConflictInfo(5, [pt(k)], []) for k in (b"b", b"d", b"f")]
+        + [TxnConflictInfo(15, [pt(b"e")], []),
+           # four reads on two slots: only the last one meets a write
+           TxnConflictInfo(5, [pt(b"b"), pt(b"d"), pt(b"f"), pt(b"g")], []),
+           TxnConflictInfo(5, [pt(b"b"), pt(b"d"), pt(b"f"), pt(b"h")], [])]
     )
-    assert got == [Verdict.CONFLICT, Verdict.COMMITTED]
+    got = cs.resolve(readers, 20)
+    assert got == oracle.resolve(readers, 20)
+    assert got == [Verdict.CONFLICT] * 4 + [Verdict.COMMITTED] * 4 + [
+        Verdict.CONFLICT, Verdict.COMMITTED]
 
 
 def test_commit_version_must_advance():

@@ -21,7 +21,11 @@ import pytest
 
 from foundationdb_tpu.core.keypack import INT32_MAX
 from foundationdb_tpu.models import conflict_kernel as ck
-from foundationdb_tpu.models.conflict_set import _ResidentMirror, _rows_to_u64
+from foundationdb_tpu.models.conflict_set import (
+    _insert_sorted,
+    _ResidentMirror,
+    _rows_to_u64,
+)
 from foundationdb_tpu.ops.lex import searchsorted_words_fp
 
 D = 4096  # dictionary capacity: D + 1 rows, the last always +inf
@@ -223,3 +227,27 @@ def test_apply_delta_rebases_two_level_history_and_shard_bounds():
     np.testing.assert_array_equal(
         want_keys[rebased(base_ranks)],
         np.asarray(res.dict_keys)[base_ranks])
+
+
+@pytest.mark.parametrize("shape", [(), (4,), (9,)], ids=["vector", "u64", "rows"])
+@pytest.mark.parametrize("n,m", [(0, 0), (0, 3), (5000, 0), (5000, 1),
+                                 (5000, 4), (5000, 5), (5000, 700),
+                                 (70000, 7), (70000, 68), (70000, 69)])
+def test_the_mirrors_splice_equals_np_insert(n, m, shape):
+    """_ResidentMirror.insert_new splices a few new rows by slice copies
+    and many by np.insert: the same array either way, on both sides of the
+    switch (a thousandth of the resident rows), with several rows landing
+    at one position, at the first and past the last."""
+    rng = np.random.default_rng([n, m, len(shape)])
+    dtype = np.uint64 if shape == (4,) else np.int32
+    arr = rng.integers(0, 1 << 30, (n, *shape)).astype(dtype)
+    vals = rng.integers(0, 1 << 30, (m, *shape)).astype(dtype)
+    ins = np.sort(rng.integers(0, n + 1, m))
+    if m >= 4:
+        ins[:2] = 0  # two at the front
+        ins[-2:] = n  # two past the end
+        ins = np.sort(ins)
+    got = _insert_sorted(arr, ins, vals)
+    want = np.insert(arr, ins, vals, axis=0)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert (got == want).all()

@@ -51,10 +51,17 @@ oracle = OracleConflictSet(wave_commit=wave)
 cv = 1000
 for batch_i in range(6):
     cv += int(rng.integers(1, 40))
+    # Every fourth transaction has up to nine ranges of a kind on four
+    # slots (continuation rows): each design judges it exactly. Wave
+    # engines level one dispatch at a time, the wave oracle the whole
+    # list, so there the list is cut to what one dispatch holds.
     txns = [
-        rand_txn(rng, read_version=int(rng.integers(max(0, cv - 200), cv)))
-        for _ in range(int(rng.integers(8, 32)))
+        rand_txn(rng, read_version=int(rng.integers(max(0, cv - 200), cv)),
+                 n_ranges=9 if i % 4 == 3 else 4)
+        for i in range(int(rng.integers(8, 32)))
     ]
+    if wave:
+        txns = txns[: cs._chunks(txns)[0][1]]
     if not wave:
         for t in txns[::3]:  # loser-range report path rides along
             object.__setattr__(t, "report_conflicting_keys", True)
@@ -67,7 +74,7 @@ for batch_i in range(6):
         assert cs.last_wave == oracle.last_wave, f"batch {batch_i} levels"
         continue
     # Loser-range completeness: every oracle conflicting range must be
-    # covered by the kernel's (possibly coalesced-wider) report.
+    # covered by the kernel's report.
     for i, ranges in oracle.last_conflicting.items():
         kernel = cs.last_conflicting.get(i)
         assert kernel is not None, f"batch {batch_i} txn {i}: no report"
@@ -479,6 +486,47 @@ _FAST = [
 )
 def test_design_flag_parity(flags):
     _run_combo(flags)
+
+
+_TWO_PHASE_CHILD = r"""
+import os
+os.environ["JAX_PLATFORMS"] = "cpu"
+from foundationdb_tpu.utils import enable_compilation_cache
+enable_compilation_cache()
+from foundationdb_tpu.models import conflict_kernel as ck
+from tests import test_wide_txn_parity as wide
+
+assert ck._HIST_DESIGN == os.environ.get("FDB_TPU_HISTORY", "window")
+assert ck._PACKED == (os.environ.get("FDB_TPU_PACKED", "1") != "0")
+assert ck._RESIDENT == (
+    os.environ.get("FDB_TPU_RESIDENT", "1") != "0" and ck._PACKED
+)
+wide.test_the_two_phase_wave_exchange_judges_wide_transactions_exactly(
+    17, 9, ck._RESIDENT)
+print("TWO-PHASE-OK")
+"""
+
+
+# The role-level wave exchange (resolve_edges / resolve_apply) has an entry
+# point of its own for each batch format and history design; the two the
+# defaults give run in-process (tests/test_wide_txn_parity.py), the other
+# three here: wide transactions, clipped to two shards, against the oracle.
+@pytest.mark.parametrize("flags", [
+    {"FDB_TPU_PACKED": "0"},
+    {"FDB_TPU_PACKED": "0", "FDB_TPU_HISTORY": "batch"},
+    {"FDB_TPU_RESIDENT": "0", "FDB_TPU_HISTORY": "batch"},
+], ids=lambda f: ",".join(f"{k[8:]}={v}" for k, v in f.items()))
+def test_two_phase_wave_exchange_of_wide_transactions_by_design(flags):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    for k in list(_FLAGS) + ["FDB_TPU_WAVE_COMMIT"]:
+        env.pop(k, None)
+    env.update(flags)
+    r = subprocess.run(
+        [sys.executable, "-c", _TWO_PHASE_CHILD], env=env,
+        capture_output=True, text=True, timeout=600, cwd=_REPO,
+    )
+    assert r.returncode == 0, f"{flags}: {r.stderr[-2000:]}"
+    assert r.stdout.strip().splitlines()[-1] == "TWO-PHASE-OK"
 
 
 _FULL = [

@@ -2,9 +2,11 @@
 
 The C packer must match the Python object path bit-for-bit: same padded
 tensors out of _pack_wire as _pack, and identical verdicts from
-resolve_wire as resolve, across truncation, coalescing, and empty-range
-edge cases (mirrors the reference's requirement that the serialized
-ResolveTransactionBatchRequest round-trips losslessly)."""
+resolve_wire as resolve, across truncation, transactions with more ranges
+than a row has slots (continuation rows, cut into dispatches at the same
+transaction by both), and empty-range edge cases (mirrors the reference's
+requirement that the serialized ResolveTransactionBatchRequest round-trips
+losslessly)."""
 
 import numpy as np
 import pytest
@@ -54,14 +56,24 @@ class TestWirePackParity:
         rng = np.random.default_rng(seed)
         obj, wirecs = make_pair()
         obj.base_version = wirecs.base_version = 0
+        # Up to 11 ranges of a kind on 4 slots: most transactions take
+        # two or three rows, so the 64 go out in several dispatches.
         txns = random_txns(rng, 64, overlong=True, many_ranges=True)
-        bt_obj = obj._pack(txns)
         buf = np.frombuffer(encode_resolve_batch(txns), np.uint8)
-        bt_wire, off = wirecs._pack_wire(buf, 0, len(txns))
-        assert off == buf.size
-        for name in bt_obj._fields:
-            a, b = getattr(bt_obj, name), getattr(bt_wire, name)
-            assert np.array_equal(np.asarray(a), np.asarray(b)), name
+        chunks = obj._chunks(txns)
+        assert len(chunks) > 1
+        off, left = 0, len(txns)
+        for lo, hi in chunks:
+            bt_obj = obj._pack(txns[lo:hi])
+            bt_wire, off, taken = wirecs._pack_wire(
+                buf, off, min(left, wirecs.batch_size))
+            assert taken == hi - lo
+            left -= taken
+            assert bt_obj.cont is not None and bt_obj.cont.any()
+            for name in bt_obj._fields:
+                a, b = getattr(bt_obj, name), getattr(bt_wire, name)
+                assert np.array_equal(np.asarray(a), np.asarray(b)), name
+        assert (off, left) == (buf.size, 0)
 
     @pytest.mark.parametrize("seed", [3, 4])
     def test_verdicts_identical_over_stream(self, seed):
@@ -101,8 +113,9 @@ class TestWirePackParity:
         )]
         bt_obj = obj._pack(txns)
         buf = np.frombuffer(encode_resolve_batch(txns), np.uint8)
-        bt_wire, _ = wirecs._pack_wire(buf, 0, 1)
-        for name in bt_obj._fields:
+        bt_wire, _off, taken = wirecs._pack_wire(buf, 0, 1)
+        assert taken == 1 and bt_obj.cont is None and bt_wire.cont is None
+        for name in bt_obj._fields[:-1]:
             assert np.array_equal(
                 np.asarray(getattr(bt_obj, name)),
                 np.asarray(getattr(bt_wire, name))), name
