@@ -51,7 +51,7 @@ from foundationdb_tpu.core.errors import (
     ValueTooLarge,
     WrongShardServer,
 )
-from foundationdb_tpu.obs.span import span_sink
+from foundationdb_tpu.obs.span import span_now, span_sink
 from foundationdb_tpu.runtime.commit_proxy import CommitRequest
 from foundationdb_tpu.runtime.shardmap import MAX_KEY, KeyShardMap
 
@@ -552,7 +552,7 @@ class Transaction:
                 sink = span_sink(self.db.loop)
                 self._obs = (sink.sample() if sink is not None
                              else None) or False
-            t_grv = self.db.loop.now if self._obs else 0.0
+            t_grv = span_now(self.db.loop) if self._obs else 0.0
             ep = self.db._pick(self.db.grv_proxies)
             try:
                 self._read_version = await ep.get_read_version(
@@ -588,7 +588,13 @@ class Transaction:
                 raise
             if self._obs:
                 # grv_wait stage: request -> grant, queue/deferral incl.
-                self._obs_grv = (t_grv, self.db.loop.now - t_grv)
+                # Kept for the commit identity (_obs_record_commit); the
+                # same interval is recorded NOW as grv_rtt, so that a
+                # sampled transaction that never commits, a read, leaves
+                # its GRV too.
+                self._obs_grv = (t_grv, span_now(self.db.loop) - t_grv)
+                self._obs_read_stage("grv_rtt", t_grv, self._obs_grv[1],
+                                     self._read_version)
         return self._read_version
 
     def set_read_version(self, version: int) -> None:
@@ -648,8 +654,9 @@ class Transaction:
     # above stays identical either way.
 
     async def _fetch_key(self, key: bytes, version: int) -> bytes | None:
-        return await self.db.read_key(key, version,
-                                      token=self.authorization_token)
+        return await self._read_rpc(
+            self.db.read_key(key, version, token=self.authorization_token),
+            version)
 
     async def _fetch_keys(self, keys: list[bytes], version: int) -> list:
         # A subclass that re-points the single-key seam (repair's replayed
@@ -657,8 +664,9 @@ class Transaction:
         # through ITS _fetch_key rather than bypassing the override.
         if type(self)._fetch_key is not Transaction._fetch_key:
             return [await self._fetch_key(k, version) for k in keys]
-        return await self.db.read_keys(keys, version,
-                                       token=self.authorization_token)
+        return await self._read_rpc(
+            self.db.read_keys(keys, version, token=self.authorization_token),
+            version)
 
     async def _fetch_range(
         self, begin: bytes, end: bytes, version: int, limit: int,
@@ -666,6 +674,29 @@ class Transaction:
     ) -> list[tuple[bytes, bytes]]:
         return await self.db.read_range(begin, end, version, limit, reverse,
                                         token=self.authorization_token)
+
+    async def _read_rpc(self, read, version: int):
+        """Await `read`, a point read's coroutine; for a sampled
+        transaction that is stage read_rpc, send -> value."""
+        if not self._obs:
+            return await read
+        t0 = span_now(self.db.loop)
+        value = await read
+        self._obs_read_stage("read_rpc", t0, span_now(self.db.loop) - t0,
+                             version)
+        return value
+
+    def _obs_read_stage(self, stage: str, start: float, dur: float,
+                        version: int) -> None:
+        """One read-path stage of this SAMPLED transaction (obs/span.py
+        READ_PATH_STAGES: grv_rtt, read_rpc): histogram sample plus a
+        span record under the transaction's tid and its read version,
+        the identifier the GRV proxy's and the storage's stages of the
+        same read carry."""
+        sink = span_sink(self.db.loop)
+        if sink is not None:
+            sink.record_stage(stage, dur)
+            sink.add_span(self._obs.tid, stage, start, dur, version=version)
 
     async def _get_special(self, key: bytes) -> bytes | None:
         """The special key space (reference: SpecialKeySpace — synthetic
@@ -965,7 +996,7 @@ class Transaction:
             trace=self._obs.tid if self._obs else None,
         )
         commit_ep = self.db._pick(self.db.commit_proxies)
-        t_commit = self.db.loop.now if self._obs else 0.0
+        t_commit = span_now(self.db.loop) if self._obs else 0.0
         try:
             res = await commit_ep.commit(req)
         except NotCommitted as e:
@@ -994,7 +1025,7 @@ class Transaction:
         if self._obs:
             try:
                 self._obs_record_commit(getattr(res, "spans", None),
-                                        t_commit, self.db.loop.now)
+                                        t_commit, span_now(self.db.loop))
             except Exception:
                 # Tracing bookkeeping must never fail a transaction that
                 # IS durably committed (a malformed spans tuple from a

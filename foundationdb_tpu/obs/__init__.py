@@ -22,8 +22,7 @@ Six pieces, one subsystem:
 - ``doctor``: deterministic root-cause reports per anomaly window
   (dominant stage + co-occurring annotations + one-line verdict), the
   chaos fault-window attribution table, and the ``--doctor-gate`` CI
-  line; ``history`` folds the committed bench artifacts into the
-  perf-trajectory table (``--bench-history``).
+  line.
 - ``selfcheck``: the CI face — ``python -m foundationdb_tpu.obs`` runs a
   short sim and verifies span completeness, the reconciliation identity,
   and the scrape audit in one JSON line; ``--ab`` measures the 1-in-64
@@ -53,7 +52,6 @@ from foundationdb_tpu.obs.doctor import (
     diagnose,
     run_doctor_gate,
 )
-from foundationdb_tpu.obs.history import bench_history
 from foundationdb_tpu.obs.recorder import (
     ANNOTATION_CLASSES,
     TRACE_CATALOG,
@@ -96,7 +94,6 @@ __all__ = [
     "TraceContext",
     "add_span_sink",
     "attribute_faults",
-    "bench_history",
     "check_txn_tree",
     "diagnose",
     "latency_probe",

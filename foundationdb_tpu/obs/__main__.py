@@ -8,7 +8,6 @@
         --record-out ring.jsonl                      # flight recorder
     python -m foundationdb_tpu.obs --doctor ring.jsonl   # incident report
     python -m foundationdb_tpu.obs --doctor-gate     # DOCTOR.json gate
-    python -m foundationdb_tpu.obs --bench-history   # perf trajectory
 
 The selfcheck is a scrape + span reconciliation on a short sim run; the
 A/B is scripts/obs_ab.sh -> OBS_AB.json. `--poll` is the deployed-cluster
@@ -18,8 +17,6 @@ ring with derived annotations and SLO tracking. `--doctor` runs the
 incident doctor over an existing ring; `--doctor-gate` runs the seeded
 mini-chaos with the recorder armed and gates the per-fault attribution
 (scripts/doctor_run.sh -> DOCTOR.json).
-`--bench-history` folds the committed BENCH_*/\\*_AB artifacts into the
-time-ordered regression table.
 """
 
 from __future__ import annotations
@@ -67,21 +64,9 @@ def main(argv: "list[str] | None" = None) -> int:
     ap.add_argument("--doctor-gate", action="store_true",
                     help="seeded mini-chaos with the recorder armed, "
                          "gated on per-fault attribution (DOCTOR.json)")
-    ap.add_argument("--bench-history", action="store_true",
-                    help="fold committed BENCH_*/*_AB.json artifacts "
-                         "into the time-ordered regression table")
-    ap.add_argument("--history-root", default=".")
     args = ap.parse_args(argv)
 
     from foundationdb_tpu.obs.selfcheck import run_overhead_ab, run_selfcheck
-
-    if args.bench_history:
-        from foundationdb_tpu.obs.history import bench_history, format_table
-
-        rec = bench_history(root=args.history_root)
-        print(format_table(rec), file=sys.stderr, flush=True)
-        print(json.dumps(rec), flush=True)
-        return 0 if rec["ok"] else 1
 
     if args.doctor:
         from foundationdb_tpu.obs.doctor import main_doctor

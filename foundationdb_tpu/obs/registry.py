@@ -281,17 +281,24 @@ def add_span_sink(reg: MetricsRegistry, sink) -> None:
     snapshots into per-window histograms, which is the only honest way
     to quote an interval p99 from a running sink."""
     b = sink.breakdown()
+
+    def leaf(stage: str) -> str:
+        # loop_busy:<role>, rpc_inbound:<service>.<method>: a registry
+        # key nests at dots and its leaves are snake_case.
+        return stage.replace(":", "_").replace(".", "_")
+
     reg.add("obs", "", {
         "txns_seen": b["txns_seen"],
         "txns_sampled": b["txns_sampled"],
         "spans": len(sink.spans),
         "unattributed_ms": b["unattributed_ms"],
         "stage_sum_ms": {
-            name: round(h.sum_ms, 4)
+            leaf(name): round(h.sum_ms, 4)
             for name, h in sorted(sink.stage_hists.items())
         },
         "stage_count": {
-            name: h.count for name, h in sorted(sink.stage_hists.items())
+            leaf(name): h.count
+            for name, h in sorted(sink.stage_hists.items())
         },
         "e2e_sum_ms": round(sink.e2e_hist.sum_ms, 4),
         "e2e_count": sink.e2e_hist.count,

@@ -27,9 +27,17 @@ Design rules:
   must not perturb the loop's seeded stream), trace ids are sequential,
   and all stamps come off the loop's virtual clock, so the same seed
   yields byte-identical span records. On a RealLoop, trace ids carry the
-  pid so records from parallel generator processes never collide, and
-  synchronous engine work is measured with ``time.perf_counter`` (the
-  virtual clock cannot advance inside one task step there).
+  pid so records from parallel generator processes never collide.
+- **One clock, and it moves inside a loop turn.** Every span boundary on
+  a role's hot path is ``span_now(loop)``, never a bare ``loop.now``: a
+  RealLoop refreshes ``now`` once a pump turn, so a wait that begins and
+  ends inside one turn would read 0 and a stamp taken behind a long
+  synchronous bracket would read the turn's start. On a wall-time loop
+  the span clock is ``time.perf_counter`` (on Linux the system-wide
+  CLOCK_MONOTONIC that ``RealLoop.now`` reads too, so the two mix and
+  two processes of one host may subtract each other's stamps); in sim it
+  is the virtual ``loop.now``. ``stage_clock`` is the same clock as a
+  callable, for synchronous brackets.
 - **One histogram machinery.** Per-stage distributions reuse loadgen's
   mergeable log-binned ``LatencyHistogram`` — scrape lines from many
   processes SUM into one honest population percentile.
@@ -128,7 +136,72 @@ reconciliation identity):
                       reconcile through the engine ring, including any
                       rollback/repair re-resolves (interior of
                       device_dispatch, the phase-B half)
-    tlog_fsync        tlog: chain-ordered push -> durable ack
+    tlog_fsync        tlog: chain-ordered push -> durable ack (the disk
+                      queue's real fsync included: it is synchronous,
+                      and the span clock moves through it)
+
+The read path (``READ_PATH_STAGES``; never in the commit identity: a read
+is no commit, and half of a YCSB-F cluster's transactions are reads). Each
+is ticked with the READ VERSION as the identifier the stages of one read
+share; the client's two also carry the sampled transaction's tid. They
+nest and overlap (grv_rtt holds grv_proxy_queue, which holds
+grv_sequencer_rtt; read_rpc holds storage_version_wait + storage_lookup
+and both transport legs), so they are never summed with one another:
+
+    grv_rtt           client: read-version request -> grant, recorded
+                      when it HAPPENS for every sampled transaction, so
+                      a read-only one leaves it too (grv_wait, the same
+                      interval, is recorded only with a commit's tree)
+    read_rpc          client: a point read or batched point read, send ->
+                      value (Transaction._fetch_key / _fetch_keys)
+    grv_sequencer_rtt GRV proxy: get_live_committed_version + the epoch
+                      confirm, n = the batch it serves. grv_proxy_queue
+                      minus this and one BATCH_INTERVAL is what a
+                      request waited for TOKENS, the ratekeeper's hand
+    storage_version_wait  storage: 0 for a read at or under the applied
+                      version (recorded, so the mean is over all reads),
+                      else the park until the pull loop passes it
+    storage_lookup    storage: get / get_multi / get_range from after
+                      the version check to the return, weighted by keys
+                      (holds read_coalesce / read_pack / read_dispatch
+                      where the read rides the coalescer)
+    storage_version_lag   storage: once a pull-loop iteration that
+                      advanced, (the tlog's version it just saw - the
+                      applied version before the apply) in SECONDS of
+                      versions: what metrics()["version_lag"] polls for
+                      the ratekeeper, as a distribution
+
+Per process and per endpoint, wall-time loops only (the names carry what
+they are about after a colon, so they are families, not tuples):
+
+    rpc_inbound:<service>.<method>
+                      transport: the sender's send stamp -> the receiver
+                      has decoded the request frame (NetTransport.
+                      _on_frame): the sender's flush, the wire, the
+                      socket buffer WHILE THE RECEIVER'S THREAD WAS BUSY,
+                      and the decode (rpc_decode is inside it). The
+                      stamp rides the frame as one trailing element only
+                      while the SENDER has a sink; recorded only while
+                      the RECEIVER has one too. A sample a FRAME, where
+                      resolve_wait is a sample a transaction. Exact
+                      between processes of one host (one clock); across
+                      hosts it holds their clock skew, clamped at 0.
+    loop_busy:<role>, loop_idle:<role>
+                      RealLoop.run_until: the seconds of each ~100 ms
+                      slice the pump spent waiting in select()/sleep()
+                      (idle) and doing anything else (busy: ready tasks,
+                      socket callbacks, timers), one sample each a
+                      slice, NOT sampled 1-in-N: sum(busy) / (sum(busy)
+                      + sum(idle)) is the process's busy share, p95 how
+                      full its worst slices are. <role> is server.py's
+                      --role (proxy, resolver, tlog, storage, sequencer,
+                      ratekeeper, ...); a loop server.py did not start
+                      takes the first service its transport serves other
+                      than admin; one that serves nothing is `client`.
+                      The wait itself is entered under
+                      TraceAnnotation fdb:loop_select, so a device idle
+                      gap in the process that holds the chip falls under
+                      it (the role had nothing to do) or under a stage.
 
 The engine identity (``ENGINE_STAGES``), per batch and by ARITHMETIC like
 the txn identity above, whether or not another batch was dispatched
@@ -228,6 +301,19 @@ READ_STAGES = (
     "read_pack",
     "read_dispatch",
     "watch_sweep",
+)
+
+#: A read from the client down (module docstring). Apart from READ_STAGES
+#: on purpose: those PARTITION the read plane's own time (the doctor sums
+#: them into one denominator); these nest in one another, hold those, and
+#: storage_version_lag is a distance, not time spent.
+READ_PATH_STAGES = (
+    "grv_rtt",
+    "read_rpc",
+    "grv_sequencer_rtt",
+    "storage_version_wait",
+    "storage_lookup",
+    "storage_version_lag",
 )
 
 
@@ -351,8 +437,8 @@ class SpanSink:
             self._stage_ticks[name] = 0
             self.record_stage(name, dur_s, n)
             if version is not None:
-                self.add_span(None, name, self.loop.now - dur_s, dur_s,
-                              version=version)
+                self.add_span(None, name, span_now(self.loop) - dur_s,
+                              dur_s, version=version)
         else:
             self._stage_ticks[name] = c
 
@@ -652,8 +738,31 @@ class stage_timer:
                 rec[self.inside] = rec.get(self.inside, 0.0) - dt
 
 
+def span_now(loop) -> float:
+    """One stamp on THE SPAN CLOCK, the clock every batch- and
+    transaction-level span boundary is read from.
+
+    A wall-time loop refreshes ``loop.now`` once a pump turn, so two
+    stamps taken in one turn read the same and a stamp taken after a long
+    synchronous bracket reads the turn's START: a queue wait measured
+    with it is 0 by construction. There the span clock is
+    ``time.perf_counter``: on Linux the same system-wide CLOCK_MONOTONIC
+    that ``RealLoop.now`` reads, so it moves inside a turn, mixes with
+    ``loop.now`` stamps, and two processes of ONE host may subtract each
+    other's stamps (``rpc_inbound``); across hosts the difference holds
+    the hosts' clock skew. A sim loop keeps its virtual ``loop.now``, so
+    simulated span records stay byte-identical under a seed.
+
+    The rule for role code: on the hot path a span boundary is
+    ``span_now(loop)``, never a bare ``loop.now``."""
+    if getattr(loop, "WALL_TIME", False):
+        return time.perf_counter()
+    return loop.now
+
+
 def stage_clock(loop):
-    """Clock for SYNCHRONOUS work (engine resolve, host pack): the loop
+    """``span_now`` as a callable, for brackets that read it many times.
+    Clock for SYNCHRONOUS work (engine resolve, host pack): the loop
     clock cannot advance inside one task step on a RealLoop, so deployed
     processes measure with perf_counter; sim keeps the virtual clock so
     records stay seed-deterministic (synchronous work is 0 virtual

@@ -30,7 +30,7 @@ from foundationdb_tpu.core.mutations import (
 )
 from foundationdb_tpu.core.types import KeyRange, TxnConflictInfo, Verdict
 from foundationdb_tpu.core.wavemesh import clip_ranges
-from foundationdb_tpu.obs.span import span_sink
+from foundationdb_tpu.obs.span import span_now, span_sink
 from foundationdb_tpu.repair.hotrange import HotRangeSketch
 from foundationdb_tpu.runtime.backup import BACKUP_TAG
 from foundationdb_tpu.runtime.flow import (
@@ -208,7 +208,7 @@ class CommitProxy:
             # lane-queue time is attributable. Stamped for EVERY request
             # while tracing is armed (one attr write); all heavier work
             # is gated on req.trace (sampled txns only).
-            req._obs_arrival = self.loop.now
+            req._obs_arrival = span_now(self.loop)
         p = Promise()
         self._queue.push((req, p), getattr(req, "priority", "default"))
         return await p.future
@@ -319,7 +319,7 @@ class CommitProxy:
                 # Shaped requests keep their FIRST pop (the admission
                 # gate re-stamps at flush so the park window is never
                 # double-counted into batch_form).
-                t_pop = self.loop.now
+                t_pop = span_now(self.loop)
                 for req, _p in batch:
                     if not hasattr(req, "_obs_pop"):
                         req._obs_pop = t_pop
@@ -426,7 +426,8 @@ class CommitProxy:
                     continue
                 req._admission_shaped = True
                 if hasattr(req, "_obs_arrival"):
-                    req._obs_park0 = self.loop.now  # traced: park begins
+                    # traced: the park begins
+                    req._obs_park0 = span_now(self.loop)
                 if not self._shaped:
                     self._shaped_since = self.loop.now  # new lane head
                 self._shaped.append((req, p))
@@ -454,7 +455,7 @@ class CommitProxy:
                     # Stage stamp: the park window closes here, and the
                     # pop is re-anchored to the flush so batch_form
                     # measures flush->version, not park-inclusive.
-                    now = self.loop.now
+                    now = span_now(self.loop)
                     req._obs_park = now - req._obs_park0
                     req._obs_pop = now
                 passed.append((req, p))
@@ -563,15 +564,15 @@ class CommitProxy:
         version: int,
     ) -> None:
         sink = span_sink(self.loop)
-        t_version = self.loop.now  # commit version in hand as of entry
+        t_version = span_now(self.loop)  # commit version in hand as of entry
         t_resolved = t_assembled = t_pushed = t_version
         try:
             verdicts, conflicting, fail_safe, wave = await self._resolve(
                 batch, prev_version, version
             )
-            t_resolved = self.loop.now
+            t_resolved = span_now(self.loop)
             tagged = self._assemble(batch, verdicts, version, wave)
-            t_assembled = self.loop.now
+            t_assembled = span_now(self.loop)
             kc = self._known_committed
             if self.loop.buggify("commit_proxy.slow_push"):
                 # Delayed push: later batches' pushes overtake ours at the
@@ -593,7 +594,7 @@ class CommitProxy:
                     for t in self.tlogs
                 ]
             )
-            t_pushed = self.loop.now  # every tlog acked its fsync
+            t_pushed = span_now(self.loop)  # every tlog acked its fsync
             self._known_committed = max(self._known_committed, version)
             await self.sequencer.report_committed(version)
         except Exception:
@@ -629,7 +630,7 @@ class CommitProxy:
                 if v == Verdict.COMMITTED:
                     accepted.extend(req.write_ranges)
             self.admission.feed_accepted(accepted, version)
-        t_reply = self.loop.now
+        t_reply = span_now(self.loop)
         for i, ((req, p), v) in enumerate(zip(batch, verdicts)):
             if v == Verdict.COMMITTED:
                 self.txns_committed += 1
@@ -757,7 +758,7 @@ class CommitProxy:
             reply = await self._with_retry(
                 lambda: r.resolve(prev_version, version, txns)
             )
-            replied_at.append(self.loop.now)
+            replied_at.append(span_now(self.loop))
             return reply
 
         replies = await all_of(

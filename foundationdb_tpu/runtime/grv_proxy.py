@@ -17,7 +17,7 @@ import itertools
 import os
 
 from foundationdb_tpu.core.errors import ProcessKilled
-from foundationdb_tpu.obs.span import span_sink
+from foundationdb_tpu.obs.span import span_now, span_sink
 from foundationdb_tpu.runtime.flow import ActorCancelled, Loop, Promise, rpc
 
 #: Unique-per-process GRV poller ids (pid + counter: deterministic in the
@@ -121,9 +121,9 @@ class GrvProxy:
         # batched grant — token-bucket waits, tag throttling, and the
         # admission-saturation deferral all land here (the interior of
         # the client-measured grv_wait stage).
-        t0 = self.loop.now
+        t0 = span_now(self.loop)
         version = await p.future
-        sink.stage_tick("grv_proxy_queue", self.loop.now - t0)
+        sink.stage_tick("grv_proxy_queue", span_now(self.loop) - t0)
         return version
 
     @rpc
@@ -224,6 +224,8 @@ class GrvProxy:
             batch = s_admitted + admitted + b_admitted
             if not batch:
                 continue
+            sink = span_sink(self.loop)
+            t_seq = span_now(self.loop) if sink is not None else 0.0
             try:
                 version = await self.sequencer.get_live_committed_version()
                 await self._confirm_epoch_live()
@@ -237,6 +239,14 @@ class GrvProxy:
                 for p in batch:
                     p.fail(ProcessKilled("proxy retired: ask again"))
                 raise
+            if sink is not None:
+                # Stage grv_sequencer_rtt (obs/span.py): what the batch
+                # paid for its version. A request's grv_proxy_queue minus
+                # this and one BATCH_INTERVAL is what it waited for
+                # TOKENS, the ratekeeper's hand.
+                sink.stage_tick("grv_sequencer_rtt",
+                                span_now(self.loop) - t_seq, n=len(batch),
+                                version=version)
             self.grvs_served += len(batch)
             for p in batch:
                 p.send(version)

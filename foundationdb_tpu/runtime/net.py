@@ -34,7 +34,7 @@ import time
 _SOFT_ERRNOS = (errno.EAGAIN, errno.EINPROGRESS, errno.ENOTCONN, errno.EALREADY)
 
 from foundationdb_tpu.core.errors import FdbError, TransactionTooLarge
-from foundationdb_tpu.obs.span import span_sink, stage_timer
+from foundationdb_tpu.obs.span import span_now, span_sink, stage_timer
 from foundationdb_tpu.runtime import wire
 from foundationdb_tpu.runtime.flow import (
     BrokenPromise, Future, Loop, Promise, rpc,
@@ -75,6 +75,13 @@ class RealLoop(Loop):
     def __init__(self, seed: "int | None" = None):
         super().__init__(seed=seed, start_time=time.monotonic())
         self.selector = selectors.DefaultSelector()
+        # What this process IS, for the per-process stages of obs/span.py
+        # (`loop_busy:<role>`): server.py names it (`--role`); a loop it
+        # did not start takes the first service its transport serves
+        # other than `admin` (NetTransport.serve); one that serves
+        # nothing is a client.
+        self.role: "str | None" = None
+        self._obs_acc = [0.0, 0.0]  # busy, idle seconds of the open slice
 
     def resync(self) -> None:
         """Snap `now` to the current monotonic clock. The pump refreshes
@@ -107,9 +114,17 @@ class RealLoop(Loop):
 
     def run_until(self, fut: Future, timeout: float = 1e9):
         deadline = time.monotonic() + timeout
+        # Busy-share accounting (obs/span.py `loop_busy:<role>`), armed
+        # only while a sink is attached: `mark` is where the busy stretch
+        # under way began on the span clock. Time outside run_until is
+        # neither busy nor idle.
+        mark = (time.perf_counter()
+                if getattr(self, "span_sink", None) is not None else None)
         while True:
             self._drain_ready()
             if fut.done():
+                if mark is not None:
+                    self._obs_turn(mark, time.perf_counter(), None)
                 return fut.result()
             now = time.monotonic()
             if now > deadline:
@@ -117,15 +132,57 @@ class RealLoop(Loop):
             wait = self.MAX_IDLE_WAIT
             if self._timers:
                 wait = min(wait, max(0.0, self._timers[0][0] - now))
-            if self.selector.get_map():
-                for key, _mask in self.selector.select(wait):
-                    key.data(key.fileobj)
-            elif wait > 0:
-                time.sleep(wait)
+            if getattr(self, "span_sink", None) is None:
+                mark = None
+                events = self._idle(wait)
+            else:
+                # Everything in the turn but the wait is BUSY: ready
+                # tasks above, socket callbacks and timers below. Under a
+                # profiler the wait is `fdb:loop_select` on its timeline.
+                t0 = time.perf_counter()
+                with stage_timer(None, "loop_select"):
+                    events = self._idle(wait)
+                t1 = time.perf_counter()
+                self._obs_turn(mark or t0, t0, t1)
+                mark = t1
+            for key, _mask in events:
+                key.data(key.fileobj)
             self._now = time.monotonic()
             while self._timers and self._timers[0][0] <= self._now:
                 _t, _seq, p = heapq.heappop(self._timers)
                 p.send(None)
+
+    def _idle(self, wait: float) -> "list | tuple":
+        """The one place the loop waits: for socket readiness, or with no
+        socket registered for the next timer."""
+        if self.selector.get_map():
+            return self.selector.select(wait)
+        if wait > 0:
+            time.sleep(wait)
+        return ()
+
+    #: One `loop_busy` / `loop_idle` sample a slice of this many seconds.
+    OBS_SLICE_S = 0.1
+
+    def _obs_turn(self, busy_from: float, idle_from: float,
+                  idle_to: "float | None") -> None:
+        """Account one pump turn, busy [busy_from, idle_from) then idle
+        [idle_from, idle_to), and flush both sums as one histogram sample
+        each once a slice is over (or at the pump's end: idle_to None).
+        Not sampled 1-in-N: the SUMS of the two stages are the process's
+        busy share, their p95 how full its worst slices are."""
+        acc = self._obs_acc
+        acc[0] += idle_from - busy_from
+        if idle_to is not None:
+            acc[1] += idle_to - idle_from
+            if acc[0] + acc[1] < self.OBS_SLICE_S:
+                return
+        sink = span_sink(self)
+        if sink is not None:
+            role = self.role or "client"
+            sink.record_stage("loop_busy:" + role, acc[0])
+            sink.record_stage("loop_idle:" + role, acc[1])
+        acc[0] = acc[1] = 0.0
 
 
 class _Conn:
@@ -446,6 +503,8 @@ class NetTransport:
                 f"{type(obj).__name__} and no explicit allowlist given"
             )
         self._services[name] = (obj, allow)
+        if name != "admin" and self.loop.role is None:
+            self.loop.role = name  # RealLoop.role: a loop nobody named
 
     def unserve(self, name: str) -> None:
         """Withdraw a service: later calls fail with "no service" (1500) —
@@ -571,7 +630,15 @@ class NetTransport:
             # Kwargs ride as a trailing element; peers without them (the C
             # client) send the 5-element form, which _dispatch also accepts.
             msg = (_REQ, msg_id, service, method, list(args))
-            frame = wire.dumps(msg + (kwargs,) if kwargs else msg)
+            if span_sink(self.loop) is not None:
+                # Stage rpc_inbound (obs/span.py): while THIS process
+                # traces, the frame carries its send stamp on the span
+                # clock as one more trailing element; a receiver that
+                # does not know it ignores it (_on_frame reads rest[:4]).
+                msg += (kwargs or None, span_now(self.loop))
+            elif kwargs:
+                msg += (kwargs,)
+            frame = wire.dumps(msg)
             conn = self._connect(addr)
             conn.pending[msg_id] = p
             key = id(p.future)
@@ -619,6 +686,14 @@ class NetTransport:
                 kind, msg_id, *rest = wire.loads(frame)
             if kind == _REQ:
                 sink.stage_tick("rpc_decode", timed.seconds)
+                if len(rest) > 4 and rest[4] is not None:
+                    # Stage rpc_inbound: the sender's stamp to here, decode
+                    # included — its flush, the wire, and the socket
+                    # buffer while this thread was busy with something
+                    # else. One host, one clock; clamped against skew.
+                    sink.stage_tick(
+                        "rpc_inbound:" + rest[0] + "." + rest[1],
+                        max(0.0, span_now(self.loop) - rest[4]))
         if kind == _REQ:
             service, method, args = rest[:3]
             kwargs = rest[3] if len(rest) > 3 else None

@@ -24,6 +24,7 @@ from foundationdb_tpu.core.types import (
 )
 from foundationdb_tpu.obs.span import (
     ENGINE_STAGES,
+    span_now,
     span_sink,
     stage_clock,
     stage_timer,
@@ -258,7 +259,7 @@ class Resolver:
         self._pending[version] = reply
         self.sched.enqueue(
             _QueuedBatch(version, txns, oldest_version, reply,
-                         t_enq=self.loop.now)
+                         t_enq=span_now(self.loop))
         )
         w = self._waiters.pop(version, None)
         if w is not None:
@@ -365,7 +366,7 @@ class Resolver:
             "oldest": oldest_version,
             "rows": rows,
             "fail_safe": fail_safe,
-            "t_edges_done": self.loop.now,
+            "t_edges_done": span_now(self.loop),
         }
         reply = payload.to_wire()
         self._cache_edge_reply(version, reply)
@@ -419,7 +420,7 @@ class Resolver:
             # legs — the global protocol's comms cost, attributed under
             # the resolver's device_dispatch umbrella (SUB_STAGES).
             sink.stage_tick("wave_exchange",
-                            self.loop.now - pend["t_edges_done"],
+                            span_now(self.loop) - pend["t_edges_done"],
                             n=max(1, len(txns)), version=version)
         if self.dispatch_cost_s:
             await self.loop.sleep(self.dispatch_cost_s)
@@ -553,23 +554,20 @@ class Resolver:
         misses one)."""
         sink = span_sink(self.loop)
         if sink is not None:
-            # Sub-stage attribution (obs subsystem), interior of the
-            # proxy-measured resolve_wait: chain admission -> dispatch
-            # start per batch, txn-weighted so the histograms reconcile
-            # against per-txn populations.
-            t0 = self.loop.now
-            # The annotation marks the dispatch start on a profiler's
-            # timeline (the queueing itself is in the past by now).
+            t_group = span_now(self.loop)
+            # The annotation marks the group's dispatch start on a
+            # profiler's timeline (the queueing is in the past by now).
             with stage_timer(None, "coalesce_queue", group[0].version):
-                for entry in group:
-                    sink.stage_tick("coalesce_queue", t0 - entry.t_enq,
-                                    n=max(1, len(entry.txns)))
+                pass
         if self.dispatch_cost_s:
             # Modeled device execution time for this window (sim-only;
             # see __init__) — spent BEFORE the verdicts resolve, like the
             # real kernel's dispatch wall time.
             await self.loop.sleep(self.dispatch_cost_s * len(group))
         clock = stage_clock(self.loop)
+        # The modeled cost just slept is device_dispatch's, not the
+        # queue's: coalesce_queue reads the clock less these seconds.
+        modeled_s = clock() - t_group if sink is not None else 0.0
         for entry in group:
             entry.rows = self._txn_rows(entry.txns)
         if getattr(self.cs, "spec", False):
@@ -578,9 +576,14 @@ class Resolver:
             # against N's optimistic paint while N's verdicts are still
             # unconfirmed — phase A below dispatches the whole group,
             # phase B reconciles in version order.
+            if sink is not None:
+                for entry in group:
+                    self._tick_queue(sink, entry, clock() - modeled_s)
             self._dispatch_group_spec(group, sink, clock)
             return
         for entry in group:
+            if sink is not None:
+                self._tick_queue(sink, entry, clock() - modeled_s)
             self._dispatch_entry(entry, sink, clock)
         if self._inflight is None:
             return
@@ -756,6 +759,18 @@ class Resolver:
         the engine (the two-phase wave exchange)."""
         if self._inflight is not None:
             self._drain("no_successor")
+
+    @staticmethod
+    def _tick_queue(sink, entry: _QueuedBatch, now: float) -> None:
+        """Stage coalesce_queue (obs/span.py), interior of the
+        proxy-measured resolve_wait: chain admission -> THIS batch's
+        dispatch start, txn-weighted so the histograms reconcile against
+        per-txn populations. That is the wait for the group and then the
+        wait behind the group's earlier batches, which are dispatched one
+        after another. (A sim loop's clock stands through synchronous
+        work: there it is admission -> group start.)"""
+        sink.stage_tick("coalesce_queue", now - entry.t_enq,
+                        n=max(1, len(entry.txns)))
 
     def _finish(self, fl: _InFlight) -> None:
         """The COLLECT half of one batch: the blocking read of its

@@ -29,11 +29,15 @@ from foundationdb_tpu.core.errors import (
     WrongShardServer,
 )
 from foundationdb_tpu.core.mutations import ATOMIC_OPS, Mutation, MutationType, apply_atomic
+from foundationdb_tpu.obs.span import span_now, span_sink
 from foundationdb_tpu.reads.coalescer import ReadCoalescer
 from foundationdb_tpu.reads.read_set import TPUReadSet
 from foundationdb_tpu.reads.watches import WatchIndex
 from foundationdb_tpu.runtime.flow import BrokenPromise, Loop, Promise, any_of, rpc
-from foundationdb_tpu.runtime.sequencer import MVCC_WINDOW_VERSIONS
+from foundationdb_tpu.runtime.sequencer import (
+    MVCC_WINDOW_VERSIONS,
+    VERSIONS_PER_SECOND,
+)
 from foundationdb_tpu.runtime.tlog import TLog
 from foundationdb_tpu.runtime.trace import trace
 
@@ -298,6 +302,18 @@ class StorageServer:
                 if advance_to > self._version:
                     self._advance(advance_to)  # idle-tag versions
                 if self._version > before:
+                    sink = span_sink(self.loop)
+                    if sink is not None:
+                        # Stage storage_version_lag (obs/span.py): how far
+                        # behind the tlog this replica was when the peek
+                        # came back, as seconds of versions — what
+                        # metrics()["version_lag"] polls for the
+                        # ratekeeper, as a distribution.
+                        sink.stage_tick(
+                            "storage_version_lag",
+                            max(0, end_version - before)
+                            / VERSIONS_PER_SECOND,
+                            version=self._version)
                     # Pop on every advance (not just on mutations) so cold
                     # tags still raise the tlog's trim floor — without this a
                     # salvage-seeded tag that never sees new writes pins the
@@ -408,8 +424,6 @@ class StorageServer:
             return
         pend, self._watch_pending = self._watch_pending, []
         from time import perf_counter
-
-        from foundationdb_tpu.obs.span import span_sink
 
         sink = span_sink(self.loop)
         t0 = perf_counter() if sink is not None else 0.0
@@ -832,18 +846,45 @@ class StorageServer:
     async def _check_version(self, version: int) -> None:
         if version < self.oldest_version:
             raise TransactionTooOld(f"read at {version} < floor {self.oldest_version}")
-        if version > self._version:
+        # Stage storage_version_wait (obs/span.py): 0 for a read at or
+        # under the applied version, recorded so the mean is over ALL
+        # reads; else the park until the pull loop passes it.
+        sink = span_sink(self.loop)
+        if version <= self._version:
+            if sink is not None:
+                sink.stage_tick("storage_version_wait", 0.0, version=version)
+        else:
             # Wait briefly for the pull loop to catch up (the reference's
             # waitForVersion); past the timeout the client sees
             # FutureVersion and retries at a fresh GRV.
             p = Promise()
             entry = (version, p)
             self._version_waiters.append(entry)
+            t0 = span_now(self.loop) if sink is not None else 0.0
             await any_of([p.future, self.loop.sleep(self.VERSION_WAIT_TIMEOUT)])
+            if sink is not None:
+                sink.stage_tick("storage_version_wait",
+                                span_now(self.loop) - t0, version=version)
             if version > self._version:
                 if entry in self._version_waiters:  # lost the race: un-park
                     self._version_waiters.remove(entry)
                 raise FutureVersion(f"read at {version} > applied {self._version}")
+
+    def _lookup_begin(self) -> "float | None":
+        """Stage storage_lookup's first stamp (None untraced)."""
+        return span_now(self.loop) if span_sink(self.loop) is not None \
+            else None
+
+    def _lookup_end(self, t0: "float | None", version: int,
+                    n: int = 1) -> None:
+        """Stage storage_lookup (obs/span.py): a served read from after
+        its version check to its return, weighted by the keys it read
+        (holds read_coalesce / read_pack / read_dispatch where the read
+        rides the coalescer). A read that raises records nothing."""
+        sink = span_sink(self.loop)
+        if sink is not None and t0 is not None:
+            sink.stage_tick("storage_lookup", span_now(self.loop) - t0,
+                            n=n, version=version)
 
     def _check_read_authz(self, begin: bytes, end: bytes,
                           token: str | None) -> None:
@@ -859,6 +900,7 @@ class StorageServer:
                   token: str | None = None) -> bytes | None:
         self._check_read_authz(key, key + b"\x00", token)
         await self._check_version(version)
+        t0 = self._lookup_begin()
         self._check_serving(key, key + b"\x00", version)
         if self._batch_scalar_reads:
             val = (await self._reads.submit_points([key], version))[0]
@@ -868,8 +910,10 @@ class StorageServer:
             # instead of wrong_shard_server (the seed's scalar path had
             # no await between this check and map.at).
             self._check_serving(key, key + b"\x00", version)
-            return val
-        return self.map.at(key, version)
+        else:
+            val = self.map.at(key, version)
+        self._lookup_end(t0, version)
+        return val
 
     @rpc
     async def get_multi(self, keys: list[bytes], version: int,
@@ -881,6 +925,7 @@ class StorageServer:
         for k in keys:
             self._check_read_authz(k, k + b"\x00", token)
         await self._check_version(version)
+        t0 = self._lookup_begin()
         for k in keys:
             self._check_serving(k, k + b"\x00", version)
         if not keys:
@@ -891,6 +936,7 @@ class StorageServer:
         # absent.
         for k in keys:
             self._check_serving(k, k + b"\x00", version)
+        self._lookup_end(t0, version, len(keys))
         return vals
 
     @rpc
@@ -946,12 +992,14 @@ class StorageServer:
             version = self._version
         else:
             await self._check_version(version)
+        t0 = self._lookup_begin()
         self._check_serving(begin, end, version)
         if self._batch_scalar_reads:
             rows = await self._reads.submit_range(
                 begin, end, limit, reverse, version)
             # Re-validate post-await: see get().
             self._check_serving(begin, end, version)
+            self._lookup_end(t0, version)
             return rows
         keys = self.map.range_keys(begin, end)
         if reverse:
@@ -963,6 +1011,7 @@ class StorageServer:
                 out.append((k, v))
                 if len(out) >= limit:
                     break
+        self._lookup_end(t0, version)
         return out
 
     @rpc

@@ -15,7 +15,7 @@ import bisect
 from dataclasses import dataclass
 
 from foundationdb_tpu.core.mutations import Mutation
-from foundationdb_tpu.obs.span import span_sink
+from foundationdb_tpu.obs.span import span_now, span_sink
 from foundationdb_tpu.runtime.flow import Loop, Promise, rpc
 
 
@@ -266,7 +266,7 @@ class TLog:
         if self.locked:
             raise TLogLocked(f"push v{version} after lock at v{self._version}")
         sink = span_sink(self.loop)
-        t_fsync = self.loop.now if sink is not None else 0.0
+        t_fsync = span_now(self.loop) if sink is not None else 0.0
         await self.loop.sleep(self.FSYNC_SECONDS)
         if self.locked:  # lock won the race while we were "fsyncing"
             raise TLogLocked(f"push v{version} after lock at v{self._version}")
@@ -296,7 +296,7 @@ class TLog:
             # Sub-stage attribution (obs subsystem), interior of the
             # proxy-measured tlog_durable: chain-ordered append ->
             # durable (fsync sleep + disk write), per push.
-            sink.stage_tick("tlog_fsync", self.loop.now - t_fsync)
+            sink.stage_tick("tlog_fsync", span_now(self.loop) - t_fsync)
         w = self._waiters.pop(version, None)
         if w is not None:
             w.send(None)

@@ -2011,6 +2011,7 @@ def main(argv: list[str] | None = None) -> None:
         os.makedirs(args.data_dir, exist_ok=True)
 
     loop = RealLoop()
+    loop.role = args.role  # names this process's loop_busy / loop_idle
     from foundationdb_tpu.runtime.trace import Tracer
 
     tracer = Tracer(loop, trace_dir=args.trace_dir,

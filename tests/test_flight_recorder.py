@@ -14,7 +14,6 @@ Covers the obs timeline plane end to end:
   baseline-poisoning guard,
 - the doctor: deterministic reports over a synthetic ring (dominant
   stage, co-occurring annotations, per-fault attribution),
-- --bench-history: valid:false records REFUSED as ratio endpoints,
 - status JSON ``workload.slo`` honesty flags, sim-cluster arming.
 """
 
@@ -568,76 +567,6 @@ class TestDoctor:
         assert f["healed"] is False
         assert f["window"][1] == pytest.approx(f["t"] + 20.0)
         assert f["attributed"] is True  # recovery@12.4 inside the grace
-
-
-# ---------------------------------------------------------------------------
-# --bench-history (satellite: the perf-trajectory table)
-# ---------------------------------------------------------------------------
-
-
-class TestBenchHistory:
-    def _write(self, d, name, rec):
-        (d / name).write_text(
-            rec if isinstance(rec, str) else json.dumps(rec))
-
-    def test_orders_rounds_and_refuses_invalid_ratio_endpoints(
-            self, tmp_path):
-        from foundationdb_tpu.obs.history import bench_history, format_table
-
-        m = "resolved_txns_per_sec_per_chip"
-        self._write(tmp_path, "BENCH_r01.json",
-                    {"metric": m, "value": 100.0, "valid": True})
-        self._write(tmp_path, "BENCH_r02.json",
-                    {"metric": m, "value": 50.0, "valid": False,
-                     "invalid_reasons": ["cpu_fallback"]})
-        self._write(tmp_path, "BENCH_r03.json",
-                    {"metric": m, "value": 70.0, "valid": True})
-        self._write(tmp_path, "BENCH_r04.json", "not json at all")
-        rec = bench_history(root=str(tmp_path))
-        rows = rec["rows"]
-        assert [r["artifact"] for r in rows] == [
-            "BENCH_r01.json", "BENCH_r02.json", "BENCH_r03.json",
-            "BENCH_r04.json"]
-        assert [r["round"] for r in rows] == [1, 2, 3, 4]
-        assert rows[3]["parsed"] is False
-        # THE satellite contract: the ratio skips the valid:false round —
-        # r01 -> r03 (0.7, drifted), never r01 -> r02 or r02 -> r03.
-        assert len(rec["drift"]) == 1
-        d = rec["drift"][0]
-        assert (d["from"], d["to"]) == ("BENCH_r01.json", "BENCH_r03.json")
-        assert d["ratio"] == pytest.approx(0.7)
-        assert d["drifted"] is True
-        refused = rec["refused_for_ratio"]
-        assert [r["artifact"] for r in refused] == ["BENCH_r02.json"]
-        table = format_table(rec)
-        assert "DRIFT" in table and "INVALID" in table and "UNPARSED" in table
-
-    def test_unwraps_autopilot_capture_and_ab_artifacts(self, tmp_path):
-        from foundationdb_tpu.obs.history import bench_history
-
-        self._write(tmp_path, "OBS_AB.json",
-                    {"cmd": "x", "rc": 0, "parsed": {
-                        "metric": "obs_sampling_overhead_ab",
-                        "overhead_frac": 0.013, "valid": True}})
-        rec = bench_history(root=str(tmp_path))
-        row = rec["rows"][0]
-        assert row["metric"] == "obs_sampling_overhead_ab"
-        assert row["value"] == pytest.approx(0.013)
-        assert row["valid"] is True
-
-    def test_own_output_artifact_is_never_ingested(self, tmp_path):
-        """This tool's record may be kept as
-        BENCH_HISTORY_*.json in the same root — the next run must not
-        fold it in as a self-referential bench row."""
-        from foundationdb_tpu.obs.history import bench_history
-
-        self._write(tmp_path, "BENCH_r01.json",
-                    {"metric": "resolved_txns_per_sec_per_chip",
-                     "value": 100.0, "valid": True})
-        self._write(tmp_path, "BENCH_HISTORY_r05.json",
-                    bench_history(root=str(tmp_path)))
-        rec = bench_history(root=str(tmp_path))
-        assert [r["artifact"] for r in rec["rows"]] == ["BENCH_r01.json"]
 
 
 # ---------------------------------------------------------------------------

@@ -468,3 +468,283 @@ def test_latency_probe_always_samples_and_restores_sink():
     assert report["unattributed_frac"] <= 0.10
     assert "tlog_durable" in report["stages"]
     assert not hasattr(c.loop, "span_sink")  # probe sink removed
+
+
+# -- the span clock, the wait in front of a loop, a process's busy share ------
+# (PR 38: obs/span.py span_now, rpc_inbound:<service>.<method>,
+# loop_busy:<role> / loop_idle:<role>, READ_PATH_STAGES)
+
+
+def _conflict_txns():
+    from foundationdb_tpu.core.types import KeyRange, TxnConflictInfo
+
+    return [TxnConflictInfo(read_version=0,
+                            read_ranges=[KeyRange(b"a", b"b")],
+                            write_ranges=[KeyRange(b"a", b"b")])]
+
+
+def test_a_wait_inside_one_loop_turn_is_seen_by_the_span_clock():
+    """Two batches admitted and dispatched in ONE pump turn, with 30 ms of
+    somebody else's synchronous work before the group's dispatch and an
+    engine that takes 20 ms a batch: `loop.now` stands still through the
+    turn (the parent read coalesce_queue 0.0 here, in every line of the
+    ledger); the span clock does not, and the second batch's wait holds
+    the first one's bracket."""
+    import time
+
+    from foundationdb_tpu.runtime.net import RealLoop
+    from foundationdb_tpu.runtime.resolver import Resolver
+    from foundationdb_tpu.sim.oracle import OracleConflictSet
+
+    class SlowEngine(OracleConflictSet):
+        def resolve(self, *args, **kwargs):
+            time.sleep(0.02)
+            return super().resolve(*args, **kwargs)
+
+    loop = RealLoop()
+    sink = SpanSink(loop, sample_every=1)
+    resolver = Resolver(loop, SlowEngine())
+    loop_clock = []
+
+    async def blocker():
+        loop_clock.append(loop.now)
+        time.sleep(0.03)
+        loop_clock.append(loop.now)
+
+    async def main():
+        asked = [loop.spawn(resolver.resolve(v - 10, v, _conflict_txns()),
+                            name="ask") for v in (10, 20)]
+        loop.spawn(blocker(), name="blocker")  # runs before the dispatch
+        for a in asked:
+            await a
+        loop_clock.append(loop.now)
+
+    loop.run(main(), timeout=30)
+    assert len(set(loop_clock)) == 1  # one turn: the loop's clock stood
+    queued = sink.stage_hists["coalesce_queue"]
+    assert queued.count == 2
+    # 30 ms for the first, 30 + the first's 20 ms for the second
+    assert queued.sum_ms >= 30.0 + 50.0 and queued.max_ms >= 50.0
+
+
+#: sha256 of the PARENT commit's (a8e48d7) `span_records(seed, txns=64)`.
+#: A sim loop's span clock is its virtual `loop.now`, so every record the
+#: parent wrote is written again, byte for byte; the read path's stages
+#: are new records beside them.
+PARENT_SPAN_RECORDS = {
+    5: "b468b160b184d9480b6e86ca75c65975bc8dffd5d34e5d8697466bd2ba0c8bb8",
+    38: "10895abd60778ac792ecbf013aec46e2931106be0469250e2bbfcbb40c8ba8eb",
+}
+
+
+@pytest.mark.parametrize("seed", sorted(PARENT_SPAN_RECORDS))
+def test_sim_span_records_are_the_parents_byte_for_byte(seed):
+    import hashlib
+
+    from foundationdb_tpu.obs.span import READ_PATH_STAGES
+
+    records = json.loads(span_records(seed, txns=64))
+    new = [r for r in records if r["name"] in READ_PATH_STAGES]
+    kept = json.dumps([r for r in records if r["name"] not in
+                       READ_PATH_STAGES], sort_keys=True)
+    assert hashlib.sha256(kept.encode()).hexdigest() == \
+        PARENT_SPAN_RECORDS[seed]
+    assert {r["name"] for r in new} >= {
+        "grv_rtt", "read_rpc", "grv_sequencer_rtt", "storage_version_wait",
+        "storage_lookup"}
+    assert all(r.get("version") is not None for r in new)
+
+
+class _Echo:
+    from foundationdb_tpu.runtime.flow import rpc as _rpc
+
+    @_rpc
+    async def echo(self, x):
+        return x
+
+
+@pytest.mark.parametrize("sender_sink,receiver_sink", [
+    (True, True), (True, False), (False, True)])
+def test_rpc_inbound_needs_a_sink_at_both_ends(sender_sink, receiver_sink):
+    """Two loops in one thread, pumped by hand: the request sits in the
+    socket for 30 ms while 'the receiver's thread is busy', then the
+    receiver's loop gets its turn. The stamp rides the frame only while
+    the sender traces; an unstamped frame records nothing, and is no
+    error."""
+    import time
+
+    from foundationdb_tpu.runtime import wire
+    from foundationdb_tpu.runtime.net import NetTransport, RealLoop
+
+    c_loop, s_loop = RealLoop(), RealLoop()
+    if sender_sink:
+        SpanSink(c_loop, sample_every=1)
+    s_sink = SpanSink(s_loop, sample_every=1) if receiver_sink else None
+    server, client = NetTransport(s_loop), NetTransport(c_loop)
+    server.serve("echo", _Echo())
+    frames = []
+    on_frame = server._on_frame
+    server._on_frame = lambda conn, frame: (
+        frames.append(wire.loads(frame)), on_frame(conn, frame))[1]
+    try:
+        fut = client.endpoint(server.addr, "echo").echo(7)
+        c_loop.run_until(c_loop.sleep(0.02), timeout=5)  # connect, flush
+        time.sleep(0.03)  # the receiver is busy with something else
+        for _ in range(200):
+            s_loop.run_until(s_loop.sleep(0.002), timeout=5)
+            c_loop.run_until(c_loop.sleep(0.002), timeout=5)
+            if fut.done():
+                break
+        assert fut.result() == 7
+    finally:
+        server.close()
+        client.close()
+    (request,) = frames
+    # no new wire element when the sender has no sink
+    assert len(request) == (7 if sender_sink else 5)
+    if s_sink is None:
+        return
+    assert s_sink.stage_hists["rpc_decode"].count == 1
+    if sender_sink:
+        inbound = s_sink.stage_hists["rpc_inbound:echo.echo"]
+        assert inbound.count == 1 and inbound.sum_ms >= 30.0
+        assert s_loop.role == "echo"  # the first service it serves
+    else:
+        assert not [s for s in s_sink.stage_hists
+                    if s.startswith("rpc_inbound")]
+
+
+def test_loop_busy_and_idle_sum_to_the_wall_time_of_a_run():
+    import time
+
+    from foundationdb_tpu.runtime.net import RealLoop
+
+    loop = RealLoop()
+    sink = SpanSink(loop, sample_every=64)  # these two are never sampled
+
+    async def main():
+        for _ in range(10):
+            time.sleep(0.02)  # synchronous work: busy
+            await loop.sleep(0.05)  # a timer set on the turn's start: idle
+
+    t0 = time.perf_counter()
+    loop.run(main(), timeout=30)
+    wall_ms = (time.perf_counter() - t0) * 1e3
+    busy = sink.stage_hists["loop_busy:client"]  # it serves nothing
+    idle = sink.stage_hists["loop_idle:client"]
+    assert busy.count == idle.count >= 3  # one sample each a >= 100 ms slice
+    assert busy.sum_ms + idle.sum_ms == pytest.approx(wall_ms, rel=0.05)
+    assert busy.sum_ms >= 200.0 * 0.95 and idle.sum_ms >= 200.0
+
+
+def test_a_loop_without_a_sink_reads_no_clock_in_the_pump(monkeypatch):
+    import time
+    import types
+
+    from foundationdb_tpu.runtime import net
+
+    def no_clock():
+        raise AssertionError("the pump read the span clock with no sink")
+
+    monkeypatch.setattr(net, "time", types.SimpleNamespace(
+        monotonic=time.monotonic, sleep=time.sleep, time=time.time,
+        perf_counter=no_clock))
+    loop = net.RealLoop()
+
+    async def main():
+        for _ in range(3):
+            await loop.sleep(0.005)
+        return "done"
+
+    assert loop.run(main(), timeout=30) == "done"
+    assert not hasattr(loop, "span_sink")
+
+
+@pytest.mark.parametrize("named,services,role", [
+    ("storage", ["admin", "storage", "worker"], "storage"),  # server.py
+    (None, ["resolver", "admin"], "resolver"),  # the share launcher's order
+    (None, ["admin", "tlog"], "tlog"),
+    (None, [], None),  # a client: the pump says `client`
+])
+def test_a_loop_is_named_for_its_process(named, services, role):
+    from foundationdb_tpu.runtime.net import NetTransport, RealLoop
+
+    loop = RealLoop()
+    loop.role = named
+    t = NetTransport(loop)
+    try:
+        for name in services:
+            t.serve(name, _Echo())
+    finally:
+        t.close()
+    assert loop.role == role
+
+
+@pytest.mark.parametrize("ahead", [False, True])
+def test_storage_version_wait_is_zero_under_the_applied_version_and_the_park_above(
+        ahead):
+    from foundationdb_tpu.runtime.storage import StorageServer
+
+    loop = Loop(seed=3)
+    sink = SpanSink(loop, sample_every=1)
+    ss = StorageServer(loop, tag=0, tlog_ep=None)
+    ss._advance(100)
+
+    async def main():
+        if not ahead:
+            return await ss.get(b"k", 100)
+        read = loop.spawn(ss.get(b"k", 200), name="read")
+        await loop.sleep(0.25)  # the pull loop is a quarter second behind
+        ss._advance(200)
+        return await read
+
+    assert loop.run(main(), timeout=60) is None
+    wait = sink.stage_hists["storage_version_wait"]
+    assert wait.count == 1
+    assert wait.sum_ms == pytest.approx(250.0 if ahead else 0.0)
+    assert sink.stage_hists["storage_lookup"].count == 1
+    by_name = {s["name"]: s for s in sink.spans}
+    assert by_name["storage_version_wait"]["version"] == \
+        by_name["storage_lookup"]["version"] == (200 if ahead else 100)
+
+
+def test_a_read_only_sampled_transaction_leaves_its_grv_and_its_read():
+    from foundationdb_tpu.client.ryw import open_database
+
+    c = _new_cluster(31, obs=True, sample_every=1)
+    db = open_database(c)
+
+    async def read_only():
+        tr = db.transaction()
+        assert await tr.get(b"obs/none") is None
+        return await tr.commit()  # read-only: nothing goes to a proxy
+
+    version = c.loop.run(read_only(), timeout=600)
+    sink = c.loop.span_sink
+    # (the sim's one sink also holds the timekeeper's transactions)
+    mine = [s for s in sink.spans if s["process"] == "<main>"]
+    assert len({s["tid"] for s in mine}) == 1 and mine[0]["tid"] is not None
+    # no grv_wait: that one is the commit identity's, and nothing committed
+    assert sorted((s["name"], s["version"]) for s in mine) == [
+        ("grv_rtt", version), ("read_rpc", version)]
+    assert sink.stage_hists["grv_rtt"].count >= 1
+    assert sink.stage_hists["read_rpc"].count == 1
+    # the GRV proxy's and the storage's stages of the same read carry it
+    for stage in ("grv_sequencer_rtt", "storage_version_wait",
+                  "storage_lookup"):
+        assert version in {s["version"] for s in sink.spans
+                           if s["name"] == stage}, stage
+
+
+def test_the_scrape_keeps_process_and_endpoint_stages_snake_case():
+    from foundationdb_tpu.obs.registry import add_span_sink
+
+    sink = SpanSink(Loop(seed=1), sample_every=1)
+    sink.record_stage("loop_busy:proxy", 0.07)
+    sink.record_stage("rpc_inbound:resolver.resolve", 0.03)
+    reg = MetricsRegistry()
+    add_span_sink(reg, sink)
+    assert reg.audit() == []
+    snap = reg.snapshot()
+    assert snap["obs.stage_sum_ms.loop_busy_proxy"] == pytest.approx(70.0)
+    assert snap["obs.stage_count.rpc_inbound_resolver_resolve"] == 1
