@@ -40,6 +40,9 @@ DOCUMENTED_COUNTERS = (
     "commit_proxy.txns_committed",
     "commit_proxy.txns_conflicted",
     "commit_proxy.conflict_losses",
+    # The known-committed bound told to the tlogs at the acknowledgement,
+    # and on each tlog which path moved its bound first (below).
+    "commit_proxy.commit_notifies_sent",
     "resolver.batches_resolved",
     "resolver.txns_resolved",
     "resolver.txns_conflicted",
@@ -79,6 +82,8 @@ DOCUMENTED_COUNTERS = (
     "resolver.engine.demotion_bytes_per_dispatch",
     "tlog.queue_bytes",
     "tlog.queue_entries",
+    "tlog.kc_advances_by_notify",
+    "tlog.kc_advances_by_push",
     "storage.version_lag",
     # Read plane + watch registry (foundationdb_tpu/reads/): exported by
     # every storage server, zeros while idle, so a healthy scrape always
@@ -92,6 +97,10 @@ DOCUMENTED_COUNTERS = (
     "storage.reads.occupancy",
     "storage.reads.per_dispatch",
     "ratekeeper.tps_limit",
+    # The budget's use in what it gates (read versions granted a second)
+    # and the times the healthy branch raised the ceiling for it.
+    "ratekeeper.grv_tps",
+    "ratekeeper.ceiling_probes",
     # Recovery MTTR counters (deployed chaos subsystem): exported by BOTH
     # controllers — runtime/cluster.py (sim) and server.py
     # DeployedController — under identical names, zeros before the first

@@ -69,7 +69,8 @@ class RepairConfig:
     # Decayed loss score at/above which immediate retry is considered
     # futile and a jittered backoff is applied first.
     hot_score_threshold: float = 6.0
-    # Backoff = min(cap, base * score) * jitter(0.5..1.5).
+    # Backoff = min(cap, base * score, the failed commit's own round
+    # trip) * jitter(0.5..1.5).
     hot_backoff_base: float = 0.002
     hot_backoff_cap: float = 0.25
     # Optional re-execution hook: ``await hook(tr, conflicting)`` runs
@@ -218,8 +219,10 @@ async def run_repairable(db, fn, max_retries: int = 50,
     tr.repair_stats = stats
     repair_round = 0
     for _ in range(max_retries):
+        asked = None
         try:
             result = await fn(tr)
+            asked = db.loop.now
             await tr.commit()
             stats.commits += 1
             if repair_round:
@@ -228,7 +231,9 @@ async def run_repairable(db, fn, max_retries: int = 50,
         except NotCommitted as e:
             repaired = False
             if repair_round < config.max_repair_attempts:
-                repaired = await _try_repair(tr, e, config, stats)
+                repaired = await _try_repair(
+                    tr, e, config, stats,
+                    turn=None if asked is None else db.loop.now - asked)
             if repaired:
                 repair_round += 1
                 stats.repair_rounds += 1
@@ -248,8 +253,11 @@ async def run_repairable(db, fn, max_retries: int = 50,
 
 
 async def _try_repair(tr: RepairableTransaction, e: NotCommitted,
-                      config: RepairConfig, stats: RepairStats) -> bool:
-    """Attempt to enter a repair round for this conflict; False = decline."""
+                      config: RepairConfig, stats: RepairStats,
+                      turn: "float | None" = None) -> bool:
+    """Attempt to enter a repair round for this conflict; False = decline.
+    `turn`: what the failed commit took, one turn of the commit pipeline
+    as this client sees it."""
     ranges = e.conflicting_ranges
     fail_cv = e.fail_version
     if not ranges or fail_cv is None or fail_cv <= 0:
@@ -257,11 +265,17 @@ async def _try_repair(tr: RepairableTransaction, e: NotCommitted,
     conflicting = [(bytes(b), bytes(end)) for b, end in ranges]
     # Contention-aware backoff: when the proxy's sketch says these ranges
     # are losing constantly, an immediate resubmit is near-certain to
-    # lose again — sleep a jittered, score-scaled delay first.
+    # lose again — sleep a jittered, score-scaled delay first. Never
+    # much longer than the failed commit itself took: by then the batch
+    # the contenders rode has been decided, and more sleep only idles
+    # (it hid behind the reads' wait for the next push until PR 39;
+    # without the bound the loop then LOSES to a full restart).
     odds = max((s for _b, _e2, s in (e.hot_ranges or [])), default=0.0)
     if odds >= config.hot_score_threshold:
         stats.hot_backoffs += 1
         delay = min(config.hot_backoff_cap, config.hot_backoff_base * odds)
+        if turn is not None:
+            delay = min(delay, turn)
         await tr.db.loop.sleep(delay * (0.5 + tr.db.loop.rng.random()))
     tr.begin_repair(fail_cv - 1, conflicting)
     if config.reexecute is not None:

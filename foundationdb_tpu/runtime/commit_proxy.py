@@ -178,6 +178,11 @@ class CommitProxy:
         # their commit version): quiesce() must see them or a batch could
         # vanish from both sets mid-await and slip past a DR switchover.
         self._admitting = 0
+        # The self-clocked batch (run()): what this proxy's last batch
+        # took from its formation to its verdicts, and the mutations its
+        # queue holds.
+        self._resolve_s = 0.0
+        self._queued_mutations = 0
         self.txns_committed = 0
         self.txns_conflicted = 0
         # Wave commit (reorder-don't-abort resolvers): with ONE resolver
@@ -198,6 +203,8 @@ class CommitProxy:
         # piggybacked on pushes so storage can bound its GC floor
         # (reference: knownCommittedVersion).
         self._known_committed = 0
+        # Bounds told to the tlogs at the acknowledgement (_notify_committed)
+        self.commit_notifies_sent = 0
 
     # -- client face ----------------------------------------------------------
 
@@ -211,6 +218,7 @@ class CommitProxy:
             req._obs_arrival = span_now(self.loop)
         p = Promise()
         self._queue.push((req, p), getattr(req, "priority", "default"))
+        self._queued_mutations += len(req.mutations)
         return await p.future
 
     @rpc
@@ -240,6 +248,7 @@ class CommitProxy:
         return {
             "txns_committed": self.txns_committed,
             "txns_conflicted": self.txns_conflicted,
+            "commit_notifies_sent": getattr(self, "commit_notifies_sent", 0),
             # Batches resolved through the global wave edge exchange
             # (multi-resolver wave commit; 0 on every other config).
             # getattr: metric-harness stubs build proxies piecemeal.
@@ -290,6 +299,34 @@ class CommitProxy:
             or len(self._shaped) >= self.SHAPE_MAX
         )
 
+    def _held(self, since_last: float) -> bool:
+        """Self-clocked batching: is the queue kept for the next tick?
+
+        A resolver takes one batch at a time and a batch costs it much
+        the same whatever it holds, so a batch every BATCH_INTERVAL
+        whatever is outstanding only queues near-empty batches behind
+        each other, and a commit waits for all of them. The interval is
+        what the LAST batch took from its formation to its verdicts
+        (reference: the proxy's batch interval follows its commit
+        latency): commits that arrive meanwhile ride ONE batch. The wait
+        is for company, so it shrinks with what the queued commits bring
+        themselves: a queue averaging w mutations a commit is kept 1/w of
+        the interval, and a bulk transaction (a load's hundred sets)
+        leaves at the plain cadence. Never kept: a system-priority commit
+        (it leaves on the next tick, as the lanes promise), a shaped lane
+        that is due, a queue that is a full batch already. A batch slower
+        than its predecessor does not hold the next one back (a stalled
+        resolver's backlog shows in ITS queue, where the ratekeeper
+        looks). Push and reply are not waited for: the next batch
+        resolves while the last is made durable."""
+        n = len(self._queue)
+        # (max: a commit of conflict ranges alone still counts as one)
+        work = max(self._queued_mutations, n)
+        return (0 < n < self.MAX_BATCH
+                and since_last * work < self._resolve_s * n
+                and not self._queue.depths()["system"]
+                and not self._shape_flush_due())
+
     async def run(self) -> None:
         last_batch = self.loop.now
         if self._admission_on():
@@ -297,6 +334,8 @@ class CommitProxy:
                             name="commit_proxy.admission_poller")
         while True:
             await self.loop.sleep(self.BATCH_INTERVAL)
+            if self._held(self.loop.now - last_batch):
+                continue
             if not len(self._queue) and not self._shape_flush_due():
                 if self.loop.now - last_batch < self.IDLE_BATCH_INTERVAL:
                     continue
@@ -314,6 +353,8 @@ class CommitProxy:
                 # batch (with aging) — a system txn is never queued behind
                 # more than the window already forming.
                 batch = self._queue.pop(max_batch)
+                self._queued_mutations -= sum(
+                    len(req.mutations) for req, _p in batch)
             if batch and span_sink(self.loop) is not None:
                 # Stage stamp: batch formation popped these requests NOW.
                 # Shaped requests keep their FIRST pop (the admission
@@ -378,7 +419,7 @@ class CommitProxy:
             # have run yet when quiesce() samples).
             self._inflight[version] = batch
             self.loop.spawn(
-                self._process(batch, prev_version, version),
+                self._process(batch, prev_version, version, last_batch),
                 name=f"commit_batch@{version}",
             )
 
@@ -505,13 +546,15 @@ class CommitProxy:
         batch: list[tuple[CommitRequest, Promise]],
         prev_version: int,
         version: int,
+        formed_at: float,
     ) -> None:
         watchdog = self.loop.spawn(
             self._wedge_watchdog(version), name=f"wedge_watchdog@{version}"
         )
         self._inflight[version] = batch
         try:
-            await self._process_inner(batch, prev_version, version)
+            await self._process_inner(batch, prev_version, version,
+                                      formed_at)
         finally:
             self._inflight.pop(version, None)
             watchdog.cancel()
@@ -529,6 +572,7 @@ class CommitProxy:
         for _req, p in self._queue.drain() + self._shaped:
             p.fail(ProcessKilled(reason))
         self._shaped = []
+        self._queued_mutations = 0
         for batch in self._inflight.values():
             for _req, p in batch:
                 p.fail(CommitUnknownResult(reason))
@@ -562,6 +606,7 @@ class CommitProxy:
         batch: list[tuple[CommitRequest, Promise]],
         prev_version: int,
         version: int,
+        formed_at: float,
     ) -> None:
         sink = span_sink(self.loop)
         t_version = span_now(self.loop)  # commit version in hand as of entry
@@ -570,6 +615,11 @@ class CommitProxy:
             verdicts, conflicting, fail_safe, wave = await self._resolve(
                 batch, prev_version, version
             )
+            # The batcher's clock (run()). Never past the idle cadence: a
+            # batch that sat out a resolver's stall must not make the
+            # commits that come after the stall wait it out again.
+            self._resolve_s = min(self.loop.now - formed_at,
+                                  self.IDLE_BATCH_INTERVAL)
             t_resolved = span_now(self.loop)
             tagged = self._assemble(batch, verdicts, version, wave)
             t_assembled = span_now(self.loop)
@@ -596,6 +646,11 @@ class CommitProxy:
             )
             t_pushed = span_now(self.loop)  # every tlog acked its fsync
             self._known_committed = max(self._known_committed, version)
+            # Started BEFORE the sequencer hears of `version` (a read
+            # version that names it finds the tlogs already told) and
+            # awaited by nobody: the reply to the client does not wait.
+            self.loop.spawn(self._notify_committed(self._known_committed),
+                            name=f"commit_notify@{version}")
             await self.sequencer.report_committed(version)
         except Exception:
             # Resolver/tlog unreachable or locked mid-batch: the batch's fate
@@ -698,6 +753,29 @@ class CommitProxy:
             ("proxy_total", arrival, t_reply - arrival),
         ]
         return tuple(spans)
+
+    async def _notify_committed(self, bound: int) -> None:
+        """Tell every tlog the known-committed bound NOW, at the
+        acknowledgement, not with this proxy's next push one pipeline
+        turn later (TLog.advance_known_committed): the storages may apply
+        `bound` on their next peek. Called only once EVERY tlog has
+        acknowledged the fsync of a batch at or above `bound`, so it says
+        nothing the next push would not say; a fenced or partitioned
+        proxy never gets all acknowledgements and never comes here. Best
+        effort, no retry: a lost call costs the readers the wait they had
+        before, and the next push carries the bound as ever."""
+        if self.loop.buggify("commit_proxy.lose_commit_notify"):
+            # Dropped, or late enough for later pushes to overtake it:
+            # the storages fall back on the bound the next push carries.
+            if self.loop.rng.random() < 0.5:
+                return
+            await self.loop.sleep(self.loop.rng.uniform(0, 0.05))
+        self.commit_notifies_sent += 1
+        try:
+            await all_of([t.advance_known_committed(bound, self.epoch)
+                          for t in self.tlogs])
+        except Exception:
+            pass  # locked, fenced or unreachable: the fallback stands
 
     RPC_RETRIES = 4  # worst case ~4.4s — must finish under WEDGE_TIMEOUT
 

@@ -98,6 +98,9 @@ class GrvProxy:
         self._tag_rates: dict[str, float] = {}  # quota'd tags only
         self._tag_tokens: dict[str, float] = {}
         self.grvs_served = 0
+        # Of those, the grants that spent the ratekeeper's budget (the
+        # system lane spends none): what the rate poll reports.
+        self._grvs_budgeted = 0
         self.tag_throttled = 0  # admissions deferred by a tag bucket
         # Admission-saturation deferral (see ADMISSION_DEFER_SAT).
         self._admission_sat = 0.0
@@ -175,8 +178,15 @@ class GrvProxy:
 
     async def run(self) -> None:
         self.loop.spawn(self._rate_poller(), name="grv.rate_poller")
+        last_refill = self.loop.now
         while True:
             await self.loop.sleep(self.BATCH_INTERVAL)
+            # Buckets refill by the loop's clock, not by the iteration: a
+            # turn is BATCH_INTERVAL plus the sequencer call plus the
+            # batch's own work, and a refill of one interval a turn hands
+            # the clients two thirds of the ratekeeper's budget.
+            now = self.loop.now
+            elapsed, last_refill = now - last_refill, now
             # Saturation deferral (admission subsystem): on deferred
             # intervals default/batch buckets DO NOT refill — skipping
             # only the admission pass would let the skipped interval's
@@ -189,17 +199,16 @@ class GrvProxy:
             defer_now = defer and self._defer_flip
             if self._tokens != float("inf") and not defer_now:
                 self._tokens = min(
-                    self.MAX_TOKENS, self._tokens + self._rate * self.BATCH_INTERVAL
+                    self.MAX_TOKENS, self._tokens + self._rate * elapsed
                 )
                 self._batch_tokens = min(
                     self.MAX_TOKENS,
-                    self._batch_tokens + self._batch_rate * self.BATCH_INTERVAL,
+                    self._batch_tokens + self._batch_rate * elapsed,
                 )
             for tag, rate in self._tag_rates.items():
                 self._tag_tokens[tag] = min(
                     self.MAX_TAG_TOKENS,
-                    self._tag_tokens.get(tag, 0.0)
-                    + rate * self.BATCH_INTERVAL,
+                    self._tag_tokens.get(tag, 0.0) + rate * elapsed,
                 )
             if (not self._queue and not self._batch_queue
                     and not self._system_queue):
@@ -248,6 +257,7 @@ class GrvProxy:
                                 span_now(self.loop) - t_seq, n=len(batch),
                                 version=version)
             self.grvs_served += len(batch)
+            self._grvs_budgeted += len(batch) - len(s_admitted)
             for p in batch:
                 p.send(version)
 
@@ -296,7 +306,11 @@ class GrvProxy:
             return
         while True:
             try:
-                rates = await self.ratekeeper.get_rates(self.poller_id)
+                # The poll also REPORTS what the budget is spent on, read
+                # versions granted: the ratekeeper's ceiling follows it
+                # (Ratekeeper._calibrate, grv_tps).
+                rates = await self.ratekeeper.get_rates(
+                    self.poller_id, self._grvs_budgeted)
                 # Per-proxy share when the ratekeeper leases one (older
                 # ratekeepers hand back only the cluster totals).
                 self._rate = rates.get("tps_limit_share",

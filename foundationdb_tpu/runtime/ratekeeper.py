@@ -88,6 +88,14 @@ class Ratekeeper:
         self.base_tps = self.BASE_TPS
         self.measured_tps = 0.0
         self._last_committed: int | None = None
+        # What the budget is SPENT on: read versions the GRV proxies
+        # granted a second, smoothed like measured_tps from the counts
+        # they report with their get_rates polls. Commits are a part of
+        # it only (YCSB F asks two read versions a commit, a read-only
+        # transaction one and no commit), so the healthy branch probes
+        # the ceiling on whichever of the two runs near it.
+        self.grv_tps = 0.0
+        self.ceiling_probes = 0
         self.tps_limit = self.BASE_TPS
         self.batch_tps_limit = self.BASE_TPS
         self.worst_lag = 0
@@ -125,17 +133,33 @@ class Ratekeeper:
         # generation, dead process) ages out after POLLER_TTL and its
         # share returns to the survivors.
         self._pollers: dict[str, float] = {}
+        # poller_id -> (its grvs_served at its last poll, its grant rate
+        # between its last two polls); aged out with the lease.
+        self._poller_grvs: dict[str, tuple[int, float]] = {}
 
     POLLER_TTL = 1.0
 
-    def _grv_pollers(self, poller_id: "str | None") -> int:
+    def _grv_pollers(self, poller_id: "str | None",
+                     grvs_served: "int | None" = None) -> int:
         now = self.loop.now
         if poller_id is not None:
+            if grvs_served is not None:
+                self._note_grvs(poller_id, grvs_served,
+                                now - self._pollers.get(poller_id, now))
             self._pollers[poller_id] = now
         for pid, seen in list(self._pollers.items()):
             if now - seen > self.POLLER_TTL:
                 del self._pollers[pid]
+                self._poller_grvs.pop(pid, None)
         return max(1, len(self._pollers))
+
+    def _note_grvs(self, poller_id: str, served: int, since: float) -> None:
+        """One GRV proxy's grant rate between its last two polls. A first
+        report, or a count under the last (a restarted proxy), baselines."""
+        last, rate = self._poller_grvs.get(poller_id, (served, 0.0))
+        if since > 0 and served >= last:
+            rate = (served - last) / since
+        self._poller_grvs[poller_id] = (served, rate)
 
     @rpc
     async def set_tag_quota(self, tag: str, tps: float | None) -> None:
@@ -153,6 +177,7 @@ class Ratekeeper:
         surviving proxies see the whole budget on their next get_rates
         poll instead of waiting out POLLER_TTL. Crash retirement still
         falls back to the TTL ageing path."""
+        self._poller_grvs.pop(poller_id, None)
         return self._pollers.pop(poller_id, None) is not None
 
     async def run(self) -> None:
@@ -254,6 +279,8 @@ class Ratekeeper:
         self._last_committed = committed
         a = self.EWMA_ALPHA
         self.measured_tps = (1 - a) * self.measured_tps + a * rate
+        self.grv_tps = (1 - a) * self.grv_tps + a * sum(
+            r for _n, r in self._poller_grvs.values())
         if self._scale(1.0) < 1.0 and backlog > 0:
             # Degrading under backlog: admission exceeds what the roles
             # service — converge the ceiling onto measurement. (Without
@@ -263,8 +290,9 @@ class Ratekeeper:
                 self.base_tps,
                 max(self.MIN_TPS, self.measured_tps * self.BACKOFF_MARGIN),
             )
-        elif self.measured_tps > 0.7 * self.base_tps:
+        elif max(self.measured_tps, self.grv_tps) > 0.7 * self.base_tps:
             self.base_tps = min(self.MAX_TPS, self.base_tps * self.PROBE_GAIN)
+            self.ceiling_probes += 1
 
     def _scale(self, frac: float) -> float:
         signals = [
@@ -307,15 +335,18 @@ class Ratekeeper:
         return self.tps_limit
 
     @rpc
-    async def get_rates(self, poller_id: "str | None" = None) -> dict:
+    async def get_rates(self, poller_id: "str | None" = None,
+                        grvs_served: "int | None" = None) -> dict:
         """Both lanes + the governing signal (status json reports these).
 
         `poller_id`: a GRV proxy identifying itself — counted into the
         live-poller set and handed its even SHARE of each lane budget
         (`tps_limit_share` / `batch_tps_limit_share`). The cluster-wide
         totals stay in `tps_limit`/`batch_tps_limit` for status and for
-        callers that don't identify themselves."""
-        n_pollers = self._grv_pollers(poller_id)
+        callers that don't identify themselves. `grvs_served`: that
+        proxy's count of read versions granted so far, the use of the
+        budget that `grv_tps` smooths."""
+        n_pollers = self._grv_pollers(poller_id, grvs_served)
         return {
             "tps_limit": self.tps_limit,
             "batch_tps_limit": self.batch_tps_limit,
@@ -345,4 +376,6 @@ class Ratekeeper:
             },
             "base_tps": self.base_tps,
             "measured_tps": self.measured_tps,
+            "grv_tps": self.grv_tps,
+            "ceiling_probes": self.ceiling_probes,
         }

@@ -146,6 +146,11 @@ class TLog:
         # entries are salvage — acked by construction — so they start
         # the bound.
         self.known_committed = self._last_appended
+        # Which path moved the bound first, per advance: the proxy's
+        # notification at the acknowledgement, or the bound a later push
+        # carried (metrics(); the share is how often the notify engages).
+        self.kc_advances_by_notify = 0
+        self.kc_advances_by_push = 0
 
     @staticmethod
     def committed_prefix(entries, end_version: int, known_committed: int):
@@ -287,10 +292,10 @@ class TLog:
         # proxies ALWAYS pass their known-committed bound — that is the
         # fence that keeps a partitioned generation's unacked appends
         # out of storage state.
-        self.known_committed = max(
-            self.known_committed,
-            version if known_committed is None else known_committed,
-        )
+        kc = version if known_committed is None else known_committed
+        if kc > self.known_committed:
+            self.known_committed = kc
+            self.kc_advances_by_push += 1
         self._maybe_spill()
         if sink is not None:
             # Sub-stage attribution (obs subsystem), interior of the
@@ -301,6 +306,32 @@ class TLog:
         if w is not None:
             w.send(None)
         return version
+
+    @rpc
+    async def advance_known_committed(
+        self, version: int, epoch: "int | None" = None,
+    ) -> int:
+        """A proxy's word that `version` is durable on EVERY tlog of the
+        generation, sent the moment its last push acknowledgement landed
+        — the bound the proxy's NEXT push would carry, one commit-pipeline
+        turn sooner, so the storages' next peek may apply `version` and a
+        read at it need not wait for another batch (in an idle cluster,
+        for IDLE_BATCH_INTERVAL). Fenced like push: refused once locked
+        and from another epoch, so a displaced generation's proxy cannot
+        move a successor's bound. Monotone, and never above what this log
+        holds. Losing the call costs the wait, nothing else: the next
+        push carries the same bound. → the bound now in force."""
+        if self.locked:
+            raise TLogLocked(f"known-committed v{version} after lock")
+        if epoch is not None and self.epoch and epoch != self.epoch:
+            raise TLogLocked(
+                f"known-committed from epoch {epoch} fenced by epoch "
+                f"{self.epoch} tlog")
+        kc = min(version, self._last_appended)
+        if kc > self.known_committed:
+            self.known_committed = kc
+            self.kc_advances_by_notify += 1
+        return self.known_committed
 
     def _maybe_spill(self) -> None:
         if self.disk is None or self._mem_bytes <= self.SPILL_BYTES:
@@ -488,6 +519,8 @@ class TLog:
             "queue_bytes": self._queue_bytes,
             "queue_entries": len(self._log) + len(self._spilled_meta),
             "spilled_entries": len(self._spilled_meta),
+            "kc_advances_by_notify": self.kc_advances_by_notify,
+            "kc_advances_by_push": self.kc_advances_by_push,
         }
 
     @rpc

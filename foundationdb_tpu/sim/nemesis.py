@@ -575,7 +575,8 @@ class WriteStorm(Nemesis):
     def __init__(self, prefix: str = "storm/", keys: int = 2,
                  clients: int = 4, txns: int = 40,
                  priority: str = "default", open_loop: bool = False,
-                 arrival_s: float = 0.003, blind: bool = False, **kw):
+                 arrival_s: float = 0.003, blind: bool = False,
+                 width: int = 1, **kw):
         kw.setdefault("count", 1)
         super().__init__(**kw)
         self.prefix = prefix.encode() if isinstance(prefix, str) else prefix
@@ -600,6 +601,12 @@ class WriteStorm(Nemesis):
         # preserved: unique keys make retries idempotent, so
         # count(keys) == acked is still an exact conservation gate.
         self.blind = blind
+        # Sets a blind transaction (`width` unique keys): the BULK shape.
+        # A commit proxy keeps small commits until its last batch is back
+        # from the resolvers and sends them as one; wide ones leave at
+        # the plain cadence (CommitProxy._held), so only a bulk storm
+        # piles batches up behind a stalled resolver.
+        self.width = width
 
     def _key(self, i: int) -> bytes:
         return self.prefix + b"%04d" % i
@@ -627,7 +634,8 @@ class WriteStorm(Nemesis):
 
                 async def body(tr, unique=unique):
                     self._set_priority(tr)
-                    tr.set(unique, b"")
+                    for j in range(self.width):
+                        tr.set(unique + b"/%02d" % j, b"")
             else:
                 k = self._key(loop.rng.randrange(self.keys))
                 marker = (self.prefix + b"mk/%02d/%04d" % (cid, seq))
@@ -678,10 +686,11 @@ class WriteStorm(Nemesis):
                 return len(rows)
 
             landed = await db.run(body)
-            if landed != acked:
+            if landed != acked * self.width:
                 raise CampaignCheckFailed(
                     f"blind storm {self.prefix!r} not conserved: {landed} "
-                    f"unique keys != {acked} acked txns (lost write)")
+                    f"unique keys != {acked} acked txns x {self.width} "
+                    f"(lost write)")
             return
         total = 0
         for i in range(self.keys):
@@ -964,7 +973,7 @@ NEMESIS_REGISTRY: dict[str, tuple[type, dict[str, str]]] = {
         **_COMMON, "prefix": "prefix", "keys": "keys",
         "clients": "clients", "txns": "txns", "priority": "priority",
         "openLoop": "open_loop", "arrivalSeconds": "arrival_s",
-        "blind": "blind",
+        "blind": "blind", "width": "width",
     }),
     "SystemProbe": (SystemProbe, {**_COMMON, "lane": "lane"}),
     "BackpressureMonitor": (BackpressureMonitor, {

@@ -10,14 +10,21 @@ are read from every process's `admin.obs_snapshot`, which an untraced role
 does not serve), plus on standard error
 
     timeline {"t_s": ..., "commits": ..., "tps_limit": ..., "base_tps": ...,
-              "measured_tps": ..., "limiting_reason": ...,
+              "measured_tps": ..., "grv_tps": ..., "ceiling_probes": ...,
+              "limiting_reason": ..., "kc_advances_by_notify": ...,
+              "kc_advances_by_push": ..., "commit_notifies_sent": ...,
               "grv_proxy_queue_p95_ms": ..., "storage_version_lag_p95_ms": ...,
               "storage_version_wait_ms": ..., ..., "busy": {"proxy": ..., ...},
               "covered_s": {"proxy": ..., ...}}
 
 a slice: the commits the proxies acknowledged in it (and the batches and
 transactions the resolvers resolved), the ratekeeper's
-`get_rates()` at its end, the slice's own `grv_proxy_queue` p95,
+`get_rates()` at its end (`grv_tps`: read versions granted a second, what
+the budget is spent on; `ceiling_probes`: the times `base_tps` was raised
+for it, since boot), which path moved the tlogs' known-committed bound
+first in the slice (`kc_advances_by_notify`: the proxy's word at the
+acknowledgement; `kc_advances_by_push`: the next push's; summed over the
+tlogs; `commit_notifies_sent` by the proxies), the slice's own `grv_proxy_queue` p95,
 `storage_version_lag` p95, `storage_version_wait` mean and the means of the
 other stages a commit and a read cross (`MEANS`; histograms of every process
 merged, slice end minus slice start) and each role's busy
@@ -57,6 +64,12 @@ ROLES = ("client", "proxy", "resolver", "tlog", "storage", "sequencer",
 MEANS = ("storage_version_wait", "read_rpc", "grv_rtt", "resolve_wait",
          "rpc_inbound:resolver.resolve", "coalesce_queue", "device_dispatch",
          "tlog_durable", "rpc_inbound:tlog.push")
+# a served counter (the proxies' get_metrics, the tlogs' metrics) -> its
+# name in a row, where it is the slice's difference
+COUNTS = {"txns_committed": "commits",
+          "commit_notifies_sent": "commit_notifies_sent",
+          "kc_advances_by_notify": "kc_advances_by_notify",
+          "kc_advances_by_push": "kc_advances_by_push"}
 READ_PATH_METRICS = (
     "grv_rtt_ms", "read_rpc_ms", "client_loop_busy_share", "grv_queue_ms",
     "grv_queue_p95_ms", "grv_sequencer_ms", "proxy_loop_busy_share",
@@ -121,13 +134,17 @@ def main() -> int:
         rk = cluster.ratekeeper_ep(t)
         proxies = [t.endpoint(parse_addr(a), "commit_proxy")
                    for a in cluster.spec["proxy"]]
+        tlogs = [t.endpoint(parse_addr(a), "tlog")
+                 for a in cluster.spec["tlog"]]
 
         async def read() -> dict:
+            served = ([await p.get_metrics() for p in proxies]
+                      + [await g.metrics() for g in tlogs])
             return {
                 "dumps": await observer.dumps(),
                 "rates": await rk.get_rates() if rk is not None else {},
-                "committed": sum([(await p.get_metrics())["txns_committed"]
-                                  for p in proxies]),
+                # .get: a parent without the notification reads 0
+                **{k: sum(m.get(k, 0) for m in served) for k in COUNTS},
                 "resolved": await observer.counters(),
             }
 
@@ -139,14 +156,14 @@ def main() -> int:
             cur = await read()
             rates = cur["rates"]
             say("timeline", dict(
-                {"t_s": round(at - t_start, 1),
-                 "commits": cur["committed"] - prev["committed"]},
+                {"t_s": round(at - t_start, 1)},
+                **{COUNTS[k]: cur[k] - prev[k] for k in COUNTS},
                 # the resolvers' batches and transactions, summed
                 **{k: cur["resolved"][k] - prev["resolved"][k]
                    for k in ("batches_resolved", "txns_resolved")},
                 **{k: rates.get(k) for k in (
-                    "tps_limit", "base_tps", "measured_tps",
-                    "limiting_reason")},
+                    "tps_limit", "base_tps", "measured_tps", "grv_tps",
+                    "ceiling_probes", "limiting_reason")},
                 **slice_row(stages_between(prev["dumps"], cur["dumps"]))))
             prev = cur
 

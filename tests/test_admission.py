@@ -444,7 +444,7 @@ class _SatRk:
         self.sat = sat
         self.tps = tps
 
-    async def get_rates(self, poller_id=None):
+    async def get_rates(self, poller_id=None, grvs_served=None):
         return {"tps_limit": self.tps, "batch_tps_limit": self.tps,
                 "admission_saturation": self.sat}
 

@@ -238,7 +238,7 @@ class FakeRatekeeper:
     def __init__(self, tps, batch_tps):
         self.tps, self.batch_tps = tps, batch_tps
 
-    async def get_rates(self, poller_id=None):
+    async def get_rates(self, poller_id=None, grvs_served=None):
         return {"tps_limit": self.tps, "batch_tps_limit": self.batch_tps}
 
 
@@ -273,7 +273,7 @@ class TestTagThrottling:
         loop = Loop(seed=0)
 
         class RkWithTags(FakeRatekeeper):
-            async def get_rates(self, poller_id=None):
+            async def get_rates(self, poller_id=None, grvs_served=None):
                 r = await super().get_rates()
                 r["tag_rates"] = {"hot": 10.0}
                 return r
@@ -312,7 +312,7 @@ class TestTagThrottling:
         class ToggleRk(FakeRatekeeper):
             tag_rates = {"hot": 5.0}
 
-            async def get_rates(self, poller_id=None):
+            async def get_rates(self, poller_id=None, grvs_served=None):
                 r = await super().get_rates()
                 r["tag_rates"] = dict(self.tag_rates)
                 return r
@@ -535,3 +535,208 @@ class TestCalibration:
 
         rates = loop.run(main(), timeout=600)
         assert rates["tps_limit"] == 0.0, rates  # throttling still reacts
+
+
+# -- the GRV budget, realised as stated and raised by what it is spent on -----
+
+
+class SlowSequencer:
+    """Answers in `seconds` of loop time: with the proxy's BATCH_INTERVAL
+    that is what a loop iteration takes."""
+
+    def __init__(self, loop, seconds):
+        self.loop, self.seconds = loop, seconds
+
+    async def get_live_committed_version(self):
+        if self.seconds:
+            await self.loop.sleep(self.seconds)
+        return 42
+
+
+class SaturatedRatekeeper(FakeRatekeeper):
+    async def get_rates(self, poller_id=None, grvs_served=None):
+        r = await super().get_rates()
+        r["admission_saturation"] = 1.0
+        return r
+
+
+def _grants_over_budget(lane, sequencer_s, ratekeeper, seconds=10.0,
+                        rate=500.0, tag=None):
+    """Demand well above `rate` on one lane for `seconds` of loop time.
+    → read versions granted over rate x seconds."""
+    loop = Loop(seed=0)
+    proxy = GrvProxy(loop, SlowSequencer(loop, sequencer_s), ratekeeper)
+    proxy._tokens = proxy._batch_tokens = 0.0  # no boot bucket to live on
+    served = [0]
+
+    async def client():
+        while True:
+            await proxy.get_read_version(lane, [tag] if tag else None)
+            served[0] += 1
+
+    async def main():
+        loop.spawn(proxy.run(), name="grv")
+        await loop.sleep(0.15)  # the first rate poll has landed
+        for _ in range(32):
+            loop.spawn(client(), name="client")
+        t0, n0 = loop.now, served[0]
+        await loop.sleep(seconds)
+        return (served[0] - n0) / (rate * (loop.now - t0))
+
+    return loop.run(main(), timeout=600)
+
+
+class TestGrvBudgetByElapsedTime:
+    @pytest.mark.parametrize("lane", ["default", PRIORITY_BATCH])
+    @pytest.mark.parametrize("sequencer_s", [0.0, 0.001, 0.003])
+    def test_grants_its_rate_whatever_an_iteration_takes(self, lane,
+                                                         sequencer_s):
+        """A refill of one BATCH_INTERVAL an ITERATION granted 1 / (1 +
+        sequencer_s / BATCH_INTERVAL) of the budget: a half at 1 ms, the
+        deployed cluster's two thirds at ~0.5 ms."""
+        share = _grants_over_budget(lane, sequencer_s,
+                                    FakeRatekeeper(500.0, 500.0))
+        assert share == pytest.approx(1.0, abs=0.05), share
+
+    def test_tag_bucket_grants_its_quota_whatever_an_iteration_takes(self):
+        class RkWithTag(FakeRatekeeper):
+            async def get_rates(self, poller_id=None, grvs_served=None):
+                r = await super().get_rates()
+                r["tag_rates"] = {"hot": 50.0}
+                return r
+
+        share = _grants_over_budget("default", 0.001, RkWithTag(1e6, 1e6),
+                                    rate=50.0, tag="hot")
+        assert share == pytest.approx(1.0, abs=0.05), share
+
+    def test_a_deferred_interval_still_accrues_nothing(self):
+        """Under admission saturation every other interval is deferred:
+        no grant, and its tokens are NOT carried to the next."""
+        share = _grants_over_budget("default", 0.0,
+                                    SaturatedRatekeeper(500.0, 500.0))
+        assert share == pytest.approx(0.5, abs=0.05), share
+
+    def test_the_poll_reports_what_was_granted(self):
+        loop = Loop(seed=0)
+        reports = []
+
+        class Rk(FakeRatekeeper):
+            async def get_rates(self, poller_id=None, grvs_served=None):
+                reports.append((poller_id, grvs_served))
+                return await super().get_rates()
+
+        proxy = GrvProxy(loop, FakeSequencer(), Rk(1e6, 1e6))
+
+        async def main():
+            loop.spawn(proxy.run(), name="grv")
+            for _ in range(7):
+                await proxy.get_read_version()
+            for _ in range(3):
+                await proxy.get_read_version("batch")
+            for _ in range(5):  # spends no budget: not the ceiling's use
+                await proxy.get_read_version("system")
+            await loop.sleep(0.25)
+
+        loop.run(main(), timeout=60)
+        assert proxy.grvs_served == 15
+        assert reports[0] == (proxy.poller_id, 0)
+        assert reports[-1] == (proxy.poller_id, 10)
+
+
+def _ceiling_after(commit_share, grv_share, seconds=10.0, ceiling=1_000.0):
+    """A healthy cluster (clean signals, no backlog) whose clients commit
+    at `commit_share` of the ceiling it starts with and are granted read
+    versions at `grv_share` of it, by two GRV proxies. → get_rates()."""
+    loop = Loop(seed=0)
+    committed = {"n": 0.0}
+
+    class Proxy:
+        def get_metrics(self):
+            async def get():
+                return {"txns_committed": int(committed["n"]), "queued": 0}
+
+            return loop.spawn(get(), name="proxy.metrics")
+
+    class CleanStorage:
+        def metrics(self):
+            async def get():
+                return {"version_lag": 0, "durability_lag": 0,
+                        "queue_bytes": 0}
+
+            return loop.spawn(get(), name="clean_storage.metrics")
+
+    rk = Ratekeeper(loop, [CleanStorage()], [], proxy_eps=[Proxy()])
+    rk.base_tps = ceiling  # as a bulk load leaves it
+
+    async def grv_proxy(poller_id):
+        served = 0.0
+        while True:
+            await rk.get_rates(poller_id, int(served))
+            await loop.sleep(0.1)
+            served += grv_share * ceiling / 2 * 0.1
+
+    async def main():
+        loop.spawn(rk.run(), name="rk")
+        loop.spawn(grv_proxy("grv-a"), name="grv-a")
+        loop.spawn(grv_proxy("grv-b"), name="grv-b")
+        t = 0.0
+        while t < seconds:
+            committed["n"] += commit_share * ceiling * 0.05
+            await loop.sleep(0.05)
+            t += 0.05
+        return await rk.get_rates()
+
+    return loop.run(main(), timeout=600)
+
+
+class TestCeilingFollowsReadVersions:
+    def test_workload_f_gets_its_budget_back(self):
+        """Commits at 0.4 of the ceiling, read versions at 0.9 of it: the
+        budget is spent on read versions, so that is what must pass 0.7
+        of the ceiling for a healthy cluster to be given more. Before,
+        only commits counted and the ceiling stood where a bulk load had
+        left it. The probe stops once the use is under 0.7 of it."""
+        rates = _ceiling_after(0.4, 0.9)
+        assert rates["grv_tps"] == pytest.approx(900.0, rel=0.05), rates
+        assert rates["measured_tps"] == pytest.approx(400.0, rel=0.05), rates
+        assert rates["ceiling_probes"] >= 5, rates
+        assert 900.0 / 0.7 <= rates["base_tps"] <= 900.0 / 0.7 * 1.06, rates
+        assert rates["tps_limit"] == rates["base_tps"]
+
+    @pytest.mark.parametrize("commit_share, grv_share", [
+        (0.0, 0.0),     # nobody there
+        (0.1, 0.2),     # idle
+        (0.3, 0.65),    # busy, and under 0.7 in both
+    ])
+    def test_a_cluster_that_does_not_spend_it_is_not_given_more(
+            self, commit_share, grv_share):
+        rates = _ceiling_after(commit_share, grv_share)
+        assert rates["ceiling_probes"] == 0, rates
+        assert rates["base_tps"] == 1_000.0, rates
+
+    def test_commits_alone_still_probe(self):
+        """A blind-write load asks few read versions: the commit rate
+        passes 0.7 of the ceiling and probes it, as before."""
+        rates = _ceiling_after(0.9, 0.1)
+        assert rates["ceiling_probes"] >= 5, rates
+        assert rates["base_tps"] >= 900.0 / 0.7, rates
+
+    def test_a_poller_that_went_silent_stops_counting(self):
+        """A retired GRV proxy's last rate ages out with its lease."""
+        loop = Loop(seed=0)
+        rk = Ratekeeper(loop, [], [])
+
+        async def main():
+            await rk.get_rates("grv-a", 0)
+            await loop.sleep(0.1)
+            await rk.get_rates("grv-a", 100)
+            assert rk._poller_grvs["grv-a"] == (100, pytest.approx(1000.0))
+            # a count under the last (a restarted proxy) only baselines
+            await loop.sleep(0.1)
+            await rk.get_rates("grv-a", 10)
+            assert rk._poller_grvs["grv-a"] == (10, pytest.approx(1000.0))
+            await loop.sleep(Ratekeeper.POLLER_TTL + 0.1)
+            await rk.get_rates("grv-b", 0)
+            return dict(rk._poller_grvs)
+
+        assert loop.run(main(), timeout=60) == {"grv-b": (0, 0.0)}

@@ -248,7 +248,7 @@ class TestFreshProxyTagDeferral:
         state = {"ready": False}
 
         class LateRk:
-            async def get_rates(self, poller_id=None):
+            async def get_rates(self, poller_id=None, grvs_served=None):
                 if not state["ready"]:
                     raise RuntimeError("ratekeeper unreachable (recovery)")
                 return {"tps_limit": 1e6, "batch_tps_limit": 1e6,
@@ -289,7 +289,7 @@ class TestSystemLaneBypass:
         loop = Loop(seed=0)
 
         class ZeroRk:  # backpressure clamped everything
-            async def get_rates(self, poller_id=None):
+            async def get_rates(self, poller_id=None, grvs_served=None):
                 return {"tps_limit": 0.0, "batch_tps_limit": 0.0}
 
         from foundationdb_tpu.runtime.grv_proxy import GrvProxy
@@ -429,12 +429,18 @@ class TestBackpressureUnderCloggedNetwork:
 
             async def one(seq):
                 async def body(tr):
-                    tr.set(b"bp/%05d" % seq, b"")
+                    for j in range(16):
+                        tr.set(b"bp/%05d/%02d" % (seq, j), b"")
 
                 await db.run(body)
 
             # Open-loop blind arrivals; a 12x stall mid-stream collapses
             # dispatch capacity so the queue must absorb the backlog.
+            # BULK transactions, sixteen sets each: a proxy keeps small
+            # commits until its last batch is back from the resolvers
+            # (CommitProxy._held, PR 39), so single sets ride a stall in
+            # a few fuller batches and queue at the proxies; wide ones
+            # leave at the plain cadence and pile up behind the stall.
             writers = []
             stall_at = 60
             for seq in range(240):

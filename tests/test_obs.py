@@ -527,13 +527,18 @@ def test_a_wait_inside_one_loop_turn_is_seen_by_the_span_clock():
     assert queued.sum_ms >= 30.0 + 50.0 and queued.max_ms >= 50.0
 
 
-#: sha256 of the PARENT commit's (a8e48d7) `span_records(seed, txns=64)`.
-#: A sim loop's span clock is its virtual `loop.now`, so every record the
-#: parent wrote is written again, byte for byte; the read path's stages
-#: are new records beside them.
+#: sha256 of `span_records(seed, txns=64)` without the read path's stages.
+#: A sim loop's span clock is its virtual `loop.now`, so a change that only
+#: ADDS spans (PR 38 did: the read path's stages are new records beside
+#: these) writes every record again, byte for byte, and so does a second
+#: run. A change to WHEN things happen moves them, and says so here: PR 39
+#: (the tlogs are told of a commit at its acknowledgement, one RPC more a
+#: batch; GRV buckets refill by elapsed time; a proxy's batch interval
+#: follows its resolve latency) re-pinned both; before it
+#: they were b468b160...0c8bb8 and 10895abd...c8ba8eb (a8e48d7, 528e60b).
 PARENT_SPAN_RECORDS = {
-    5: "b468b160b184d9480b6e86ca75c65975bc8dffd5d34e5d8697466bd2ba0c8bb8",
-    38: "10895abd60778ac792ecbf013aec46e2931106be0469250e2bbfcbb40c8ba8eb",
+    5: "36476c973d35b6299405ebb0851351cd157787a6c9ae9028ae150284e7696ec2",
+    38: "c492c73660ea04b37e5a2c257e8191859e2a2b7be74b32a8be9888bf7b0586c3",
 }
 
 
