@@ -67,7 +67,11 @@ MODES = {
     # `mako_share_g8ui` (BENCHMARK.json; benchmark/configs/
     # mako_resolver_share.json), g8ui through the served role's 8 slots.
     "mako": ModeConfig(9, 1, 1.0, 0.99, 4096),
-    # TPC-C new-order shape: wide txns (12 reads, 8 writes), uniform items.
+    # TPC-C new-order shape: wide txns (12 reads, 8 writes), uniform items,
+    # kernel only. No cell: the benchmark's TPC-C is `tpcc_share_mix`
+    # (BENCHMARK.json; benchmark/configs/tpcc_resolver_share.json), the
+    # published mix of new-order, payment and delivery through the served
+    # role, true range reads and all.
     "tpcc": ModeConfig(12, 8, 1.0, 0.0, 2048),
 }
 

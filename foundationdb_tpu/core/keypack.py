@@ -14,7 +14,8 @@ raw StringRefs):
 
 Keys longer than ``max_key_bytes`` are widened conservatively (range begins
 truncate down, range ends round up to the prefix-successor), which can only
-produce false conflicts — never missed ones. The packing loop is the host hot
+produce false conflicts — never missed ones; ``KeyCodec.keys_widened``
+counts them. The packing loop is the host hot
 path; a C++ packer (native/keypack.cpp) accelerates it with a pure-numpy
 fallback here.
 """
@@ -49,6 +50,8 @@ class KeyCodec:
         self.n_words = max_key_bytes // 4
         # +1 column for the length tiebreaker.
         self.width = self.n_words + 1
+        # Keys `pack` had to shorten since this codec was made.
+        self.keys_widened = 0
 
     # -- scalar sentinels ---------------------------------------------------
 
@@ -80,7 +83,9 @@ class KeyCodec:
         if lengths.max(initial=0) > self.max_key_bytes:
             # Rare slow path: shorten overlong keys in place first.
             keys = list(keys)
-            for i in np.flatnonzero(lengths > self.max_key_bytes):
+            overlong = np.flatnonzero(lengths > self.max_key_bytes)
+            self.keys_widened += len(overlong)
+            for i in overlong:
                 k = self._shorten(keys[i], mode)
                 if k is None:  # end-mode prefix was all 0xff → +inf
                     inf_rows.append(int(i))

@@ -1405,6 +1405,7 @@ class TPUConflictSet:
         e = max(1, s["endpoints"])
         s.update(
             resident_keys=self._mirror.n,
+            keys_widened=self.codec.keys_widened,
             dict_capacity=self._mirror.capacity,
             delta_slots=self.dict_delta_slots,
             unique_keys_per_dispatch=round(s["unique_keys"] / d, 1),

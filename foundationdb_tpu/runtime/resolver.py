@@ -83,6 +83,20 @@ class _InFlight:
 
 
 class Resolver:
+    """One resolver role over one conflict engine.
+
+    What it counts, all monotone since boot and read as differences
+    (`get_metrics`): batches and transactions resolved; what it was SENT,
+    counted as sent and before any engine sees it (`ranges_received`,
+    `txns_with_ranges`, of the ranges the true ones,
+    `true_ranges_received`, and the non-empty ones, `slots_filled`); what
+    the engine's layout made of it (`rows_dispatched`, `wide_txns`; the
+    engine's own `dispatches` and `keys_widened` under "engine"); verdict
+    attribution (`txns_conflicted`, `txns_reordered`,
+    `txns_cycle_aborted`); the pipeline (`batches_overlapped`,
+    `pipeline_drains`); and the failure counters a run is held to
+    (`overflow_events`, `txns_rejected_fail_safe`, `resolve_failures`)."""
+
     REPLY_CACHE_SIZE = 256  # recent batches kept for retransmit replay
     DRAIN_CAUSES = ("no_successor", "headroom", "repack", "small_batch")
 
@@ -134,6 +148,12 @@ class Resolver:
         # every batch, so only these say whether the key split is even.
         self.ranges_received = 0
         self.txns_with_ranges = 0
+        # Of those ranges, the TRUE ones (an end that is not begin +
+        # "\x00": a getRange's prefix, a clear of more than one key) and
+        # the non-empty ones, each of which fills one slot of an engine
+        # row: over rows_dispatched x slots a row, what is not padding.
+        self.true_ranges_received = 0
+        self.slots_filled = 0
         # Padded engine rows the resolved transactions took, and the
         # transactions with more ranges than one row's slots (_txn_rows).
         self.rows_dispatched = 0
@@ -453,11 +473,22 @@ class Resolver:
                         rows: tuple[int, int]) -> None:
         self.batches_resolved += 1
         self.txns_resolved += len(txns)
+        true = empty = received = 0
         for t in txns:
             n = len(t.read_ranges) + len(t.write_ranges)
             if n:
-                self.ranges_received += n
+                received += n
                 self.txns_with_ranges += 1
+                for ranges in (t.read_ranges, t.write_ranges):
+                    for r in ranges:
+                        if r.end != r.begin + b"\x00":  # one test a point
+                            if r.end == r.begin:
+                                empty += 1
+                            else:
+                                true += 1
+        self.ranges_received += received
+        self.true_ranges_received += true
+        self.slots_filled += received - empty
         self.rows_dispatched += rows[0]
         self.wide_txns += rows[1]
 
@@ -1200,8 +1231,17 @@ class Resolver:
             # rows; rows a transaction is a window difference of the two).
             "rows_dispatched": self.rows_dispatched,
             "wide_txns": self.wide_txns,
+            # Conflict ranges as SENT (after the proxy's clip) and the
+            # transactions that brought any; of the ranges, those whose
+            # end is not begin + "\x00" (true ranges, which must arrive
+            # as themselves), and the non-empty ones: each fills one slot
+            # of a row, so over rows_dispatched x (read + write slots a
+            # row) they are the share of a dispatch that is not padding.
+            # Dispatches a batch: engine.dispatches over batches_resolved.
             "ranges_received": self.ranges_received,
             "txns_with_ranges": self.txns_with_ranges,
+            "true_ranges_received": self.true_ranges_received,
+            "slots_filled": self.slots_filled,
             "version": self._version,
             "fail_safe_active": self._fail_safe_on
             or self._unsafe_until is not None,
@@ -1293,6 +1333,9 @@ class Resolver:
                     "delta_empty_dispatches"),
                 "compiles": self._engine_dict_stat("compiles"),
                 "compile_s": self._engine_dict_fstat("compile_s"),
+                # Keys longer than the codec's max_key_bytes, which it
+                # widened (core/keypack.py): a false conflict at worst.
+                "keys_widened": self._engine_dict_stat("keys_widened"),
                 # Tiered-dictionary economics (all zero when tiering is
                 # off — FDB_TPU_DICT_HOT_CAPACITY unset — or the engine
                 # is not resident): obs/doctor's dict_thrash detector
