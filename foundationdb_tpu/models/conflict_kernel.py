@@ -982,11 +982,12 @@ def _paint_and_compact(
     two sorted sequences are then interleaved by rank arithmetic (the
     merge-path construction: each element's output slot is its own index
     plus its cross-rank in the other sequence, history winning ties), and
-    the surviving boundaries are compacted to the front by gathering the
-    j-th kept entry (binary search into the keep prefix-sum). Everything is
-    sorts-of-small + gathers: no full-history sort (the first version of
-    this kernel re-sorted all of C per batch) and no large scatters (XLA
-    TPU scatters serialize; gathers tile onto the VPU)."""
+    the surviving boundaries are compacted to the front by streaming
+    shifts (_dedup_compact). No full-history sort (the first version of
+    this kernel re-sorted all of C per batch). The interleave itself
+    (_paint_tail) still searches and gathers once a slot: at the delta's
+    size, n = 16,386, that is what is left of ROADMAP A5. What the chip
+    charges for each kind of pass is in _dedup_compact's docstring."""
     c, w = state.keys.shape
     b, q, _ = batch.write_begin.shape
     e2 = b * q
@@ -1044,7 +1045,9 @@ def _paint_tail(
     n2 = snew.shape[0]
     n = c + n2
 
-    # Merge-path, scatter-free (TPU scatters serialize badly; gathers tile).
+    # Merge-path by rank arithmetic, one search and three gathers a slot
+    # (the construction _merge_delta had until PR 41; here n is the delta's
+    # 16,386 slots, not the history's capacity, and ROADMAP A5 is its item).
     # pos_n[j] = output slot of sorted-new[j] = j + its cross-rank in the
     # history ('right' side puts history entries before equal new entries —
     # a collision-free permutation of [0, n) even with duplicate keys).
@@ -1077,6 +1080,33 @@ def _paint_tail(
     )
 
 
+def _stream_shift(cols, shift, k: int, up: bool):
+    """One streaming pass of a staged move: every row whose remaining
+    ``shift`` has the binary digit ``k`` moves k slots (``up``: to the
+    higher index), its columns and its shift riding together; a slot left
+    behind reads shift 0, so it stays put and whatever arrives overwrites
+    it. The rows' shifts are nondecreasing along the array, so rows keep
+    their order after every digit and no two ever meet: upwards the digits
+    run high to low (holes open between the rows, _merge_delta), downwards
+    low to high (the same states in reverse: holes close, _dedup_compact).
+    ``cols`` are arrays with the rows on axis 0."""
+
+    def moved(x):
+        pad = jnp.zeros((k,) + x.shape[1:], x.dtype)
+        if up:
+            return jnp.concatenate([pad, x[:-k]])
+        return jnp.concatenate([x[k:], pad])
+
+    from_k = moved(shift)
+    arrives = (from_k & k) != 0
+    leaves = (shift & k) != 0
+    cols = tuple(
+        jnp.where(arrives.reshape((-1,) + (1,) * (x.ndim - 1)), moved(x), x)
+        for x in cols
+    )
+    return cols, jnp.where(arrives, from_k, jnp.where(leaves, 0, shift))
+
+
 def _dedup_compact(skeys, newv, c_out, prior_overflow):
     """Shared compaction tail of every step-function rewrite (paint and
     the window-history merge): dedup equal keys, drop boundaries that no
@@ -1084,8 +1114,28 @@ def _dedup_compact(skeys, newv, c_out, prior_overflow):
 
     skeys [n, W] sorted (ties allowed), newv [n] already GC'd (expired and
     padding rows hold the sentinel). Returns (keys, versions, n_used,
-    overflow) at capacity c_out."""
-    n, w = skeys.shape
+    overflow) at capacity c_out.
+
+    Nothing searches and nothing gathers once per row: the version of the
+    previous kept boundary is a forward fill by doubling (one streaming
+    pass a binary digit of the longest run of equal keys: one pass for a
+    merge, whose runs are a base row and the delta row equal to it), and
+    the survivors move DOWN by the count of dropped rows before them in
+    streaming shifts (_stream_shift), a pass skipped when no survivor's
+    count has that digit.
+
+    What the chip charges (TPU v5 lite, n = 532,482 rows of one key word;
+    my chip run, PR 41, PERF.md section 6's table): a GATHERED row 7.9 ns
+    whatever it is gathered from (4.2 ms a gather, and a binary search is
+    14-20 of them: the three searches this function and _merge_delta made
+    over the capacity were 250 ms of a 316 ms merge); a STREAMING shift
+    pass over keys, versions and shift 0.05 ms, a forward-fill pass 0.02
+    ms, a prefix sum under 0.05 ms; a SCATTER of 8,194 sorted, unique
+    updates 0.22 ms (27 ns an update: scatters do serialize, and 8,194 of
+    them still cost a twentieth of one gather over the history). At the
+    paint's size (n = 16,386) this body takes 0.45 ms where the searching
+    one took 1.4 ms, so one construction serves both callers."""
+    n = skeys.shape[0]
     is_inf = jnp.all(skeys == INT32_MAX, axis=-1)
     # Dedup equal keys: keep the LAST occurrence (it carries the full
     # coverage sum and the consistent old version).
@@ -1093,13 +1143,25 @@ def _dedup_compact(skeys, newv, c_out, prior_overflow):
     keep1 = jnp.concatenate([neq_next, jnp.ones((1,), jnp.bool_)])
     # Drop boundaries whose version equals the previous KEPT boundary's —
     # they no longer change the step function (this is what erases interior
-    # boundaries of freshly painted ranges and expired segments).
-    idx = jnp.arange(n, dtype=jnp.int32)
-    kept_idx = jnp.where(keep1, idx, -1)
-    prev_kept = jnp.concatenate(
-        [jnp.full((1,), -1, jnp.int32), jax.lax.cummax(kept_idx, axis=0)[:-1]]
-    )
-    prev_v = jnp.where(prev_kept >= 0, newv[jnp.maximum(prev_kept, 0)], NEG_VERSION - 1)
+    # boundaries of freshly painted ranges and expired segments). prev_v[i]
+    # is the version of the last keep1 row before i (none: a value no
+    # version takes): after the pass of digit k a row knows it if that row
+    # lies within 2k rows behind it. A +inf row is never kept, whatever it
+    # reads, so the padding's long run of equal keys costs no pass.
+    no_prev = NEG_VERSION - 1
+    prev_v = jnp.concatenate([jnp.full((1,), no_prev, newv.dtype), newv[:-1]])
+    known = jnp.concatenate([jnp.ones((1,), jnp.bool_), (keep1 | is_inf)[:-1]])
+
+    def fill(pv, ok, k):
+        pv_k = jnp.concatenate([jnp.full((k,), no_prev, pv.dtype), pv[:-k]])
+        ok_k = jnp.concatenate([jnp.ones((k,), jnp.bool_), ok[:-k]])
+        return jnp.where(ok, pv, pv_k), ok | ok_k
+
+    for b in range((n - 1).bit_length()):
+        prev_v, known = jax.lax.cond(
+            jnp.all(known), lambda pv, ok: (pv, ok),
+            functools.partial(fill, k=1 << b), prev_v, known,
+        )
     keep = keep1 & (newv != prev_v) & ~is_inf
 
     # The keyspace minimum must always remain a boundary. Force its run's
@@ -1111,19 +1173,25 @@ def _dedup_compact(skeys, newv, c_out, prior_overflow):
     min_last = n - 1 - jnp.argmax(is_min[::-1])
     keep = keep.at[min_last].set(True)
 
-    # Compact survivors to the front, gather-style: output slot j pulls the
-    # (j+1)-th kept entry (binary search into the keep prefix-sum) — the
-    # scatter-free dual of a prefix-sum scatter compaction.
+    # Compact survivors to the front: the j-th kept row moves down by the
+    # dropped rows before it, a count that never falls from one survivor
+    # to the next. A dropped row's count reads 0: it stays where it is
+    # until a survivor lands on it, or ends behind the last survivor.
     keep_cum = jnp.cumsum(keep.astype(jnp.int32))  # [n], non-decreasing
     n_used = keep_cum[-1]
-    out_j = jnp.arange(c_out, dtype=jnp.int32)
-    src = jnp.searchsorted(keep_cum, out_j + 1, side="left").astype(jnp.int32)
-    src = jnp.clip(src, 0, n - 1)
-    live_out = out_j < n_used
-    fkeys = jnp.where(
-        live_out[:, None], skeys[src], jnp.full((w,), INT32_MAX, jnp.int32)
-    )
-    fv = jnp.where(live_out, newv[src], NEG_VERSION)
+    down = jnp.where(keep, jnp.arange(1, n + 1, dtype=jnp.int32) - keep_cum, 0)
+    digits = jnp.bitwise_or.reduce(down)
+    cols = (skeys, newv)
+    for b in range((n - 1).bit_length()):
+        k = 1 << b
+        cols, down = jax.lax.cond(
+            (digits & k) != 0,
+            functools.partial(_stream_shift, k=k, up=False),
+            lambda cols, down: (cols, down), cols, down,
+        )
+    live_out = jnp.arange(c_out, dtype=jnp.int32) < n_used
+    fkeys = jnp.where(live_out[:, None], cols[0][:c_out], INT32_MAX)
+    fv = jnp.where(live_out, cols[1][:c_out], NEG_VERSION)
     overflow = prior_overflow | (n_used > c_out)
     return fkeys, fv, jnp.minimum(n_used, c_out), overflow
 
@@ -1323,8 +1391,16 @@ def resolve_many(
 #   range-max via a per-batch table over Cd).
 # - When the next batch's worst-case paint wouldn't fit the delta, the
 #   delta is folded into the base (pointwise-max merge of two step
-#   functions over their union boundary set — one O(C+Cd) pass) and the
-#   base table rebuilt, all inside the same compiled program (lax.cond).
+#   functions over their union boundary set) and the base table rebuilt,
+#   all inside the same compiled program (lax.cond). A merge searches the
+#   delta's Cd rows into the base and nothing else; over the base it makes
+#   a constant number of STREAMING passes (a histogram, prefix sums, one
+#   shift pass a binary digit, one scatter of Cd rows: _merge_delta,
+#   _dedup_compact). On the chip, Cd = 8,194 holding 6,500 boundaries:
+#   4.4 / 4.9 / 5.6 ms with the table's rebuild at C = 1<<17 / 1<<18 /
+#   1<<19, of which the search is 2.5 (my chip run, PR 41; until then
+#   three searches and a dozen gathers a BASE row: 75 / 162 / 316 ms).
+#   HistState.merges counts them; the engine serves it as `hist_merges`.
 #
 # Freezing base between merges is sound: base versions only become STALE
 # (≤ the advancing floor), and the conflict test `newest > read_version`
@@ -1338,6 +1414,7 @@ class HistState(NamedTuple):
     base: ConflictState
     base_st: jax.Array  # sparse table over base.versions [L, C]
     delta: ConflictState  # capacity Cd; oldest = the LIVE window floor
+    merges: jax.Array  # int32 — merges since boot (_maybe_merge, advance_hist)
 
 
 def init_hist(capacity: int, width: int, min_key,
@@ -1347,6 +1424,7 @@ def init_hist(capacity: int, width: int, min_key,
         base=base,
         base_st=sparse_table(base.versions),
         delta=init_state(delta_capacity, width, min_key),
+        merges=jnp.int32(0),
     )
 
 
@@ -1369,38 +1447,70 @@ def _merge_delta(base: ConflictState, delta: ConflictState,
     """Fold the delta into the base: pointwise max of the two step
     functions over the union boundary set, then GC (≤ floor) + compact.
     Max is exact because delta writes postdate every base write they
-    cover. Same merge-path construction as _paint_and_compact — all
-    sorts-of-small + gathers, no scatters."""
-    c, w = base.keys.shape
+    cover.
+
+    Costs what the delta costs plus a constant number of streaming passes
+    over the base, as _dict_insert does next door. ONE search is left, the
+    delta's Cd rows into the base (``cross_d``); everything per base row
+    comes out of it by a histogram and a prefix sum:
+
+    - delta row j lands at slot j + cross_d[j] ('right' puts a base row
+      before an equal delta row, so keep-last dedup keeps the delta's);
+      base row r lands at r + (delta rows with cross_d <= r), the running
+      count of a histogram of cross_d, by _stream_shift, one pass a binary
+      digit of that count; the delta's rows fill the holes by one sorted,
+      unique scatter.
+    - The delta's version over base row r is that of the last delta row
+      before it: the running sum of the delta's version STEPS added into
+      the same bins (int32 sums telescope exactly, wrap or not), started
+      at the delta's first version. For a base row whose key EQUALS a delta
+      key this is the delta segment one before the one that covers it;
+      that base row is the duplicate keep-last dedup drops, so its version
+      is never read (_dedup_compact: not keep1, and prev_v passes over it).
+    - The base's version under delta row j is one gather of Cd rows."""
+    c = base.keys.shape[0]
     cd = delta.keys.shape[0]
-    n = c + cd
     # The packed design's fingerprint search also serves the merge (both
     # operands are step-function key arrays); unpacked keeps the r5
     # full-width search so the A/B baseline is untouched.
     _ss = searchsorted_words_fp if _PACKED else searchsorted_words
     cross_d = _ss(base.keys, delta.keys, side="right")  # [Cd]
-    seg_b_for_d = jnp.maximum(cross_d - 1, 0)
-    cross_b = _ss(delta.keys, base.keys, side="right")  # [C]
-    seg_d_for_b = jnp.maximum(cross_b - 1, 0)
-
-    # Merge-path: delta entry j lands at slot j + its cross-rank ('right'
-    # puts base entries before equal delta entries → keep-last dedup keeps
-    # the delta occurrence; both carry the same max so either is correct).
     pos_d = jnp.arange(cd, dtype=jnp.int32) + cross_d
-    idx = jnp.arange(n, dtype=jnp.int32)
-    cnt_le = jnp.searchsorted(pos_d, idx, side="right").astype(jnp.int32)
-    k_d = jnp.maximum(cnt_le - 1, 0)
-    from_d = (cnt_le > 0) & (pos_d[k_d] == idx)
-    b_idx = jnp.clip(idx - cnt_le, 0, c - 1)
 
-    skeys = jnp.where(from_d[:, None], delta.keys[k_d], base.keys[b_idx])
-    vb = jnp.where(from_d, base.versions[seg_b_for_d[k_d]],
-                   base.versions[b_idx])
-    vd = jnp.where(from_d, delta.versions[k_d],
-                   delta.versions[seg_d_for_b[b_idx]])
-    v = jnp.maximum(vb, vd)
-    is_inf = jnp.all(skeys == INT32_MAX, axis=-1)
-    v = jnp.where((v <= floor) | is_inf, NEG_VERSION, v)
+    def gc(v, keys):
+        is_inf = jnp.all(keys == INT32_MAX, axis=-1)
+        return jnp.where((v <= floor) | is_inf, NEG_VERSION, v)
+
+    dv = delta.versions
+    v_d = gc(jnp.maximum(base.versions[jnp.maximum(cross_d - 1, 0)], dv),
+             delta.keys)
+
+    def running(x):
+        """Over base row r, the sum of x[j] for the delta rows j before
+        it (cross_d[j] <= r). A +inf delta row's cross_d is C: outside
+        the bins, behind every base row, and it lands at the very top."""
+        return jnp.cumsum(jnp.zeros((c,), jnp.int32).at[cross_d].add(
+            x, mode="drop", indices_are_sorted=True))
+
+    up = running(1)
+    dv_b = dv[0] + running(dv - jnp.concatenate([dv[:1], dv[:-1]]))
+    v_b = gc(jnp.maximum(base.versions, dv_b), base.keys)
+
+    def grown(x):
+        return jnp.concatenate([x, jnp.zeros((cd,) + x.shape[1:], x.dtype)])
+
+    cols, up_n = (grown(base.keys), grown(v_b)), grown(up)
+    m = up[-1]
+    for b in reversed(range(cd.bit_length())):
+        k = 1 << b
+        cols, up_n = jax.lax.cond(
+            m >= k,
+            functools.partial(_stream_shift, k=k, up=True),
+            lambda cols, up_n: (cols, up_n), cols, up_n,
+        )
+    scatter = dict(mode="drop", indices_are_sorted=True, unique_indices=True)
+    skeys = cols[0].at[pos_d].set(delta.keys, **scatter)
+    v = cols[1].at[pos_d].set(v_d, **scatter)
 
     fkeys, fv, n_used, overflow = _dedup_compact(
         skeys, v, c, base.overflow | delta.overflow
@@ -1420,7 +1530,7 @@ def _maybe_merge(hist: HistState, demand: jax.Array,
     without this, headroom would stay pinned after the MVCC floor slides
     past old history, starving the resolver fail-safe's release check).
     The sparse-table rebuild rides inside the taken branch only."""
-    base, base_st, delta = hist
+    base, _, delta, _ = hist
     cd = delta.keys.shape[0]
     c = base.keys.shape[0]
 
@@ -1430,9 +1540,9 @@ def _maybe_merge(hist: HistState, demand: jax.Array,
     )
 
     def do_merge(h):
-        b, _st, d = h
-        nb = _merge_delta(b, d, floor)
-        return HistState(nb, sparse_table(nb.versions), _reset_delta(d, floor))
+        nb = _merge_delta(h.base, h.delta, floor)
+        return HistState(nb, sparse_table(nb.versions),
+                         _reset_delta(h.delta, floor), h.merges + 1)
 
     need = (delta.n_used + demand > cd) | (reclaimable >= max(c // 8, 1))
     return jax.lax.cond(need, do_merge, lambda h: h, hist)
@@ -1493,7 +1603,7 @@ def resolve_batch_hist(
         .astype(jnp.int32)
     )
     hist = _maybe_merge(hist, demand, floor)
-    base_h, base_st, delta = hist
+    base_h, base_st, delta, _ = hist
     hist_mask = _history_conflict_ranges_hist(base_h, base_st, delta, batch)
     hist_conflict = jnp.any(hist_mask, axis=1)
     ok = batch.txn_mask & ~too_old & ~hist_conflict
@@ -1501,7 +1611,7 @@ def resolve_batch_hist(
     accepted, levels = _accept_or_schedule(ok, ranks, wave, batch.cont)
     verdicts = assemble_verdicts(too_old, batch.txn_mask, accepted)
     delta = _paint_and_compact(delta, batch, accepted, commit_version, floor)
-    new_hist = HistState(base_h, base_st, delta)
+    new_hist = hist._replace(delta=delta)
     out = (verdicts, levels) if wave else (verdicts,)
     if report:
         losers = loser_range_mask(hist_mask, ranks, accepted, verdicts)
@@ -1538,7 +1648,7 @@ def advance_hist(hist: HistState, commit_version: jax.Array,
     floor = jnp.maximum(hist.delta.oldest, new_oldest)
     nb = _merge_delta(hist.base, hist.delta, floor)
     return HistState(nb, sparse_table(nb.versions),
-                     _reset_delta(hist.delta, floor))
+                     _reset_delta(hist.delta, floor), hist.merges + 1)
 
 
 # ---------------------------------------------------------------------------
@@ -1781,7 +1891,7 @@ def resolve_batch_hist_packed(
         (pb.write_mask & (pb.write_begin < pb.write_end)).astype(jnp.int32)
     )
     hist = _maybe_merge(hist, demand, floor)
-    base_h, base_st, delta = hist
+    base_h, base_st, delta, _ = hist
     rs_b, ls_b = _dict_history_search(base_h.keys, pb.dict_keys)
     rs_d, ls_d = _dict_history_search(delta.keys, pb.dict_keys)
     hist_mask = _history_conflict_ranges_hist_packed(
@@ -1795,7 +1905,7 @@ def resolve_batch_hist_packed(
     delta = _paint_and_compact_packed(
         delta, pb, accepted, commit_version, floor, rs_d
     )
-    new_hist = HistState(base_h, base_st, delta)
+    new_hist = hist._replace(delta=delta)
     out = (verdicts, levels) if wave else (verdicts,)
     if report:
         losers = loser_range_mask(hist_mask, ranks, accepted, verdicts)
@@ -1887,7 +1997,7 @@ def _advance_hist_jit(hist, commit_version, new_oldest):
 def _rebase_hist_jit(hist, delta_v):
     base = rebase(hist.base, delta_v)
     return HistState(base, sparse_table(base.versions),
-                     rebase(hist.delta, delta_v))
+                     rebase(hist.delta, delta_v), hist.merges)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -1906,17 +2016,33 @@ def _rebase_jit(state, delta):
 
 
 @jax.jit
-def _capacity_reading_jit(n_used, overflow):
-    """int32 [2] off a history's ``n_used`` / ``overflow`` leaves (one of
-    each for the plain history, base and delta for the window history):
-    boundary slots in use, the fullest shard of each summed, and whether
-    any overflow flag is up. The leaves are read, not donated, and the
-    result is no part of the state: enqueued behind a batch's last
-    dispatch it holds what THAT batch left, whatever is enqueued after it
-    (TPUConflictSet.resolve_async)."""
-    used = sum(jnp.max(u).astype(jnp.int32) for u in n_used)
+def _capacity_reading_jit(n_used, overflow, merges, frozen=None):
+    """int32 [3] off a history's ``n_used`` / ``overflow`` leaves (one of
+    each for the plain history, base and delta for the window history)
+    and the window history's ``merges`` (0 for the plain one): boundary
+    slots in use, the fullest shard of each summed, whether any overflow
+    flag is up, and the merges since boot. The leaves are read, not
+    donated, and the result is no part of the state: enqueued behind a
+    batch's last dispatch it holds what THAT batch left, whatever is
+    enqueued after it (TPUConflictSet.resolve_async).
+
+    ``frozen`` is the window history's (base.versions, live floor), and
+    the FIRST of ``n_used`` then its base's: the base is frozen between
+    merges, so it holds rows that expired since the last one, and the
+    next merge drops them before anything can overflow. They are no use
+    of the capacity: the base counts as that merge would leave it alone,
+    one slot where its versions, clamped at the floor, change (a merged
+    step function changes only where the clamped base or the delta does,
+    so this plus the delta's rows bounds what any merge keeps)."""
+    used = [jnp.max(u).astype(jnp.int32) for u in n_used]
+    if frozen is not None:
+        v, floor = frozen
+        v = jnp.where(v <= floor, NEG_VERSION, v)
+        steps = 1 + jnp.sum((v[1:] != v[:-1]).astype(jnp.int32))
+        used[0] = jnp.minimum(used[0], steps)
     over = functools.reduce(jnp.logical_or, [jnp.any(o) for o in overflow])
-    return jnp.stack([used, over.astype(jnp.int32)])
+    return jnp.stack([sum(used), over.astype(jnp.int32),
+                      jnp.asarray(merges, jnp.int32)])
 
 
 # -- wave-commit entry points (FDB_TPU_WAVE_COMMIT=1 engines) ---------------
@@ -2176,10 +2302,11 @@ def _shift_rank_vec(v: jax.Array, shift: jax.Array) -> jax.Array:
 
 def _shift_hist(hist, shift):
     if isinstance(hist, HistState):
-        return HistState(
-            hist.base._replace(keys=_shift_rank_rows(hist.base.keys, shift)),
-            hist.base_st,  # versions untouched — the RMQ table survives
-            hist.delta._replace(keys=_shift_rank_rows(hist.delta.keys, shift)),
+        return hist._replace(  # versions untouched: the RMQ table survives
+            base=hist.base._replace(
+                keys=_shift_rank_rows(hist.base.keys, shift)),
+            delta=hist.delta._replace(
+                keys=_shift_rank_rows(hist.delta.keys, shift)),
         )
     return hist._replace(keys=_shift_rank_rows(hist.keys, shift))
 
@@ -2275,10 +2402,9 @@ def apply_dict_remap(res: ResState, new_dict, new_n, remap) -> ResState:
 
     hist = res.hist
     if isinstance(hist, HistState):
-        hist = HistState(
-            hist.base._replace(keys=rr(hist.base.keys)),
-            hist.base_st,
-            hist.delta._replace(keys=rr(hist.delta.keys)),
+        hist = hist._replace(
+            base=hist.base._replace(keys=rr(hist.base.keys)),
+            delta=hist.delta._replace(keys=rr(hist.delta.keys)),
         )
     else:
         hist = hist._replace(keys=rr(hist.keys))
@@ -2441,7 +2567,7 @@ def _resolve_core_res(hist, rbk: RankBatch, commit_version, new_oldest,
                 )
             )
         hist = _maybe_merge(hist, demand, floor)
-        base_h, base_st, delta = hist
+        base_h, base_st, delta, _ = hist
         hist_mask = _history_conflict_ranges_hist_res(
             base_h, base_st, delta, rbk
         )
@@ -2458,7 +2584,7 @@ def _resolve_core_res(hist, rbk: RankBatch, commit_version, new_oldest,
         delta = _paint_and_compact_res(
             delta, rbk, accepted, commit_version, floor
         )
-        new_hist: ConflictState | HistState = HistState(base_h, base_st, delta)
+        new_hist: ConflictState | HistState = hist._replace(delta=delta)
     else:
         new_hist = _paint_and_compact_res(
             hist, rbk, accepted, commit_version, floor
@@ -2549,7 +2675,7 @@ def _rebase_res_jit(res, delta_v):
         base = rebase(hist.base, delta_v)
         # base versions shifted — the prebuilt RMQ table must follow.
         hist = HistState(base, sparse_table(base.versions),
-                         rebase(hist.delta, delta_v))
+                         rebase(hist.delta, delta_v), hist.merges)
     else:
         hist = rebase(hist, delta_v)
     return res._replace(hist=hist)
@@ -2697,11 +2823,11 @@ def wave_apply_batch_hist(
         .astype(jnp.int32)
     )
     hist = _maybe_merge(hist, demand, floor)
-    base_h, base_st, delta = hist
+    base_h, base_st, delta, _ = hist
     accepted, levels = wave_level_from_graph(cand, p)
     accepted = _accepted_rows(accepted, batch.cont)
     delta = _paint_and_compact(delta, batch, accepted, commit_version, floor)
-    return levels, HistState(base_h, base_st, delta)
+    return levels, hist._replace(delta=delta)
 
 
 def wave_apply_batch_packed(
@@ -2725,13 +2851,13 @@ def wave_apply_batch_hist_packed(
         (pb.write_mask & (pb.write_begin < pb.write_end)).astype(jnp.int32)
     )
     hist = _maybe_merge(hist, demand, floor)
-    base_h, base_st, delta = hist
+    base_h, base_st, delta, _ = hist
     accepted, levels = wave_level_from_graph(cand, p)
     accepted = _accepted_rows(accepted, pb.cont)
     delta = _paint_and_compact_packed(
         delta, pb, accepted, commit_version, floor
     )
-    return levels, HistState(base_h, base_st, delta)
+    return levels, hist._replace(delta=delta)
 
 
 def wave_apply_res(
@@ -2750,11 +2876,11 @@ def wave_apply_res(
             )
         )
         hist = _maybe_merge(hist, demand, floor)
-        base_h, base_st, delta = hist
+        base_h, base_st, delta, _ = hist
         delta = _paint_and_compact_res(
             delta, rbk, accepted, commit_version, floor
         )
-        new_hist: ConflictState | HistState = HistState(base_h, base_st, delta)
+        new_hist: ConflictState | HistState = hist._replace(delta=delta)
     else:
         floor = jnp.maximum(hist.oldest, new_oldest)
         new_hist = _paint_and_compact_res(
@@ -2851,10 +2977,10 @@ def paint_batch_hist_packed(hist: HistState, pb: PackedBatch, accepted,
         (pb.write_mask & (pb.write_begin < pb.write_end)).astype(jnp.int32)
     )
     hist = _maybe_merge(hist, demand, floor)
-    base_h, base_st, delta = hist
+    base_h, base_st, delta, _ = hist
     delta = _paint_and_compact_packed(delta, pb, accepted, commit_version,
                                       floor)
-    return HistState(base_h, base_st, delta)
+    return hist._replace(delta=delta)
 
 
 def paint_many_hist_packed(hist, pbs, accepted, commit_versions, new_oldests):
@@ -2878,10 +3004,10 @@ def _paint_core_res(hist, rbk: RankBatch, accepted, commit_version,
             )
         )
         hist = _maybe_merge(hist, demand, floor)
-        base_h, base_st, delta = hist
+        base_h, base_st, delta, _ = hist
         delta = _paint_and_compact_res(delta, rbk, accepted, commit_version,
                                        floor)
-        return HistState(base_h, base_st, delta)
+        return hist._replace(delta=delta)
     floor = jnp.maximum(hist.oldest, new_oldest)
     return _paint_and_compact_res(hist, rbk, accepted, commit_version, floor)
 

@@ -67,7 +67,8 @@ class _Collector:
     def reading(self) -> "tuple[int, bool] | None":
         if self._reading is None:
             return None
-        used, over = (int(x) for x in np.asarray(self._reading))
+        used, over, merges = (int(x) for x in np.asarray(self._reading))
+        self._engine.hist_merges = merges
         return self._engine._headroom_of(used), bool(over)
 
 
@@ -839,6 +840,12 @@ class TPUConflictSet:
         # reads the difference over a dispatch to know its pipeline
         # drained there.
         self.pack_syncs = 0
+        # The window history's merges since boot (ck.HistState.merges), as
+        # of the last capacity reading collected: a word of the reading a
+        # role fetches with every batch's verdicts, so no device read of
+        # its own. hist_merges / dispatches says how often a dispatch's
+        # paint did not fit the delta; advance() merges every time.
+        self.hist_merges = 0
         self._empty_dev_batch = None  # advance()'s constant batch, packed lazily
         # Admission subsystem (attach_admission_filter): a RecentWritesFilter
         # fed from each dispatch's ACCEPTED write sets using the endpoint
@@ -2406,18 +2413,26 @@ class TPUConflictSet:
                 if isinstance(self.state, ck.ResState)
                 else ck._advance_hist_jit)
 
-    def _enqueue_reading(self, commit_version: int = 0):
-        """Enqueue the capacity reading of the state as it stands, i.e.
-        as the last dispatch leaves it, and start its copy to the host.
-        int32 [2] on the device: (boundary slots in use, overflowed).
-        One more enqueue of the dispatch: stage ``engine_enqueue``."""
+    def _reading(self):
+        """The capacity reading of the state as it stands, enqueued:
+        int32 [3] on the device (boundary slots in use, overflowed, the
+        window history's merges since boot): ck._capacity_reading_jit."""
         st = self._hist_core
-        parts = (st.base, st.delta) if self._is_hist else (st,)
+        if not self._is_hist:
+            return ck._capacity_reading_jit(
+                (st.n_used,), (st.overflow,), np.int32(0))
+        return ck._capacity_reading_jit(
+            (st.base.n_used, st.delta.n_used),
+            (st.base.overflow, st.delta.overflow), st.merges,
+            (st.base.versions, st.delta.oldest))
+
+    def _enqueue_reading(self, commit_version: int = 0):
+        """Enqueue the capacity reading of the state as the last dispatch
+        leaves it, and start its copy to the host. One more enqueue of
+        the dispatch: stage ``engine_enqueue``."""
         with stage_timer(self.last_stage_s, "engine_enqueue",
                          commit_version):
-            out = ck._capacity_reading_jit(
-                tuple(p.n_used for p in parts),
-                tuple(p.overflow for p in parts))
+            out = self._reading()
             out.copy_to_host_async()
         return out
 
@@ -2444,20 +2459,18 @@ class TPUConflictSet:
         how the fixed-capacity engine earns the same guarantee.
 
         Window-history engine: a merge keeps at most base+delta live
-        boundaries, and the just-in-time merge empties the delta before a
-        dispatch that wouldn't fit — so admission needs room in the merged
-        base AND a delta that can absorb one whole DISPATCH. The delta is
+        boundaries (the base counted as that merge's GC would leave it:
+        rows that expired since the last merge use no capacity,
+        ck._capacity_reading_jit), and the just-in-time merge empties the
+        delta before a dispatch that wouldn't fit — so admission needs
+        room in the merged base AND a delta that can absorb one whole
+        DISPATCH. The delta is
         built to (``delta_capacity`` defaults to a full dispatch's worst
         case), and a batch of more rows than ``batch_size`` — 512 wide
         transactions, say — goes through it a dispatch at a time; only a
         delta configured smaller than that caps what a batch may bring.
         """
-        st = self._hist_core
-        if self._is_hist:
-            return self._headroom_of(
-                int(np.asarray(st.base.n_used).max())
-                + int(np.asarray(st.delta.n_used).max()))
-        return self._headroom_of(int(np.asarray(st.n_used).max()))
+        return self._headroom_of(int(np.asarray(self._reading())[0]))
 
     def _headroom_of(self, used: int) -> int:
         """headroom() given the boundary slots in use."""
@@ -2477,14 +2490,15 @@ class TPUConflictSet:
         reacted — see Resolver's unsafe-window handling)."""
         hc = self._hist_core
         if self._is_hist:
-            base, st, delta = hc
-            new = ck.HistState(
-                base._replace(overflow=base.overflow & False),
-                st,
-                delta._replace(overflow=delta.overflow & False),
+            new = hc._replace(
+                base=hc.base._replace(overflow=hc.base.overflow & False),
+                delta=hc.delta._replace(overflow=hc.delta.overflow & False),
             )
         else:
             new = hc._replace(overflow=hc.overflow & False)
+        self._set_hist_core(new)
+
+    def _set_hist_core(self, new) -> None:
         if isinstance(self.state, ck.ResState):
             self.state = self.state._replace(hist=new)
         else:
@@ -2566,11 +2580,19 @@ class TPUConflictSet:
             identity = np.arange(mir.capacity + 1, dtype=np.int32)
             steps["repack"] = lambda: self._repack_fn(
                 self.state, dict_dev, np.int32(mir.n), identity)
+        merges = np.asarray(self._hist_core.merges) if self._is_hist else None
         seconds: dict[str, float] = {}
         for name, step in steps.items():
             t0 = _perf_counter()
             self.state = jax.block_until_ready(step())
             seconds[name] = round(_perf_counter() - t0, 3)
+        if merges is not None:
+            # The advance step merged, and a warm-up is none of the count's.
+            # Placed as init_hist placed it (on no named device): an array
+            # put on one by name is another argument type to every compiled
+            # entry point, and the first batch would compile them again.
+            self._set_hist_core(self._hist_core._replace(
+                merges=jax.numpy.asarray(merges)))
         return seconds
 
     def device_info(self) -> dict:

@@ -1331,6 +1331,11 @@ class Resolver:
                 "dispatches": self._engine_dict_stat("dispatches"),
                 "delta_empty_dispatches": self._engine_dict_stat(
                     "delta_empty_dispatches"),
+                # The window history's merges (delta folded into base) as
+                # of the last batch collected with its reading: over
+                # `dispatches`, how often a dispatch's paint did not fit
+                # the delta. 0 for engines without a window history.
+                "hist_merges": getattr(self.cs, "hist_merges", 0),
                 "compiles": self._engine_dict_stat("compiles"),
                 "compile_s": self._engine_dict_fstat("compile_s"),
                 # Keys longer than the codec's max_key_bytes, which it

@@ -8,7 +8,11 @@ overlap engaged.
 standard error each time the run reads the resolver's counters:
 
     overlap {"batches_resolved": ..., "batches_overlapped": ...,
-             "pipeline_drains": {...}, "share": ...}
+             "pipeline_drains": {...}, "share": ...,
+             "dispatches": ..., "hist_merges": ..., "merges_per_dispatch": ...}
+
+(the last three where the program's engine counts the window history's
+merges: how often a dispatch's paint did not fit the delta, PR 41)
 
 summed over the cell's resolvers, cumulative since boot (the last such
 line is the whole run's). `benchmark/lib/observe.py` reads a fixed tuple
@@ -41,11 +45,18 @@ def _report(metrics: list) -> None:
             drains[cause] = drains.get(cause, 0) + n
     resolved = sum(m["batches_resolved"] for m in metrics)
     overlapped = sum(m["batches_overlapped"] for m in metrics)
-    print("overlap " + json.dumps({
+    out = {
         "batches_resolved": resolved, "batches_overlapped": overlapped,
         "pipeline_drains": drains,
         "share": round(overlapped / resolved, 4) if resolved else None,
-    }), file=sys.stderr, flush=True)
+    }
+    if all("hist_merges" in m["engine"] for m in metrics):
+        dispatches = sum(m["engine"]["dispatches"] for m in metrics)
+        merges = sum(m["engine"]["hist_merges"] for m in metrics)
+        out.update(dispatches=dispatches, hist_merges=merges,
+                   merges_per_dispatch=round(merges / dispatches, 4)
+                   if dispatches else None)
+    print("overlap " + json.dumps(out), file=sys.stderr, flush=True)
 
 
 def _wrap(cls) -> None:
@@ -62,11 +73,11 @@ def _wrap(cls) -> None:
 
 def main() -> int:
     from benchmark import run
-    from benchmark.drivers import resolver_replay_mako
+    from benchmark.drivers import resolver_replay_mako, resolver_replay_tpcc
     from benchmark.lib import observe, observe_nr
 
     for cls in (observe.Observer, observe_nr.ObserverNR,
-                resolver_replay_mako.Observer):
+                resolver_replay_mako.Observer, resolver_replay_tpcc.Observer):
         _wrap(cls)
     return run.main()
 

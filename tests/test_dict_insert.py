@@ -191,9 +191,9 @@ def test_apply_delta_rebases_two_level_history_and_shard_bounds():
     delta_ranks = np.sort(rng.choice(n, size=20, replace=False))
     delta_ranks[0] = 0
     hist = res.hist
-    res = res._replace(hist=ck.HistState(
-        with_ranks(hist.base, base_ranks), hist.base_st,
-        with_ranks(hist.delta, delta_ranks)))
+    res = res._replace(hist=hist._replace(
+        base=with_ranks(hist.base, base_ranks),
+        delta=with_ranks(hist.delta, delta_ranks)))
 
     out = jax.jit(ck.apply_delta)(
         res, delta_keys,
