@@ -259,6 +259,7 @@ def four_chip_phase(capacity: int = FOUR_CHIP_CAPACITY,
     import __graft_entry__
     import bench
     from foundationdb_tpu.parallel.sharded_resolver import ShardedConflictSet
+    from foundationdb_tpu.server import make_conflict_set
 
     def placement(cs) -> list[list[int]]:
         """Device ids per sharded leaf; each must hold one shard on each
@@ -278,11 +279,16 @@ def four_chip_phase(capacity: int = FOUR_CHIP_CAPACITY,
     dry = __graft_entry__.dryrun_multichip(4)
     placement(dry)
     mode, blob, ends, ref = _ycsb_stream(n_batches, n_keys, batch, seed)
-    cs = ShardedConflictSet(
-        n_shards=4, capacity=capacity, batch_size=mode.batch,
+    # Through the factory the resolver role is served from (a spec with
+    # "resolver_mesh": 4), at this phase's sizes: what is proved here is
+    # the construction that is deployed.
+    cs = make_conflict_set(
+        "tpu", mesh=4, capacity=capacity, batch_size=mode.batch,
         max_read_ranges=mode.n_reads, max_write_ranges=mode.n_writes,
         max_key_bytes=bench.KEY_BYTES, window_versions=bench.WINDOW,
     )
+    check(type(cs) is ShardedConflictSet and cs.n_shards == 4,
+          f"the factory built {type(cs).__name__} for mesh=4")
     check(cs.auto_reshard, "auto_reshard is no longer the default")
     placed_before = placement(cs)
     got, window_s = _resolve_stream(cs, mode, blob, ends, n_batches, window)
@@ -365,11 +371,14 @@ def served_phase(workdir: str, n_keys: int = SERVED_KEYS,
                  keys_per_txn: int = SERVED_KEYS_PER_TXN,
                  rate: float = SERVED_RATE,
                  duration_s: float = SERVED_DURATION_S,
-                 env: "dict | None" = None, seed: int = SEED) -> dict:
+                 env: "dict | None" = None, seed: int = SEED,
+                 mesh: "int | None" = None) -> dict:
     """A store that loads data and answers queries, the resolver on the
     device. Guarantees held to: strict serializability (the conflicting
     pair), durability (tlogs fsync before the ack: data_dirs=True) and
-    two-way replication (both replicas hold every acknowledged write)."""
+    two-way replication (both replicas hold every acknowledged write).
+    ``mesh``: the spec's `resolver_mesh`, the one resolver's history
+    sharded over that many chips."""
     from foundationdb_tpu.consistency import run_deployed_check
     from foundationdb_tpu.core.errors import NotCommitted
     from foundationdb_tpu.loadgen.deploy import REPO, SocketCluster
@@ -382,10 +391,13 @@ def served_phase(workdir: str, n_keys: int = SERVED_KEYS,
     def value_of(i: int) -> bytes:
         return (b"%012d" % i).ljust(SERVED_VALUE_BYTES, b".")
 
+    spec_extra = {"replicas": 2}
+    if mesh:
+        spec_extra["resolver_mesh"] = mesh
     t_boot = time.monotonic()
     with SocketCluster(workdir, proxies=2, tlogs=2, storages=2, resolvers=1,
                        ratekeeper=True, engine="tpu", data_dirs=True,
-                       spec_extra={"replicas": 2}, env=env) as cluster:
+                       spec_extra=spec_extra, env=env) as cluster:
         out["boot_s"] = round(time.monotonic() - t_boot, 1)
         with open(os.path.join(workdir, "resolver0.log")) as f:
             out["resolver_log"] = [ln.strip() for ln in f
@@ -540,6 +552,8 @@ def served_phase(workdir: str, n_keys: int = SERVED_KEYS,
             out["resolver"]["commits"] = commits
             out["resolver"]["dictionary_full_repacks"] = (
                 metrics["engine"]["full_repacks"])
+            out["resolver"]["auto_reshards"] = (
+                metrics["engine"]["auto_reshards"])
             check(metrics["txns_resolved"] >= commits
                   and metrics["overflow_events"] == 0
                   and metrics["txns_rejected_fail_safe"] == 0

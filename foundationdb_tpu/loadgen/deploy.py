@@ -254,7 +254,10 @@ class SocketCluster:
         inherits the caller's environment as it stands (a caller that
         wants that resolver on the CPU backend says JAX_PLATFORMS=cpu
         itself, and the role refuses to boot on a CPU it was not told
-        about). With several such resolvers on this host each is bound to
+        about). A LONE such resolver therefore sees every chip of the
+        host, which is what a spec with `resolver_mesh` counts on: its
+        one process shards its history over them (server.resolver_mesh).
+        With several such resolvers on this host each is bound to
         the chip of its own index — libtpu's TPU_VISIBLE_CHIPS, with the
         process bounds that make one chip a whole topology — and one
         whose chip does not exist fails its boot, which fails start().

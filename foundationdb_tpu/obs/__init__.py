@@ -65,6 +65,7 @@ from foundationdb_tpu.obs.selfcheck import (
 )
 from foundationdb_tpu.obs.span import (
     ENGINE_STAGES,
+    MESH_ENGINE_STAGES,
     READ_STAGES,
     SUB_STAGES,
     TXN_STAGES,
@@ -81,6 +82,7 @@ __all__ = [
     "CHAOS_DOCUMENTED_COUNTERS",
     "DOCUMENTED_COUNTERS",
     "ENGINE_STAGES",
+    "MESH_ENGINE_STAGES",
     "FlightRecorder",
     "MetricsPoller",
     "MetricsRegistry",

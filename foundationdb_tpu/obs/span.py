@@ -88,9 +88,11 @@ reconciliation identity):
                       host_pack. What runs between the two halves, the
                       successor's host half, is `overlap`, not this.
                       Despite the name it is host AND device time: the
-                      stages below are its interior. (Under the global
-                      wave protocol: both phases' engine work, edges +
-                      level/paint.)
+                      stages below are its interior, the mesh engine's
+                      reshard_probe and reshard among them, in the
+                      dispatch half of the batch whose dispatch made
+                      them. (Under the global wave protocol: both
+                      phases' engine work, edges + level/paint.)
     dict_rank         engine: endpoints -> u64, mirror probe, delta
                       build, insert_new, ranks (_pack_resident without
                       its repack; _pack_dict)
@@ -114,6 +116,17 @@ reconciliation identity):
     resolve_post      engine + resolver: verdict list, loser ranges,
                       admission feed (_collect after the wait), then
                       hot ranges, filter feed, counters (_finish_entry)
+    reshard_probe     mesh engine only (ShardedConflictSet, the spec's
+                      `resolver_mesh`): every AUTO_RESHARD_INTERVAL-th
+                      dispatch, the device_get of the shards' rows in
+                      use before the batch is packed. It waits for
+                      whatever the device still runs: the bubble the
+                      default policy costs. One sample a probe, on the
+                      batch whose dispatch made it
+    reshard           mesh engine only: one re-split, the quantiles of
+                      the live history and the move of rows between
+                      chips included (history down, re-clipped on the
+                      host, up). One sample a re-split
     engine_unattributed  resolver: the bracket minus every stage above;
                       recorded, never dropped
     overlap           resolver: the end of a batch's dispatch half to the
@@ -210,6 +223,7 @@ between the batch's two halves:
     host_pack + device_dispatch ==
         host_pack + dict_rank + dict_repack + engine_enqueue
         + verdict_wait + headroom_sync + resolve_post
+        + reshard_probe + reshard
         + engine_unattributed
 
 The engine fills one per-dispatch record of stage seconds
@@ -268,6 +282,8 @@ SUB_STAGES = (
     "verdict_wait",
     "headroom_sync",
     "resolve_post",
+    "reshard_probe",
+    "reshard",
     "engine_unattributed",
     "overlap",
     "wave_exchange",
@@ -288,6 +304,16 @@ ENGINE_STAGES = (
     "verdict_wait",
     "headroom_sync",
     "resolve_post",
+)
+
+#: What the mesh engine's split policy adds to that interior, on the
+#: batches whose dispatch looked or moved (ShardedConflictSet.
+#: _maybe_auto_reshard); no other engine records them. The identity over
+#: a mesh is  host_pack + device_dispatch == sum(ENGINE_STAGES)
+#: + sum(MESH_ENGINE_STAGES) + engine_unattributed.
+MESH_ENGINE_STAGES = (
+    "reshard_probe",
+    "reshard",
 )
 
 #: Read-plane batch-level stages (foundationdb_tpu/reads/): stamped via

@@ -35,6 +35,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from foundationdb_tpu.core.keypack import INT32_MAX, KeyCodec, row_sort_keys
 from foundationdb_tpu.core.types import TxnConflictInfo
 from foundationdb_tpu.models import conflict_kernel as ck
+from foundationdb_tpu.obs.span import stage_timer
 from foundationdb_tpu.ops.bitset import pack_bits_u32, unpack_bits_u32
 from foundationdb_tpu.models.conflict_set import (
     TPUConflictSet,
@@ -77,16 +78,24 @@ def density_splits(n_shards: int, sample_keys: list[bytes]) -> list[bytes]:
     pathological). Falls back to uniform prefixes when the sample is too
     small or too concentrated to yield n_shards distinct quantiles."""
     ks = sorted(set(sample_keys))
-    if len(ks) < 2 * n_shards:
+    interior = _quantiles(n_shards, ks)
+    if interior is None or interior[0] == b"":
         return interior_uniform(n_shards)
-    interior: list[bytes] = []
+    return interior
+
+
+def _quantiles(n_shards: int, ks) -> "list | None":
+    """The n_shards-1 interior quantiles of a SORTED, UNIQUE population
+    (keys, or ranks of a sorted dictionary: the rule is one); None where
+    it is too small or too concentrated to give that many distinct ones."""
+    if len(ks) < 2 * n_shards:
+        return None
+    interior: list = []
     for d in range(1, n_shards):
         q = ks[(d * len(ks)) // n_shards]
         if interior and q <= interior[-1]:
-            return interior_uniform(n_shards)  # degenerate sample
+            return None  # degenerate sample
         interior.append(q)
-    if interior[0] == b"":
-        return interior_uniform(n_shards)
     return interior
 
 
@@ -107,20 +116,10 @@ def _sharded_resolve(state, batch, commit_version, new_oldest, lo, hi,
 
     floor, too_old = ck.too_old_mask(state, batch, new_oldest)
 
-    local = ck.clip_batch(batch, lo, hi)
+    with jax.named_scope("shard_clip"):
+        local = ck.clip_batch(batch, lo, hi)
     hist_local = ck._history_conflicts(state, local)
-    b = hist_local.shape[0]
-    if ck._PACKED and b % 32 == 0:
-        # Packed masks across the mesh combine (FDB_TPU_PACKED): the
-        # per-shard conflict verdicts cross ICI as a uint32 bitset —
-        # B/32 words per device instead of B int32 lanes, a 32x byte cut
-        # on the reduction the proxy-AND step pays every batch. OR of
-        # bitsets isn't a psum/pmax, so all_gather the packed words (D
-        # small) and fold locally.
-        gathered = jax.lax.all_gather(pack_bits_u32(hist_local), AXIS)
-        hist_conflict = jnp.any(unpack_bits_u32(gathered, b), axis=0)
-    else:
-        hist_conflict = jax.lax.psum(hist_local.astype(jnp.int32), AXIS) > 0
+    hist_conflict = _sum_over_shards(hist_local, packed=ck._PACKED)
 
     # Intra-batch acceptance is a pure function of the (unclipped) batch
     # plus the psum'd history verdicts, so every device computes it
@@ -157,6 +156,23 @@ def _sharded_resolve(state, batch, commit_version, new_oldest, lo, hi,
     return verdicts, new_state
 
 
+@jax.named_scope("shard_psum")
+def _sum_over_shards(hist_local, packed: bool = True):
+    """bool [B]: did ANY shard's history conflict with the row — the
+    tensor analogue of the proxy ANDing per-resolver verdicts, taken
+    BEFORE acceptance and paint, so every shard paints what ONE history
+    would have accepted. Packed (FDB_TPU_PACKED, and always in rank
+    space), the per-shard bits cross ICI as a uint32 bitset: B/32 words a
+    device instead of B int32 lanes, a 32x byte cut on the reduction
+    every batch pays. OR of bitsets isn't a psum/pmax, so all_gather the
+    packed words (D small) and fold locally."""
+    b = hist_local.shape[0]
+    if packed and b % 32 == 0:
+        gathered = jax.lax.all_gather(pack_bits_u32(hist_local), AXIS)
+        return jnp.any(unpack_bits_u32(gathered, b), axis=0)
+    return jax.lax.psum(hist_local.astype(jnp.int32), AXIS) > 0
+
+
 def _wave_exchange_and_level(base, clipped_ranks, cont=None):
     """Shared mesh wave body (runs under shard_map): clipped per-shard
     predecessor tiles -> packed all_gather -> OR-reduce -> replicated
@@ -186,7 +202,8 @@ def _wave_exchange_and_level(base, clipped_ranks, cont=None):
     return accepted, levels, stats
 
 
-def _res_shard_step(hist, lo, hi, rbk, commit_version, new_oldest, wave):
+def _res_shard_step(hist, lo, hi, rbk, commit_version, new_oldest, wave,
+                    report=False):
     """One resident-mode per-shard resolve step (runs under shard_map).
 
     hist: the local shard's width-1 rank-space history; lo/hi: the shard's
@@ -194,16 +211,17 @@ def _res_shard_step(hist, lo, hi, rbk, commit_version, new_oldest, wave):
     dictionary inserts). The batch is replicated rank tensors; clipping is
     scalar int32 (clip_ranks), the cross-shard combine is the same packed
     all_gather as the full-key body, and acceptance runs replicated on the
-    UNCLIPPED batch exactly as before."""
+    UNCLIPPED batch exactly as before. `report` (static; sequential
+    order only) also returns the conflicting-keys report's loser mask,
+    replicated: each shard's per-range history bits summed over the mesh
+    (a range conflicts where any shard's slice of it does), then
+    conflict_kernel.loser_range_mask on the unclipped batch, as one chip
+    computes it."""
     floor, too_old = ck.too_old_mask_packed(hist, rbk, new_oldest)
-    local = ck.clip_ranks(rbk, lo, hi)
-    hist_local = ck._history_conflicts_res(hist, local)
-    b = hist_local.shape[0]
-    if b % 32 == 0:
-        gathered = jax.lax.all_gather(pack_bits_u32(hist_local), AXIS)
-        hist_conflict = jnp.any(unpack_bits_u32(gathered, b), axis=0)
-    else:
-        hist_conflict = jax.lax.psum(hist_local.astype(jnp.int32), AXIS) > 0
+    with jax.named_scope("shard_clip"):
+        local = ck.clip_ranks(rbk, lo, hi)
+    hist_mask = ck._history_conflict_ranges_res(hist, local)
+    hist_conflict = _sum_over_shards(jnp.any(hist_mask, axis=1))
     base = rbk.txn_mask & ~too_old & ~hist_conflict
     stats = None
     if wave:
@@ -222,10 +240,17 @@ def _res_shard_step(hist, lo, hi, rbk, commit_version, new_oldest, wave):
     new_hist = ck._paint_and_compact_res(
         hist, local, accepted, commit_version, floor
     )
+    if report:
+        with jax.named_scope("shard_psum"):
+            mask = jax.lax.psum(hist_mask.astype(jnp.int32), AXIS) > 0
+        losers = ck.loser_range_mask(
+            mask, ck.endpoint_ranks_live_packed(rbk), accepted, verdicts)
+        return verdicts, ck.pack_loser_mask(losers), new_hist
     return verdicts, levels, stats, new_hist
 
 
-def _sharded_resolve_res(res, rb, commit_version, new_oldest, wave=False):
+def _sharded_resolve_res(res, rb, commit_version, new_oldest, wave=False,
+                         report=False):
     """Resident mesh body: replicated dictionary-delta insert (every device
     takes the same host-shipped ranks, rb.delta_cross, and computes the
     identical merged dictionary), per-shard rank-rebase of histories AND
@@ -238,11 +263,14 @@ def _sharded_resolve_res(res, rb, commit_version, new_oldest, wave=False):
         shard_hi=res.shard_hi,
     )
     local = ck.apply_delta(local, rb.delta_keys, rb.delta_cross)
-    verdicts, levels, stats, new_hist = _res_shard_step(
+    *out, new_hist = _res_shard_step(
         local.hist, local.shard_lo[0], local.shard_hi[0], rb.ranks,
-        commit_version, new_oldest, wave,
+        commit_version, new_oldest, wave, report,
     )
     new_res = local._replace(hist=jax.tree.map(lambda x: x[None], new_hist))
+    if report:
+        return (*out, new_res)  # verdicts, packed loser mask
+    verdicts, levels, stats = out
     if wave:
         return verdicts, levels, stats, new_res
     return verdicts, new_res
@@ -330,6 +358,16 @@ class ShardedConflictSet(TPUConflictSet):
         self.reshard_interval = max(1, reshard_interval)
         self.reshard_skew = reshard_skew
         self.auto_reshards = 0  # re-splits the default policy performed
+        # The default policy's own cost, for the role's get_metrics():
+        # occupancy probes made and the seconds they waited for the
+        # device, the seconds re-splits took (quantiles and move), and
+        # the shards' rows in use as the last probe or re-split left
+        # them: read off what the policy fetched anyway, never a device
+        # read of its own.
+        self.reshard_probes = 0
+        self.reshard_probe_s = 0.0
+        self.reshard_s = 0.0
+        self.shard_rows_in_use = [1] * (n_shards or mesh.devices.size)
         self._dispatches = 0
         # Wave-exchange economics (wave_commit engines): per-dispatch
         # (occupied tiles, dense tiles) device scalars, folded lazily by
@@ -403,14 +441,22 @@ class ShardedConflictSet(TPUConflictSet):
     # -- density resharding as the default policy ----------------------------
 
     def resolve_async(self, txns, commit_version, oldest_version=None):
-        self._maybe_auto_reshard()
+        self._maybe_auto_reshard(commit_version)
         return super().resolve_async(txns, commit_version, oldest_version)
 
     def resolve_wire_async(self, wire, commit_version, oldest_version=None,
                            count=None, as_array=False):
-        self._maybe_auto_reshard()
+        self._maybe_auto_reshard(commit_version)
         return super().resolve_wire_async(
             wire, commit_version, oldest_version, count, as_array)
+
+    def advance(self, commit_version, oldest_version=None):
+        # The role's fail-safe advances instead of resolving, and it
+        # engages on the FULLEST shard: a skewed split would otherwise
+        # hold it there until the MVCC window slid, with three shards
+        # all but empty. The policy looks here too.
+        self._maybe_auto_reshard(commit_version)
+        return super().advance(commit_version, oldest_version)
 
     def dispatch_window(self, prepared):
         # Dispatch-thread hook (the window path packs on a worker thread).
@@ -422,31 +468,41 @@ class ShardedConflictSet(TPUConflictSet):
         self._maybe_auto_reshard()
         return super().dispatch_window(prepared)
 
-    def _maybe_auto_reshard(self) -> None:
+    def _maybe_auto_reshard(self, commit_version: int = 0) -> None:
         """Between dispatches: if per-shard occupancy skew exceeds the
         threshold, move the bounds to the live-history quantiles. Runs on
-        the dispatching thread with no dispatch in flight; device_get
-        inside reshard() blocks on the previous dispatch's state.
+        the dispatching thread, in front of the dispatch of
+        ``commit_version``; a batch the served role holds in flight keeps
+        its own verdicts and reading, and the state it left is what is
+        probed and moved: the device_get blocks on it.
 
         Cost note: the occupancy probe is a device_get of n_used [D]
         int32, which synchronizes with the previous dispatch — one
         pipeline bubble every reshard_interval windows even when skew is
         under threshold. That is the price of the default; latency-A/B
         harnesses that must not pay it pass auto_reshard=False (bench
-        does)."""
+        does). Both are stages of the dispatch in hand (obs/span.py:
+        ``reshard_probe``, ``reshard``) and counted (reshard_probes,
+        reshard_probe_s, reshard_s)."""
         if not self.auto_reshard:
             return
         self._dispatches += 1
         if self._dispatches % self.reshard_interval:
             return
-        occ = self.shard_occupancy()
+        with stage_timer(self.last_stage_s, "reshard_probe",
+                         commit_version) as probe:
+            occ = self.shard_occupancy()
+        self.reshard_probes += 1
+        self.reshard_probe_s += probe.seconds
         if max(occ) <= self.reshard_skew * max(1, min(occ)):
             return
-        splits = self.density_splits_from_history()
-        if splits is None:
-            return
-        self.reshard(splits)
-        self.auto_reshards += 1
+        with stage_timer(self.last_stage_s, "reshard",
+                         commit_version) as move:
+            splits = self.density_splits_from_history()
+            if splits is not None:
+                self.reshard(splits)
+                self.auto_reshards += 1
+        self.reshard_s += move.seconds
 
     def density_splits_from_history(self) -> "list[bytes] | None":
         """Interior split keys at the quantiles of the LIVE history
@@ -456,11 +512,10 @@ class ShardedConflictSet(TPUConflictSet):
         None when the history is too small or too concentrated to yield
         n_shards-1 distinct interior keys (density_splits' uniform
         fallback means "don't move the bounds" here)."""
-        st = jax.device_get(self._hist_core)
-        keys = np.asarray(st.keys)
-        n_used = np.asarray(st.n_used)
+        hc = self._hist_core
+        keys, n_used = (np.asarray(x)
+                        for x in jax.device_get((hc.keys, hc.n_used)))
         nw = self.codec.n_words
-        sample: list[bytes] = []
         if self.resident:
             # Rank-space history: boundary ranks map to key bytes through
             # the mirror — which also means every candidate split key is
@@ -472,26 +527,36 @@ class ShardedConflictSet(TPUConflictSet):
             # ranks, which at worst maps a boundary to a NEIGHBORING
             # resident key — a load-balance skew, never a wrong verdict
             # (any resident key is a legal split).
+            # The shards' live prefixes are the global sorted boundary
+            # list, and rank order is key order: the quantiles are taken
+            # over the ranks (_quantiles, the rule density_splits
+            # applies to keys) and only the n_shards-1 chosen rows are
+            # unpacked — a history of a quarter million rows costs
+            # numpy passes, not a Python loop, in the resolver's thread.
             mir = self._mirror
             with mir.lock:
                 rows = mir.rows
-                n_mir = len(rows)
-                for d in range(self.n_shards):
-                    for r in keys[d, : int(n_used[d]), 0]:
-                        r = int(r)
-                        if r >= n_mir or int(rows[r][nw]) >= int(INT32_MAX):
-                            continue
-                        sample.append(self.codec.unpack(rows[r]))
+                ranks = np.concatenate([
+                    keys[d, : int(n_used[d]), 0]
+                    for d in range(self.n_shards)]).astype(np.int64)
+                ranks = ranks[(ranks >= 0) & (ranks < len(rows))]
+                ranks = np.unique(
+                    ranks[rows[ranks, nw] < int(INT32_MAX)])
+                picks = _quantiles(self.n_shards, ranks)
+                if picks is None:
+                    return None
+                splits = [self.codec.unpack(rows[int(r)]) for r in picks]
         else:
-            for d in range(self.n_shards):
-                for row in keys[d, : int(n_used[d])]:
-                    if int(row[nw]) >= int(ck.INT32_MAX):
-                        continue  # +inf sentinel cannot be a split key
-                    sample.append(self.codec.unpack(row))
-        if len(sample) < 2 * self.n_shards:
+            sample = [
+                self.codec.unpack(row)
+                for d in range(self.n_shards)
+                for row in keys[d, : int(n_used[d])]
+                if int(row[nw]) < int(ck.INT32_MAX)  # +inf is no split key
+            ]
+            splits = density_splits(self.n_shards, sample)
+        if splits[0] == b"" or splits == interior_uniform(self.n_shards):
             return None
-        splits = density_splits(self.n_shards, sample)
-        return None if splits == interior_uniform(self.n_shards) else splits
+        return splits
 
     def _init_engine(self) -> None:
         if self.batch_size % self.n_shards:
@@ -664,15 +729,28 @@ class ShardedConflictSet(TPUConflictSet):
         self._rebase_fn = ck._rebase_res_jit
         self._repack_fn = ck._repack_res_jit
         self._evict_fn = ck._evict_res_jit
-        self._resolve_report_fn = None
+        # The conflicting-keys report, as the one-chip engine serves it
+        # (exact loser ranges); a wave engine keeps the resolver-side
+        # conservative superset (runtime/resolver.py).
+        self._resolve_report_fn = None if wave else jax.jit(
+            _shard_map(
+                functools.partial(_sharded_resolve_res, report=True),
+                mesh=self.mesh,
+                in_specs=(state_specs, batch_specs, P(), P()),
+                out_specs=(P(), P(), state_specs),
+            ),
+            donate_argnums=(0,),
+        )
 
     def shard_occupancy(self) -> list[int]:
         """Live history boundary count per shard — the load-balance signal
         the density splits are judged by."""
-        return [
+        occ = [
             int(x)
             for x in np.asarray(jax.device_get(self._hist_core.n_used))
         ]
+        self.shard_rows_in_use = occ
+        return occ
 
     def reshard(self, splits: list[bytes]) -> None:
         """Re-split the keyspace between dispatch windows.
@@ -707,6 +785,7 @@ class ShardedConflictSet(TPUConflictSet):
             overflow=jax.device_put(np.asarray(st.overflow) | nover, shard),
         )
         self._interior_splits = list(splits)
+        self.shard_rows_in_use = [int(x) for x in nu]
         self._lo, self._hi = lo, hi
         self._lo_dev = jax.device_put(lo, shard)
         self._hi_dev = jax.device_put(hi, shard)
@@ -726,7 +805,12 @@ class ShardedConflictSet(TPUConflictSet):
         explicit reshard()."""
         mir = self._mirror
         with mir.lock:
-            st = jax.device_get(self.state)
+            # The replicated dictionary stays where it is unless a bound
+            # key has to be inserted (never on the auto path): only the
+            # histories and the bounds come down and go back up.
+            live = self.state
+            st = live._replace(dict_keys=None, n_keys=None)
+            st = jax.device_get(st)
             keys = np.asarray(st.hist.keys)  # [S, C, 1] int32 ranks
             vers = np.asarray(st.hist.versions)
             n_used = np.asarray(st.hist.n_used).astype(np.int64)
@@ -823,13 +907,12 @@ class ShardedConflictSet(TPUConflictSet):
 
             shard = self._shard_sharding
             repl = NamedSharding(self.mesh, P())
+            self.shard_rows_in_use = [int(x) for x in new_used]
             self.state = ck.ResState(
-                dict_keys=jax.device_put(
-                    dict_dev if dict_dev is not None
-                    else np.asarray(st.dict_keys),
-                    repl,
-                ),
-                n_keys=jax.device_put(np.int32(mir.n), repl),
+                dict_keys=(jax.device_put(dict_dev, repl)
+                           if dict_dev is not None else live.dict_keys),
+                n_keys=(jax.device_put(np.int32(mir.n), repl)
+                        if dict_dev is not None else live.n_keys),
                 hist=ck.ConflictState(
                     keys=jax.device_put(new_keys, shard),
                     versions=jax.device_put(new_vers, shard),

@@ -24,6 +24,7 @@ from foundationdb_tpu.core.types import (
 )
 from foundationdb_tpu.obs.span import (
     ENGINE_STAGES,
+    MESH_ENGINE_STAGES,
     span_now,
     span_sink,
     stage_clock,
@@ -886,7 +887,7 @@ class Resolver:
         if wall:
             # Its interior, and the residue: the engine identity
             # (obs/span.py), by arithmetic per batch.
-            for stage in ENGINE_STAGES[1:]:
+            for stage in ENGINE_STAGES[1:] + MESH_ENGINE_STAGES:
                 stage_s = rec.get(stage)
                 if stage_s is not None:
                     sink.stage_tick(stage, stage_s, n=n, version=version)
@@ -1309,6 +1310,19 @@ class Resolver:
                 "auto_reshards": getattr(self.cs, "auto_reshards", 0),
                 "reshard_moved_shards": getattr(
                     self.cs, "reshard_moved_shards", 0),
+                # What the mesh engine's default split policy costs
+                # (ShardedConflictSet._maybe_auto_reshard; 0 and [] for
+                # every other engine): occupancy probes, each a device
+                # read that waits for the last dispatch, and their
+                # seconds; the seconds of the re-splits themselves; and
+                # the history rows in use a shard, as the last probe or
+                # re-split read them (the role's headroom is the
+                # FULLEST shard's: engine.headroom()).
+                "reshard_probes": getattr(self.cs, "reshard_probes", 0),
+                "reshard_probe_s": getattr(self.cs, "reshard_probe_s", 0.0),
+                "reshard_s": getattr(self.cs, "reshard_s", 0.0),
+                "shard_rows_in_use": list(getattr(
+                    self.cs, "shard_rows_in_use", ())),
                 "full_repacks": self._engine_dict_stat("full_repacks"),
                 "evictions": self._engine_dict_stat("evictions"),
                 # WHY the dictionary repacked (the arms of the engine's

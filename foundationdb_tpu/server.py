@@ -36,7 +36,11 @@ failure/recovery testing; this is the deployment data plane):
   them and KeyShardMap.uniform over the resolver count where it does not.
   `uniform` splits by FIRST BYTE (0x40, 0x80, 0xC0 for four): even for
   keys spread over the byte range, and one resolver's work for a key set
-  that shares a prefix — such a deployment states its splits.
+  that shares a prefix — such a deployment states its splits. Or it
+  lists ONE resolver and says `"resolver_mesh": N` (engine "tpu"): that
+  process shards its history over N chips of its host and moves the
+  splits itself (`resolver_mesh`, `make_conflict_set`); the proxies see
+  one resolver and clip nothing.
 
 Service names are unindexed ("sequencer", "tlog", ...): the address
 already identifies the instance. The ReadRouter is also served under the
@@ -67,6 +71,7 @@ def load_spec(path: str) -> dict:
             raise ValueError(f"cluster spec missing role {role!r}")
     _validate_regions(spec)
     resolver_shard_map(spec)  # bad resolver_splits fail every boot
+    resolver_mesh(spec)  # and so does a mesh the deployment cannot have
     # Resolve key-material paths against the cluster file's directory at
     # LOAD time (the one choke point every entry point — server, cli,
     # dr_tool, tests — goes through), so consumers never depend on cwd.
@@ -212,6 +217,45 @@ def resolver_shard_map(spec: dict,
     return KeyShardMap(keys, tags=list(range(len(keys) + 1)))
 
 
+def resolver_mesh(spec: dict) -> "int | None":
+    """How many chips the spec's ONE resolver spans: `resolver_mesh`, N >= 2
+    — upstream's `configure resolvers=N` served as one resolver process
+    whose history is sharded by key range over N chips of its host, one
+    shard a chip (parallel/sharded_resolver.py: every shard judges its own
+    keys, the conflict bits are summed on the device before anything is
+    painted, the splits follow the live history). None where the spec
+    does not say: one engine on one chip, as ever.
+
+    Refused here, at every role's boot (load_spec), as a bad
+    `resolver_splits` is: the key with an engine other than "tpu" (only
+    that engine has a mesh), with several resolver addresses (the proxies
+    would clip every batch by `resolver_shard_map` and each process would
+    shard its clip again: the mesh IS the four resolvers), or beside
+    `resolver_splits` (the mesh moves its own). That the process sees N
+    chips only the resolver can know: its own boot refuses
+    (make_conflict_set)."""
+    n = spec.get("resolver_mesh")
+    if n is None:
+        return None
+    if not isinstance(n, int) or isinstance(n, bool) or n < 2:
+        raise ValueError(
+            f"resolver_mesh must be a whole number of chips, 2 or more, "
+            f"got {n!r}")
+    if spec.get("engine", "cpu") != "tpu":
+        raise ValueError(
+            f"resolver_mesh={n} needs engine 'tpu' (the only engine with "
+            f"a mesh), got engine {spec.get('engine', 'cpu')!r}")
+    if len(spec["resolver"]) != 1:
+        raise ValueError(
+            f"resolver_mesh={n} is ONE resolver over {n} chips; the spec "
+            f"lists {len(spec['resolver'])} resolver addresses")
+    if spec.get("resolver_splits") is not None:
+        raise ValueError(
+            f"resolver_mesh={n} moves its own splits (auto_reshard); "
+            "resolver_splits beside it would state what nothing reads")
+    return n
+
+
 def _system_token(spec: dict) -> str | None:
     """Operator-minted system-scope authz token for in-process system
     actors (TimeKeeper) — spec key `authz_system_token`, a path to the
@@ -298,18 +342,33 @@ def tls_config(spec: dict, spec_path: str) -> dict | None:
 
 #: One exchange carries ONE schedule domain: the commit proxies cap
 #: multi-resolver wave batches at the deployed engine's chunk.
-#: make_conflict_set builds TPUConflictSet with its DEFAULT batch_size --
-#: this constant mirrors that default in the proxy process (which must
-#: not import the jax engine just to read a number); the resolver's
-#: resolve_edges refuses oversized windows loudly if the two ever drift.
+#: make_conflict_set builds TPUConflictSet -- or, where the spec's
+#: `resolver_mesh` says so, ShardedConflictSet, which inherits it -- with
+#: its DEFAULT batch_size; this constant mirrors that default in the
+#: proxy process (which must not import the jax engine just to read a
+#: number); the resolver's resolve_edges refuses oversized windows
+#: loudly if the two ever drift.
 DEPLOYED_WAVE_BATCH_LIMIT = 512
 
 
-def make_conflict_set(engine: str, n_resolvers: int = 1):
+def make_conflict_set(engine: str, n_resolvers: int = 1,
+                      mesh: "int | None" = None, **sizes):
     """Resolver engine: 'tpu' is the production kernel; 'cpu' (C++ skiplist)
     keeps a cluster deployable on hosts with no accelerator. 'tpu' refuses
     to build on any other platform (utils.require_tpu): JAX's own fallback
     to the CPU is silent, and a resolver that took it would look deployed.
+
+    What 'tpu' builds: TPUConflictSet on the process's first chip, or,
+    with ``mesh`` = N (the spec's `resolver_mesh`, see resolver_mesh()),
+    ShardedConflictSet(n_shards=N) over the process's first N chips at
+    its runtime defaults (auto_reshard and its interval and skew): the
+    same class below the device entry points, so the role serves either
+    through the same code. Both at the served role's sizes, which are the
+    engine's constructor defaults (capacity 1<<16 a shard, batch 512,
+    8 + 8 slots, 32 key bytes); a mesh wider than the chips the process
+    sees is refused here, naming the key. ``sizes`` are constructor
+    arguments for a harness that proves the served construction at
+    another size (chip_smoke.py); no deployed path passes any.
 
     ``n_resolvers`` is the DEPLOYMENT's resolver role count (the spec's
     resolver list), not this process's: wave commit (FDB_TPU_WAVE_COMMIT=1)
@@ -338,10 +397,26 @@ def make_conflict_set(engine: str, n_resolvers: int = 1):
         )
 
         enable_compilation_cache()
-        require_tpu("a resolver with engine 'tpu'")
+        found = require_tpu("a resolver with engine 'tpu'")
+        if mesh is not None:
+            if mesh > found["count"]:
+                raise ValueError(
+                    f"resolver_mesh={mesh} asks for {mesh} chips and this "
+                    f"process sees {found['count']} "
+                    f"({found['platform']}, {found['device_kind']})")
+            from foundationdb_tpu.parallel.sharded_resolver import (
+                ShardedConflictSet,
+            )
+
+            return ShardedConflictSet(n_shards=mesh, wave_commit=wave,
+                                      **sizes)
         from foundationdb_tpu.models.conflict_set import TPUConflictSet
 
-        return TPUConflictSet(wave_commit=wave)
+        return TPUConflictSet(wave_commit=wave, **sizes)
+    if mesh is not None or sizes:
+        raise ValueError(
+            f"resolver_mesh and engine sizes are engine 'tpu's, "
+            f"not {engine!r}'s")
     if engine == "cpu":
         from foundationdb_tpu.models.cpu_conflict_set import CPUSkipListConflictSet
 
@@ -359,7 +434,8 @@ def make_engine(spec: dict, name: str):
     (TPUConflictSet.warm_up), and what its arrays sit on is printed to the
     role's log; Resolver.get_metrics()["device"] serves the same."""
     engine = spec.get("engine", "cpu")
-    cs = make_conflict_set(engine, len(spec["resolver"]))
+    cs = make_conflict_set(engine, len(spec["resolver"]),
+                           mesh=resolver_mesh(spec))
     if hasattr(cs, "warm_up"):
         warm = cs.warm_up()
         dev = cs.device_info()
