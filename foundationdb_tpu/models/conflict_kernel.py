@@ -1993,11 +1993,19 @@ def _advance_hist_jit(hist, commit_version, new_oldest):
     )
 
 
+def rebase_hist(hist, delta_v):
+    """rebase over either history design. The window history's base
+    versions shift, so its prebuilt RMQ table must follow."""
+    if isinstance(hist, HistState):
+        base = rebase(hist.base, delta_v)
+        return HistState(base, sparse_table(base.versions),
+                         rebase(hist.delta, delta_v), hist.merges)
+    return rebase(hist, delta_v)
+
+
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _rebase_hist_jit(hist, delta_v):
-    base = rebase(hist.base, delta_v)
-    return HistState(base, sparse_table(base.versions),
-                     rebase(hist.delta, delta_v), hist.merges)
+    return rebase_hist(hist, delta_v)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -2015,16 +2023,11 @@ def _rebase_jit(state, delta):
     return rebase(state, delta)
 
 
-@jax.jit
-def _capacity_reading_jit(n_used, overflow, merges, frozen=None):
-    """int32 [3] off a history's ``n_used`` / ``overflow`` leaves (one of
-    each for the plain history, base and delta for the window history)
-    and the window history's ``merges`` (0 for the plain one): boundary
-    slots in use, the fullest shard of each summed, whether any overflow
-    flag is up, and the merges since boot. The leaves are read, not
-    donated, and the result is no part of the state: enqueued behind a
-    batch's last dispatch it holds what THAT batch left, whatever is
-    enqueued after it (TPUConflictSet.resolve_async).
+def _rows_in_use(n_used, frozen=None):
+    """int32, the boundary slots a history uses, its levels summed: off its
+    ``n_used`` leaves (one for the plain history, base and delta for the
+    window history). Leaves with a leading shard axis (the mesh engine's,
+    parallel/sharded_resolver.py) give one count a shard.
 
     ``frozen`` is the window history's (base.versions, live floor), and
     the FIRST of ``n_used`` then its base's: the base is frozen between
@@ -2034,15 +2037,39 @@ def _capacity_reading_jit(n_used, overflow, merges, frozen=None):
     one slot where its versions, clamped at the floor, change (a merged
     step function changes only where the clamped base or the delta does,
     so this plus the delta's rows bounds what any merge keeps)."""
-    used = [jnp.max(u).astype(jnp.int32) for u in n_used]
+    used = [jnp.asarray(u, jnp.int32) for u in n_used]
     if frozen is not None:
         v, floor = frozen
-        v = jnp.where(v <= floor, NEG_VERSION, v)
-        steps = 1 + jnp.sum((v[1:] != v[:-1]).astype(jnp.int32))
+        floor = jnp.asarray(floor)
+        v = jnp.where(v <= floor.reshape(floor.shape + (1,)), NEG_VERSION, v)
+        steps = 1 + jnp.sum((v[..., 1:] != v[..., :-1]).astype(jnp.int32),
+                            axis=-1)
         used[0] = jnp.minimum(used[0], steps)
+    return sum(used)
+
+
+_rows_in_use_jit = jax.jit(_rows_in_use)
+
+
+@jax.jit
+def _capacity_reading_jit(n_used, overflow, merges, frozen=None):
+    """int32 [3] off a history's ``n_used`` / ``overflow`` leaves (one of
+    each for the plain history, base and delta for the window history)
+    and the window history's ``merges`` (0 for the plain one): boundary
+    slots in use (_rows_in_use, which ``frozen`` is for), whether any
+    overflow flag is up, and the merges since boot. The leaves are read,
+    not donated, and the result is no part of the state: enqueued behind
+    a batch's last dispatch it holds what THAT batch left, whatever is
+    enqueued after it (TPUConflictSet.resolve_async).
+
+    Where the leaves carry a shard axis the slots in use are the FULLEST
+    shard's (the fail-safe engages where the first shard would overflow)
+    and the merges are summed over the shards. With no such axis this is
+    the program it always was."""
     over = functools.reduce(jnp.logical_or, [jnp.any(o) for o in overflow])
-    return jnp.stack([sum(used), over.astype(jnp.int32),
-                      jnp.asarray(merges, jnp.int32)])
+    return jnp.stack([jnp.max(_rows_in_use(n_used, frozen)),
+                      over.astype(jnp.int32),
+                      jnp.sum(jnp.asarray(merges, jnp.int32))])
 
 
 # -- wave-commit entry points (FDB_TPU_WAVE_COMMIT=1 engines) ---------------
@@ -2554,43 +2581,64 @@ def _paint_and_compact_res(
 
 
 def _resolve_core_res(hist, rbk: RankBatch, commit_version, new_oldest,
-                      report: bool = False, wave: bool = False):
+                      report: bool = False, wave: bool = False,
+                      clip=None, combine=None, accept=None):
     """Shared resident resolve body over either history design. Returns
-    (verdicts[, levels][, losers], new_hist)."""
+    (verdicts[, levels][, losers], new_hist).
+
+    The three hooks are the mesh's (parallel/sharded_resolver.py), where
+    `hist` is ONE shard's and the batch is replicated; one chip hands in
+    none and traces what it always traced. `clip(rbk)` is the batch cut to
+    the shard's slice of the rank space: the shard probes and paints that,
+    and merges on ITS demand, so whether the delta is folded in differs a
+    shard, which is why no hook may run inside _maybe_merge's branches.
+    `combine(mask)` ORs a shard's history bits over the shards, before
+    acceptance, so every shard paints what ONE history would accept.
+    `accept(base, local)` replaces the acceptance where it needs the
+    shards' clipped graphs (the wave exchange); what it returns beside
+    `accepted` rides out in the place of the levels, untouched."""
     two_level = isinstance(hist, HistState)
+    local = rbk if clip is None else clip(rbk)
     if two_level:
         floor, too_old = too_old_mask_packed(hist.delta, rbk, new_oldest)
         with jax.named_scope("hist_merge"):
             demand = 2 * jnp.sum(
-                (rbk.write_mask & (rbk.write_begin < rbk.write_end)).astype(
-                    jnp.int32
-                )
+                (local.write_mask
+                 & (local.write_begin < local.write_end)).astype(jnp.int32)
             )
         hist = _maybe_merge(hist, demand, floor)
         base_h, base_st, delta, _ = hist
         hist_mask = _history_conflict_ranges_hist_res(
-            base_h, base_st, delta, rbk
+            base_h, base_st, delta, local
         )
     else:
         floor, too_old = too_old_mask_packed(hist, rbk, new_oldest)
-        hist_mask = _history_conflict_ranges_res(hist, rbk)
+        hist_mask = _history_conflict_ranges_res(hist, local)
     with jax.named_scope("history_probe"):
         hist_conflict = jnp.any(hist_mask, axis=1)
+    if combine is not None:
+        hist_conflict = combine(hist_conflict)
+    with jax.named_scope("history_probe"):
         base = rbk.txn_mask & ~too_old & ~hist_conflict
     ranks = endpoint_ranks_live_packed(rbk)
-    accepted, levels = _accept_or_schedule(base, ranks, wave, rbk.cont)
+    if accept is None:
+        accepted, levels = _accept_or_schedule(base, ranks, wave, rbk.cont)
+    else:
+        accepted, levels = accept(base, local)
     verdicts = assemble_verdicts(too_old, rbk.txn_mask, accepted)
     if two_level:
         delta = _paint_and_compact_res(
-            delta, rbk, accepted, commit_version, floor
+            delta, local, accepted, commit_version, floor
         )
         new_hist: ConflictState | HistState = hist._replace(delta=delta)
     else:
         new_hist = _paint_and_compact_res(
-            hist, rbk, accepted, commit_version, floor
+            hist, local, accepted, commit_version, floor
         )
     out = (verdicts, levels) if wave else (verdicts,)
     if report:
+        if combine is not None:
+            hist_mask = combine(hist_mask)
         losers = loser_range_mask(hist_mask, ranks, accepted, verdicts)
         return (*out, pack_loser_mask(losers), new_hist)
     return (*out, new_hist)
@@ -2670,15 +2718,7 @@ _resolve_many_hist_res_wave_jit = _resolve_many_res_wave_jit
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _rebase_res_jit(res, delta_v):
-    hist = res.hist
-    if isinstance(hist, HistState):
-        base = rebase(hist.base, delta_v)
-        # base versions shifted — the prebuilt RMQ table must follow.
-        hist = HistState(base, sparse_table(base.versions),
-                         rebase(hist.delta, delta_v), hist.merges)
-    else:
-        hist = rebase(hist, delta_v)
-    return res._replace(hist=hist)
+    return res._replace(hist=rebase_hist(res.hist, delta_v))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))

@@ -840,11 +840,13 @@ class TPUConflictSet:
         # reads the difference over a dispatch to know its pipeline
         # drained there.
         self.pack_syncs = 0
-        # The window history's merges since boot (ck.HistState.merges), as
-        # of the last capacity reading collected: a word of the reading a
-        # role fetches with every batch's verdicts, so no device read of
-        # its own. hist_merges / dispatches says how often a dispatch's
-        # paint did not fit the delta; advance() merges every time.
+        # The window history's merges since boot (ck.HistState.merges; the
+        # mesh engine's shards summed), as of the last capacity reading
+        # collected: a word of the reading a role fetches with every
+        # batch's verdicts (a collector's reading(), or headroom() for a
+        # batch collected at once), so no device read of its own.
+        # hist_merges / dispatches says how often a dispatch's paint did
+        # not fit the delta; advance() merges every time.
         self.hist_merges = 0
         self._empty_dev_batch = None  # advance()'s constant batch, packed lazily
         # Admission subsystem (attach_admission_filter): a RecentWritesFilter
@@ -2470,7 +2472,11 @@ class TPUConflictSet:
         transactions, say — goes through it a dispatch at a time; only a
         delta configured smaller than that caps what a batch may bring.
         """
-        return self._headroom_of(int(np.asarray(self._reading())[0]))
+        used, _over, merges = (int(x) for x in np.asarray(self._reading()))
+        # A batch collected at once is read here, not through its
+        # collector's reading(): the count of merges rides along as there.
+        self.hist_merges = merges
+        return self._headroom_of(used)
 
     def _headroom_of(self, used: int) -> int:
         """headroom() given the boundary slots in use."""
@@ -2588,12 +2594,18 @@ class TPUConflictSet:
             seconds[name] = round(_perf_counter() - t0, 3)
         if merges is not None:
             # The advance step merged, and a warm-up is none of the count's.
-            # Placed as init_hist placed it (on no named device): an array
-            # put on one by name is another argument type to every compiled
-            # entry point, and the first batch would compile them again.
             self._set_hist_core(self._hist_core._replace(
-                merges=jax.numpy.asarray(merges)))
+                merges=self._device_merges(merges)))
         return seconds
+
+    def _device_merges(self, merges: np.ndarray):
+        """A merge count back on the device, placed as the engine's
+        set-up placed it (here init_hist's: on no named device): an array
+        placed otherwise is another argument type to every compiled entry
+        point, and the first batch would compile them again."""
+        import jax
+
+        return jax.numpy.asarray(merges)
 
     def device_info(self) -> dict:
         """Where this engine's state lives, read off the arrays

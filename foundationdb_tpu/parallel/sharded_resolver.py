@@ -8,7 +8,9 @@ conflict). Here the same design is one SPMD program over
 
 - each device owns a keyspace shard ``[split_d, split_{d+1})`` and holds its
   own step-function history (state arrays carry a leading device axis,
-  sharded over the mesh);
+  sharded over the mesh); the resident engine's is of the design one chip
+  keeps (conflict_kernel._HIST_DESIGN): by default the window history, a
+  frozen base, its RMQ table and a small delta a shard;
 - the batch is replicated; each device clips ranges to its shard
   (clip_batch), checks reads against its local history, and contributes
   conflict bits via ``psum`` — the tensor analogue of the proxy ANDing
@@ -202,51 +204,71 @@ def _wave_exchange_and_level(base, clipped_ranks, cont=None):
     return accepted, levels, stats
 
 
+def _any_shard(mask):
+    """A shard's history bits ORed over the shards: the packed all_gather
+    for a batch's [B] rows, a psum for the report's [B, R] ranges (a range
+    conflicts where any shard's slice of it does)."""
+    if mask.ndim == 1:
+        return _sum_over_shards(mask)
+    with jax.named_scope("shard_psum"):
+        return jax.lax.psum(mask.astype(jnp.int32), AXIS) > 0
+
+
 def _res_shard_step(hist, lo, hi, rbk, commit_version, new_oldest, wave,
                     report=False):
-    """One resident-mode per-shard resolve step (runs under shard_map).
+    """One resident-mode per-shard resolve step (runs under shard_map):
+    conflict_kernel._resolve_core_res, the body one chip runs, handed what
+    differs a shard.
 
-    hist: the local shard's width-1 rank-space history; lo/hi: the shard's
+    hist: the local shard's width-1 rank-space history, of the design one
+    chip keeps (ck._HIST_DESIGN): the window history's frozen base, its
+    table and its delta, or the one-level history. lo/hi: the shard's
     keyspace bounds AS RANKS (already rebased past this dispatch's
     dictionary inserts). The batch is replicated rank tensors; clipping is
-    scalar int32 (clip_ranks), the cross-shard combine is the same packed
-    all_gather as the full-key body, and acceptance runs replicated on the
-    UNCLIPPED batch exactly as before. `report` (static; sequential
+    scalar int32 (clip_ranks) and the shard probes, merges on the demand
+    of, and paints its CLIPPED batch alone, so one shard may fold its
+    delta into its base at a dispatch where the others do not: the merge
+    holds no collective. The cross-shard combine is the same packed
+    all_gather as the full-key body, and acceptance runs replicated on
+    the UNCLIPPED batch exactly as before. `report` (static; sequential
     order only) also returns the conflicting-keys report's loser mask,
-    replicated: each shard's per-range history bits summed over the mesh
-    (a range conflicts where any shard's slice of it does), then
-    conflict_kernel.loser_range_mask on the unclipped batch, as one chip
-    computes it."""
-    floor, too_old = ck.too_old_mask_packed(hist, rbk, new_oldest)
-    with jax.named_scope("shard_clip"):
-        local = ck.clip_ranks(rbk, lo, hi)
-    hist_mask = ck._history_conflict_ranges_res(hist, local)
-    hist_conflict = _sum_over_shards(jnp.any(hist_mask, axis=1))
-    base = rbk.txn_mask & ~too_old & ~hist_conflict
-    stats = None
+    replicated: each shard's per-range history bits summed over the mesh,
+    then conflict_kernel.loser_range_mask on the unclipped batch, as one
+    chip computes it."""
+    accept = None
     if wave:
         # Same global-graph exchange as the full-key body, in rank space:
         # the clipped RankBatch's intervals witness exactly this shard's
         # slice of every edge (clip_ranks is a two-sided clamp on shared
         # global ranks), so the OR across shards is the exact graph.
-        accepted, levels, stats = _wave_exchange_and_level(
-            base, ck.endpoint_ranks_live_packed(local), rbk.cont
-        )
-    else:
-        accepted, levels = ck._accept_or_schedule(
-            base, ck.endpoint_ranks_live_packed(rbk), False, rbk.cont
-        )
-    verdicts = ck.assemble_verdicts(too_old, rbk.txn_mask, accepted)
-    new_hist = ck._paint_and_compact_res(
-        hist, local, accepted, commit_version, floor
+        def accept(base, local):
+            accepted, levels, stats = _wave_exchange_and_level(
+                base, ck.endpoint_ranks_live_packed(local), rbk.cont
+            )
+            return accepted, (levels, stats)
+
+    def clip(rbk):
+        with jax.named_scope("shard_clip"):
+            return ck.clip_ranks(rbk, lo, hi)
+
+    out = ck._resolve_core_res(
+        hist, rbk, commit_version, new_oldest, report=report, wave=wave,
+        clip=clip, combine=_any_shard, accept=accept,
     )
     if report:
-        with jax.named_scope("shard_psum"):
-            mask = jax.lax.psum(hist_mask.astype(jnp.int32), AXIS) > 0
-        losers = ck.loser_range_mask(
-            mask, ck.endpoint_ranks_live_packed(rbk), accepted, verdicts)
-        return verdicts, ck.pack_loser_mask(losers), new_hist
-    return verdicts, levels, stats, new_hist
+        return out  # verdicts, packed loser mask, new_hist
+    levels, stats = out[1] if wave else (None, None)
+    return out[0], levels, stats, out[-1]
+
+
+def _unstack(tree):
+    """Under shard_map a stacked leaf is the local [1, ...] slice: drop the
+    shard axis for the kernel's bodies, which know nothing of it."""
+    return jax.tree.map(lambda x: x[0], tree)
+
+
+def _restack(tree):
+    return jax.tree.map(lambda x: x[None], tree)
 
 
 def _sharded_resolve_res(res, rb, commit_version, new_oldest, wave=False,
@@ -255,19 +277,14 @@ def _sharded_resolve_res(res, rb, commit_version, new_oldest, wave=False,
     takes the same host-shipped ranks, rb.delta_cross, and computes the
     identical merged dictionary), per-shard rank-rebase of histories AND
     shard bounds, then the rank-space shard step."""
-    local = ck.ResState(
-        dict_keys=res.dict_keys,  # replicated (P())
-        n_keys=res.n_keys,
-        hist=jax.tree.map(lambda x: x[0], res.hist),
-        shard_lo=res.shard_lo,  # local [1] slice
-        shard_hi=res.shard_hi,
-    )
+    # dict_keys / n_keys are replicated (P()), the bounds the local [1].
+    local = res._replace(hist=_unstack(res.hist))
     local = ck.apply_delta(local, rb.delta_keys, rb.delta_cross)
     *out, new_hist = _res_shard_step(
         local.hist, local.shard_lo[0], local.shard_hi[0], rb.ranks,
         commit_version, new_oldest, wave, report,
     )
-    new_res = local._replace(hist=jax.tree.map(lambda x: x[None], new_hist))
+    new_res = local._replace(hist=_restack(new_hist))
     if report:
         return (*out, new_res)  # verdicts, packed loser mask
     verdicts, levels, stats = out
@@ -280,13 +297,7 @@ def _sharded_resolve_res_many(res, rb, commit_versions, new_oldests,
                               wave=False):
     """Window scan: ONE dictionary merge + rank rebase per window, then a
     pure rank-space scan — no per-step dictionary work at all."""
-    local = ck.ResState(
-        dict_keys=res.dict_keys,
-        n_keys=res.n_keys,
-        hist=jax.tree.map(lambda x: x[0], res.hist),
-        shard_lo=res.shard_lo,
-        shard_hi=res.shard_hi,
-    )
+    local = res._replace(hist=_unstack(res.hist))
     local = ck.apply_delta(local, rb.delta_keys, rb.delta_cross)
     lo = local.shard_lo[0]
     hi = local.shard_hi[0]
@@ -301,7 +312,7 @@ def _sharded_resolve_res_many(res, rb, commit_versions, new_oldests,
     hist, stacked = jax.lax.scan(
         body, local.hist, (rb.ranks, commit_versions, new_oldests)
     )
-    new_res = local._replace(hist=jax.tree.map(lambda x: x[None], hist))
+    new_res = local._replace(hist=_restack(hist))
     return (*stacked, new_res)
 
 
@@ -512,7 +523,7 @@ class ShardedConflictSet(TPUConflictSet):
         None when the history is too small or too concentrated to yield
         n_shards-1 distinct interior keys (density_splits' uniform
         fallback means "don't move the bounds" here)."""
-        hc = self._hist_core
+        hc = self._folded_history()
         keys, n_used = (np.asarray(x)
                         for x in jax.device_get((hc.keys, hc.n_used)))
         nw = self.codec.n_words
@@ -653,7 +664,16 @@ class ShardedConflictSet(TPUConflictSet):
         bounds carried as ranks INSIDE device state so dictionary inserts
         rebase them exactly like history ranks. The host mirror is seeded
         with the keyspace minimum + interior shard bounds, pinned so no
-        repack can ever evict a bound."""
+        repack can ever evict a bound.
+
+        A shard keeps the history one chip keeps, by the switch one chip
+        reads (ck._HIST_DESIGN): by default the window history, a
+        ck.HistState a shard (a base of ``capacity`` rows frozen between
+        merges, its RMQ table, a delta of ``delta_capacity`` rows that
+        every dispatch probes and paints, the merges counted), every leaf
+        stacked on the shard axis; under FDB_TPU_HISTORY=batch the
+        one-level ck.ConflictState. Each shard's first row, in either
+        level, is its lower bound's rank."""
         s = self.n_shards
         # self._lo rows are sorted unique (row 0 = packed b"").
         self._mirror = _ResidentMirror(
@@ -672,9 +692,11 @@ class ShardedConflictSet(TPUConflictSet):
             (self.dict_capacity + 1, self.codec.width), INT32_MAX, np.int32
         )
         dict_dev[:s] = self._lo
+        window = ck._HIST_DESIGN == "window"
         states = [
-            ck.init_state(self.capacity, 1, np.array([d], np.int32))
-            for d in range(s)
+            ck.init_hist(self.capacity, 1, first, self.delta_capacity)
+            if window else ck.init_state(self.capacity, 1, first)
+            for first in lo_ranks[:, None]
         ]
         stacked = jax.tree.map(lambda *xs: np.stack(xs), *states)
         shard = self._shard_sharding
@@ -682,15 +704,11 @@ class ShardedConflictSet(TPUConflictSet):
         self.state = ck.ResState(
             dict_keys=jax.device_put(dict_dev, repl),
             n_keys=jax.device_put(np.int32(s), repl),
-            hist=jax.tree.map(
-                lambda x: jax.device_put(x, shard), ck.ConflictState(*stacked)
-            ),
+            hist=jax.tree.map(lambda x: jax.device_put(x, shard), stacked),
             shard_lo=jax.device_put(lo_ranks, shard),
             shard_hi=jax.device_put(hi_ranks, shard),
         )
-        hist_specs = ck.ConflictState(
-            *(P(AXIS) for _ in ck.ConflictState._fields)
-        )
+        hist_specs = jax.tree.map(lambda _: P(AXIS), stacked)
         state_specs = ck.ResState(
             dict_keys=P(), n_keys=P(), hist=hist_specs,
             shard_lo=P(AXIS), shard_hi=P(AXIS),
@@ -721,12 +739,35 @@ class ShardedConflictSet(TPUConflictSet):
         self._resolve_many_fn = (
             self._strip_exchange(resolve_many) if wave else resolve_many
         )
-        # Rebase/repack/evict touch versions/ranks elementwise — the plain
-        # resident entry points shard transparently under jit (the evict
-        # shift table derives from the replicated dictionary, so every
-        # device applies the identical demotion delta and the rank space
-        # stays coherent across shards by construction).
-        self._rebase_fn = ck._rebase_res_jit
+
+        def each_shard(fn, name):
+            """``fn(hist, *scalars) -> hist`` of the kernel, run a shard on
+            the stacked histories (donated): what is NOT elementwise over
+            a history's rows (a table rebuilt, a merge) knows no shard
+            axis. The dictionary and the bounds pass by untouched."""
+
+            def run(hist, *args):
+                return _shard_map(
+                    lambda h, *a: _restack(fn(_unstack(h), *a)),
+                    mesh=self.mesh,
+                    in_specs=(hist_specs,) + (P(),) * len(args),
+                    out_specs=hist_specs,
+                )(hist, *args)
+
+            run.__name__ = name  # the program's name in a device trace
+            jitted = jax.jit(run, donate_argnums=(0,))
+            return lambda res, *args: res._replace(
+                hist=jitted(res.hist, *args))
+
+        self._rebase_fn = each_shard(ck.rebase_hist, "_shard_rebase")
+        # The window history's GC-only step, which is also its forced
+        # merge (_folded_history): ck.advance_hist a shard.
+        self._advance_fn = each_shard(ck.advance_hist, "_shard_advance")
+        # Repack/evict touch ranks elementwise — the plain resident entry
+        # points shard transparently under jit (the evict shift table
+        # derives from the replicated dictionary, so every device applies
+        # the identical demotion delta and the rank space stays coherent
+        # across shards by construction).
         self._repack_fn = ck._repack_res_jit
         self._evict_fn = ck._evict_res_jit
         # The conflicting-keys report, as the one-chip engine serves it
@@ -742,13 +783,49 @@ class ShardedConflictSet(TPUConflictSet):
             donate_argnums=(0,),
         )
 
+    @property
+    def _advance_hist_fn(self):
+        """TPUConflictSet's GC-only entry point, a shard (resident engines:
+        the only mesh engine with a window history)."""
+        return lambda res, cv, old: (None, self._advance_fn(res, cv, old))
+
+    def _device_merges(self, merges: np.ndarray):
+        return jax.device_put(merges, self._shard_sharding)
+
+    def _folded_history(self) -> ck.ConflictState:
+        """The stacked ONE-level history that holds every shard's rows:
+        the engine's own or, of the window history, the bases after a
+        forced merge a shard at the floor that stands (ck.advance_hist:
+        the base then holds everything and the delta its one row; no
+        verdict changes, a merge never does). For what reads or moves
+        rows between dispatches: the split policy's quantiles and the
+        re-split itself."""
+        if not self._is_hist:
+            return self._hist_core
+        # Nothing to fold where every delta holds its one row: the policy
+        # folds for its quantiles and the re-split asks again behind it.
+        if np.max(jax.device_get(self._hist_core.delta.n_used)) > 1:
+            zero = np.int32(0)
+            self.state = self._advance_fn(self.state, zero, zero)
+        return self._hist_core.base
+
     def shard_occupancy(self) -> list[int]:
         """Live history boundary count per shard — the load-balance signal
-        the density splits are judged by."""
-        occ = [
-            int(x)
-            for x in np.asarray(jax.device_get(self._hist_core.n_used))
-        ]
+        the density splits are judged by. The window history's rows are
+        its base's, as its next merge would leave it (a frozen base keeps
+        what expired since the last one, which is no load), and its
+        delta's: what the capacity reading counts, a shard
+        (ck._rows_in_use), less the shard's lower bound, which is the
+        first row of both levels."""
+        hc = self._hist_core
+        if self._is_hist:
+            used = ck._rows_in_use_jit(
+                (hc.base.n_used, hc.delta.n_used),
+                (hc.base.versions, hc.delta.oldest))
+        else:
+            used = hc.n_used
+        occ = [int(x) - self._is_hist
+               for x in np.asarray(jax.device_get(used))]
         self.shard_rows_in_use = occ
         return occ
 
@@ -808,14 +885,19 @@ class ShardedConflictSet(TPUConflictSet):
             # The replicated dictionary stays where it is unless a bound
             # key has to be inserted (never on the auto path): only the
             # histories and the bounds come down and go back up.
+            # The window history is folded first, a merge a shard on the
+            # device: its bases then hold every row, and they move as a
+            # one-level history's rows move.
+            rows = self._folded_history()
             live = self.state
-            st = live._replace(dict_keys=None, n_keys=None)
-            st = jax.device_get(st)
-            keys = np.asarray(st.hist.keys)  # [S, C, 1] int32 ranks
-            vers = np.asarray(st.hist.versions)
-            n_used = np.asarray(st.hist.n_used).astype(np.int64)
-            old_lo = np.asarray(st.shard_lo).astype(np.int64)
-            old_hi = np.asarray(st.shard_hi).astype(np.int64)
+            keys, vers, n_used, over, old_lo, old_hi = (
+                np.asarray(x) for x in jax.device_get((
+                    rows.keys, rows.versions, rows.n_used, rows.overflow,
+                    live.shard_lo, live.shard_hi)))
+            # keys: [S, C, 1] int32 ranks
+            n_used = n_used.astype(np.int64)
+            old_lo = old_lo.astype(np.int64)
+            old_hi = old_hi.astype(np.int64)
             bounds = pack_splits(self.codec, splits)
             brows = np.ascontiguousarray(bounds[:-1])  # S lo rows
             bu = _rows_to_u64(brows)
@@ -877,7 +959,7 @@ class ShardedConflictSet(TPUConflictSet):
             new_keys = np.full_like(keys, INT32_MAX)
             new_vers = np.full_like(vers, ck.NEG_VERSION)
             new_used = np.zeros(s, np.int32)
-            new_over = np.asarray(st.hist.overflow).copy()
+            new_over = over.copy()
             moved = 0
             for d in range(s):
                 if lo_ranks[d] == old_lo[d] and hi_ranks[d] == old_hi[d]:
@@ -907,24 +989,41 @@ class ShardedConflictSet(TPUConflictSet):
 
             shard = self._shard_sharding
             repl = NamedSharding(self.mesh, P())
+            hist = rows._replace(  # oldest: where it is, as it was
+                keys=jax.device_put(new_keys, shard),
+                versions=jax.device_put(new_vers, shard),
+                n_used=jax.device_put(new_used, shard),
+                overflow=jax.device_put(new_over, shard),
+            )
             self.shard_rows_in_use = [int(x) for x in new_used]
+            if self._is_hist:
+                # The folded delta holds ONE row a shard, its lower
+                # bound's rank, which has moved with the bound.
+                was = live.hist
+                dkeys = np.full(was.delta.keys.shape, INT32_MAX, np.int32)
+                dkeys[:, 0, 0] = lo_ranks
+                hist = was._replace(
+                    base=hist,
+                    delta=was.delta._replace(
+                        keys=jax.device_put(dkeys, shard)),
+                )
             self.state = ck.ResState(
                 dict_keys=(jax.device_put(dict_dev, repl)
                            if dict_dev is not None else live.dict_keys),
                 n_keys=(jax.device_put(np.int32(mir.n), repl)
                         if dict_dev is not None else live.n_keys),
-                hist=ck.ConflictState(
-                    keys=jax.device_put(new_keys, shard),
-                    versions=jax.device_put(new_vers, shard),
-                    n_used=jax.device_put(new_used, shard),
-                    oldest=jax.device_put(np.asarray(st.hist.oldest), shard),
-                    overflow=jax.device_put(new_over, shard),
-                ),
+                hist=hist,
                 shard_lo=jax.device_put(lo_ranks.astype(np.int32), shard),
                 shard_hi=jax.device_put(
                     np.minimum(hi_ranks, INT32_MAX).astype(np.int32), shard
                 ),
             )
+            if self._is_hist:
+                # base_st is still the table of the rows that left. A
+                # rebase by 0 moves no version and rebuilds every shard's
+                # table from its base as it stands (ck.rebase_hist): the
+                # program is compiled since the warm-up.
+                self.state = self._rebase_fn(self.state, np.int32(0))
             self._interior_splits = list(splits)
             self._lo = np.ascontiguousarray(bounds[:-1])
             self._hi = np.ascontiguousarray(bounds[1:])

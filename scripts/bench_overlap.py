@@ -73,10 +73,15 @@ def _wrap(cls) -> None:
 
 def main() -> int:
     from benchmark import run
-    from benchmark.drivers import resolver_replay_mako, resolver_replay_tpcc
+    from benchmark.drivers import (
+        cluster_mesh,
+        resolver_replay_mako,
+        resolver_replay_tpcc,
+    )
     from benchmark.lib import observe, observe_nr
 
     for cls in (observe.Observer, observe_nr.ObserverNR,
+                cluster_mesh.Observer,
                 resolver_replay_mako.Observer, resolver_replay_tpcc.Observer):
         _wrap(cls)
     return run.main()

@@ -117,6 +117,7 @@ def drive(cs, stream, judge: bool) -> dict:
                 conflicts += want.count(reference.CONFLICT)
                 too_old += want.count(reference.TOO_OLD)
     rows = cs.shard_occupancy()
+    cs.headroom()  # a capacity reading: cs.hist_merges is as of the last
     ms_sorted = sorted(ms)
     return {
         "batches": len(ms), "verdicts": verdicts, "mismatched": mismatched,

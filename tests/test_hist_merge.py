@@ -500,3 +500,77 @@ def test_the_reading_counts_the_base_as_the_next_merge_would_leave_it():
     cs.advance(1010, 45)  # the merge itself, at the same floor
     assert int(np.asarray(cs._hist_core.merges)) == merges + 1
     assert raw < room <= cs.headroom() + 1  # the empty delta's one row
+
+
+# -- the reading with a shard axis (PR 45) ------------------------------------
+
+
+def stacked_leaves(seed, shards, c=256):
+    """Random window-history leaves a shard, as the mesh engine stacks them:
+    a base whose versions step up and down with runs of equal and of
+    expired values, counts, floors, flags."""
+    rng = np.random.default_rng(seed)
+    versions = np.full((shards, c), NEG_VERSION, np.int32)
+    base_n = rng.integers(1, c, shards).astype(np.int32)
+    for d in range(shards):
+        runs = rng.integers(0, 40, base_n[d]).astype(np.int32) * 10
+        runs[rng.random(base_n[d]) < 0.3] = NEG_VERSION
+        versions[d, :base_n[d]] = np.repeat(
+            runs[::3], 3)[:base_n[d]] if seed % 2 else runs
+    return dict(
+        versions=versions, base_n=base_n,
+        delta_n=rng.integers(1, 64, shards).astype(np.int32),
+        floor=rng.integers(0, 300, shards).astype(np.int32),
+        base_over=rng.random(shards) < 0.2,
+        delta_over=np.zeros(shards, bool),
+        merges=rng.integers(0, 50, shards).astype(np.int32))
+
+
+def reading_of(x, d=None):
+    pick = (lambda a: a) if d is None else (lambda a: a[d])
+    return [int(v) for v in np.asarray(ck._capacity_reading_jit(
+        (pick(x["base_n"]), pick(x["delta_n"])),
+        (pick(x["base_over"]), pick(x["delta_over"])), pick(x["merges"]),
+        (pick(x["versions"]), pick(x["floor"]))))]
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+def test_the_reading_with_a_shard_axis_is_the_fullest_shards(seed):
+    """Stacked leaves: the slots in use are the maximum over the shards of
+    the reading each shard gives alone, any overflow flag counts, and the
+    merges are summed; _rows_in_use_jit, the split policy's probe, gives
+    the same counts a shard."""
+    x = stacked_leaves(seed, shards=4)
+    alone = [reading_of(x, d) for d in range(4)]
+    assert reading_of(x) == [max(r[0] for r in alone),
+                             int(any(r[1] for r in alone)),
+                             sum(r[2] for r in alone)]
+    rows = np.asarray(ck._rows_in_use_jit(
+        (x["base_n"], x["delta_n"]), (x["versions"], x["floor"])))
+    assert [int(r) for r in rows] == [r[0] for r in alone]
+    assert len({r[0] for r in alone}) > 1  # the shards do differ
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+def test_the_reading_without_a_shard_axis_is_what_it_was(seed):
+    """No shard axis: PR 41's rule, computed here in numpy from the same
+    leaves: the base counts one slot where its versions, clamped at the
+    floor, change, never more than it holds; plus the delta's rows."""
+    x = stacked_leaves(seed, shards=1)
+    v = x["versions"][0]
+    v = np.where(v <= x["floor"][0], NEG_VERSION, v)
+    steps = 1 + int((v[1:] != v[:-1]).sum())
+    want = [min(int(x["base_n"][0]), steps) + int(x["delta_n"][0]),
+            int(x["base_over"][0]), int(x["merges"][0])]
+    assert reading_of(x, 0) == want
+    # and the plain history's, which has no frozen base
+    plain = ck._capacity_reading_jit(
+        (x["base_n"][0],), (x["base_over"][0],), np.int32(0))
+    assert [int(a) for a in np.asarray(plain)] == [
+        int(x["base_n"][0]), int(x["base_over"][0]), 0]
+    # the stacked plain history (the mesh under FDB_TPU_HISTORY=batch)
+    many = stacked_leaves(seed, shards=4)
+    plain = ck._capacity_reading_jit(
+        (many["base_n"],), (many["base_over"],), np.int32(0))
+    assert [int(a) for a in np.asarray(plain)] == [
+        int(many["base_n"].max()), int(many["base_over"].any()), 0]

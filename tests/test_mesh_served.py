@@ -368,8 +368,11 @@ def test_the_fullest_shard_bounds_the_roles_headroom():
     assert max(occ) > 100 and sorted(occ)[:3] == [1, 1, 1]
     assert sum(occ) / 4 < cs.capacity / 4
     m = loop.run(res.get_metrics())
-    # the role was served the fullest shard's reading, not the mean
-    assert m["history_headroom"] == cs.capacity - max(occ)
+    # the role was served the fullest shard's reading, not the mean: its
+    # rows in use and one more, its lower bound being the first row of its
+    # base AND of its delta
+    assert m["history_headroom"] == cs.headroom() == (
+        cs.capacity - max(occ) - 1)
     assert m["history_headroom"] < cs.worst_case_growth(32)
     assert m["fail_safe_active"]
 
@@ -405,7 +408,95 @@ def test_the_bootstrap_split_is_left_before_the_lone_shard_fills():
     assert m["auto_reshards"] == cs.auto_reshards
     assert m["reshard_probes"] == 30 // 8 and m["reshard_probe_s"] > 0
     assert m["reshard_s"] > 0 and m["shard_rows_in_use"] == occ
-    assert m["hist_merges"] == 0  # the mesh keeps the one-level history
+    # the mesh keeps the window history a shard: the shards' merges, summed
+    merges = np.asarray(cs._hist_core.merges)
+    assert m["hist_merges"] == int(merges.sum()) > 0 and len(merges) == 4
+    assert m["dispatches"] == 30
+
+
+def reading_a_shard(cs):
+    """The capacity reading's rule from the stacked leaves, in numpy: a
+    shard's base as its next merge would leave it (one row where its
+    versions, clamped at the floor, change) plus its delta's rows."""
+    hc = cs._hist_core
+    versions, floor, base_n, delta_n = (np.asarray(x) for x in (
+        hc.base.versions, hc.delta.oldest, hc.base.n_used, hc.delta.n_used))
+    out = []
+    for d in range(cs.n_shards):
+        v = np.where(versions[d] <= floor[d], versions[d].min(), versions[d])
+        steps = 1 + int((v[1:] != v[:-1]).sum())
+        out.append(min(int(base_n[d]), steps) + int(delta_n[d]))
+    return out
+
+
+def test_the_roles_headroom_and_merges_are_the_window_historys_a_shard():
+    """`history_headroom` is the FULLEST shard's base as its next merge
+    would leave it plus its delta's rows; `hist_merges` the shards' merges
+    summed; both as of the batch the role collected last, whether it read
+    them through the collector or, collected at once, through headroom()."""
+    loop, cs, res = mesh_role(capacity=512, batch_size=16,
+                              max_write_ranges=2)
+    assert cs._is_hist and cs.delta_capacity == 66
+    run = Compared(loop, res, point_judge())
+    rng = np.random.default_rng(8)
+    for b in range(30):
+        v = run.version
+        # three keys of four under the first bytes 0x40-0x7f: the splits
+        # start by first byte and the policy moves them as it sees fit
+        keys = [bytes([0x40 + int(rng.integers(0, 64)) if j % 4 else
+                       int(rng.integers(0, 256))])
+                + bytes(rng.integers(97, 123, 5).astype(np.uint8))
+                for j in range(16)]
+        run.batch([(v - 150, [point(k)], [point(k)]) for k in keys], 600)
+        m = loop.run(res.get_metrics())
+        per_shard = reading_a_shard(cs)
+        assert m["history_headroom"] == cs.capacity - max(per_shard), b
+        assert m["engine"]["hist_merges"] == int(
+            np.asarray(cs._hist_core.merges).sum()), b
+    merges = [int(x) for x in np.asarray(cs._hist_core.merges)]
+    # every shard folded its delta in, by itself or for a re-split
+    assert min(merges) >= 1 and run.fail_safe_batches == 0
+    assert max(per_shard) > 40 and not cs.overflowed
+    assert m["engine"]["dispatches"] == 30
+
+
+def test_the_fail_safes_advance_merges_every_shard_and_frees_its_base():
+    """The fail-safe advances in place of resolving: on the mesh that is
+    ck.advance_hist a shard, a forced merge at the sliding floor. It is
+    what lets the fullest shard's BASE go once the window has passed it,
+    and the role out of the fail-safe."""
+    loop, cs, res = mesh_role(capacity=256, max_write_ranges=2)
+    cs.auto_reshard = False  # the bootstrap split stays: one shard fills
+    prev, version, n = 0, 1000, 0
+    while res.txns_rejected_fail_safe == 0:
+        txns = [info(version - 50, [], [point(b"k%08d" % (n + j))])
+                for j in range(32)]
+        n += 32
+        _v, fail_safe = drive(loop, res, prev, version, txns, 0)
+        prev, version = version, version + STEP
+        assert n <= 32 * 8
+    assert fail_safe and not cs.overflowed
+    before = [int(x) for x in np.asarray(cs._hist_core.merges)]
+    base_rows = int(np.asarray(cs._hist_core.base.n_used)[1])
+    assert base_rows > 100
+    # the window slides past every write; each rejected batch advances
+    advances = 0
+    while fail_safe:
+        txns = [info(version - 50, [], [point(b"k%08d" % (n + j))])
+                for j in range(32)]
+        n += 32
+        _v, fail_safe = drive(loop, res, prev, version, txns, version - 200)
+        prev, version = version, version + STEP
+        advances += fail_safe
+        assert advances < 12, "the fail-safe never let go"
+    after = [int(x) for x in np.asarray(cs._hist_core.merges)]
+    assert advances >= 1
+    assert all(a - b >= advances for a, b in zip(after, before))
+    assert int(np.asarray(cs._hist_core.base.n_used)[1]) < base_rows
+    m = loop.run(res.get_metrics())
+    assert m["engine"]["hist_merges"] == sum(after)
+    assert m["history_headroom"] == cs.capacity - max(reading_a_shard(cs))
+    assert res.overflow_events == 0 and not cs.overflowed
 
 
 def test_the_policys_cost_is_a_stage_of_the_batch_that_paid_it():
