@@ -227,9 +227,7 @@ def run_tpu_wire(
     resolver RPC). Returns (sec, conflicts, overflow, window_latency_ms,
     shard_occupancy, extras) — occupancy empty unless n_resolvers > 1;
     extras carries the HOST-PACK seconds (the pack half of each window,
-    timed apart from dispatch so the resident-dictionary A/B can quote
-    host pack time per dispatch) and the dictionary-economics counters
-    when the resident engine is active.
+    timed apart from dispatch) and the dictionary-economics counters.
 
     Dispatch is a bounded pipeline (`pipeline_depth` windows in flight,
     the way a real proxy caps outstanding resolver RPCs): window i+depth
@@ -785,18 +783,13 @@ TIERED_DEMOTE_FRAC = 0.05
 
 
 def _roofline_one(mode: ModeConfig, capacity: int, wave_rounds: int,
-                  packed: bool, hist_design: str, peaks: dict,
-                  resident: bool = False) -> dict:
-    """One design point of the analytic per-batch model (see
-    roofline_estimate). Both the packed and unpacked kernels are scored
-    with the SAME term structure so the bytes ratio isolates the format
-    change, and the history terms follow FDB_TPU_HISTORY (the window
-    design amortizes the base table rebuild + merge over the batches one
-    delta fill lasts). `resident` (implies packed) models the
-    device-resident dictionary: per-dispatch dictionary traffic drops to
-    the miss-fraction delta, history probes become 4-byte rank searches,
-    and every history stream (paint, compact, merge) moves 4-byte ranks
-    instead of full-width key rows."""
+                  peaks: dict) -> dict:
+    """The analytic per-batch model of the one kernel design (see
+    roofline_estimate): the window history amortizes the base table
+    rebuild + merge over the batches one delta fill lasts; the
+    device-resident dictionary takes the miss-fraction delta a dispatch;
+    history probes are 4-byte rank searches, and every history stream
+    (paint, compact, merge) moves 4-byte ranks."""
     B, R, Q = mode.batch, mode.n_reads, mode.n_writes
     H = capacity
     G = min(512, B)  # conflict_kernel._ACCEPT_BLOCK
@@ -804,111 +797,56 @@ def _roofline_one(mode: ModeConfig, capacity: int, wave_rounds: int,
     W = (KEY_BYTES + 3) // 4 + 1  # +1 length/terminator word (keypack)
     kb = 4 * W  # bytes per packed key row
     lgH = max(1.0, np.log2(H))
-    N = 2 * B * (R + Q)  # batch endpoints (the deduped dict size bound)
-    lgN = max(1.0, np.log2(N))
+    N = 2 * B * (R + Q)  # batch endpoints (the delta's size bound)
     n2 = 2 * B * Q  # paint endpoints
-    lgn2 = max(1.0, np.log2(max(n2, 2)))
     probes = 2 * B * R  # read endpoints probing the history
 
-    def sp(lg):  # bitonic sort network depth
-        return lg * (lg + 1) / 2
-
-    windowed = hist_design == "window"
     cd = min(H, n2 + 2)  # delta capacity (conflict_set default sizing)
     lgCd = max(1.0, np.log2(cd))
     live = max(1.0, n2 * mode.write_frac)  # endpoints painted per batch
     period = max(1.0, cd / live)  # batches between delta→base merges
 
-    # RMQ table builds; window design pays the delta table per batch and
-    # the base rebuild once per merge.
-    if windowed:
-        table_bytes = lgCd * cd * 8 + (lgH * H * 8) / period
-        table_ops = lgCd * cd + (lgH * H) / period
-        lg_probe = lgH + lgCd  # each endpoint probes base AND delta
-    else:
-        table_bytes = lgH * H * 8
-        table_ops = lgH * H
-        lg_probe = lgH
+    # RMQ table builds: the delta table per batch, the base rebuild once
+    # per merge; each endpoint probes base AND delta.
+    table_bytes = lgCd * cd * 8 + (lgH * H * 8) / period
+    table_ops = lgCd * cd + (lgH * H) / period
+    lg_probe = lgH + lgCd
 
-    # History probes + endpoint rank space + paint endpoint sort.
-    if resident:
-        # Per-slot 4-byte rank probes into the width-1 resident history —
-        # ranks ARE the fingerprint, no cascade, no full-width fallback.
-        search_bytes = probes * lg_probe * 4 + probes * 8
-        search_ops = probes * (lg_probe + 2)
-        # Dictionary traffic is the miss-fraction delta ship plus the
-        # amortized on-device merge rewrite (dict capacity ~2H default).
-        dict_bytes = RESIDENT_MISS_FRAC * (
-            (N + 1) * kb + 2 * (2 * H) * kb + H * 4
-        )
-        rank_sort_bytes = rank_sort_ops = 0.0
-        # Rank paint: the sort permutation ships precomputed from the host
-        # (acceptance-independent — rejected writes merge as delta-0
-        # no-ops), so the device paint is pure gathers over rank rows.
-        paint_sort_bytes = n2 * 24.0 + n2 * 4.0
-        paint_sort_ops = n2 * 6.0
-        rows_bytes = B * B / 8
-        wave_bytes = nblk * wave_rounds * 2 * G * G / 8
-        mask_ops = (B * B + nblk * wave_rounds * 2 * G * G) / 32
-        mxu_flops = 0.0
-    elif packed:
-        # One fingerprint search per UNIQUE dictionary key per side: every
-        # step gathers the 4-byte first-word column; full-width rows only
-        # on first-word ties (~2 per probe); slots gather bounds by rank.
-        # The endpoint rank sort is GONE (host packer dedups+sorts), and
-        # the paint sorts 1-word ranks + an index payload, gathering keys
-        # back from the dictionary.
-        searches = 2 * (N + 1)
-        search_bytes = searches * (lg_probe * 4 + 2 * kb) + probes * 8
-        search_ops = searches * (lg_probe + 2 * W) + probes * 2
-        dict_bytes = (N + 1) * kb
-        rank_sort_bytes = rank_sort_ops = 0.0
-        paint_sort_bytes = sp(lgn2) * n2 * 8 * 2 + n2 * kb
-        paint_sort_ops = sp(lgn2) * n2 + n2 * W
-        # Bit-packed masks: uint32 bitset rows and wave tiles.
-        rows_bytes = B * B / 8
-        wave_bytes = nblk * wave_rounds * 2 * G * G / 8
-        mask_ops = (B * B + nblk * wave_rounds * 2 * G * G) / 32
-        mxu_flops = 0.0  # acceptance is pure VPU bitwise under packing
-    else:
-        search_bytes = probes * lg_probe * kb + probes * 16
-        search_ops = probes * lg_probe * W * 2 + probes * 8
-        dict_bytes = 0.0
-        rank_sort_bytes = sp(lgN) * N * kb * 2
-        rank_sort_ops = sp(lgN) * N * W + 2 * N * lgN * W
-        paint_sort_bytes = sp(lgn2) * n2 * (kb + 12) * 2
-        paint_sort_ops = sp(lgn2) * n2 * W
-        rows_bytes = B * B  # bool rows written+consumed once
-        wave_bytes = nblk * wave_rounds * 2 * G * G
-        mask_ops = 0.0
-        mxu_flops = (
-            nblk * 2.0 * G * B  # cross-block demotion matvecs
-            + nblk * wave_rounds * 2.0 * 2 * G * G  # wave rounds
-        )
-    overlap_ops = B * B * R * Q * 3  # fused overlap compares (both forms)
+    # Per-slot 4-byte rank probes into the width-1 history — ranks ARE
+    # the fingerprint, no cascade, no full-width fallback.
+    search_bytes = probes * lg_probe * 4 + probes * 8
+    search_ops = probes * (lg_probe + 2)
+    # Dictionary traffic is the miss-fraction delta ship plus the
+    # amortized on-device merge rewrite (dict capacity ~2H default).
+    dict_bytes = RESIDENT_MISS_FRAC * (
+        (N + 1) * kb + 2 * (2 * H) * kb + H * 4
+    )
+    # Rank paint: the sort permutation ships precomputed from the host
+    # (acceptance-independent — rejected writes merge as delta-0
+    # no-ops), so the device paint is pure gathers over rank rows.
+    paint_sort_bytes = n2 * 24.0 + n2 * 4.0
+    paint_sort_ops = n2 * 6.0
+    # Bit-packed masks: uint32 bitset rows and wave tiles; acceptance is
+    # pure VPU bitwise.
+    rows_bytes = B * B / 8
+    wave_bytes = nblk * wave_rounds * 2 * G * G / 8
+    mask_ops = (B * B + nblk * wave_rounds * 2 * G * G) / 32
+    mxu_flops = 0.0
+    overlap_ops = B * B * R * Q * 3  # fused overlap compares
 
-    # Paint/compact streaming; window design compacts the small delta per
-    # batch and the full base once per merge. The resident history streams
-    # 4-byte RANK rows where the key formats stream full kb-byte rows.
-    hist_kb = 4 if resident else kb
-    hist_w = 1 if resident else W
-    if windowed:
-        m_batch = cd + n2
-        m_merge = H + cd
-        compact_bytes = (6 * m_batch * hist_kb
-                         + (6 * m_merge * hist_kb) / period)
-        compact_ops = (
-            m_batch * np.log2(max(m_batch, 2)) * hist_w
-            + (m_merge * np.log2(max(m_merge, 2)) * hist_w) / period
-        )
-    else:
-        m_batch = H + n2
-        compact_bytes = 6 * m_batch * hist_kb
-        compact_ops = m_batch * np.log2(max(m_batch, 2)) * hist_w
+    # Paint/compact streaming over 4-byte rank rows: the small delta per
+    # batch and the full base once per merge.
+    m_batch = cd + n2
+    m_merge = H + cd
+    compact_bytes = 6 * m_batch * 4 + (6 * m_merge * 4) / period
+    compact_ops = (
+        m_batch * np.log2(max(m_batch, 2))
+        + (m_merge * np.log2(max(m_merge, 2))) / period
+    )
 
-    int_ops = (table_ops + search_ops + rank_sort_ops + paint_sort_ops
+    int_ops = (table_ops + search_ops + paint_sort_ops
                + overlap_ops + mask_ops + compact_ops)
-    bytes_moved = (table_bytes + search_bytes + dict_bytes + rank_sort_bytes
+    bytes_moved = (table_bytes + search_bytes + dict_bytes
                    + paint_sort_bytes + rows_bytes + wave_bytes
                    + compact_bytes)
     t_vpu = int_ops / peaks["vpu_int_ops_per_s"]
@@ -929,60 +867,21 @@ def _roofline_one(mode: ModeConfig, capacity: int, wave_rounds: int,
 
 
 def roofline_estimate(mode: ModeConfig, capacity: int, device_kind: str,
-                      wave_rounds: int = 4, packed: "bool | None" = None,
-                      hist_design: "str | None" = None,
-                      resident: "bool | None" = None,
-                      n_shards: int = 1,
+                      wave_rounds: int = 4, n_shards: int = 1,
                       exchange_stats: "dict | None" = None) -> dict:
-    """Per-batch work estimate for resolve_batch at this mode's shapes,
-    against the published peaks of `device_kind` (ValueError for a device
-    the table does not hold).
+    """Per-batch work estimate for the resolve program at this mode's
+    shapes, against the published peaks of `device_kind` (ValueError for a
+    device the table does not hold).
 
-    Models the kernel under the ACTIVE design flags (FDB_TPU_PACKED /
-    FDB_TPU_HISTORY, defaulting to the env the way conflict_kernel reads
-    them): history table builds + probes (fingerprint dictionary probes
-    when packed), endpoint rank space (host-side when packed), per-block
-    fused overlap rows [G, B] (uint32 bitsets when packed) with the
-    within-block [G, G] waves, then the merge/compact paint. Word width
-    W is the packed-key int32 width; sorts modeled as bitonic log²N.
-    Bounds which resource saturates and what peak txns/s/chip the
-    hardware admits — not exact. Always carries the UNPACKED counterfactual
-    (same shapes, same term structure) so the packed-format byte cut is
-    auditable from one record."""
+    Models the one kernel design: history table builds + rank probes,
+    per-block fused overlap rows [G, B] as uint32 bitsets with the
+    within-block [G, G] waves, then the merge/compact paint over rank
+    rows. Bounds which resource saturates and what peak txns/s/chip the
+    hardware admits — not exact."""
     import os
 
-    if packed is None:
-        packed = os.environ.get("FDB_TPU_PACKED", "1") != "0"
-    if hist_design is None:
-        hist_design = os.environ.get("FDB_TPU_HISTORY", "window")
-    # Explicit resident=False pins the packed (non-resident) model — a
-    # caller asserting on the packed design must not silently score the
-    # resident one because the env default is on.
-    if resident is None:
-        resident = os.environ.get("FDB_TPU_RESIDENT", "1") != "0"
-    resident = packed and resident
     peaks = device_peaks(device_kind)
-    est = _roofline_one(mode, capacity, wave_rounds, packed, hist_design,
-                        peaks, resident=resident)
-    base = (est if not packed
-            else _roofline_one(mode, capacity, wave_rounds, False, hist_design,
-                               peaks))
-    # The resident counterfactual rides in EVERY record (bytes/batch with
-    # the per-dispatch dictionary traffic removed), next to the existing
-    # packed/unpacked pair, so the modeled HBM saving is auditable from
-    # one artifact regardless of which design actually ran.
-    res = (est if resident else _roofline_one(
-        mode, capacity, wave_rounds, True, hist_design, peaks, resident=True
-    ))
-    pk = (est if packed and not resident else _roofline_one(
-        mode, capacity, wave_rounds, True, hist_design, peaks
-    ))
-    est["packed"] = packed
-    est["resident"] = resident
-    est["history_design"] = hist_design
-    est["bytes_per_batch_unpacked"] = base["bytes_per_batch"]
-    est["bytes_per_batch_packed"] = pk["bytes_per_batch"]
-    est["bytes_per_batch_resident"] = res["bytes_per_batch"]
+    est = _roofline_one(mode, capacity, wave_rounds, peaks)
     est["resident_miss_frac_modeled"] = RESIDENT_MISS_FRAC
     # Tiered-dictionary counterfactual (ISSUE 18): the resident model at
     # the HOT-tier capacity (the dictionary the device actually holds)
@@ -993,9 +892,8 @@ def roofline_estimate(mode: ModeConfig, capacity: int, device_kind: str,
     # record still carries the counterfactual at the full capacity.
     hot_cap = int(os.environ.get("FDB_TPU_DICT_HOT_CAPACITY", "0") or 0)
     hot_cap = hot_cap if 0 < hot_cap < capacity else capacity
-    tr = (_roofline_one(mode, hot_cap, wave_rounds, True, hist_design,
-                        peaks, resident=True)
-          if hot_cap != capacity else res)
+    tr = (_roofline_one(mode, hot_cap, wave_rounds, peaks)
+          if hot_cap != capacity else est)
     n_words = (KEY_BYTES + 3) // 4
     demote_slots = min(hot_cap // 2,
                        2 * mode.batch * mode.n_writes + 2)  # delta sizing
@@ -1012,12 +910,6 @@ def roofline_estimate(mode: ModeConfig, capacity: int, device_kind: str,
         "repack_vs_demote_ratio": round(
             repack_bytes / max(demote_bytes, 1.0), 1),
     }
-    est["packed_bytes_ratio"] = round(
-        base["bytes_per_batch"] / max(est["bytes_per_batch"], 1), 2
-    )
-    est["resident_bytes_ratio"] = round(
-        pk["bytes_per_batch"] / max(res["bytes_per_batch"], 1), 2
-    )
     # Buffer-donation audit (ISSUE 17 satellite): every state-mutating jit
     # in conflict_kernel (_resolve*, _advance*, _paint_many*) donates
     # argnum 0, so XLA aliases the history arrays in place instead of
@@ -1243,9 +1135,8 @@ def run_config(
             "p50_ms": pct(tpu_lat, 50),
             "p99_ms": pct(tpu_lat, 99),
             "batches_per_dispatch": window,
-            # Host pack seconds measured apart from dispatch — the
-            # resident-dictionary A/B's pack-time yardstick — plus the
-            # dictionary-economics counters (None unless resident).
+            # Host pack seconds measured apart from dispatch, plus the
+            # dictionary-economics counters.
             **wire_extras,
         }, len(tpu_lat)),
         # Adaptive dispatch (sched subsystem): deadline coalescing +

@@ -214,7 +214,6 @@ def engine_phase(capacity: int = ENGINE_CAPACITY, n_keys: int = ENGINE_KEYS,
                  batch: "int | None" = None, seed: int = SEED) -> dict:
     """The resolve engine alone, at deployment size, against the skiplist."""
     import bench
-    from foundationdb_tpu.models import conflict_kernel as ck
     from foundationdb_tpu.models.conflict_set import TPUConflictSet
 
     check(n_batches % window == 0, "n_batches must be whole windows")
@@ -228,8 +227,7 @@ def engine_phase(capacity: int = ENGINE_CAPACITY, n_keys: int = ENGINE_KEYS,
         max_read_ranges=mode.n_reads, max_write_ranges=mode.n_writes,
         max_key_bytes=bench.KEY_BYTES, window_versions=bench.WINDOW,
     )
-    check(cs.resident and ck._HIST_DESIGN == "window" and ck._PACKED
-          and not (cs.wave_commit or cs.spec or cs.tiered),
+    check(not (cs.wave_commit or cs.spec or cs.tiered),
           "engine is not at its default design point (FDB_TPU_* set?)")
     got, window_s = _resolve_stream(cs, mode, blob, ends, n_batches, window)
     log(f"engine: windows took {window_s}s")

@@ -53,8 +53,8 @@ WAVE_LEVEL_CYCLE = -2
 def env_choice(name: str, default: str, allowed: tuple[str, ...]) -> str:
     """Validated FDB_TPU_* env flag: an unknown value raises with the
     accepted list instead of silently falling through to the default (a
-    typo'd FDB_TPU_ACCEPT=Seq used to bench the wave design while
-    claiming the seq one). One definition here — importable WITHOUT
+    typo'd value would otherwise run the default while claiming the
+    variant). One definition here — importable WITHOUT
     device code — serves the kernel's import-once flags, the sim/server
     wave default, and the compile-cache knob alike."""
     import os

@@ -10,16 +10,22 @@ completely different shape:
   exact, not approximate, because the reference hands out ONE commit version
   per resolve batch (masterserver → CommitProxy getVersion), so every write
   of a batch lands at the same version.
+- ONE design (ROADMAP C1): the endpoint-key dictionary and the history live
+  on the device across dispatches (ResState); the host ships each dispatch's
+  never-seen keys with their ranks and every endpoint as a rank
+  (ResidentBatch), so the history is a width-1 step function over ranks; it
+  is kept in two levels (HistState: a frozen base with its prebuilt RMQ
+  table, a small delta every batch probes and paints, merged on demand).
 - A batch resolve is one ``jit``ted call of dense ops: binary-search every
   read endpoint into K, sparse-table range-max for "newest write version
   overlapping this read", a rank-space pairwise overlap matrix for intra-batch
-  read-vs-earlier-write conflicts, and a wave-relaxation loop (matvec rounds)
-  that reproduces the reference's sequential acceptance order without a
-  sequential scan.
-- Accepted writes are painted into the step function with a sort-merge +
-  coverage prefix-sum, then boundaries made redundant (equal adjacent
-  versions, expired segments) are compacted out — the analogue of the
-  reference skiplist's insert + version-window GC.
+  read-vs-earlier-write conflicts, and a wave-relaxation loop (rounds over
+  bit-packed [G, G/32] tiles) that reproduces the reference's sequential
+  acceptance order without a sequential scan.
+- Accepted writes are painted into the step function with a merge-path
+  interleave + coverage prefix-sum, then boundaries made redundant (equal
+  adjacent versions, expired segments) are compacted out — the analogue of
+  the reference skiplist's insert + version-window GC.
 
 Phases carry ``jax.named_scope`` names, so a profiler trace of the REAL
 program says which phase an operation belongs to (its ``op_name`` path):
@@ -28,8 +34,9 @@ upkeep: apply_delta, apply_evict, apply_dict_remap), ``hist_merge``
 (_maybe_merge, advance_hist), ``history_probe`` (too-old mask + reads vs
 history), ``endpoint_ranks``, ``accept`` (block scan or wave schedule),
 ``paint_compact`` and ``verdicts``. The names sit on the shared helpers,
-so every entry point (flat / hist, raw / packed / resident, wave) speaks
-one vocabulary. Metadata only: the compiled program does not change.
+so every entry point (one batch / a scanned window, report, wave, the
+mesh's shard step) speaks one vocabulary. Metadata only: the compiled
+program does not change.
 
 Everything is static-shape; hosts pad batches (see conflict_set.TPUConflictSet).
 Versions on device are int32, relative to a host-held base (the MVCC window
@@ -39,7 +46,6 @@ is ~5-7M versions, far inside int32; the host rebases periodically).
 from __future__ import annotations
 
 import functools
-import os
 from typing import NamedTuple
 
 import jax
@@ -57,67 +63,11 @@ from foundationdb_tpu.ops.bitset import (
     pack_bits_u32,
     unpack_bits_u32,
 )
-from foundationdb_tpu.ops.lex import (
-    lex_lt,
-    lex_max,
-    lex_min,
-    searchsorted_words,
-    searchsorted_words_2sided_fp,
-    searchsorted_words_fp,
-    sort_keys_with_payload,
-    sort_ranks_with_payload,
-)
-from foundationdb_tpu.ops.rmq import (
-    block_table,
-    range_max,
-    range_max_blocked,
-    sparse_table,
-)
+from foundationdb_tpu.ops.lex import searchsorted_words, searchsorted_words_fp
+from foundationdb_tpu.ops.rmq import range_max, sparse_table
 
 NEG_VERSION = -(2**31) + 1
 
-
-# History RMQ implementation: "sparse" (default) | "blocked". Read once at
-# import — flipping it mid-process would silently split jit caches.
-_RMQ_DESIGN = _env_choice("FDB_TPU_RMQ", "sparse", ("sparse", "blocked"))
-
-# Within-block acceptance design: "wave" (default — data-dependent matvec
-# relaxation rounds) | "seq" (a fixed G-step sequential fori_loop over the
-# block tile). The wave wins when conflict chains are shallow (few rounds,
-# each an MXU matvec); mako-shaped 95%-conflict Zipf batches drive deep
-# chains where the wave's round count approaches G anyway with two [G, G]
-# matvecs per round — there the bounded trivial-step scan may win
-# (VERDICT r3 item 4). Same import-once rule as the RMQ flag; neither
-# arm has been ranked on the chip at full-kernel level.
-_ACCEPT_DESIGN = _env_choice("FDB_TPU_ACCEPT", "wave", ("wave", "seq"))
-
-# History design: "window" (default — two-level base+delta: the base
-# sparse table is built once per merge epoch, per-batch work touches only
-# the small delta) | "batch" (r4 behavior: one flat step function whose
-# sparse table is rebuilt EVERY batch — the O(C·log C)/batch hot-path
-# cost VERDICT r4 item 2 ordered out). Import-once rule as above;
-# neither arm has been ranked on the chip.
-_HIST_DESIGN = _env_choice("FDB_TPU_HISTORY", "window", ("window", "batch"))
-
-# Packed-kernel design: "1" (default) | "0" (the r5 unpacked kernel, kept
-# as the A/B baseline — scripts/kernel_ab.sh). Three stacked HBM-diet
-# reductions, byte-identical verdicts (oracle-tested):
-#   1. rank-space history probes — the host packer dedups+sorts the
-#      batch's endpoint keys ONCE per dispatch (PackedBatch.dict_keys);
-#      the [C, W] history is probed once per UNIQUE key with a first-word
-#      fingerprint fast path (ops/lex.searchsorted_words_fp), so the
-#      common probe step touches 4 bytes instead of 4·W, and the device
-#      endpoint-rank sort disappears entirely (ranks arrive precomputed).
-#   2. rank-carried paint — the paint pass sorts int32 ranks (1 word)
-#      instead of [n2, W] keys and gathers boundary keys back from the
-#      dictionary (the step-function analogue of Redwood's page prefix
-#      compression: the shared key bytes live once, in the dictionary).
-#   3. bit-packed conflict masks — the [G, B] overlap rows, the [G, G]
-#      wave tiles, and the per-txn loser-range report become uint32
-#      bitsets (ops/bitset): 8x fewer bytes than bool, 16x fewer than
-#      the bf16 MXU tiles, on the acceptance loop's hottest operands.
-# Same import-once rule as the flags above.
-_PACKED = _env_choice("FDB_TPU_PACKED", "1", ("0", "1")) != "0"
 
 # Wave-commit mode: "0" (default — sequential-order acceptance, conflicts
 # abort) | "1" (reorder-don't-abort: the same conflict graph schedules
@@ -125,22 +75,9 @@ _PACKED = _env_choice("FDB_TPU_PACKED", "1", ("0", "1")) != "0"
 # see _wave_commit_accept). Selects the ENGINE DEFAULT only: both modes'
 # entry points are separate jitted programs, so hosts can construct
 # engines of either mode in one process (TPUConflictSet(wave_commit=...)).
+# Read once at import — flipping it mid-process would silently split jit
+# caches.
 _WAVE_COMMIT = _env_choice("FDB_TPU_WAVE_COMMIT", "0", ("0", "1")) == "1"
-
-# Device-resident dictionary mode: "1" (default) | "0" (the per-dispatch
-# repack baseline — scripts/resident_ab.sh A/Bs the two). Under resident
-# mode the endpoint-key dictionary AND the MVCC history PERSIST in device
-# memory across dispatches: the host ships only the DELTA of
-# never-before-seen endpoint keys per dispatch, each with the rank its
-# mirror found for it (merged on-device by _dict_insert, which therefore
-# searches nothing, with a rank-rebase that shifts existing history ranks
-# past the inserted positions), and the history itself lives in RANK
-# SPACE — width-1 int32 rank rows instead of [C, W] key rows — so every
-# history probe, paint sort, and merge streams 1/W of the key bytes and
-# the full dictionary never crosses PCIe after the first repack.
-# Requires the packed kernel (rank-space batches); under FDB_TPU_PACKED=0
-# the flag is inert. Same import-once rule as the flags above.
-_RESIDENT = (_env_choice("FDB_TPU_RESIDENT", "1", ("0", "1")) == "1") and _PACKED
 
 # Speculative pipelined resolve: "0" (default — windows resolve strictly
 # in order, the A/B baseline) | "1" (window N+1 dispatches against window
@@ -148,12 +85,8 @@ _RESIDENT = (_env_choice("FDB_TPU_RESIDENT", "1", ("0", "1")) == "1") and _PACKE
 # committed while N's verdicts are still in flight / unconfirmed by the
 # upper layer; a host-side reconcile ring confirms or repairs when the
 # verdicts land — see conflict_set.TPUConflictSet.spec_dispatch_window).
-# Requires the packed kernel (the dependency probe runs over the batch
-# dictionary); inert under FDB_TPU_PACKED=0, mirroring _RESIDENT's
-# gating. Same import-once rule as the flags above.
-_SPEC_RESOLVE = (
-    _env_choice("FDB_TPU_SPEC_RESOLVE", "0", ("0", "1")) == "1"
-) and _PACKED
+# Same import-once rule as the flag above.
+_SPEC_RESOLVE = _env_choice("FDB_TPU_SPEC_RESOLVE", "0", ("0", "1")) == "1"
 
 # Verdict encoding (core.types.Verdict values, as device int8).
 V_COMMITTED = 0
@@ -187,31 +120,6 @@ class BatchTensors(NamedTuple):
     cont: jax.Array | None = None
 
 
-class PackedBatch(NamedTuple):
-    """One padded resolver batch in RANK SPACE (FDB_TPU_PACKED=1).
-
-    The host packer dedups+sorts all of the batch's endpoint keys once per
-    dispatch (conflict_set.TPUConflictSet._pack_dict): ``dict_keys`` holds
-    the sorted unique keys padded with +inf rows (the LAST row is always
-    +inf — paint parks masked slots there), and every range endpoint is an
-    int32 rank into it. Ranks are order-isomorphic to byte order with
-    identical tie structure (equal keys share a rank), so emptiness and
-    overlap tests are scalar int32 compares, the history is probed once
-    per unique key instead of once per endpoint slot, and the paint pass
-    sorts 1-word ranks instead of W-word keys."""
-
-    dict_keys: jax.Array  # int32 [N + 1, W] sorted unique, +inf padded
-    read_begin: jax.Array  # int32 [B, R] ranks into dict_keys
-    read_end: jax.Array  # int32 [B, R]
-    read_mask: jax.Array  # bool [B, R]
-    write_begin: jax.Array  # int32 [B, Q]
-    write_end: jax.Array  # int32 [B, Q]
-    write_mask: jax.Array  # bool [B, Q]
-    read_version: jax.Array  # int32 [B] (relative)
-    txn_mask: jax.Array  # bool [B]
-    cont: jax.Array | None = None  # as BatchTensors.cont
-
-
 def init_state(capacity: int, width: int, min_key) -> ConflictState:
     """min_key: the codec's packed b"" (KeyCodec.min_key) — boundary 0."""
     keys = jnp.full((capacity, width), INT32_MAX, dtype=jnp.int32)
@@ -224,50 +132,6 @@ def init_state(capacity: int, width: int, min_key) -> ConflictState:
         oldest=jnp.int32(0),
         overflow=jnp.zeros((), jnp.bool_),
     )
-
-
-# ---------------------------------------------------------------------------
-# Phase 1: history conflicts (reads vs committed writes of earlier batches)
-# ---------------------------------------------------------------------------
-
-
-@jax.named_scope("history_probe")
-def _history_conflict_ranges(
-    state: ConflictState, batch: BatchTensors
-) -> jax.Array:
-    """bool [B, R]: read range slot overlaps a historical write newer than
-    rv — the per-range form the conflicting-keys report path needs (which
-    read ranges LOST, reference: conflictingKRIndices)."""
-    b, r, w = batch.read_begin.shape
-    rb = batch.read_begin.reshape(b * r, w)
-    re_ = batch.read_end.reshape(b * r, w)
-    # Segments [lo, hi) intersect [rb, re): lo = segment containing rb,
-    # hi = first segment starting at/after re.
-    lo = searchsorted_words(state.keys, rb, side="right") - 1
-    hi = searchsorted_words(state.keys, re_, side="left")
-    # RMQ design: sparse table by default. The blocked two-level
-    # alternative wins its ISOLATED build+query A/B 3.5x on CPU-XLA but
-    # regressed the FULL kernel 27% there (fusion effects) — production
-    # stays on the sparse table; FDB_TPU_RMQ=blocked flips it so the
-    # auto-bench can rank both at full-kernel level on the real chip.
-    if _RMQ_DESIGN == "blocked":
-        bt = block_table(state.versions, NEG_VERSION)
-        newest = range_max_blocked(
-            bt, jnp.maximum(lo, 0), hi, NEG_VERSION
-        ).reshape(b, r)
-    else:
-        st = sparse_table(state.versions)
-        newest = range_max(
-            st, jnp.maximum(lo, 0), hi, NEG_VERSION
-        ).reshape(b, r)
-    nonempty = lex_lt(batch.read_begin, batch.read_end)
-    live = batch.read_mask & nonempty
-    return live & (newest > batch.read_version[:, None])
-
-
-def _history_conflicts(state: ConflictState, batch: BatchTensors) -> jax.Array:
-    """bool [B]: some read range overlaps a historical write newer than rv."""
-    return jnp.any(_history_conflict_ranges(state, batch), axis=1)
 
 
 def _read_vs_accepted_writes(
@@ -302,34 +166,6 @@ def _read_vs_accepted_writes(
 # ---------------------------------------------------------------------------
 # Phase 2: intra-batch conflict graph + wave acceptance
 # ---------------------------------------------------------------------------
-
-
-def _endpoint_ranks(batch: BatchTensors) -> tuple[jax.Array, ...]:
-    """Map all batch endpoints into a shared dense rank space.
-
-    Strict byte order is preserved among the batch's own endpoints (ranks via
-    searchsorted-left into the sorted endpoint multiset), so interval overlap
-    tests downstream are scalar int32 compares — no word axis.
-    """
-    b, r, w = batch.read_begin.shape
-    q = batch.write_begin.shape[1]
-    flat = jnp.concatenate(
-        [
-            batch.read_begin.reshape(b * r, w),
-            batch.read_end.reshape(b * r, w),
-            batch.write_begin.reshape(b * q, w),
-            batch.write_end.reshape(b * q, w),
-        ]
-    )
-    (sorted_keys,) = sort_keys_with_payload(flat)
-    ranks = searchsorted_words(sorted_keys, flat, side="left")
-    n_r = b * r
-    n_q = b * q
-    rb = ranks[:n_r].reshape(b, r)
-    re_ = ranks[n_r : 2 * n_r].reshape(b, r)
-    wb = ranks[2 * n_r : 2 * n_r + n_q].reshape(b, q)
-    we = ranks[2 * n_r + n_q :].reshape(b, q)
-    return rb, re_, wb, we
 
 
 # Above this many (read-slot × write-slot) pairs the unrolled overlap form
@@ -378,23 +214,6 @@ def _overlap_rows(
     return m
 
 
-@jax.named_scope("endpoint_ranks")
-def endpoint_ranks_live(batch: BatchTensors) -> tuple[jax.Array, ...]:
-    """(rb, re, read_live, wb, we, write_live): endpoint ranks plus the
-    liveness masks (slot populated AND range non-empty in rank space) —
-    the shared precursor of every acceptance path."""
-    rb, re_, wb, we = _endpoint_ranks(batch)
-    read_live = batch.read_mask & (rb < re_)  # [B, R]
-    write_live = batch.write_mask & (wb < we)  # [B, Q]
-    return rb, re_, read_live, wb, we, write_live
-
-
-def _pairwise_overlap(batch: BatchTensors) -> jax.Array:
-    """M[i, j] (bool [B, B]): some read range of txn i overlaps some write
-    range of txn j."""
-    return _overlap_rows(*endpoint_ranks_live(batch))
-
-
 # Block size for the block-sequential acceptance scan. Within a block the
 # wave relaxation runs on a [G, G] tile (0.5 MB at G=512 — VMEM-resident);
 # cross-block influence is a single [G, B] matvec per block. This bounds
@@ -420,19 +239,18 @@ def _block_scan_accept(base, xs_rows, make_rows):
     xs_rows: pytree whose leaves have leading axis nblk; make_rows maps
     one slice of it to that block's [G, B] overlap rows.
 
-    Packed-mask form (FDB_TPU_PACKED=1, block size a multiple of 32): the
-    [G, B] rows are uint32-packed the moment they are built and never
-    touched as bool again — the cross-block demotion matvec becomes a
+    Packed-mask form (block size a multiple of 32; a smaller batch keeps
+    bool rows and the bf16 matvec): the [G, B] rows are uint32-packed the
+    moment they are built and never touched as bool again — the cross-block demotion matvec becomes a
     bitwise AND + any-reduce against the packed accepted vector (1/8 the
     row bytes, no bool→bf16 conversion, no MXU round trip), the accepted
     carry itself is a [B/32] bitset, and the within-block tile handed to
-    the wave/seq accept is the packed [G, G/32] diagonal slice.
+    the wave accept is the packed [G, G/32] diagonal slice.
     """
     b = base.shape[0]
     g = min(_ACCEPT_BLOCK, b)
     nblk = b // g
-    packed = _PACKED and g % 32 == 0
-    seq = _ACCEPT_DESIGN == "seq"
+    packed = g % 32 == 0
 
     def body(acc, xs):
         rows_x, base_k, k = xs
@@ -443,8 +261,7 @@ def _block_scan_accept(base, xs_rows, make_rows):
             sub = jax.lax.dynamic_slice(
                 rp, (jnp.int32(0), k * (g // 32)), (g, g // 32)
             )
-            accept_fn = _seq_accept_packed if seq else _wave_accept_packed
-            acc_k = accept_fn(base_k & ~prior_hit, sub)
+            acc_k = _wave_accept_packed(base_k & ~prior_hit, sub)
             acc = jax.lax.dynamic_update_slice(
                 acc, pack_bits_u32(acc_k), (k * (g // 32),)
             )
@@ -458,8 +275,7 @@ def _block_scan_accept(base, xs_rows, make_rows):
                 > 0.0
             )
             sub = jax.lax.dynamic_slice(rows_k, (jnp.int32(0), k * g), (g, g))
-            accept_fn = _seq_accept if seq else _wave_accept
-            acc_k = accept_fn(base_k & ~prior_hit, sub)
+            acc_k = _wave_accept(base_k & ~prior_hit, sub)
             acc = jax.lax.dynamic_update_slice(acc, acc_k, (k * g,))
         return acc, None
 
@@ -586,28 +402,6 @@ def txn_fold_overlap(m: jax.Array, seg: TxnSegments) -> jax.Array:
     return _seg_any(m, seg, 0) & seg.head[:, None]
 
 
-def _seq_accept(base: jax.Array, m: jax.Array) -> jax.Array:
-    """Exact sequential acceptance as a fixed G-step fori_loop.
-
-    The literal transcription of the reference's per-txn order
-    (ConflictBatch processes transactions strictly in sequence): step i
-    accepts txn i iff base[i] and no already-accepted predecessor's writes
-    overlap its reads. Each step is a [G] AND + any-reduce + one-element
-    update — trivial VPU work, no matvec, no data-dependent trip count.
-    Worst case and best case cost the same G steps, which beats the wave
-    exactly when conflict chains are deep enough that its data-dependent
-    round count (2 [G, G] matvecs per round) approaches G."""
-    g = base.shape[0]
-    tri = jnp.tril(jnp.ones((g, g), jnp.bool_), k=-1)
-    p = m & tri
-
-    def body(i, acc):
-        hit = jnp.any(p[i] & acc)
-        return acc.at[i].set(base[i] & ~hit)
-
-    return jax.lax.fori_loop(0, g, body, jnp.zeros_like(base))
-
-
 def _wave_accept(base: jax.Array, m: jax.Array) -> jax.Array:
     """Reproduce sequential in-order acceptance with O(depth) matvec rounds.
 
@@ -688,25 +482,6 @@ def _wave_accept_packed(base: jax.Array, p: jax.Array) -> jax.Array:
     acc0 = jnp.zeros_like(base)
     _, acc, _ = jax.lax.while_loop(cond, step, (det0, acc0, jnp.int32(0)))
     return acc
-
-
-def _seq_accept_packed(base: jax.Array, p: jax.Array) -> jax.Array:
-    """_seq_accept over the packed [G, G/32] bitset: step i ANDs its
-    predecessor row against the packed accepted set and sets one bit. No
-    triangle mask is needed — bits j >= i are still zero in the accepted
-    set when step i runs, exactly the sequential invariant."""
-    g = base.shape[0]
-
-    def body(i, accp):
-        hit = jnp.any((p[i] & accp) != 0)
-        bit = (base[i] & ~hit).astype(jnp.uint32) << (i & 31).astype(
-            jnp.uint32
-        )
-        word = i >> 5
-        return accp.at[word].set(accp[word] | bit)
-
-    accp = jax.lax.fori_loop(0, g, body, jnp.zeros((g // 32,), jnp.uint32))
-    return unpack_bits_u32(accp, g)
 
 
 # ---------------------------------------------------------------------------
@@ -968,64 +743,6 @@ def _wave_commit_accept(
 # ---------------------------------------------------------------------------
 
 
-@jax.named_scope("paint_compact")
-def _paint_and_compact(
-    state: ConflictState,
-    batch: BatchTensors,
-    accepted: jax.Array,
-    commit_version: jax.Array,
-    new_oldest: jax.Array,
-) -> ConflictState:
-    """Fold accepted writes into the step function WITHOUT re-sorting the
-    whole history. The history keys are already sorted, so only the batch's
-    2·B·Q new endpoints are sorted ([2BQ, W], tiny next to [C+2BQ, W]); the
-    two sorted sequences are then interleaved by rank arithmetic (the
-    merge-path construction: each element's output slot is its own index
-    plus its cross-rank in the other sequence, history winning ties), and
-    the surviving boundaries are compacted to the front by streaming
-    shifts (_dedup_compact). No full-history sort (the first version of
-    this kernel re-sorted all of C per batch). The interleave itself
-    (_paint_tail) still searches and gathers once a slot: at the delta's
-    size, n = 16,386, that is what is left of ROADMAP A5. What the chip
-    charges for each kind of pass is in _dedup_compact's docstring."""
-    c, w = state.keys.shape
-    b, q, _ = batch.write_begin.shape
-    e2 = b * q
-    n2 = 2 * e2
-    n = c + n2
-
-    valid = (
-        accepted[:, None]
-        & batch.write_mask
-        & lex_lt(batch.write_begin, batch.write_end)
-    )  # [B, Q]
-    inf_row = jnp.full((w,), INT32_MAX, jnp.int32)
-    wb = jnp.where(valid[..., None], batch.write_begin, inf_row).reshape(e2, w)
-    we = jnp.where(valid[..., None], batch.write_end, inf_row).reshape(e2, w)
-
-    # New endpoints with their coverage delta and their segment's pre-paint
-    # version (the version a split boundary must inherit).
-    new_keys = jnp.concatenate([wb, we])  # [n2, W]
-    new_delta = jnp.concatenate(
-        [valid.reshape(e2).astype(jnp.int32), -valid.reshape(e2).astype(jnp.int32)]
-    )
-    # ONE history search serves both uses below: cross_rank on the raw
-    # endpoints gives seg (containing segment), and — carried through the
-    # sort as a payload — its sorted permutation IS the cross-rank of the
-    # sorted endpoints (searchsorted of a permuted set permutes the same
-    # way), which the merge-path needs for pos_n.
-    cross_rank = searchsorted_words(state.keys, new_keys, side="right")
-    seg = cross_rank - 1
-    new_oldv = state.versions[jnp.maximum(seg, 0)]
-
-    snew, sdelta_new, soldv_new, scross = sort_keys_with_payload(
-        new_keys, new_delta, new_oldv, cross_rank
-    )
-    return _paint_tail(
-        state, snew, sdelta_new, soldv_new, scross, commit_version, new_oldest
-    )
-
-
 def _paint_tail(
     state: ConflictState,
     snew: jax.Array,
@@ -1038,9 +755,8 @@ def _paint_tail(
     """Shared merge-path + coverage + compact tail of the paint pass.
 
     Inputs are the SORTED new endpoints (snew [n2, W] keys, coverage
-    deltas, pre-paint segment versions, cross-ranks into the history) —
-    produced by the W-word key sort on the unpacked path and by the
-    1-word rank sort + dictionary gather on the packed path."""
+    deltas, pre-paint segment versions, cross-ranks into the history), as
+    _paint_and_compact_res gathers them; nothing here assumes W = 1."""
     c, w = state.keys.shape
     n2 = snew.shape[0]
     n = c + n2
@@ -1196,49 +912,9 @@ def _dedup_compact(skeys, newv, c_out, prior_overflow):
     return fkeys, fv, jnp.minimum(n_used, c_out), overflow
 
 
-def clip_batch(batch: BatchTensors, lo: jax.Array, hi: jax.Array) -> BatchTensors:
-    """Restrict every range to the keyspace shard [lo, hi).
-
-    The device-side analogue of the reference CommitProxy's per-resolver
-    conflict-range split (CommitProxyServer.actor.cpp: ranges are routed to
-    resolvers by keyRange shard). Ranges outside the shard become empty and
-    drop out of their masks; read_version/txn_mask are untouched (TOO_OLD is
-    judged on the unclipped batch so all shards agree).
-    """
-    rb = lex_max(batch.read_begin, lo)
-    re_ = lex_min(batch.read_end, hi)
-    wb = lex_max(batch.write_begin, lo)
-    we = lex_min(batch.write_end, hi)
-    return batch._replace(
-        read_begin=rb,
-        read_end=re_,
-        read_mask=batch.read_mask & lex_lt(rb, re_),
-        write_begin=wb,
-        write_end=we,
-        write_mask=batch.write_mask & lex_lt(wb, we),
-    )
-
-
 # ---------------------------------------------------------------------------
-# Entry: full resolve step
+# Verdicts, the loser report and the acceptance dispatch
 # ---------------------------------------------------------------------------
-
-
-@jax.named_scope("history_probe")
-def too_old_mask(
-    state: ConflictState, batch: BatchTensors, new_oldest: jax.Array
-) -> tuple[jax.Array, jax.Array]:
-    """(floor, too_old[B]). The window floor advances BEFORE resolution
-    (reference: Resolver sets ConflictSet::oldestVersion from the request,
-    then detects conflicts) and never regresses — a caller passing a
-    regressed new_oldest must not reopen a window whose writes were GC'd.
-    Write-only transactions are never too old."""
-    has_reads = jnp.any(
-        batch.read_mask & lex_lt(batch.read_begin, batch.read_end), axis=1
-    )
-    floor = jnp.maximum(state.oldest, new_oldest)
-    too_old = batch.txn_mask & has_reads & (batch.read_version < floor)
-    return floor, too_old
 
 
 @jax.named_scope("verdicts")
@@ -1295,41 +971,6 @@ def _accept_or_schedule(base, ranks, wave: bool, cont=None):
             None if levels is None else txn_spread(levels, seg))
 
 
-def resolve_batch(
-    state: ConflictState,
-    batch: BatchTensors,
-    commit_version: jax.Array,
-    new_oldest: jax.Array,
-    report: bool = False,
-    wave: bool = False,
-):
-    """Resolve one batch and fold its accepted writes into the history.
-
-    Returns (verdicts int8 [B], new_state) — with `report` (a static
-    Python flag; each value compiles its own program), (verdicts,
-    loser_mask bool [B, R], new_state). Mirrors the reference call
-    sequence ConflictBatch::detectConflicts → combineWriteConflictRanges →
-    SkipList::addConflictRanges, as one compiled program.
-
-    `wave` (static) switches intra-batch acceptance to the wave-commit
-    schedule and inserts the int32 [B] wave levels right after the
-    verdicts in every return shape.
-    """
-    floor, too_old = too_old_mask(state, batch, new_oldest)
-    hist_mask = _history_conflict_ranges(state, batch)
-    hist_conflict = jnp.any(hist_mask, axis=1)
-    base = batch.txn_mask & ~too_old & ~hist_conflict
-    ranks = endpoint_ranks_live(batch)
-    accepted, levels = _accept_or_schedule(base, ranks, wave, batch.cont)
-    verdicts = assemble_verdicts(too_old, batch.txn_mask, accepted)
-    new_state = _paint_and_compact(state, batch, accepted, commit_version, floor)
-    out = (verdicts, levels) if wave else (verdicts,)
-    if report:
-        losers = loser_range_mask(hist_mask, ranks, accepted, verdicts)
-        return (*out, losers, new_state)
-    return (*out, new_state)
-
-
 def rebase(state: ConflictState, delta: jax.Array) -> ConflictState:
     """Shift all relative versions down by delta (host rebases its offset).
 
@@ -1343,44 +984,13 @@ def rebase(state: ConflictState, delta: jax.Array) -> ConflictState:
     )
 
 
-def resolve_many(
-    state: ConflictState,
-    batches: BatchTensors,  # leading scan axis [k, ...] on every leaf
-    commit_versions: jax.Array,  # int32 [k], strictly increasing
-    new_oldests: jax.Array,  # int32 [k], non-decreasing
-    wave: bool = False,
-):
-    """Resolve k batches in ONE compiled program (device-side lax.scan).
-
-    Semantically identical to k sequential resolve_batch calls; exists
-    because per-dispatch host→device latency would otherwise dominate
-    the per-batch compute (the 32-batch window bench.py dispatches was
-    sized to a 66 ms dispatch measured on an installation that is gone;
-    ROADMAP A2 re-decides it from the first trace). The reference
-    amortizes the same way at a different layer:
-    CommitProxy batches many client commits per ResolveTransactionBatch
-    RPC (CommitProxyServer.actor.cpp). With `wave` (static) the int32
-    [k, B] wave levels are returned after the verdicts.
-    """
-
-    def body(st, xs):
-        batch, cv, old = xs
-        out = resolve_batch(st, batch, cv, old, wave=wave)
-        return out[-1], out[:-1]
-
-    state, stacked = jax.lax.scan(
-        body, state, (batches, commit_versions, new_oldests)
-    )
-    return (*stacked, state)
-
-
 # ---------------------------------------------------------------------------
-# Window history (default, FDB_TPU_HISTORY=window): two-level base + delta
+# Window history: two-level base + delta
 # ---------------------------------------------------------------------------
 #
-# VERDICT r4 item 2: the flat design above rebuilds sparse_table(versions)
-# — O(C·log C) HBM traffic at C=262k — inside EVERY resolve_batch of the
-# resolve_many scan. The two-level design amortizes it:
+# VERDICT r4 item 2: one flat step function rebuilds sparse_table(versions)
+# — O(C·log C) HBM traffic at C=262k — inside EVERY batch of a scanned
+# window. The two-level design amortizes it:
 #
 # - `base`: the bulk history, FROZEN between merges, with its sparse table
 #   carried alongside (built once per merge, not per batch).
@@ -1470,11 +1080,9 @@ def _merge_delta(base: ConflictState, delta: ConflictState,
     - The base's version under delta row j is one gather of Cd rows."""
     c = base.keys.shape[0]
     cd = delta.keys.shape[0]
-    # The packed design's fingerprint search also serves the merge (both
-    # operands are step-function key arrays); unpacked keeps the r5
-    # full-width search so the A/B baseline is untouched.
-    _ss = searchsorted_words_fp if _PACKED else searchsorted_words
-    cross_d = _ss(base.keys, delta.keys, side="right")  # [Cd]
+    # The fingerprint search (both operands are step-function key arrays).
+    cross_d = searchsorted_words_fp(
+        base.keys, delta.keys, side="right")  # [Cd]
     pos_d = jnp.arange(cd, dtype=jnp.int32) + cross_d
 
     def gc(v, keys):
@@ -1548,95 +1156,6 @@ def _maybe_merge(hist: HistState, demand: jax.Array,
     return jax.lax.cond(need, do_merge, lambda h: h, hist)
 
 
-@jax.named_scope("history_probe")
-def _history_conflict_ranges_hist(base: ConflictState, base_st: jax.Array,
-                                  delta: ConflictState,
-                                  batch: BatchTensors) -> jax.Array:
-    """bool [B, R]: _history_conflict_ranges against base (prebuilt table)
-    + delta (small per-batch table)."""
-    b, r, w = batch.read_begin.shape
-    rb = batch.read_begin.reshape(b * r, w)
-    re_ = batch.read_end.reshape(b * r, w)
-    lo = searchsorted_words(base.keys, rb, side="right") - 1
-    hi = searchsorted_words(base.keys, re_, side="left")
-    newest_b = range_max(base_st, jnp.maximum(lo, 0), hi, NEG_VERSION)
-    lo_d = searchsorted_words(delta.keys, rb, side="right") - 1
-    hi_d = searchsorted_words(delta.keys, re_, side="left")
-    if _RMQ_DESIGN == "blocked":
-        dt = block_table(delta.versions, NEG_VERSION)
-        newest_d = range_max_blocked(dt, jnp.maximum(lo_d, 0), hi_d,
-                                     NEG_VERSION)
-    else:
-        dt = sparse_table(delta.versions)
-        newest_d = range_max(dt, jnp.maximum(lo_d, 0), hi_d, NEG_VERSION)
-    newest = jnp.maximum(newest_b, newest_d).reshape(b, r)
-    nonempty = lex_lt(batch.read_begin, batch.read_end)
-    live = batch.read_mask & nonempty
-    return live & (newest > batch.read_version[:, None])
-
-
-def _history_conflicts_hist(base: ConflictState, base_st: jax.Array,
-                            delta: ConflictState,
-                            batch: BatchTensors) -> jax.Array:
-    """bool [B]: any-reduce of _history_conflict_ranges_hist."""
-    return jnp.any(
-        _history_conflict_ranges_hist(base, base_st, delta, batch), axis=1
-    )
-
-
-def resolve_batch_hist(
-    hist: HistState,
-    batch: BatchTensors,
-    commit_version: jax.Array,
-    new_oldest: jax.Array,
-    report: bool = False,
-    wave: bool = False,
-):
-    """resolve_batch over the two-level history. Identical verdicts to
-    resolve_batch (oracle-tested); only the history data structure
-    differs. `report` (static) additionally returns the loser-range mask
-    bool [B, R] (see loser_range_mask); `wave` (static) inserts the wave
-    levels after the verdicts."""
-    floor, too_old = too_old_mask(hist.delta, batch, new_oldest)
-    demand = 2 * jnp.sum(
-        (batch.write_mask & lex_lt(batch.write_begin, batch.write_end))
-        .astype(jnp.int32)
-    )
-    hist = _maybe_merge(hist, demand, floor)
-    base_h, base_st, delta, _ = hist
-    hist_mask = _history_conflict_ranges_hist(base_h, base_st, delta, batch)
-    hist_conflict = jnp.any(hist_mask, axis=1)
-    ok = batch.txn_mask & ~too_old & ~hist_conflict
-    ranks = endpoint_ranks_live(batch)
-    accepted, levels = _accept_or_schedule(ok, ranks, wave, batch.cont)
-    verdicts = assemble_verdicts(too_old, batch.txn_mask, accepted)
-    delta = _paint_and_compact(delta, batch, accepted, commit_version, floor)
-    new_hist = hist._replace(delta=delta)
-    out = (verdicts, levels) if wave else (verdicts,)
-    if report:
-        losers = loser_range_mask(hist_mask, ranks, accepted, verdicts)
-        return (*out, losers, new_hist)
-    return (*out, new_hist)
-
-
-def resolve_many_hist(
-    hist: HistState,
-    batches: BatchTensors,
-    commit_versions: jax.Array,
-    new_oldests: jax.Array,
-    wave: bool = False,
-):
-    def body(h, xs):
-        batch, cv, old = xs
-        out = resolve_batch_hist(h, batch, cv, old, wave=wave)
-        return out[-1], out[:-1]
-
-    hist, stacked = jax.lax.scan(
-        body, hist, (batches, commit_versions, new_oldests)
-    )
-    return (*stacked, hist)
-
-
 @jax.named_scope("hist_merge")
 def advance_hist(hist: HistState, commit_version: jax.Array,
                  new_oldest: jax.Array) -> HistState:
@@ -1652,18 +1171,22 @@ def advance_hist(hist: HistState, commit_version: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# Packed kernel (FDB_TPU_PACKED=1): rank-space probes over the host-deduped
-# key dictionary, fingerprint history search, bit-packed masks. Byte-
-# identical verdicts to the unpacked entry points (oracle-tested); only
-# the data movement differs.
+# Rank-space batch helpers: the host packer emits every endpoint as an
+# int32 rank (RankBatch below), so emptiness and liveness are scalar
+# compares and the loser report leaves the device as a bitset.
 # ---------------------------------------------------------------------------
 
 
 @jax.named_scope("history_probe")
 def too_old_mask_packed(
-    state: ConflictState, pb: PackedBatch, new_oldest: jax.Array
+    state: ConflictState, pb: RankBatch, new_oldest: jax.Array
 ) -> tuple[jax.Array, jax.Array]:
-    """too_old_mask in rank space (emptiness is a scalar int32 compare)."""
+    """(floor, too_old[B]) in rank space (emptiness is a scalar int32
+    compare). The window floor advances BEFORE resolution (reference:
+    Resolver sets ConflictSet::oldestVersion from the request, then detects
+    conflicts) and never regresses — a caller passing a regressed
+    new_oldest must not reopen a window whose writes were GC'd. Write-only
+    transactions are never too old."""
     has_reads = jnp.any(pb.read_mask & (pb.read_begin < pb.read_end), axis=1)
     floor = jnp.maximum(state.oldest, new_oldest)
     too_old = pb.txn_mask & has_reads & (pb.read_version < floor)
@@ -1671,109 +1194,16 @@ def too_old_mask_packed(
 
 
 @jax.named_scope("endpoint_ranks")
-def endpoint_ranks_live_packed(pb: PackedBatch) -> tuple[jax.Array, ...]:
-    """endpoint_ranks_live without the device sort: the host packer
-    already emitted rank-space intervals (order-isomorphic with exact tie
-    structure), so this is just the liveness mask computation."""
+def endpoint_ranks_live_packed(pb: RankBatch) -> tuple[jax.Array, ...]:
+    """(rb, re, read_live, wb, we, write_live): the shared precursor of
+    every acceptance path, with no device sort: the host packer already
+    emitted rank-space intervals (order-isomorphic with exact tie
+    structure), so this is just the liveness masks (slot populated AND
+    range non-empty)."""
     read_live = pb.read_mask & (pb.read_begin < pb.read_end)
     write_live = pb.write_mask & (pb.write_begin < pb.write_end)
     return (pb.read_begin, pb.read_end, read_live,
             pb.write_begin, pb.write_end, write_live)
-
-
-@jax.named_scope("history_probe")
-def _dict_history_search(
-    state_keys: jax.Array, dict_keys: jax.Array
-) -> tuple[jax.Array, jax.Array]:
-    """(rs, ls) int32 [N+1]: ONE column-cascade fingerprint search of
-    every UNIQUE batch key into the history yields both searchsorted
-    sides; per-slot probes then gather by rank. rs ('right') - 1 is the
-    containing segment for a range begin; ls ('left') is the first
-    segment at/after a range end; rs is also exactly the paint pass's
-    cross-rank."""
-    ls, rs = searchsorted_words_2sided_fp(state_keys, dict_keys)
-    return rs, ls
-
-
-@jax.named_scope("history_probe")
-def _history_conflict_ranges_packed(
-    state: ConflictState, pb: PackedBatch,
-    rs: jax.Array | None = None, ls: jax.Array | None = None,
-) -> jax.Array:
-    """_history_conflict_ranges over the dictionary: the [C, W] history is
-    probed once per unique key (4-byte fingerprint steps, full-width
-    compares only on first-word ties); read slots gather their bounds by
-    rank."""
-    b, r = pb.read_begin.shape
-    if rs is None:
-        rs, ls = _dict_history_search(state.keys, pb.dict_keys)
-    lo = rs[pb.read_begin.reshape(-1)] - 1
-    hi = ls[pb.read_end.reshape(-1)]
-    if _RMQ_DESIGN == "blocked":
-        bt = block_table(state.versions, NEG_VERSION)
-        newest = range_max_blocked(
-            bt, jnp.maximum(lo, 0), hi, NEG_VERSION
-        ).reshape(b, r)
-    else:
-        st = sparse_table(state.versions)
-        newest = range_max(
-            st, jnp.maximum(lo, 0), hi, NEG_VERSION
-        ).reshape(b, r)
-    live = pb.read_mask & (pb.read_begin < pb.read_end)
-    return live & (newest > pb.read_version[:, None])
-
-
-def _history_conflicts_packed(state: ConflictState, pb: PackedBatch) -> jax.Array:
-    return jnp.any(_history_conflict_ranges_packed(state, pb), axis=1)
-
-
-@jax.named_scope("paint_compact")
-def _paint_and_compact_packed(
-    state: ConflictState,
-    pb: PackedBatch,
-    accepted: jax.Array,
-    commit_version: jax.Array,
-    new_oldest: jax.Array,
-    rs: jax.Array | None = None,
-) -> ConflictState:
-    """_paint_and_compact with rank-carried endpoints: sorts 1-word int32
-    ranks (plus one index payload) instead of [n2, W] keys, gathers the
-    boundary keys back from the dictionary, and reuses the history search
-    already done per unique key (rs) as the merge-path cross-rank."""
-    b, q = pb.write_begin.shape
-    e2 = b * q
-    n_dict = pb.dict_keys.shape[0]
-
-    valid = (
-        accepted[:, None] & pb.write_mask & (pb.write_begin < pb.write_end)
-    )  # [B, Q]
-    inf_rank = jnp.int32(n_dict - 1)  # last dictionary row is always +inf
-    wr = jnp.where(valid, pb.write_begin, inf_rank).reshape(e2)
-    er = jnp.where(valid, pb.write_end, inf_rank).reshape(e2)
-    new_ranks = jnp.concatenate([wr, er])  # [n2]
-    new_delta = jnp.concatenate(
-        [valid.reshape(e2).astype(jnp.int32), -valid.reshape(e2).astype(jnp.int32)]
-    )
-    if rs is None:
-        rs = searchsorted_words_fp(state.keys, pb.dict_keys, side="right")
-    cross_rank = rs[new_ranks]
-    seg = cross_rank - 1
-    new_oldv = state.versions[jnp.maximum(seg, 0)]
-
-    # Rank order IS key order with identical ties, so the stable 1-word
-    # sort yields the same permutation as sort_keys_with_payload; the
-    # other columns ride as one gathered index payload.
-    idx = jnp.arange(2 * e2, dtype=jnp.int32)
-    sranks, sidx = sort_ranks_with_payload(new_ranks, idx)
-    return _paint_tail(
-        state,
-        pb.dict_keys[sranks],
-        new_delta[sidx],
-        new_oldv[sidx],
-        cross_rank[sidx],
-        commit_version,
-        new_oldest,
-    )
 
 
 @jax.named_scope("verdicts")
@@ -1790,209 +1220,6 @@ def pack_loser_mask(losers: jax.Array) -> jax.Array:
     )
 
 
-def resolve_batch_packed(
-    state: ConflictState,
-    pb: PackedBatch,
-    commit_version: jax.Array,
-    new_oldest: jax.Array,
-    report: bool = False,
-    wave: bool = False,
-):
-    """resolve_batch over a PackedBatch — identical verdicts, rank-space
-    data movement. With `report`, the loser mask returns uint32-packed;
-    with `wave`, the wave levels ride after the verdicts."""
-    floor, too_old = too_old_mask_packed(state, pb, new_oldest)
-    rs, ls = _dict_history_search(state.keys, pb.dict_keys)
-    hist_mask = _history_conflict_ranges_packed(state, pb, rs, ls)
-    hist_conflict = jnp.any(hist_mask, axis=1)
-    base = pb.txn_mask & ~too_old & ~hist_conflict
-    ranks = endpoint_ranks_live_packed(pb)
-    accepted, levels = _accept_or_schedule(base, ranks, wave, pb.cont)
-    verdicts = assemble_verdicts(too_old, pb.txn_mask, accepted)
-    new_state = _paint_and_compact_packed(
-        state, pb, accepted, commit_version, floor, rs
-    )
-    out = (verdicts, levels) if wave else (verdicts,)
-    if report:
-        losers = loser_range_mask(hist_mask, ranks, accepted, verdicts)
-        return (*out, pack_loser_mask(losers), new_state)
-    return (*out, new_state)
-
-
-def resolve_many_packed(
-    state: ConflictState,
-    pbs: PackedBatch,  # leading scan axis [k, ...] on every leaf
-    commit_versions: jax.Array,
-    new_oldests: jax.Array,
-    wave: bool = False,
-):
-    def body(st, xs):
-        pb, cv, old = xs
-        out = resolve_batch_packed(st, pb, cv, old, wave=wave)
-        return out[-1], out[:-1]
-
-    state, stacked = jax.lax.scan(
-        body, state, (pbs, commit_versions, new_oldests)
-    )
-    return (*stacked, state)
-
-
-@jax.named_scope("history_probe")
-def _history_conflict_ranges_hist_packed(
-    base: ConflictState, base_st: jax.Array, delta: ConflictState,
-    pb: PackedBatch,
-    rs_b: jax.Array, ls_b: jax.Array, rs_d: jax.Array, ls_d: jax.Array,
-) -> jax.Array:
-    """_history_conflict_ranges_hist over the dictionary: base and delta
-    are each fingerprint-searched once per unique key."""
-    b, r = pb.read_begin.shape
-    rbf = pb.read_begin.reshape(-1)
-    ref = pb.read_end.reshape(-1)
-    newest_b = range_max(
-        base_st, jnp.maximum(rs_b[rbf] - 1, 0), ls_b[ref], NEG_VERSION
-    )
-    lo_d = jnp.maximum(rs_d[rbf] - 1, 0)
-    hi_d = ls_d[ref]
-    if _RMQ_DESIGN == "blocked":
-        dt = block_table(delta.versions, NEG_VERSION)
-        newest_d = range_max_blocked(dt, lo_d, hi_d, NEG_VERSION)
-    else:
-        dt = sparse_table(delta.versions)
-        newest_d = range_max(dt, lo_d, hi_d, NEG_VERSION)
-    newest = jnp.maximum(newest_b, newest_d).reshape(b, r)
-    live = pb.read_mask & (pb.read_begin < pb.read_end)
-    return live & (newest > pb.read_version[:, None])
-
-
-def _history_conflicts_hist_packed(hist: HistState, pb: PackedBatch) -> jax.Array:
-    rs_b, ls_b = _dict_history_search(hist.base.keys, pb.dict_keys)
-    rs_d, ls_d = _dict_history_search(hist.delta.keys, pb.dict_keys)
-    return jnp.any(
-        _history_conflict_ranges_hist_packed(
-            hist.base, hist.base_st, hist.delta, pb, rs_b, ls_b, rs_d, ls_d
-        ),
-        axis=1,
-    )
-
-
-def resolve_batch_hist_packed(
-    hist: HistState,
-    pb: PackedBatch,
-    commit_version: jax.Array,
-    new_oldest: jax.Array,
-    report: bool = False,
-    wave: bool = False,
-):
-    """resolve_batch_hist over a PackedBatch. The delta's right-side
-    dictionary search doubles as the paint pass's cross-rank (both run
-    against the post-merge delta)."""
-    floor, too_old = too_old_mask_packed(hist.delta, pb, new_oldest)
-    demand = 2 * jnp.sum(
-        (pb.write_mask & (pb.write_begin < pb.write_end)).astype(jnp.int32)
-    )
-    hist = _maybe_merge(hist, demand, floor)
-    base_h, base_st, delta, _ = hist
-    rs_b, ls_b = _dict_history_search(base_h.keys, pb.dict_keys)
-    rs_d, ls_d = _dict_history_search(delta.keys, pb.dict_keys)
-    hist_mask = _history_conflict_ranges_hist_packed(
-        base_h, base_st, delta, pb, rs_b, ls_b, rs_d, ls_d
-    )
-    hist_conflict = jnp.any(hist_mask, axis=1)
-    ok = pb.txn_mask & ~too_old & ~hist_conflict
-    ranks = endpoint_ranks_live_packed(pb)
-    accepted, levels = _accept_or_schedule(ok, ranks, wave, pb.cont)
-    verdicts = assemble_verdicts(too_old, pb.txn_mask, accepted)
-    delta = _paint_and_compact_packed(
-        delta, pb, accepted, commit_version, floor, rs_d
-    )
-    new_hist = hist._replace(delta=delta)
-    out = (verdicts, levels) if wave else (verdicts,)
-    if report:
-        losers = loser_range_mask(hist_mask, ranks, accepted, verdicts)
-        return (*out, pack_loser_mask(losers), new_hist)
-    return (*out, new_hist)
-
-
-def resolve_many_hist_packed(
-    hist: HistState,
-    pbs: PackedBatch,
-    commit_versions: jax.Array,
-    new_oldests: jax.Array,
-    wave: bool = False,
-):
-    def body(h, xs):
-        pb, cv, old = xs
-        out = resolve_batch_hist_packed(h, pb, cv, old, wave=wave)
-        return out[-1], out[:-1]
-
-    hist, stacked = jax.lax.scan(
-        body, hist, (pbs, commit_versions, new_oldests)
-    )
-    return (*stacked, hist)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_packed_jit(state, pb, commit_version, new_oldest):
-    return resolve_batch_packed(state, pb, commit_version, new_oldest)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_report_packed_jit(state, pb, commit_version, new_oldest):
-    return resolve_batch_packed(state, pb, commit_version, new_oldest,
-                                report=True)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_many_packed_jit(state, pbs, commit_versions, new_oldests):
-    return resolve_many_packed(state, pbs, commit_versions, new_oldests)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_hist_packed_jit(hist, pb, commit_version, new_oldest):
-    return resolve_batch_hist_packed(hist, pb, commit_version, new_oldest)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_report_hist_packed_jit(hist, pb, commit_version, new_oldest):
-    return resolve_batch_hist_packed(hist, pb, commit_version, new_oldest,
-                                     report=True)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_many_hist_packed_jit(hist, pbs, commit_versions, new_oldests):
-    return resolve_many_hist_packed(hist, pbs, commit_versions, new_oldests)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_hist_jit(hist, batch, commit_version, new_oldest):
-    return resolve_batch_hist(hist, batch, commit_version, new_oldest)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_report_hist_jit(hist, batch, commit_version, new_oldest):
-    return resolve_batch_hist(hist, batch, commit_version, new_oldest,
-                              report=True)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_report_jit(state, batch, commit_version, new_oldest):
-    return resolve_batch(state, batch, commit_version, new_oldest,
-                         report=True)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_many_hist_jit(hist, batches, commit_versions, new_oldests):
-    return resolve_many_hist(hist, batches, commit_versions, new_oldests)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _advance_hist_jit(hist, commit_version, new_oldest):
-    return (
-        jnp.zeros((1,), jnp.int8),
-        advance_hist(hist, commit_version, new_oldest),
-    )
-
-
 def rebase_hist(hist, delta_v):
     """rebase over either history design. The window history's base
     versions shift, so its prebuilt RMQ table must follow."""
@@ -2001,26 +1228,6 @@ def rebase_hist(hist, delta_v):
         return HistState(base, sparse_table(base.versions),
                          rebase(hist.delta, delta_v), hist.merges)
     return rebase(hist, delta_v)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _rebase_hist_jit(hist, delta_v):
-    return rebase_hist(hist, delta_v)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_jit(state, batch, commit_version, new_oldest):
-    return resolve_batch(state, batch, commit_version, new_oldest)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_many_jit(state, batches, commit_versions, new_oldests):
-    return resolve_many(state, batches, commit_versions, new_oldests)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _rebase_jit(state, delta):
-    return rebase(state, delta)
 
 
 def _rows_in_use(n_used, frozen=None):
@@ -2072,88 +1279,9 @@ def _capacity_reading_jit(n_used, overflow, merges, frozen=None):
                       jnp.sum(jnp.asarray(merges, jnp.int32))])
 
 
-# -- wave-commit entry points (FDB_TPU_WAVE_COMMIT=1 engines) ---------------
-# Same four engine configurations as above; every return shape gains the
-# int32 [B] (or [k, B]) wave levels right after the verdicts.
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_wave_jit(state, batch, commit_version, new_oldest):
-    return resolve_batch(state, batch, commit_version, new_oldest, wave=True)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_report_wave_jit(state, batch, commit_version, new_oldest):
-    return resolve_batch(state, batch, commit_version, new_oldest,
-                         report=True, wave=True)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_many_wave_jit(state, batches, commit_versions, new_oldests):
-    return resolve_many(state, batches, commit_versions, new_oldests,
-                        wave=True)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_hist_wave_jit(hist, batch, commit_version, new_oldest):
-    return resolve_batch_hist(hist, batch, commit_version, new_oldest,
-                              wave=True)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_report_hist_wave_jit(hist, batch, commit_version, new_oldest):
-    return resolve_batch_hist(hist, batch, commit_version, new_oldest,
-                              report=True, wave=True)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_many_hist_wave_jit(hist, batches, commit_versions, new_oldests):
-    return resolve_many_hist(hist, batches, commit_versions, new_oldests,
-                             wave=True)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_packed_wave_jit(state, pb, commit_version, new_oldest):
-    return resolve_batch_packed(state, pb, commit_version, new_oldest,
-                                wave=True)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_report_packed_wave_jit(state, pb, commit_version, new_oldest):
-    return resolve_batch_packed(state, pb, commit_version, new_oldest,
-                                report=True, wave=True)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_many_packed_wave_jit(state, pbs, commit_versions, new_oldests):
-    return resolve_many_packed(state, pbs, commit_versions, new_oldests,
-                               wave=True)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_hist_packed_wave_jit(hist, pb, commit_version, new_oldest):
-    return resolve_batch_hist_packed(hist, pb, commit_version, new_oldest,
-                                     wave=True)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_report_hist_packed_wave_jit(hist, pb, commit_version,
-                                         new_oldest):
-    return resolve_batch_hist_packed(hist, pb, commit_version, new_oldest,
-                                     report=True, wave=True)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _resolve_many_hist_packed_wave_jit(hist, pbs, commit_versions,
-                                       new_oldests):
-    return resolve_many_hist_packed(hist, pbs, commit_versions, new_oldests,
-                                    wave=True)
-
-
 # ---------------------------------------------------------------------------
-# Resident kernel (FDB_TPU_RESIDENT=1, requires FDB_TPU_PACKED=1): the
-# endpoint-key dictionary and the MVCC history persist in device memory
-# across dispatches. The history is stored in RANK SPACE — a width-1
+# Resident kernel: the endpoint-key dictionary and the MVCC history persist
+# in device memory across dispatches. The history is stored in RANK SPACE — a width-1
 # ConflictState/HistState whose "key" rows are int32 ranks into the
 # resident dictionary (INT32_MAX = the +inf sentinel, exactly the role the
 # all-inf row plays at full width) — so ALL of the step-function machinery
@@ -2167,8 +1295,7 @@ class RankBatch(NamedTuple):
     """One padded resolver batch in RESIDENT rank space: every endpoint is
     an int32 rank into the resident dictionary (host-computed against the
     post-merge mirror — see conflict_set._ResidentMirror), INT32_MAX for
-    masked/padding slots. Field names match PackedBatch minus dict_keys so
-    too_old_mask_packed / endpoint_ranks_live_packed apply unchanged.
+    masked/padding slots.
 
     ``paint_src`` is the HOST-precomputed stable argsort of the write
     endpoints [wb..., we...] — the resident paint's sort permutation. It
@@ -2448,9 +1575,12 @@ def apply_dict_remap(res: ResState, new_dict, new_n, remap) -> ResState:
 
 
 def clip_ranks(rbk: RankBatch, lo, hi) -> RankBatch:
-    """clip_batch in rank space: restrict every range to the shard's rank
-    interval [lo, hi). Scalar int32 compares — out-of-shard ranges fall
-    out of their masks via rb' >= re'. Both endpoints take the SAME
+    """Restrict every range to the shard's rank interval [lo, hi): the
+    device-side analogue of the reference CommitProxy's per-resolver
+    conflict-range split (CommitProxyServer.actor.cpp routes ranges to
+    resolvers by keyRange shard). read_version/txn_mask are untouched
+    (TOO_OLD is judged on the unclipped batch so all shards agree). Scalar
+    int32 compares — out-of-shard ranges fall out of their masks via rb' >= re'. Both endpoints take the SAME
     two-sided clamp: one monotone map over all endpoints, so the host's
     paint permutation (RankBatch.paint_src, computed on unclipped ranks)
     stays sorted for the clipped view — a one-sided max/min pair would
@@ -2476,22 +1606,18 @@ def _rank_probe(keys: jax.Array, q: jax.Array, side: str) -> jax.Array:
 
 @jax.named_scope("history_probe")
 def _history_conflict_ranges_res(state: ConflictState, rbk: RankBatch) -> jax.Array:
-    """_history_conflict_ranges over the rank-space history: per-slot
-    probes (the host already deduped the rank space; a probe step gathers
-    4 bytes, so per-slot beats the probe-per-unique-key indirection)."""
+    """bool [B, R]: read range slot overlaps a historical write newer than
+    rv — the per-range form the conflicting-keys report path needs (which
+    read ranges LOST, reference: conflictingKRIndices). Per-slot probes
+    (the host already deduped the rank space; a probe step gathers 4
+    bytes, so per-slot beats the probe-per-unique-key indirection)."""
     b, r = rbk.read_begin.shape
     lo = _rank_probe(state.keys, rbk.read_begin.reshape(-1), "right") - 1
     hi = _rank_probe(state.keys, rbk.read_end.reshape(-1), "left")
-    if _RMQ_DESIGN == "blocked":
-        bt = block_table(state.versions, NEG_VERSION)
-        newest = range_max_blocked(
-            bt, jnp.maximum(lo, 0), hi, NEG_VERSION
-        ).reshape(b, r)
-    else:
-        st = sparse_table(state.versions)
-        newest = range_max(
-            st, jnp.maximum(lo, 0), hi, NEG_VERSION
-        ).reshape(b, r)
+    st = sparse_table(state.versions)
+    newest = range_max(
+        st, jnp.maximum(lo, 0), hi, NEG_VERSION
+    ).reshape(b, r)
     live = rbk.read_mask & (rbk.read_begin < rbk.read_end)
     return live & (newest > rbk.read_version[:, None])
 
@@ -2516,12 +1642,8 @@ def _history_conflict_ranges_hist_res(
     )
     lo_d = jnp.maximum(_rank_probe(delta.keys, qb, "right") - 1, 0)
     hi_d = _rank_probe(delta.keys, qe, "left")
-    if _RMQ_DESIGN == "blocked":
-        dt = block_table(delta.versions, NEG_VERSION)
-        newest_d = range_max_blocked(dt, lo_d, hi_d, NEG_VERSION)
-    else:
-        dt = sparse_table(delta.versions)
-        newest_d = range_max(dt, lo_d, hi_d, NEG_VERSION)
+    dt = sparse_table(delta.versions)
+    newest_d = range_max(dt, lo_d, hi_d, NEG_VERSION)
     newest = jnp.maximum(newest_b, newest_d).reshape(b, r)
     live = rbk.read_mask & (rbk.read_begin < rbk.read_end)
     return live & (newest > rbk.read_version[:, None])
@@ -2544,7 +1666,16 @@ def _paint_and_compact_res(
     commit_version: jax.Array,
     new_oldest: jax.Array,
 ) -> ConflictState:
-    """_paint_and_compact in rank space, WITHOUT the device endpoint sort.
+    """Fold accepted writes into the step function WITHOUT re-sorting the
+    whole history, and without a device endpoint sort: the history rows are
+    already sorted, the batch's 2·B·Q new endpoints arrive with their sort
+    permutation, and the two sorted sequences are interleaved by rank
+    arithmetic (_paint_tail: the merge-path construction, each element's
+    output slot its own index plus its cross-rank in the other sequence,
+    history winning ties; at the delta's size, n = 16,386, its search and
+    gathers once a slot are what is left of ROADMAP A5), the surviving
+    boundaries compacted to the front by streaming shifts (_dedup_compact,
+    whose docstring says what the chip charges for each kind of pass).
 
     The host ships the stable argsort of the write endpoints
     (rbk.paint_src) — legal because the permutation must not depend on
@@ -2646,9 +1777,17 @@ def _resolve_core_res(hist, rbk: RankBatch, commit_version, new_oldest,
 
 def resolve_batch_res(res: ResState, rb: ResidentBatch, commit_version,
                       new_oldest, report: bool = False, wave: bool = False):
-    """resolve_batch over the resident state: delta merge + rank rebase,
-    then the rank-space resolve core. Identical verdicts to the packed
-    per-dispatch-dictionary path (oracle- and A/B-parity tested)."""
+    """Resolve one batch and fold its accepted writes into the history:
+    delta merge + rank rebase, then the rank-space resolve core. Mirrors
+    the reference call sequence ConflictBatch::detectConflicts →
+    combineWriteConflictRanges → SkipList::addConflictRanges, as one
+    compiled program.
+
+    Returns (verdicts int8 [B], new_res) — with `report` (a static Python
+    flag; each value compiles its own program), (verdicts, loser bitset
+    uint32 [B], new_res); `wave` (static) switches intra-batch acceptance
+    to the wave-commit schedule and inserts the int32 [B] wave levels right
+    after the verdicts in every return shape."""
     res = apply_delta(res, rb.delta_keys, rb.delta_cross)
     out = _resolve_core_res(res.hist, rb.ranks, commit_version, new_oldest,
                             report=report, wave=wave)
@@ -2702,18 +1841,6 @@ def _resolve_report_res_wave_jit(res, rb, commit_version, new_oldest):
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _resolve_many_res_wave_jit(res, rb, commit_versions, new_oldests):
     return resolve_many_res(res, rb, commit_versions, new_oldests, wave=True)
-
-
-# The hist/flat distinction is carried by the ResState PYTREE (res.hist is
-# a HistState or a ConflictState), so the _hist entry names alias the same
-# functions — jit specializes per pytree structure. The aliases keep the
-# engine's suffix-composition naming total.
-_resolve_hist_res_jit = _resolve_res_jit
-_resolve_report_hist_res_jit = _resolve_report_res_jit
-_resolve_many_hist_res_jit = _resolve_many_res_jit
-_resolve_hist_res_wave_jit = _resolve_res_wave_jit
-_resolve_report_hist_res_wave_jit = _resolve_report_res_wave_jit
-_resolve_many_hist_res_wave_jit = _resolve_many_res_wave_jit
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -2775,48 +1902,6 @@ def _accepted_rows(accepted, cont):
     return accepted[jnp.cumsum((~cont).astype(jnp.int32)) - 1]
 
 
-def wave_edges_batch(state: ConflictState, batch: BatchTensors, new_oldest):
-    """(too_old [B], hist_conflict [B], pred uint32 [BP, BP/32]): the
-    phase-1 body — gate verdicts for THIS shard's clipped view plus its
-    clipped predecessor matrix, all three by row (_edge_pred). Reads the
-    history, never paints it."""
-    _floor, too_old = too_old_mask(state, batch, new_oldest)
-    hist_conflict = _history_conflicts(state, batch)
-    base = batch.txn_mask & ~too_old & ~hist_conflict
-    p = _edge_pred(base, endpoint_ranks_live(batch), batch.cont)
-    return too_old, hist_conflict, p
-
-
-def wave_edges_batch_hist(hist: HistState, batch: BatchTensors, new_oldest):
-    """wave_edges_batch over the two-level history. No merge here — the
-    probe against base+delta is merge-invariant (pointwise max), and the
-    capacity merge runs in the apply phase, just before the paint that
-    needs the room."""
-    _floor, too_old = too_old_mask(hist.delta, batch, new_oldest)
-    hist_conflict = _history_conflicts_hist(
-        hist.base, hist.base_st, hist.delta, batch
-    )
-    base = batch.txn_mask & ~too_old & ~hist_conflict
-    p = _edge_pred(base, endpoint_ranks_live(batch), batch.cont)
-    return too_old, hist_conflict, p
-
-
-def wave_edges_batch_packed(state: ConflictState, pb: PackedBatch, new_oldest):
-    _floor, too_old = too_old_mask_packed(state, pb, new_oldest)
-    hist_conflict = _history_conflicts_packed(state, pb)
-    base = pb.txn_mask & ~too_old & ~hist_conflict
-    p = _edge_pred(base, endpoint_ranks_live_packed(pb), pb.cont)
-    return too_old, hist_conflict, p
-
-
-def wave_edges_batch_hist_packed(hist: HistState, pb: PackedBatch, new_oldest):
-    _floor, too_old = too_old_mask_packed(hist.delta, pb, new_oldest)
-    hist_conflict = _history_conflicts_hist_packed(hist, pb)
-    base = pb.txn_mask & ~too_old & ~hist_conflict
-    p = _edge_pred(base, endpoint_ranks_live_packed(pb), pb.cont)
-    return too_old, hist_conflict, p
-
-
 def wave_edges_res(res: ResState, rb: ResidentBatch, new_oldest):
     """Resident phase-1: the dictionary delta merges HERE (the host
     packed ranks against the post-merge mirror), so the returned state
@@ -2834,70 +1919,6 @@ def wave_edges_res(res: ResState, rb: ResidentBatch, new_oldest):
     p = _edge_pred(base, endpoint_ranks_live_packed(rb.ranks),
                    rb.ranks.cont)
     return too_old, hist_conflict, p, res
-
-
-def wave_apply_batch(
-    state: ConflictState, batch: BatchTensors, cand, p, commit_version,
-    new_oldest,
-):
-    """(levels int32 [B], new_state): level the GLOBAL graph, paint the
-    globally accepted writes. ``cand``/``p`` are the combined candidate
-    mask and OR-reduced predecessor matrix — identical on every shard,
-    so the returned schedule is identical on every shard. Graph and
-    levels go by transaction; only the paint goes by row
-    (_accepted_rows)."""
-    floor = jnp.maximum(state.oldest, new_oldest)
-    accepted, levels = wave_level_from_graph(cand, p)
-    accepted = _accepted_rows(accepted, batch.cont)
-    new_state = _paint_and_compact(state, batch, accepted, commit_version,
-                                   floor)
-    return levels, new_state
-
-
-def wave_apply_batch_hist(
-    hist: HistState, batch: BatchTensors, cand, p, commit_version, new_oldest,
-):
-    floor = jnp.maximum(hist.delta.oldest, new_oldest)
-    demand = 2 * jnp.sum(
-        (batch.write_mask & lex_lt(batch.write_begin, batch.write_end))
-        .astype(jnp.int32)
-    )
-    hist = _maybe_merge(hist, demand, floor)
-    base_h, base_st, delta, _ = hist
-    accepted, levels = wave_level_from_graph(cand, p)
-    accepted = _accepted_rows(accepted, batch.cont)
-    delta = _paint_and_compact(delta, batch, accepted, commit_version, floor)
-    return levels, hist._replace(delta=delta)
-
-
-def wave_apply_batch_packed(
-    state: ConflictState, pb: PackedBatch, cand, p, commit_version,
-    new_oldest,
-):
-    floor = jnp.maximum(state.oldest, new_oldest)
-    accepted, levels = wave_level_from_graph(cand, p)
-    accepted = _accepted_rows(accepted, pb.cont)
-    new_state = _paint_and_compact_packed(
-        state, pb, accepted, commit_version, floor
-    )
-    return levels, new_state
-
-
-def wave_apply_batch_hist_packed(
-    hist: HistState, pb: PackedBatch, cand, p, commit_version, new_oldest,
-):
-    floor = jnp.maximum(hist.delta.oldest, new_oldest)
-    demand = 2 * jnp.sum(
-        (pb.write_mask & (pb.write_begin < pb.write_end)).astype(jnp.int32)
-    )
-    hist = _maybe_merge(hist, demand, floor)
-    base_h, base_st, delta, _ = hist
-    accepted, levels = wave_level_from_graph(cand, p)
-    accepted = _accepted_rows(accepted, pb.cont)
-    delta = _paint_and_compact_packed(
-        delta, pb, accepted, commit_version, floor
-    )
-    return levels, hist._replace(delta=delta)
 
 
 def wave_apply_res(
@@ -2929,24 +1950,10 @@ def wave_apply_res(
     return levels, res._replace(hist=new_hist)
 
 
-# Edge entries are NOT donated (the apply phase reuses the same state);
-# the resident edge entry IS donated (the delta merge replaces the
-# state, returned alongside). Apply entries donate like every resolve.
-_wave_edges_jit = jax.jit(wave_edges_batch)
-_wave_edges_hist_jit = jax.jit(wave_edges_batch_hist)
-_wave_edges_packed_jit = jax.jit(wave_edges_batch_packed)
-_wave_edges_hist_packed_jit = jax.jit(wave_edges_batch_hist_packed)
+# The edge entry is donated (the delta merge replaces the state, returned
+# alongside); the apply entry donates like every resolve.
 _wave_edges_res_jit = jax.jit(wave_edges_res, donate_argnums=(0,))
-_wave_edges_hist_res_jit = _wave_edges_res_jit
-
-_wave_apply_jit = jax.jit(wave_apply_batch, donate_argnums=(0,))
-_wave_apply_hist_jit = jax.jit(wave_apply_batch_hist, donate_argnums=(0,))
-_wave_apply_packed_jit = jax.jit(wave_apply_batch_packed, donate_argnums=(0,))
-_wave_apply_hist_packed_jit = jax.jit(
-    wave_apply_batch_hist_packed, donate_argnums=(0,)
-)
 _wave_apply_res_jit = jax.jit(wave_apply_res, donate_argnums=(0,))
-_wave_apply_hist_res_jit = _wave_apply_res_jit
 
 
 # ---------------------------------------------------------------------------
@@ -2955,7 +1962,7 @@ _wave_apply_hist_res_jit = _wave_apply_res_jit
 # (the resolve programs above paint accepted-so-far writes in the same
 # program that decides them) while N's verdicts are still in flight —
 # i.e. unconfirmed by the upper layer (tlog durability, wave apply,
-# ratekeeper). The kernel side of the reconcile is three programs:
+# ratekeeper). The kernel side of the reconcile is two kinds of program:
 #
 # - _snapshot_jit: fresh device buffers for the pre-window state, taken
 #   right before a speculative dispatch. The resolve entry points donate
@@ -2963,21 +1970,14 @@ _wave_apply_hist_res_jit = _wave_apply_res_jit
 #   double-buffers; the snapshot is the explicit, depth-bounded HBM cost
 #   of speculation (one state copy per in-flight window), and rolling
 #   back a mis-speculated window is a host pointer swap.
-# - paint-only entry points (_paint{,_many}{_hist}{_packed|_res}_jit):
+# - paint-only entry points (_paint_res_jit, _paint_many_res_jit):
 #   re-advance a rolled-back state with a FORCED accept mask (the
 #   speculative accepts ∩ the upper layer's confirmation) — the same
 #   merge/GC/paint pipeline as the resolve bodies, minus the verdict
 #   decision the upper layer already overrode.
-# - the verdict-dependency mask (_spec_mark_rejected / _spec_dep_*): did
-#   ANY read of a younger in-flight window overlap a write the older
-#   window's confirmation rejected? Rejected writes are painted into a
-#   small scratch step function at +inf version; a younger window whose
-#   probe comes back clean provably kept its speculative verdicts (its
-#   reads never saw a rejected boundary; its floor and intra-window graph
-#   are unchanged), so reconcile re-paints it instead of re-resolving.
-#   A dirty (or scratch-overflowed) probe sends the whole window through
-#   the repair path: re-resolve against the corrected history — only
-#   genuinely-conflicted txns flip.
+# Every younger in-flight window is re-resolved against the corrected
+# history (only genuinely-conflicted txns flip): the windows' ranks live in
+# per-window coordinate systems, so no probe can prove a window clean.
 # ---------------------------------------------------------------------------
 
 
@@ -2986,52 +1986,6 @@ def _snapshot_jit(tree):
     """Device copy of an arbitrary state pytree (NOT donated — the live
     state keeps executing; see the speculation ring in conflict_set)."""
     return jax.tree_util.tree_map(jnp.copy, tree)
-
-
-def paint_batch_packed(state: ConflictState, pb: PackedBatch, accepted,
-                       commit_version, new_oldest) -> ConflictState:
-    """Paint-only advance: apply a host-forced accept mask to the flat
-    packed history — resolve_batch_packed minus the verdict decision."""
-    floor = jnp.maximum(state.oldest, new_oldest)
-    return _paint_and_compact_packed(state, pb, accepted, commit_version,
-                                     floor)
-
-
-def paint_many_packed(state, pbs, accepted, commit_versions, new_oldests):
-    def body(st, xs):
-        pb, acc, cv, old = xs
-        return paint_batch_packed(st, pb, acc, cv, old), None
-
-    state, _ = jax.lax.scan(
-        body, state, (pbs, accepted, commit_versions, new_oldests)
-    )
-    return state
-
-
-def paint_batch_hist_packed(hist: HistState, pb: PackedBatch, accepted,
-                            commit_version, new_oldest) -> HistState:
-    """Two-level edition: same demand-driven merge as the resolve body (a
-    forced paint must respect delta capacity exactly like a decided one)."""
-    floor, _ = too_old_mask_packed(hist.delta, pb, new_oldest)
-    demand = 2 * jnp.sum(
-        (pb.write_mask & (pb.write_begin < pb.write_end)).astype(jnp.int32)
-    )
-    hist = _maybe_merge(hist, demand, floor)
-    base_h, base_st, delta, _ = hist
-    delta = _paint_and_compact_packed(delta, pb, accepted, commit_version,
-                                      floor)
-    return hist._replace(delta=delta)
-
-
-def paint_many_hist_packed(hist, pbs, accepted, commit_versions, new_oldests):
-    def body(h, xs):
-        pb, acc, cv, old = xs
-        return paint_batch_hist_packed(h, pb, acc, cv, old), None
-
-    hist, _ = jax.lax.scan(
-        body, hist, (pbs, accepted, commit_versions, new_oldests)
-    )
-    return hist
 
 
 def _paint_core_res(hist, rbk: RankBatch, accepted, commit_version,
@@ -3078,31 +2032,6 @@ def paint_many_res(res, rb, accepted, commit_versions, new_oldests):
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
-def _paint_packed_jit(state, pb, accepted, commit_version, new_oldest):
-    return paint_batch_packed(state, pb, accepted, commit_version, new_oldest)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _paint_many_packed_jit(state, pbs, accepted, commit_versions,
-                           new_oldests):
-    return paint_many_packed(state, pbs, accepted, commit_versions,
-                             new_oldests)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _paint_hist_packed_jit(hist, pb, accepted, commit_version, new_oldest):
-    return paint_batch_hist_packed(hist, pb, accepted, commit_version,
-                                   new_oldest)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _paint_many_hist_packed_jit(hist, pbs, accepted, commit_versions,
-                                new_oldests):
-    return paint_many_hist_packed(hist, pbs, accepted, commit_versions,
-                                  new_oldests)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
 def _paint_res_jit(res, rb, accepted, commit_version, new_oldest):
     return paint_batch_res(res, rb, accepted, commit_version, new_oldest)
 
@@ -3110,52 +2039,3 @@ def _paint_res_jit(res, rb, accepted, commit_version, new_oldest):
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _paint_many_res_jit(res, rb, accepted, commit_versions, new_oldests):
     return paint_many_res(res, rb, accepted, commit_versions, new_oldests)
-
-
-# Hist/flat distinction rides the ResState pytree (see the resident alias
-# block above) — same totality trick for the paint entry names.
-_paint_hist_res_jit = _paint_res_jit
-_paint_many_hist_res_jit = _paint_many_res_jit
-
-
-# -- verdict-dependency mask -------------------------------------------------
-# Scratch = a small flat ConflictState holding ONLY the rejected writes of
-# the reconciling window, painted at +inf version so any overlapping read
-# trips the probe regardless of its read version. Works for flat AND
-# two-level non-resident engines (the scratch is its own flat state; only
-# the batches' dictionaries are probed). Resident engines skip the probe
-# (their ranks live in per-window coordinate systems) and repair
-# pessimistically — see conflict_set._spec_dep_windows.
-
-_SPEC_DEP_VERSION = INT32_MAX - 1
-
-
-def _spec_mark_rejected(scratch: ConflictState, pbs: PackedBatch,
-                        rejected) -> ConflictState:
-    def body(st, xs):
-        pb, rej = xs
-        st = _paint_and_compact_packed(
-            st, pb, rej, jnp.int32(_SPEC_DEP_VERSION), jnp.int32(0)
-        )
-        return st, None
-
-    scratch, _ = jax.lax.scan(body, scratch, (pbs, rejected))
-    return scratch
-
-
-def _spec_dep_window(scratch: ConflictState, pbs: PackedBatch):
-    def body(acc, pb):
-        return acc | jnp.any(_history_conflict_ranges_packed(scratch, pb)), None
-
-    dep, _ = jax.lax.scan(body, jnp.bool_(False), pbs)
-    return dep | scratch.overflow
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _spec_mark_rejected_jit(scratch, pbs, rejected):
-    return _spec_mark_rejected(scratch, pbs, rejected)
-
-
-@jax.jit  # scratch NOT donated: one marked scratch probes every younger window
-def _spec_dep_window_jit(scratch, pbs):
-    return _spec_dep_window(scratch, pbs)

@@ -26,7 +26,7 @@ from typing import Callable, NamedTuple
 
 import numpy as np
 
-from foundationdb_tpu.core.keypack import INT32_MAX, KeyCodec, row_sort_keys
+from foundationdb_tpu.core.keypack import INT32_MAX, KeyCodec
 from foundationdb_tpu.core.types import KeyRange, TxnConflictInfo, Verdict
 from foundationdb_tpu.models import conflict_kernel as ck
 from foundationdb_tpu.obs.span import stage_timer
@@ -114,13 +114,13 @@ def count_compiles() -> None:
 
 
 # ---------------------------------------------------------------------------
-# Resident-dictionary host mirror (FDB_TPU_RESIDENT=1)
+# Resident-dictionary host mirror
 # ---------------------------------------------------------------------------
 #
 # The host keeps a sorted mirror of the device-resident endpoint-key
 # dictionary so per-dispatch rank computation is a membership lookup plus
-# arithmetic instead of the full np.unique dedup+sort _pack_dict pays, and
-# only the never-before-seen keys (the DELTA) ever cross PCIe. Keys are
+# arithmetic instead of a dedup+sort of the batch's endpoints, and only the
+# never-before-seen keys (the DELTA) ever cross PCIe. Keys are
 # compared as uint64 column pairs (the packed int32 words re-biased and
 # packed big-endian two-per-word), so every comparison in the vectorized
 # binary search below is a native numpy op — no structured-dtype memcmp
@@ -220,35 +220,6 @@ def _u64_unique_sorted(u: np.ndarray, rows: np.ndarray):
     if len(us) > 1:
         keep[1:] = (us[1:] != us[:-1]).any(axis=1)
     return us[keep], rows[order][keep]
-
-
-def pack_rank_dictionary(flat: np.ndarray, pad_rows: int | None = None):
-    """THE shared pack/dictionary entry point: dedup+sort a flat [n, W]
-    packed-key stack into a sorted-unique dictionary plus int32 ranks.
-
-    Both the resolver's batch pack (:meth:`TPUConflictSet._pack_dict`) and
-    the read plane (:mod:`foundationdb_tpu.reads`) rewrite their key sets
-    through this one definition, so rank semantics (equal keys share a
-    rank; ranks are exact order isomorphisms) cannot drift between roles.
-
-    Returns ``(dict_keys, ranks)`` where ``dict_keys`` is ``[pad_rows, W]``
-    (default ``n + 1``) with every row past the unique keys +inf
-    (``INT32_MAX`` — kernels park masked slots there), and ``ranks`` is the
-    int32 rank of each input row in the sorted dictionary."""
-    n, w = flat.shape
-    if pad_rows is None:
-        pad_rows = n + 1
-    _, first, inverse = np.unique(
-        row_sort_keys(flat), return_index=True, return_inverse=True
-    )
-    if len(first) >= pad_rows:
-        raise ValueError(
-            f"{len(first)} unique keys need >= {len(first) + 1} dictionary "
-            f"rows (one +inf pad), got pad_rows={pad_rows}"
-        )
-    dict_keys = np.full((pad_rows, w), INT32_MAX, np.int32)
-    dict_keys[: len(first)] = flat[first]
-    return dict_keys, inverse.astype(np.int32)
 
 
 class _RepackPlan(NamedTuple):
@@ -638,9 +609,9 @@ class PreparedWindow(NamedTuple):
     """A host-packed dispatch window awaiting device dispatch.
 
     The pack half (``pack_wire_window``) is pure host work — the C wire
-    pass, padding, and (under FDB_TPU_PACKED) the ``_pack_dict``
-    dedup+sort — so a scheduler can run it on a worker thread for window
-    N+1 while the device still executes window N (sched/packing.py). The
+    pass, padding, and the rank pack against the mirror — so a scheduler
+    can run it on a worker thread for window N+1 while the device still
+    executes window N (sched/packing.py). The
     dispatch half (``dispatch_window``) threads device state and must run
     on the dispatching thread, in commit-version order."""
 
@@ -664,7 +635,7 @@ class _SpecPending(NamedTuple):
 
     seq: int
     snapshot: object  # device state BEFORE dispatch (rollback target)
-    batch: object  # device-format batch (PackedBatch / ResidentBatch)
+    batch: object  # device-format batch (ResidentBatch)
     cvs_rel: np.ndarray
     olds_rel: np.ndarray
     count: int
@@ -685,7 +656,6 @@ class TPUConflictSet:
         window_versions: int = DEFAULT_WINDOW_VERSIONS,
         delta_capacity: int | None = None,
         wave_commit: bool | None = None,
-        resident: bool | None = None,
         dict_capacity: int | None = None,
         dict_delta_slots: int | None = None,
         dict_hot_capacity: int | None = None,
@@ -694,25 +664,14 @@ class TPUConflictSet:
         spec_depth: int = 2,
     ):
         self.codec = KeyCodec(max_key_bytes)
-        # Resident-dictionary mode (FDB_TPU_RESIDENT default; requires the
-        # packed kernel): the endpoint dictionary and rank-space history
-        # persist on device across dispatches; the host ships key DELTAS.
-        # Per-engine override (like wave_commit) so a process can A/B both
-        # modes; forced off when the packed kernel is off.
-        self.resident = (
-            ck._RESIDENT if resident is None else bool(resident)
-        ) and ck._PACKED
-        # Speculative pipelined resolve (FDB_TPU_SPEC_RESOLVE default;
-        # requires the packed kernel — the reconcile dependency probe runs
-        # over the batch dictionary): dispatches run against the
-        # OPTIMISTICALLY advanced state while earlier windows' verdicts are
-        # still unconfirmed by the upper layer; a bounded reconcile ring
-        # (spec_depth in-flight windows, one device-state snapshot each)
-        # confirms or rolls back + repairs. Same per-engine override shape
-        # as resident/wave_commit; inert under FDB_TPU_PACKED=0.
-        self.spec = (
-            ck._SPEC_RESOLVE if spec_resolve is None else bool(spec_resolve)
-        ) and ck._PACKED
+        # Speculative pipelined resolve (FDB_TPU_SPEC_RESOLVE default):
+        # dispatches run against the OPTIMISTICALLY advanced state while
+        # earlier windows' verdicts are still unconfirmed by the upper
+        # layer; a bounded reconcile ring (spec_depth in-flight windows,
+        # one device-state snapshot each) confirms or rolls back + repairs.
+        # Same per-engine override shape as wave_commit.
+        self.spec = (ck._SPEC_RESOLVE if spec_resolve is None
+                     else bool(spec_resolve))
         self.spec_depth = max(1, int(spec_depth))
         self._spec_ring: deque[_SpecPending] = deque()
         self._spec_seq = 0
@@ -731,7 +690,6 @@ class TPUConflictSet:
         # all. Default None = every window confirms (the production fast
         # path; revocation is the exception speculation bets against).
         self.spec_confirm_hook: Callable | None = None
-        self._nat_win: bool | None = None  # lazy kp_pack_window gate
         self.dict_capacity = int(
             dict_capacity
             or int(os.environ.get("FDB_TPU_DICT_CAPACITY", "0"))
@@ -746,8 +704,8 @@ class TPUConflictSet:
                    max(1024, 2 * batch_size * (max_read_ranges
                                                + max_write_ranges)))
         )
-        # Two-tier dictionary (FDB_TPU_DICT_HOT_CAPACITY > 0, resident
-        # engines only): the device dictionary becomes the HOT tier at
+        # Two-tier dictionary (FDB_TPU_DICT_HOT_CAPACITY > 0): the device
+        # dictionary becomes the HOT tier at
         # this capacity and the mirror's ID space the authoritative host
         # COLD store. Crossing the hot watermark demotes rank-contiguous
         # victim batches through _dict_evict (the inverse of the insert
@@ -759,7 +717,7 @@ class TPUConflictSet:
             if dict_hot_capacity is not None
             else int(os.environ.get("FDB_TPU_DICT_HOT_CAPACITY", "0") or 0)
         )
-        self.tiered = bool(hot > 0) and self.resident
+        self.tiered = bool(hot > 0)
         if self.tiered:
             self.dict_capacity = hot
             self.dict_delta_slots = min(
@@ -848,7 +806,6 @@ class TPUConflictSet:
         # hist_merges / dispatches says how often a dispatch's paint did
         # not fit the delta; advance() merges every time.
         self.hist_merges = 0
-        self._empty_dev_batch = None  # advance()'s constant batch, packed lazily
         # Admission subsystem (attach_admission_filter): a RecentWritesFilter
         # fed from each dispatch's ACCEPTED write sets using the endpoint
         # u64 columns the resident pack already computed — no re-hash, no
@@ -867,128 +824,48 @@ class TPUConflictSet:
         self._init_engine()
 
     def attach_admission_filter(self, f) -> None:
-        """Attach a RecentWritesFilter to the resident engine: every
-        resolve feeds the accepted write-set fingerprints (resident mode
-        only — the fingerprints ARE the mirror's u64 key columns)."""
-        if not self.resident:
-            raise ValueError(
-                "admission filter attaches to the resident engine only "
-                "(FDB_TPU_RESIDENT=1 / resident=True)")
+        """Attach a RecentWritesFilter: every resolve feeds the accepted
+        write-set fingerprints (they ARE the mirror's u64 key columns)."""
         self.admission_filter = f
 
     def _init_engine(self) -> None:
         """Build device state + entry points. Subclasses (the mesh-sharded
-        engine) override this; all host-side logic is shared. Under
-        FDB_TPU_PACKED (default) the packer additionally emits the batch's
-        deduped key dictionary (_pack_dict) and the device runs the
-        rank-space kernel entry points; under FDB_TPU_RESIDENT (default)
-        the dictionary instead PERSISTS on device and the packer emits
-        rank batches + key deltas against the host mirror."""
-        hist = ck._HIST_DESIGN == "window"
-        self._mirror: _ResidentMirror | None = None
-        self._dev_batch_deferred = None  # window-path packer (may defer repack)
-        if self.resident:
-            self._mirror = _ResidentMirror(
-                self.codec.min_key[None, :], self.dict_capacity,
-                self.dict_delta_slots, tiered=self.tiered,
-            )
-            self.state = ck.init_res(
-                self._mirror.rows, self.dict_capacity, self.capacity,
-                self.delta_capacity if hist else None,
-            )
-            self._dev_batch = lambda bt: self._pack_resident(bt)
-            self._dev_batch_deferred = lambda bt: self._pack_resident(
-                bt, defer_repack=True
-            )
-            self._rebase_fn = ck._rebase_res_jit
-            self._repack_fn = ck._repack_res_jit
-            self._evict_fn = ck._evict_res_jit
-        else:
-            self._dev_batch = self._pack_dict if ck._PACKED else (lambda bt: bt)
-            self._dev_batch_deferred = self._dev_batch
-            if hist:
-                self.state = ck.init_hist(
-                    self.capacity, self.codec.width, self.codec.min_key,
-                    self.delta_capacity,
-                )
-                self._rebase_fn = ck._rebase_hist_jit
-            else:
-                self.state = ck.init_state(
-                    self.capacity, self.codec.width, self.codec.min_key
-                )
-                self._rebase_fn = ck._rebase_jit
-        # Entry points follow one naming convention —
-        # _resolve{,_report,_many}{_hist}{_packed|_res}{_wave}_jit — so the
-        # (history, packed/resident, wave) design point composes the names
-        # instead of a hand-written table a mis-paired branch could
-        # silently skew.
-        fmt = "_res" if self.resident else ("_packed" if ck._PACKED else "")
-        suffix = (("_hist" if hist else "") + fmt
-                  + ("_wave" if self.wave_commit else "") + "_jit")
-        self._resolve_fn = getattr(ck, "_resolve" + suffix)
-        self._resolve_report_fn = getattr(ck, "_resolve_report" + suffix)
-        self._resolve_many_fn = getattr(ck, "_resolve_many" + suffix)
-        if self.wave_commit:
-            # Two-phase entry points for the role-level global wave
-            # protocol (resolve_edges/resolve_apply) — same suffix
-            # composition as above.
-            two = ("_hist" if hist else "") + fmt + "_jit"
-            self._wave_edges_fn = getattr(ck, "_wave_edges" + two)
-            self._wave_apply_fn = getattr(ck, "_wave_apply" + two)
-        if self.spec:
-            # Paint-only re-advance entry points for the reconcile path
-            # (no _wave variant: a forced accept mask has no levels to
-            # compute — wave engines paint with levels >= 0).
-            pfx = ("_hist" if hist else "") + fmt + "_jit"
-            self._paint_many_fn = getattr(ck, "_paint_many" + pfx)
-
-    def _pack_dict(self, bt: ck.BatchTensors) -> ck.PackedBatch:
-        """Dedup+sort ALL batch endpoint keys once per dispatch (host
-        numpy — a memcmp sort over the biased byte view) and rewrite the
-        batch in rank space: the kernel receives the sorted unique key
-        dictionary plus int32 ranks per endpoint slot. The dictionary's
-        static size is the endpoint count + 1, with the last row always
-        +inf (paint parks masked slots there); ranks are exact order
-        isomorphisms (equal keys share a rank)."""
-        with stage_timer(self.last_stage_s, "dict_rank", self._last_commit):
-            return self._pack_dict_rows(bt)
-
-    def _pack_dict_rows(self, bt: ck.BatchTensors) -> ck.PackedBatch:
-        rb = np.asarray(bt.read_begin)
-        if rb.ndim == 4:  # [k, B, R, W] window path: pack per scan step
-            parts = [
-                self._pack_dict_rows(
-                    ck.BatchTensors(*(
-                        None if x is None else np.asarray(x)[i] for x in bt))
-                )
-                for i in range(rb.shape[0])
-            ]
-            return ck.PackedBatch(*(
-                None if x[0] is None else np.stack(x) for x in zip(*parts)))
-        b, r, w = rb.shape
-        q = bt.write_begin.shape[1]
-        flat = np.concatenate([
-            rb.reshape(-1, w),
-            np.asarray(bt.read_end).reshape(-1, w),
-            np.asarray(bt.write_begin).reshape(-1, w),
-            np.asarray(bt.write_end).reshape(-1, w),
-        ])
-        dict_keys, inv = pack_rank_dictionary(flat)
-        n_r, n_q = b * r, b * q
-        return ck.PackedBatch(
-            dict_keys=dict_keys,
-            read_begin=inv[:n_r].reshape(b, r),
-            read_end=inv[n_r : 2 * n_r].reshape(b, r),
-            read_mask=np.asarray(bt.read_mask),
-            write_begin=inv[2 * n_r : 2 * n_r + n_q].reshape(b, q),
-            write_end=inv[2 * n_r + n_q :].reshape(b, q),
-            write_mask=np.asarray(bt.write_mask),
-            read_version=np.asarray(bt.read_version),
-            txn_mask=np.asarray(bt.txn_mask),
-            cont=bt.cont,
+        engine) override this; all host-side logic is shared. The endpoint
+        dictionary and the rank-space window history PERSIST on the device
+        (ck.ResState); the packer emits rank batches + key deltas against
+        the host mirror."""
+        self._mirror = _ResidentMirror(
+            self.codec.min_key[None, :], self.dict_capacity,
+            self.dict_delta_slots, tiered=self.tiered,
         )
+        self.state = ck.init_res(
+            self._mirror.rows, self.dict_capacity, self.capacity,
+            self.delta_capacity,
+        )
+        self._rebase_fn = ck._rebase_res_jit
+        self._advance_hist_fn = ck._advance_hist_res_jit
+        self._repack_fn = ck._repack_res_jit
+        self._evict_fn = ck._evict_res_jit
+        wave = self.wave_commit
+        self._resolve_fn = (
+            ck._resolve_res_wave_jit if wave else ck._resolve_res_jit)
+        self._resolve_report_fn = (
+            ck._resolve_report_res_wave_jit if wave
+            else ck._resolve_report_res_jit)
+        self._resolve_many_fn = (
+            ck._resolve_many_res_wave_jit if wave
+            else ck._resolve_many_res_jit)
+        if wave:
+            # Two-phase entry points for the role-level global wave
+            # protocol (resolve_edges/resolve_apply).
+            self._wave_edges_fn = ck._wave_edges_res_jit
+            self._wave_apply_fn = ck._wave_apply_res_jit
+        # Paint-only re-advance for the speculative reconcile path (no
+        # _wave variant: a forced accept mask has no levels to compute —
+        # wave engines paint with levels >= 0).
+        self._paint_many_fn = ck._paint_many_res_jit
 
-    # -- resident-dictionary packing (FDB_TPU_RESIDENT=1) --------------------
+    # -- resident-dictionary packing -----------------------------------------
 
     def _flat_endpoints(self, bt: ck.BatchTensors):
         """All endpoint key rows of a (possibly [k]-leading) batch, flat in
@@ -1254,8 +1131,7 @@ class TPUConflictSet:
                     raise ValueError(
                         f"resident dictionary cannot fit {must} live/pinned"
                         f" + {m} new keys in capacity {mir.capacity};"
-                        " raise dict_capacity / FDB_TPU_DICT_CAPACITY or"
-                        " run with FDB_TPU_RESIDENT=0"
+                        " raise dict_capacity / FDB_TPU_DICT_CAPACITY"
                     )
                 # Of the rest, only a key used at or after the MVCC floor
                 # stays: a stale one that nothing references comes back, if
@@ -1404,11 +1280,9 @@ class TPUConflictSet:
         return self._pack_resident(plan.bt)
 
     @property
-    def dict_stats(self) -> dict | None:
-        """Dictionary-economics counters (None unless resident): unique
-        keys/dispatch, delta hit rate, evictions, forced full repacks."""
-        if self._mirror is None:
-            return None
+    def dict_stats(self) -> dict:
+        """Dictionary-economics counters: unique keys/dispatch, delta hit
+        rate, evictions, forced full repacks."""
         s = dict(self._mirror.stats, **_COMPILE_STATS)
         d = max(1, s["dispatches"])
         e = max(1, s["endpoints"])
@@ -1486,7 +1360,7 @@ class TPUConflictSet:
                 batch, reads = self._pack(chunk, collect_reads=True)
                 # Pack BEFORE reading self.state: a resident-dictionary
                 # repack inside the packer replaces (and donates) it.
-                dev = self._dev_batch(_for_kernel(batch, self.wave_commit))
+                dev = self._pack_resident(_for_kernel(batch, self.wave_commit))
                 with stage_timer(self.last_stage_s, "engine_enqueue",
                                  commit_version):
                     out = self._resolve_report_fn(self.state, dev, cv,
@@ -1498,7 +1372,7 @@ class TPUConflictSet:
             else:
                 batch = self._pack(chunk)
                 # may repack: order matters
-                dev = self._dev_batch(_for_kernel(batch, self.wave_commit))
+                dev = self._pack_resident(_for_kernel(batch, self.wave_commit))
                 with stage_timer(self.last_stage_s, "engine_enqueue",
                                  commit_version):
                     out = self._resolve_fn(self.state, dev, cv, oldest)
@@ -1567,7 +1441,7 @@ class TPUConflictSet:
             batch, offset, n = self._pack_wire(
                 buf, offset, min(remaining, self.batch_size))
             # may repack: order matters
-            dev = self._dev_batch(_for_kernel(batch, self.wave_commit))
+            dev = self._pack_resident(_for_kernel(batch, self.wave_commit))
             with stage_timer(self.last_stage_s, "engine_enqueue",
                              commit_version):
                 out = self._resolve_fn(self.state, dev, cv, oldest)
@@ -1680,33 +1554,26 @@ class TPUConflictSet:
                 np.int32,
             )
 
-            if self._native_window_pack:
-                # Fused C pass: wire walk + padding + the per-batch
-                # dictionary dedup/sort/rank emission that _pack_dict pays
-                # in numpy — the host half of the speculative pipeline,
-                # sized so packing N+2 never stalls the device on N+1.
-                dev_batch = self._pack_window_native(buf, k, count)
-            else:
-                batches = self._empty_batch(k)
-                offset = 0
-                for i in range(k):
-                    offset = _wire_offset(lib.kp_pack_batch(
-                        _u8(buf), buf.size, offset, count,
-                        self.batch_size, self.max_read_ranges,
-                        self.max_write_ranges,
-                        self.codec.n_words, self.base_version,
-                        _i32(batches.read_begin[i]), _i32(batches.read_end[i]),
-                        _u8(batches.read_mask[i]),
-                        _i32(batches.write_begin[i]), _i32(batches.write_end[i]),
-                        _u8(batches.write_mask[i]),
-                        _i32(batches.read_version[i]), _u8(batches.txn_mask[i]),
-                        None, None,
-                    ))
-                # The deferred-repack packer variant: a resident-dictionary
-                # overflow on the packing thread becomes a _RepackPlan
-                # executed by dispatch_window (which may sync device
-                # state), not an inline repack here.
-                dev_batch = self._dev_batch_deferred(batches)
+            batches = self._empty_batch(k)
+            offset = 0
+            for i in range(k):
+                offset = _wire_offset(lib.kp_pack_batch(
+                    _u8(buf), buf.size, offset, count,
+                    self.batch_size, self.max_read_ranges,
+                    self.max_write_ranges,
+                    self.codec.n_words, self.base_version,
+                    _i32(batches.read_begin[i]), _i32(batches.read_end[i]),
+                    _u8(batches.read_mask[i]),
+                    _i32(batches.write_begin[i]), _i32(batches.write_end[i]),
+                    _u8(batches.write_mask[i]),
+                    _i32(batches.read_version[i]), _u8(batches.txn_mask[i]),
+                    None, None,
+                ))
+            # The deferred-repack packer variant: a resident-dictionary
+            # overflow on the packing thread becomes a _RepackPlan
+            # executed by dispatch_window (which may sync device
+            # state), not an inline repack here.
+            dev_batch = self._pack_resident(batches, defer_repack=True)
         except BaseException:
             self.base_version, self.oldest_version, self._last_commit = snap
             raise
@@ -1716,58 +1583,6 @@ class TPUConflictSet:
             olds_rel=olds_rel,
             count=count,
             rebase_delta=rebase_delta,
-        )
-
-    @property
-    def _native_window_pack(self) -> bool:
-        """Use the fused native window packer (kp_pack_window)? Gated to
-        the speculative non-resident packed path — the arm whose pipeline
-        the fused pack exists to feed (the resident path already replaced
-        _pack_dict with the mirror; serial stays the honest A/B baseline).
-        FDB_TPU_NATIVE_WINDOW_PACK=0 forces the numpy packer for parity
-        tests."""
-        if self._nat_win is None:
-            self._nat_win = (
-                self.spec
-                and not self.resident
-                and os.environ.get("FDB_TPU_NATIVE_WINDOW_PACK", "1") != "0"
-            )
-        return self._nat_win
-
-    def _pack_window_native(self, buf: np.ndarray, k: int,
-                            count: int) -> ck.PackedBatch:
-        """One kp_pack_window call → the window's PackedBatch (rank layout
-        bit-identical to _pack_dict over kp_pack_batch output)."""
-        lib = _keypack_lib()
-        b, r, q = self.batch_size, self.max_read_ranges, self.max_write_ranges
-        w = self.codec.width
-        n = 2 * b * (r + q)
-        bt = self._empty_batch(k)
-        dict_keys = np.full((k, n + 1, w), INT32_MAX, np.int32)
-        rb_rank = np.empty((k, b, r), np.int32)
-        re_rank = np.empty((k, b, r), np.int32)
-        wb_rank = np.empty((k, b, q), np.int32)
-        we_rank = np.empty((k, b, q), np.int32)
-        off = lib.kp_pack_window(
-            _u8(buf), buf.size, 0, k, count, b, r, q,
-            self.codec.n_words, self.base_version,
-            _i32(bt.read_begin), _i32(bt.read_end), _u8(bt.read_mask),
-            _i32(bt.write_begin), _i32(bt.write_end), _u8(bt.write_mask),
-            _i32(bt.read_version), _u8(bt.txn_mask),
-            _i32(dict_keys), _i32(rb_rank), _i32(re_rank),
-            _i32(wb_rank), _i32(we_rank),
-        )
-        _wire_offset(off)
-        return ck.PackedBatch(
-            dict_keys=dict_keys,
-            read_begin=rb_rank,
-            read_end=re_rank,
-            read_mask=bt.read_mask,
-            write_begin=wb_rank,
-            write_end=we_rank,
-            write_mask=bt.write_mask,
-            read_version=bt.read_version,
-            txn_mask=bt.txn_mask,
         )
 
     def dispatch_window(self, prepared: PreparedWindow) -> Callable[[], np.ndarray]:
@@ -1839,13 +1654,11 @@ class TPUConflictSet:
     # fills), reconcile either confirms (the overwhelmingly common case —
     # drop N's snapshot, done) or rolls the state back to N's snapshot,
     # re-paints N with only the confirmed accepts, and repairs every
-    # younger in-flight window against the corrected history. A
-    # dependency probe (reads of the younger window vs N's rejected
-    # writes, probed through the packed batch dictionary) distinguishes
-    # windows whose verdicts provably survived (paint-only re-advance)
-    # from windows that must re-resolve (the repair path — only
-    # genuinely-conflicted txns flip). Serializability is therefore
-    # preserved by construction; the A/B harness additionally replays
+    # younger in-flight window against the corrected history: each is
+    # re-resolved (only genuinely-conflicted txns flip; the windows' ranks
+    # live in per-window coordinate systems, so nothing can prove a
+    # younger window clean without resolving it). Serializability is
+    # therefore preserved by construction; the A/B harness additionally replays
     # both arms through a fresh serial engine and compares verdict bytes.
 
     def spec_dispatch_window(self, prepared: PreparedWindow) -> int:
@@ -1903,9 +1716,7 @@ class TPUConflictSet:
         MUST be masked out)."""
         if levels is not None:
             return np.asarray(levels) >= 0
-        txn_mask = (batch.ranks.txn_mask if isinstance(batch, ck.ResidentBatch)
-                    else batch.txn_mask)
-        return (np.asarray(verdicts) == 0) & np.asarray(txn_mask)
+        return (np.asarray(verdicts) == 0) & np.asarray(batch.ranks.txn_mask)
 
     def reconcile_window(self, confirmed: np.ndarray | None = None) -> np.ndarray:
         """Reconcile the OLDEST in-flight window against its upper-layer
@@ -1953,62 +1764,27 @@ class TPUConflictSet:
             p.cvs_rel, p.olds_rel,
         )
         # 3) Repair every younger in-flight window against the corrected
-        #    history, in dispatch order. The dependency probe says which
-        #    ones provably kept their verdicts (reads never touched a
-        #    rejected write → paint-only re-advance) and which must
-        #    re-resolve (the repair path; only genuinely-conflicted txns
-        #    flip).
+        #    history, in dispatch order: re-resolve it (only
+        #    genuinely-conflicted txns flip).
         younger = list(self._spec_ring)
         self._spec_ring.clear()
-        deps = self._spec_dep_windows(p.batch, rejected, younger)
-        for y, dep in zip(younger, deps):
+        for y in younger:
             snap = ck._snapshot_jit(self.state)
-            if dep:
-                out = self._resolve_many_fn(
-                    self.state, y.batch, y.cvs_rel, y.olds_rel
-                )
-                nv, nl, self.state = (
-                    out if self.wave_commit else (out[0], None, out[1])
-                )
-                old_acc = self._spec_accept_mask(y.batch, y.verdicts, y.levels)
-                new_acc = self._spec_accept_mask(y.batch, nv, nl)
-                self._spec_stats["spec_flipped"] += int(
-                    (old_acc != new_acc)[:, : y.count].sum()
-                )
-                y = y._replace(snapshot=snap, verdicts=nv, levels=nl)
-            else:
-                acc = self._spec_accept_mask(y.batch, y.verdicts, y.levels)
-                self.state = self._paint_many_fn(
-                    self.state, y.batch, acc, y.cvs_rel, y.olds_rel
-                )
-                y = y._replace(snapshot=snap)
-            self._spec_ring.append(y)
+            out = self._resolve_many_fn(
+                self.state, y.batch, y.cvs_rel, y.olds_rel
+            )
+            nv, nl, self.state = (
+                out if self.wave_commit else (out[0], None, out[1])
+            )
+            old_acc = self._spec_accept_mask(y.batch, y.verdicts, y.levels)
+            new_acc = self._spec_accept_mask(y.batch, nv, nl)
+            self._spec_stats["spec_flipped"] += int(
+                (old_acc != new_acc)[:, : y.count].sum()
+            )
+            self._spec_ring.append(
+                y._replace(snapshot=snap, verdicts=nv, levels=nl))
         self._spec_done[p.seq] = (verdicts_np, levels_np)
         return verdicts_np
-
-    def _spec_dep_windows(self, batch, rejected: np.ndarray,
-                          younger: list[_SpecPending]) -> list[bool]:
-        """Per younger window: did ANY of its reads overlap a write the
-        reconciling window's confirmation rejected? Rejected writes are
-        painted into a small scratch step function at +inf version, then
-        each younger window's batch dictionary probes it — a clean probe
-        proves the window's verdicts survived (its floor and intra-window
-        graph are unchanged, and no read saw a rejected boundary).
-        Resident engines skip the probe (batch ranks live in per-window
-        coordinate systems the scratch can't share) and repair
-        pessimistically — still exact, just never paint-only."""
-        if not younger:
-            return []
-        if self.resident:
-            return [True] * len(younger)
-        k, b = rejected.shape
-        cap = min(self.capacity, 2 * k * b * self.max_write_ranges + 2)
-        scratch = ck.init_state(cap, self.codec.width, self.codec.min_key)
-        scratch = ck._spec_mark_rejected_jit(scratch, batch, rejected)
-        return [
-            bool(np.asarray(ck._spec_dep_window_jit(scratch, y.batch)))
-            for y in younger
-        ]
 
     def reconcile_all(self) -> None:
         """Drain the in-flight ring (confirmations via spec_confirm_hook).
@@ -2062,22 +1838,18 @@ class TPUConflictSet:
         old_rel = np.asarray([self._rel(self.oldest_version)], np.int32)
         batch = self._pack(txns)
         self._adm_stash = None
-        dev = self._dev_batch_deferred(batch)
+        dev = self._pack_resident(batch, defer_repack=True)
         if isinstance(dev, _RepackPlan):
             self.reconcile_all()
             dev = self._repack_and_rank(dev)
         elif isinstance(dev, _DemotePlan):
             self.reconcile_all()
             dev = self._demote_and_rank(dev)
-        if isinstance(dev, ck.ResidentBatch):
-            # k=1 lift: the scan axis goes on the ranks; the key delta is
-            # per-window (merged once) exactly as the window packer emits.
-            dev = dev._replace(ranks=type(dev.ranks)(*(
-                None if f is None else np.asarray(f)[None]
-                for f in dev.ranks)))
-        else:
-            dev = type(dev)(*(
-                None if f is None else np.asarray(f)[None] for f in dev))
+        # k=1 lift: the scan axis goes on the ranks; the key delta is
+        # per-window (merged once) exactly as the window packer emits.
+        dev = dev._replace(ranks=type(dev.ranks)(*(
+            None if f is None else np.asarray(f)[None]
+            for f in dev.ranks)))
         snap = ck._snapshot_jit(self.state)
         out = self._resolve_many_fn(self.state, dev, cv_rel, old_rel)
         verdicts, levels, self.state = (
@@ -2173,13 +1945,10 @@ class TPUConflictSet:
                 hist_conflict=np.zeros(0, bool), chunks=[],
             )
         batch = self._pack(txns)
-        dev = self._dev_batch(batch)
-        if self.resident:
-            too_old, hist_c, p, self.state = self._wave_edges_fn(
-                self.state, dev, oldest
-            )
-        else:
-            too_old, hist_c, p = self._wave_edges_fn(self.state, dev, oldest)
+        dev = self._pack_resident(batch)
+        too_old, hist_c, p, self.state = self._wave_edges_fn(
+            self.state, dev, oldest
+        )
         # The exchange goes by TRANSACTION: every shard is sent every
         # transaction of the window, clipped, and lays a wide one out in
         # rows of its own. A transaction's gate is the OR over its rows
@@ -2239,9 +2008,9 @@ class TPUConflictSet:
                 )
             cand = np.zeros(self.batch_size, bool)
             cand[:n] = graph.cand[gi : gi + n]
-            rbk = dev.ranks if self.resident else dev
             levels, self.state = self._wave_apply_fn(
-                self.state, rbk, cand, np.ascontiguousarray(pred, np.uint32),
+                self.state, dev.ranks, cand,
+                np.ascontiguousarray(pred, np.uint32),
                 cv, oldest,
             )
             lv = np.asarray(levels)[:n]  # by transaction, like the graph
@@ -2350,7 +2119,7 @@ class TPUConflictSet:
             if losers is not None:
                 m = np.asarray(losers)[:n]
                 if m.dtype != np.bool_:
-                    # uint32 bitset rows (packed kernel): bit c = read
+                    # uint32 bitset rows (ck.pack_loser_mask): bit c = read
                     # slot c lost — unpack to the bool [n, R] layout.
                     m = (
                         (m[:, None] >> np.arange(r, dtype=np.uint32)) & 1
@@ -2399,30 +2168,15 @@ class TPUConflictSet:
         return delta
 
     @property
-    def _hist_core(self):
-        """The history state proper (unwraps the resident ResState)."""
-        st = self.state
-        return st.hist if isinstance(st, ck.ResState) else st
-
-    @property
-    def _is_hist(self) -> bool:
-        return isinstance(self._hist_core, ck.HistState)
-
-    @property
-    def _advance_hist_fn(self):
-        """The window-history engine's GC-only entry point."""
-        return (ck._advance_hist_res_jit
-                if isinstance(self.state, ck.ResState)
-                else ck._advance_hist_jit)
+    def _hist_core(self) -> ck.HistState:
+        """The window history proper, out of the ResState."""
+        return self.state.hist
 
     def _reading(self):
         """The capacity reading of the state as it stands, enqueued:
         int32 [3] on the device (boundary slots in use, overflowed, the
         window history's merges since boot): ck._capacity_reading_jit."""
         st = self._hist_core
-        if not self._is_hist:
-            return ck._capacity_reading_jit(
-                (st.n_used,), (st.overflow,), np.int32(0))
         return ck._capacity_reading_jit(
             (st.base.n_used, st.delta.n_used),
             (st.base.overflow, st.delta.overflow), st.merges,
@@ -2441,12 +2195,10 @@ class TPUConflictSet:
     @property
     def overflowed(self) -> bool:
         st = self._hist_core
-        if self._is_hist:
-            return bool(
-                np.asarray(st.base.overflow).any()
-                or np.asarray(st.delta.overflow).any()
-            )
-        return bool(np.asarray(st.overflow).any())
+        return bool(
+            np.asarray(st.base.overflow).any()
+            or np.asarray(st.delta.overflow).any()
+        )
 
     def headroom(self) -> int:
         """Free boundary slots in the tightest shard (device sync).
@@ -2460,7 +2212,7 @@ class TPUConflictSet:
         SkipList never loses history inside the MVCC window; this check is
         how the fixed-capacity engine earns the same guarantee.
 
-        Window-history engine: a merge keeps at most base+delta live
+        A merge keeps at most base+delta live
         boundaries (the base counted as that merge's GC would leave it:
         rows that expired since the last merge use no capacity,
         ck._capacity_reading_jit), and the just-in-time merge empties the
@@ -2481,7 +2233,7 @@ class TPUConflictSet:
     def _headroom_of(self, used: int) -> int:
         """headroom() given the boundary slots in use."""
         free = self.capacity - used
-        if self._is_hist and self.delta_capacity < min(
+        if self.delta_capacity < min(
                 self.capacity, self.worst_case_growth(self.batch_size)):
             return min(free, self.delta_capacity)
         return free
@@ -2495,52 +2247,31 @@ class TPUConflictSet:
         """Reset the sticky device overflow flag (after the host has
         reacted — see Resolver's unsafe-window handling)."""
         hc = self._hist_core
-        if self._is_hist:
-            new = hc._replace(
-                base=hc.base._replace(overflow=hc.base.overflow & False),
-                delta=hc.delta._replace(overflow=hc.delta.overflow & False),
-            )
-        else:
-            new = hc._replace(overflow=hc.overflow & False)
-        self._set_hist_core(new)
-
-    def _set_hist_core(self, new) -> None:
-        if isinstance(self.state, ck.ResState):
-            self.state = self.state._replace(hist=new)
-        else:
-            self.state = new
+        self.state = self.state._replace(hist=hc._replace(
+            base=hc.base._replace(overflow=hc.base.overflow & False),
+            delta=hc.delta._replace(overflow=hc.delta.overflow & False),
+        ))
 
     def advance(self, commit_version: int, oldest_version: int | None = None) -> None:
         """GC-only dispatch: move the version chain and MVCC floor forward
         without painting any writes. Expired segments compact out, so
         headroom recovers as the window slides — this is what lets the
-        Resolver's fail-safe mode drain and exit. The window-history
-        engine forces a merge here (the lazy base would otherwise hold
-        expired segments until the next organic merge)."""
+        Resolver's fail-safe mode drain and exit. It forces a merge (the
+        lazy base would otherwise hold expired segments until the next
+        organic merge)."""
         self._spec_drain_serial()
         self._begin_resolve(commit_version, oldest_version)
         if self.admission_filter is not None:
             self.admission_filter.advance(commit_version)  # age the banks
         cv = np.int32(self._rel(commit_version))
         oldest = np.int32(self._rel(self.oldest_version))
-        if self._is_hist:
-            _, self.state = self._advance_hist_fn(self.state, cv, oldest)
-            return
-        if self._empty_dev_batch is None:
-            # The packed dictionary build is real host work (np.unique over
-            # all endpoint rows) and advance()'s all-masked batch is a
-            # constant — pack it once. The batch argument is never donated.
-            self._empty_dev_batch = self._dev_batch(self._empty_batch())
-        self.state = self._resolve_fn(
-            self.state, self._empty_dev_batch, cv, oldest
-        )[-1]
+        _, self.state = self._advance_hist_fn(self.state, cv, oldest)
 
     def warm_up(self) -> dict[str, float]:
         """Compile the entry points a serving resolver dispatches, before
         the first request: resolve, the conflicting-keys report, the
-        GC-only advance, the version rebase, the capacity reading and
-        (resident engines) the full dictionary repack, each run once at
-        this engine's shapes on
+        GC-only advance, the version rebase, the capacity reading and the
+        full dictionary repack, each run once at this engine's shapes on
         an all-masked batch at relative version 0 — which paints nothing,
         moves no floor and remaps every rank to itself, so the state that
         comes out equals the state that went in. Host version bookkeeping
@@ -2556,14 +2287,11 @@ class TPUConflictSet:
         count_compiles()
         zero = np.int32(0)
         bt = self._empty_batch()
-        if self.resident:
-            # Assembled directly, not through _pack_resident: a warm-up is
-            # no dispatch, and the mirror's counters should not say so.
-            flat, dims = self._flat_endpoints(bt)
-            empty = self._ranks_to_batch(
-                bt, np.full(len(flat), INT32_MAX, np.int32), dims)
-        else:
-            empty = self._dev_batch(bt)
+        # Assembled directly, not through _pack_resident: a warm-up is
+        # no dispatch, and the mirror's counters should not say so.
+        flat, dims = self._flat_endpoints(bt)
+        empty = self._ranks_to_batch(
+            bt, np.full(len(flat), INT32_MAX, np.int32), dims)
         steps: dict[str, Callable] = {
             "resolve": lambda: self._resolve_fn(
                 self.state, empty, zero, zero)[-1],
@@ -2571,31 +2299,28 @@ class TPUConflictSet:
         if getattr(self, "_resolve_report_fn", None) is not None:
             steps["resolve_report"] = lambda: self._resolve_report_fn(
                 self.state, empty, zero, zero)[-1]
-        if self._is_hist:
-            steps["advance"] = lambda: self._advance_hist_fn(
-                self.state, zero, zero)[-1]
+        steps["advance"] = lambda: self._advance_hist_fn(
+            self.state, zero, zero)[-1]
         steps["rebase"] = lambda: self._rebase_fn(self.state, zero)
         # Reads the state, returns none: the state goes through untouched.
         steps["reading"] = lambda: (
             jax.block_until_ready(self._enqueue_reading()), self.state)[1]
-        if self.resident:
-            mir = self._mirror
-            dict_dev = np.full((mir.capacity + 1, mir.rows.shape[1]),
-                               INT32_MAX, np.int32)
-            dict_dev[: mir.n] = mir.rows
-            identity = np.arange(mir.capacity + 1, dtype=np.int32)
-            steps["repack"] = lambda: self._repack_fn(
-                self.state, dict_dev, np.int32(mir.n), identity)
-        merges = np.asarray(self._hist_core.merges) if self._is_hist else None
+        mir = self._mirror
+        dict_dev = np.full((mir.capacity + 1, mir.rows.shape[1]),
+                           INT32_MAX, np.int32)
+        dict_dev[: mir.n] = mir.rows
+        identity = np.arange(mir.capacity + 1, dtype=np.int32)
+        steps["repack"] = lambda: self._repack_fn(
+            self.state, dict_dev, np.int32(mir.n), identity)
+        merges = np.asarray(self._hist_core.merges)
         seconds: dict[str, float] = {}
         for name, step in steps.items():
             t0 = _perf_counter()
             self.state = jax.block_until_ready(step())
             seconds[name] = round(_perf_counter() - t0, 3)
-        if merges is not None:
-            # The advance step merged, and a warm-up is none of the count's.
-            self._set_hist_core(self._hist_core._replace(
-                merges=self._device_merges(merges)))
+        # The advance step merged, and a warm-up is none of the count's.
+        self.state = self.state._replace(hist=self._hist_core._replace(
+            merges=self._device_merges(merges)))
         return seconds
 
     def _device_merges(self, merges: np.ndarray):
@@ -2881,8 +2606,7 @@ def _per_txn(rows, n: int, heads) -> np.ndarray:
 
 
 def _wire_offset(ret: int) -> int:
-    """kp_pack_batch / kp_pack_window's return, or the ValueError it
-    stands for."""
+    """kp_pack_batch's return, or the ValueError it stands for."""
     if ret == -2:
         raise ValueError(
             "a transaction has more ranges than a row has slots, and this "
@@ -2931,13 +2655,6 @@ def _keypack_lib():
         ]
         lib.kp_count_txns.restype = i64
         lib.kp_count_txns.argtypes = [u8p, i64, i64]
-        lib.kp_pack_window.restype = i64
-        lib.kp_pack_window.argtypes = [
-            u8p, i64, i64, ctypes.c_int, ctypes.c_int, ctypes.c_int,
-            ctypes.c_int, ctypes.c_int, ctypes.c_int, i64,
-            i32p, i32p, u8p, i32p, i32p, u8p, i32p, u8p,
-            i32p, i32p, i32p, i32p, i32p,
-        ]
         _KP_LIB = lib
     return _KP_LIB
 

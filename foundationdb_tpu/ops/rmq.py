@@ -78,14 +78,12 @@ def _popcount32(x: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Two-level blocked RMQ: the PRODUCTION range-max for the conflict
-# kernel's history check (conflict_kernel._history_conflicts). Its BUILD
-# is ~3 passes over [N] (in-block prefix/suffix cummax + a small table
-# over block maxima) instead of the sparse table's log2(N) passes —
-# measured 3.5x cheaper for the build+query shape on CPU-XLA; queries pay
-# one [Nq, G] row gather for the same-block case. sparse_table remains
-# for small/top-level tables and for an on-chip A/B that has not run
-# (the TPU may rank the designs differently).
+# Two-level blocked RMQ. The conflict kernel does NOT call it (its history
+# check uses sparse_table above; the blocked arm went with ROADMAP C1 and
+# this structure is the debt that item names). Its BUILD is ~3 passes over
+# [N] (in-block prefix/suffix cummax + a small table over block maxima)
+# instead of the sparse table's log2(N) passes; queries pay one [Nq, G]
+# row gather for the same-block case.
 # ---------------------------------------------------------------------------
 
 RMQ_BLOCK = 256

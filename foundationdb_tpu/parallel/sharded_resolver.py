@@ -8,11 +8,10 @@ conflict). Here the same design is one SPMD program over
 
 - each device owns a keyspace shard ``[split_d, split_{d+1})`` and holds its
   own step-function history (state arrays carry a leading device axis,
-  sharded over the mesh); the resident engine's is of the design one chip
-  keeps (conflict_kernel._HIST_DESIGN): by default the window history, a
-  frozen base, its RMQ table and a small delta a shard;
+  sharded over the mesh), of the design one chip keeps: the rank-space
+  window history, a frozen base, its RMQ table and a small delta a shard;
 - the batch is replicated; each device clips ranges to its shard
-  (clip_batch), checks reads against its local history, and contributes
+  (clip_ranks), checks reads against its local history, and contributes
   conflict bits via ``psum`` — the tensor analogue of the proxy ANDing
   per-resolver verdicts;
 - intra-batch acceptance runs replicated with the fused block scan (it
@@ -34,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from foundationdb_tpu.core.keypack import INT32_MAX, KeyCodec, row_sort_keys
+from foundationdb_tpu.core.keypack import INT32_MAX, KeyCodec
 from foundationdb_tpu.core.types import TxnConflictInfo
 from foundationdb_tpu.models import conflict_kernel as ck
 from foundationdb_tpu.obs.span import stage_timer
@@ -101,75 +100,17 @@ def _quantiles(n_shards: int, ks) -> "list | None":
     return interior
 
 
-# Host-side memcmp sort keys for packed rows: shared with the packed-batch
-# dictionary builder (core/keypack.row_sort_keys).
-_row_sort_keys = row_sort_keys
-
-
-def _sharded_resolve(state, batch, commit_version, new_oldest, lo, hi,
-                     wave=False):
-    """Per-device body (runs under shard_map; state/lo/hi are the local shard,
-    batch is replicated). `wave` (static) switches intra-batch acceptance
-    to the wave-commit schedule; the int32 [B] levels ride after the
-    verdicts, replicated like them."""
-    state = jax.tree.map(lambda x: x[0], state)  # drop leading device axis
-    lo = lo[0]
-    hi = hi[0]
-
-    floor, too_old = ck.too_old_mask(state, batch, new_oldest)
-
-    with jax.named_scope("shard_clip"):
-        local = ck.clip_batch(batch, lo, hi)
-    hist_local = ck._history_conflicts(state, local)
-    hist_conflict = _sum_over_shards(hist_local, packed=ck._PACKED)
-
-    # Intra-batch acceptance is a pure function of the (unclipped) batch
-    # plus the psum'd history verdicts, so every device computes it
-    # redundantly with the fused block scan — the blocked [G, B] overlap
-    # rows are cheap to rebuild from rank vectors, while the earlier
-    # row-sharded design all-gathered a [B, B] matrix (67 MB at B=8192)
-    # over ICI only to run the full-matrix wave on every device anyway.
-    base = batch.txn_mask & ~too_old & ~hist_conflict
-    if wave:
-        # Global wave commit over per-shard graphs: each shard builds the
-        # predecessor bitsets from its CLIPPED ranges only (edges whose
-        # read∩write overlap falls inside its keyspace slice — shards
-        # partition the keyspace, so the OR across shards IS the exact
-        # global graph), the packed [BP, BP/32] tiles cross ICI in one
-        # all_gather, and every device levels the identical OR-reduced
-        # matrix — byte-identical (wave, index) schedules and min-index
-        # cycle victims on every shard, no device ever trusting an edge
-        # it cannot see. This is the same exchange the role-level
-        # resolve_edges/resolve_apply protocol runs through the commit
-        # proxy (core/wavemesh), here fused into the device program.
-        accepted, levels, stats = _wave_exchange_and_level(
-            base, ck.endpoint_ranks_live(local), batch.cont
-        )
-    else:
-        accepted, _ = ck._accept_or_schedule(
-            base, ck.endpoint_ranks_live(batch), False, batch.cont
-        )
-    verdicts = ck.assemble_verdicts(too_old, batch.txn_mask, accepted)
-
-    new_state = ck._paint_and_compact(state, local, accepted, commit_version, floor)
-    new_state = jax.tree.map(lambda x: x[None], new_state)
-    if wave:
-        return verdicts, levels, stats, new_state
-    return verdicts, new_state
-
-
 @jax.named_scope("shard_psum")
-def _sum_over_shards(hist_local, packed: bool = True):
+def _sum_over_shards(hist_local):
     """bool [B]: did ANY shard's history conflict with the row — the
     tensor analogue of the proxy ANDing per-resolver verdicts, taken
     BEFORE acceptance and paint, so every shard paints what ONE history
-    would have accepted. Packed (FDB_TPU_PACKED, and always in rank
-    space), the per-shard bits cross ICI as a uint32 bitset: B/32 words a
-    device instead of B int32 lanes, a 32x byte cut on the reduction
-    every batch pays. OR of bitsets isn't a psum/pmax, so all_gather the
-    packed words (D small) and fold locally."""
+    would have accepted. The per-shard bits cross ICI as a uint32 bitset:
+    B/32 words a device instead of B int32 lanes, a 32x byte cut on the
+    reduction every batch pays. OR of bitsets isn't a psum/pmax, so
+    all_gather the packed words (D small) and fold locally."""
     b = hist_local.shape[0]
-    if packed and b % 32 == 0:
+    if b % 32 == 0:
         gathered = jax.lax.all_gather(pack_bits_u32(hist_local), AXIS)
         return jnp.any(unpack_bits_u32(gathered, b), axis=0)
     return jax.lax.psum(hist_local.astype(jnp.int32), AXIS) > 0
@@ -216,31 +157,39 @@ def _any_shard(mask):
 
 def _res_shard_step(hist, lo, hi, rbk, commit_version, new_oldest, wave,
                     report=False):
-    """One resident-mode per-shard resolve step (runs under shard_map):
+    """One per-shard resolve step (runs under shard_map):
     conflict_kernel._resolve_core_res, the body one chip runs, handed what
     differs a shard.
 
-    hist: the local shard's width-1 rank-space history, of the design one
-    chip keeps (ck._HIST_DESIGN): the window history's frozen base, its
-    table and its delta, or the one-level history. lo/hi: the shard's
+    hist: the local shard's width-1 rank-space window history: the frozen
+    base, its table and its delta. lo/hi: the shard's
     keyspace bounds AS RANKS (already rebased past this dispatch's
     dictionary inserts). The batch is replicated rank tensors; clipping is
     scalar int32 (clip_ranks) and the shard probes, merges on the demand
     of, and paints its CLIPPED batch alone, so one shard may fold its
     delta into its base at a dispatch where the others do not: the merge
-    holds no collective. The cross-shard combine is the same packed
-    all_gather as the full-key body, and acceptance runs replicated on
-    the UNCLIPPED batch exactly as before. `report` (static; sequential
+    holds no collective. The cross-shard combine is a packed all_gather
+    (_sum_over_shards), and acceptance runs replicated on the UNCLIPPED
+    batch: it is a pure function of the batch plus the combined history
+    bits, so every device computes it redundantly with the fused block
+    scan (rebuilding each block's [G, B] overlap rows from rank vectors is
+    cheaper than moving a [B, B] matrix over ICI). `report` (static; sequential
     order only) also returns the conflicting-keys report's loser mask,
     replicated: each shard's per-range history bits summed over the mesh,
     then conflict_kernel.loser_range_mask on the unclipped batch, as one
     chip computes it."""
     accept = None
     if wave:
-        # Same global-graph exchange as the full-key body, in rank space:
-        # the clipped RankBatch's intervals witness exactly this shard's
-        # slice of every edge (clip_ranks is a two-sided clamp on shared
-        # global ranks), so the OR across shards is the exact graph.
+        # Global wave commit over per-shard graphs: the clipped
+        # RankBatch's intervals witness exactly this shard's slice of
+        # every edge (clip_ranks is a two-sided clamp on shared global
+        # ranks; shards partition the keyspace), so the OR across shards
+        # IS the exact global graph. The packed [BP, BP/32] tiles cross
+        # ICI in one all_gather and every device levels the identical
+        # OR-reduced matrix: byte-identical (wave, index) schedules and
+        # min-index cycle victims on every shard. The same exchange the
+        # role-level resolve_edges/resolve_apply protocol runs through
+        # the commit proxy (core/wavemesh), fused into the device program.
         def accept(base, local):
             accepted, levels, stats = _wave_exchange_and_level(
                 base, ck.endpoint_ranks_live_packed(local), rbk.cont
@@ -273,7 +222,7 @@ def _restack(tree):
 
 def _sharded_resolve_res(res, rb, commit_version, new_oldest, wave=False,
                          report=False):
-    """Resident mesh body: replicated dictionary-delta insert (every device
+    """The mesh body: replicated dictionary-delta insert (every device
     takes the same host-shipped ranks, rb.delta_cross, and computes the
     identical merged dictionary), per-shard rank-rebase of histories AND
     shard bounds, then the rank-space shard step."""
@@ -471,9 +420,8 @@ class ShardedConflictSet(TPUConflictSet):
 
     def dispatch_window(self, prepared):
         # Dispatch-thread hook (the window path packs on a worker thread).
-        # Non-resident: reshard only touches device state, which the pack
-        # never reads. Resident: reshard also reads/mutates the host
-        # mirror — those touches are serialized by mir.lock, and the auto
+        # A reshard also reads/mutates the host mirror — those touches
+        # are serialized by mir.lock, and the auto
         # policy only ever splits at already-resident boundary keys, so
         # no rank shift is introduced under packed windows in flight.
         self._maybe_auto_reshard()
@@ -527,49 +475,53 @@ class ShardedConflictSet(TPUConflictSet):
         keys, n_used = (np.asarray(x)
                         for x in jax.device_get((hc.keys, hc.n_used)))
         nw = self.codec.n_words
-        if self.resident:
-            # Rank-space history: boundary ranks map to key bytes through
-            # the mirror — which also means every candidate split key is
-            # ALREADY RESIDENT, so the auto-reshard path never has to
-            # insert dictionary keys (safe with packed windows in flight).
-            # mir.lock guards against a concurrent pack-worker insert
-            # rebinding the mirror arrays mid-read; a pack that landed
-            # between the device snapshot and this read can still shift
-            # ranks, which at worst maps a boundary to a NEIGHBORING
-            # resident key — a load-balance skew, never a wrong verdict
-            # (any resident key is a legal split).
-            # The shards' live prefixes are the global sorted boundary
-            # list, and rank order is key order: the quantiles are taken
-            # over the ranks (_quantiles, the rule density_splits
-            # applies to keys) and only the n_shards-1 chosen rows are
-            # unpacked — a history of a quarter million rows costs
-            # numpy passes, not a Python loop, in the resolver's thread.
-            mir = self._mirror
-            with mir.lock:
-                rows = mir.rows
-                ranks = np.concatenate([
-                    keys[d, : int(n_used[d]), 0]
-                    for d in range(self.n_shards)]).astype(np.int64)
-                ranks = ranks[(ranks >= 0) & (ranks < len(rows))]
-                ranks = np.unique(
-                    ranks[rows[ranks, nw] < int(INT32_MAX)])
-                picks = _quantiles(self.n_shards, ranks)
-                if picks is None:
-                    return None
-                splits = [self.codec.unpack(rows[int(r)]) for r in picks]
-        else:
-            sample = [
-                self.codec.unpack(row)
-                for d in range(self.n_shards)
-                for row in keys[d, : int(n_used[d])]
-                if int(row[nw]) < int(ck.INT32_MAX)  # +inf is no split key
-            ]
-            splits = density_splits(self.n_shards, sample)
+        # Rank-space history: boundary ranks map to key bytes through
+        # the mirror — which also means every candidate split key is
+        # ALREADY RESIDENT, so the auto-reshard path never has to
+        # insert dictionary keys (safe with packed windows in flight).
+        # mir.lock guards against a concurrent pack-worker insert
+        # rebinding the mirror arrays mid-read; a pack that landed
+        # between the device snapshot and this read can still shift
+        # ranks, which at worst maps a boundary to a NEIGHBORING
+        # resident key — a load-balance skew, never a wrong verdict
+        # (any resident key is a legal split).
+        # The shards' live prefixes are the global sorted boundary
+        # list, and rank order is key order: the quantiles are taken
+        # over the ranks (_quantiles, the rule density_splits
+        # applies to keys) and only the n_shards-1 chosen rows are
+        # unpacked — a history of a quarter million rows costs
+        # numpy passes, not a Python loop, in the resolver's thread.
+        mir = self._mirror
+        with mir.lock:
+            rows = mir.rows
+            ranks = np.concatenate([
+                keys[d, : int(n_used[d]), 0]
+                for d in range(self.n_shards)]).astype(np.int64)
+            ranks = ranks[(ranks >= 0) & (ranks < len(rows))]
+            ranks = np.unique(
+                ranks[rows[ranks, nw] < int(INT32_MAX)])
+            picks = _quantiles(self.n_shards, ranks)
+            if picks is None:
+                return None
+            splits = [self.codec.unpack(rows[int(r)]) for r in picks]
         if splits[0] == b"" or splits == interior_uniform(self.n_shards):
             return None
         return splits
 
     def _init_engine(self) -> None:
+        """ONE replicated dictionary (coherent by construction — every
+        device computes the identical delta merge), per-shard RANK-SPACE
+        histories, and shard bounds carried as ranks INSIDE device state so
+        dictionary inserts rebase them exactly like history ranks. The host
+        mirror is seeded with the keyspace minimum + interior shard bounds,
+        pinned so no repack can ever evict a bound.
+
+        A shard keeps the history one chip keeps: the window history, a
+        ck.HistState a shard (a base of ``capacity`` rows frozen between
+        merges, its RMQ table, a delta of ``delta_capacity`` rows that
+        every dispatch probes and paints, the merges counted), every leaf
+        stacked on the shard axis. Each shard's first row, in either
+        level, is its lower bound's rank."""
         if self.batch_size % self.n_shards:
             raise ValueError("batch_size must be divisible by n_shards")
         codec = self.codec
@@ -578,111 +530,13 @@ class ShardedConflictSet(TPUConflictSet):
         else:
             bounds = uniform_splits(codec, self.n_shards)
         self._lo = np.ascontiguousarray(bounds[:-1])  # [D, W]
-        self._hi = np.ascontiguousarray(bounds[1:])  # [D, W]
         self._shard_sharding = NamedSharding(self.mesh, P(AXIS))
         self.reshard_moved_shards = 0  # scoped-repack economy counter
-        if self.resident:
-            self._init_engine_resident()
-            return
-        # Non-resident: the mesh engine keeps full-key BatchTensors on
-        # device (clip_batch needs real key words at the shard bounds);
-        # only the cross-shard conflict combine rides the packed-bitset
-        # path (_sharded_resolve).
-        self._mirror = None
-        self._dev_batch = lambda bt: bt
-        self._dev_batch_deferred = self._dev_batch
-
-        # Per-shard states stacked on a leading device axis.
-        states = [
-            ck.init_state(self.capacity, codec.width, self._lo[d])
-            for d in range(self.n_shards)
-        ]
-        stacked = jax.tree.map(lambda *xs: np.stack(xs), *states)
-
-        shard = self._shard_sharding
-        self.state = jax.tree.map(
-            lambda x: jax.device_put(x, shard), ck.ConflictState(*stacked)
-        )
-        # lo/hi ride as ARGUMENTS (not compile-time constants) so reshard()
-        # can swap bounds without recompiling the engine.
-        self._lo_dev = jax.device_put(self._lo, shard)
-        self._hi_dev = jax.device_put(self._hi, shard)
-
-        state_specs = ck.ConflictState(*(P(AXIS) for _ in ck.ConflictState._fields))
-        batch_specs = ck.BatchTensors(*(P() for _ in ck.BatchTensors._fields))
-        wave = self.wave_commit
-        out_specs = ((P(), P(), P(), state_specs) if wave
-                     else (P(), state_specs))
-        body = _shard_map(
-            functools.partial(_sharded_resolve, wave=wave),
-            mesh=self.mesh,
-            in_specs=(state_specs, batch_specs, P(), P(), P(AXIS), P(AXIS)),
-            out_specs=out_specs,
-        )
-        jitted = jax.jit(body, donate_argnums=(0,))
-        resolve = lambda s, bt, cv, old: jitted(  # noqa: E731
-            s, bt, cv, old, self._lo_dev, self._hi_dev
-        )
-        self._resolve_fn = self._strip_exchange(resolve) if wave else resolve
-
-        def many(s, bts, cvs, olds, lo, hi):
-            def scan_body(st, xs):
-                bt, cv, old = xs
-                out = body(st, bt, cv, old, lo, hi)
-                return out[-1], out[:-1]
-
-            st, stacked = jax.lax.scan(scan_body, s, (bts, cvs, olds))
-            return (*stacked, st)
-
-        many_jit = jax.jit(many, donate_argnums=(0,))
-        resolve_many = lambda s, bts, cvs, olds: many_jit(  # noqa: E731
-            s, bts, cvs, olds, self._lo_dev, self._hi_dev
-        )
-        self._resolve_many_fn = (
-            self._strip_exchange(resolve_many) if wave else resolve_many
-        )
-        self._rebase_fn = jax.jit(
-            _shard_map(
-                lambda s, d: jax.tree.map(
-                    lambda x: x[None],
-                    ck.rebase(jax.tree.map(lambda x: x[0], s), d),
-                ),
-                mesh=self.mesh,
-                in_specs=(state_specs, P()),
-                out_specs=state_specs,
-            ),
-            donate_argnums=(0,),
-        )
-        # No mesh report entry yet: conflicting-keys reports degrade to
-        # the resolver-side conservative superset (runtime/resolver.py).
-        self._resolve_report_fn = None
-
-    def _init_engine_resident(self) -> None:
-        """Resident mesh engine (FDB_TPU_RESIDENT): ONE replicated
-        dictionary (coherent by construction — every device computes the
-        identical delta merge), per-shard RANK-SPACE histories, and shard
-        bounds carried as ranks INSIDE device state so dictionary inserts
-        rebase them exactly like history ranks. The host mirror is seeded
-        with the keyspace minimum + interior shard bounds, pinned so no
-        repack can ever evict a bound.
-
-        A shard keeps the history one chip keeps, by the switch one chip
-        reads (ck._HIST_DESIGN): by default the window history, a
-        ck.HistState a shard (a base of ``capacity`` rows frozen between
-        merges, its RMQ table, a delta of ``delta_capacity`` rows that
-        every dispatch probes and paints, the merges counted), every leaf
-        stacked on the shard axis; under FDB_TPU_HISTORY=batch the
-        one-level ck.ConflictState. Each shard's first row, in either
-        level, is its lower bound's rank."""
         s = self.n_shards
         # self._lo rows are sorted unique (row 0 = packed b"").
         self._mirror = _ResidentMirror(
             self._lo, self.dict_capacity, self.dict_delta_slots,
             tiered=self.tiered,
-        )
-        self._dev_batch = lambda bt: self._pack_resident(bt)
-        self._dev_batch_deferred = lambda bt: self._pack_resident(
-            bt, defer_repack=True
         )
         lo_ranks = np.arange(s, dtype=np.int32)
         hi_ranks = np.concatenate(
@@ -692,10 +546,8 @@ class ShardedConflictSet(TPUConflictSet):
             (self.dict_capacity + 1, self.codec.width), INT32_MAX, np.int32
         )
         dict_dev[:s] = self._lo
-        window = ck._HIST_DESIGN == "window"
         states = [
             ck.init_hist(self.capacity, 1, first, self.delta_capacity)
-            if window else ck.init_state(self.capacity, 1, first)
             for first in lo_ranks[:, None]
         ]
         stacked = jax.tree.map(lambda *xs: np.stack(xs), *states)
@@ -763,6 +615,9 @@ class ShardedConflictSet(TPUConflictSet):
         # The window history's GC-only step, which is also its forced
         # merge (_folded_history): ck.advance_hist a shard.
         self._advance_fn = each_shard(ck.advance_hist, "_shard_advance")
+        # TPUConflictSet's GC-only entry point, a shard.
+        self._advance_hist_fn = lambda res, cv, old: (
+            None, self._advance_fn(res, cv, old))
         # Repack/evict touch ranks elementwise — the plain resident entry
         # points shard transparently under jit (the evict shift table
         # derives from the replicated dictionary, so every device applies
@@ -783,25 +638,17 @@ class ShardedConflictSet(TPUConflictSet):
             donate_argnums=(0,),
         )
 
-    @property
-    def _advance_hist_fn(self):
-        """TPUConflictSet's GC-only entry point, a shard (resident engines:
-        the only mesh engine with a window history)."""
-        return lambda res, cv, old: (None, self._advance_fn(res, cv, old))
-
     def _device_merges(self, merges: np.ndarray):
         return jax.device_put(merges, self._shard_sharding)
 
     def _folded_history(self) -> ck.ConflictState:
-        """The stacked ONE-level history that holds every shard's rows:
-        the engine's own or, of the window history, the bases after a
-        forced merge a shard at the floor that stands (ck.advance_hist:
+        """The stacked ONE-level history that holds every shard's rows: the
+        window history's bases after a forced merge a shard at the floor
+        that stands (ck.advance_hist:
         the base then holds everything and the delta its one row; no
         verdict changes, a merge never does). For what reads or moves
         rows between dispatches: the split policy's quantiles and the
         re-split itself."""
-        if not self._is_hist:
-            return self._hist_core
         # Nothing to fold where every delta holds its one row: the policy
         # folds for its quantiles and the re-split asks again behind it.
         if np.max(jax.device_get(self._hist_core.delta.n_used)) > 1:
@@ -818,57 +665,19 @@ class ShardedConflictSet(TPUConflictSet):
         (ck._rows_in_use), less the shard's lower bound, which is the
         first row of both levels."""
         hc = self._hist_core
-        if self._is_hist:
-            used = ck._rows_in_use_jit(
-                (hc.base.n_used, hc.delta.n_used),
-                (hc.base.versions, hc.delta.oldest))
-        else:
-            used = hc.n_used
-        occ = [int(x) - self._is_hist
-               for x in np.asarray(jax.device_get(used))]
+        used = ck._rows_in_use_jit(
+            (hc.base.n_used, hc.delta.n_used),
+            (hc.base.versions, hc.delta.oldest))
+        occ = [int(x) - 1 for x in np.asarray(jax.device_get(used))]
         self.shard_rows_in_use = occ
         return occ
 
     def reshard(self, splits: list[bytes]) -> None:
-        """Re-split the keyspace between dispatch windows.
-
-        The device-resident histories are pulled to host, re-clipped to
-        the new bounds (a pure step-function transform — no information
-        loss), and pushed back; the engine is NOT recompiled because
-        shard bounds ride as runtime arguments. Verdicts are unchanged
-        (tested); only the per-shard load balance moves. The kernel
-        analogue of the reference keeping resolver ranges balanced from
-        DD metrics (CommitProxyServer.actor.cpp resolver splits)."""
-        if len(splits) != self.n_shards - 1:
-            raise ValueError(
-                f"need {self.n_shards - 1} interior splits, got {len(splits)}"
-            )
-        if self.resident:
-            return self._reshard_resident(splits)
-        st = jax.device_get(self.state)
-        bounds = pack_splits(self.codec, splits)
-        lo = np.ascontiguousarray(bounds[:-1])
-        hi = np.ascontiguousarray(bounds[1:])
-        nk, nv, nu, nover = _redistribute_history(
-            np.asarray(st.keys), np.asarray(st.versions),
-            np.asarray(st.n_used), lo, hi, self.capacity,
-        )
-        shard = self._shard_sharding
-        self.state = ck.ConflictState(
-            keys=jax.device_put(nk, shard),
-            versions=jax.device_put(nv, shard),
-            n_used=jax.device_put(nu.astype(np.int32), shard),
-            oldest=jax.device_put(np.asarray(st.oldest), shard),
-            overflow=jax.device_put(np.asarray(st.overflow) | nover, shard),
-        )
-        self._interior_splits = list(splits)
-        self.shard_rows_in_use = [int(x) for x in nu]
-        self._lo, self._hi = lo, hi
-        self._lo_dev = jax.device_put(lo, shard)
-        self._hi_dev = jax.device_put(hi, shard)
-
-    def _reshard_resident(self, splits: list[bytes]) -> None:
-        """Resident-mode reshard: a SCOPED repack of moved shards only.
+        """Re-split the keyspace between dispatch windows: a SCOPED repack
+        of moved shards only. Verdicts are unchanged (tested); only the
+        per-shard load balance moves. The kernel analogue of the reference
+        keeping resolver ranges balanced from DD metrics
+        (CommitProxyServer.actor.cpp resolver splits).
 
         The per-shard histories are rank arrays, so redistribution is pure
         int32 slicing against the new bound ranks; shards whose (lo, hi)
@@ -880,14 +689,17 @@ class ShardedConflictSet(TPUConflictSet):
         shift the delta merge applies, which is only safe with no packed-
         but-undispatched windows outstanding — the documented contract of
         explicit reshard()."""
+        if len(splits) != self.n_shards - 1:
+            raise ValueError(
+                f"need {self.n_shards - 1} interior splits, got {len(splits)}"
+            )
         mir = self._mirror
         with mir.lock:
             # The replicated dictionary stays where it is unless a bound
             # key has to be inserted (never on the auto path): only the
             # histories and the bounds come down and go back up.
             # The window history is folded first, a merge a shard on the
-            # device: its bases then hold every row, and they move as a
-            # one-level history's rows move.
+            # device: its bases then hold every row.
             rows = self._folded_history()
             live = self.state
             keys, vers, n_used, over, old_lo, old_hi = (
@@ -996,17 +808,16 @@ class ShardedConflictSet(TPUConflictSet):
                 overflow=jax.device_put(new_over, shard),
             )
             self.shard_rows_in_use = [int(x) for x in new_used]
-            if self._is_hist:
-                # The folded delta holds ONE row a shard, its lower
-                # bound's rank, which has moved with the bound.
-                was = live.hist
-                dkeys = np.full(was.delta.keys.shape, INT32_MAX, np.int32)
-                dkeys[:, 0, 0] = lo_ranks
-                hist = was._replace(
-                    base=hist,
-                    delta=was.delta._replace(
-                        keys=jax.device_put(dkeys, shard)),
-                )
+            # The folded delta holds ONE row a shard, its lower bound's
+            # rank, which has moved with the bound.
+            was = live.hist
+            dkeys = np.full(was.delta.keys.shape, INT32_MAX, np.int32)
+            dkeys[:, 0, 0] = lo_ranks
+            hist = was._replace(
+                base=hist,
+                delta=was.delta._replace(
+                    keys=jax.device_put(dkeys, shard)),
+            )
             self.state = ck.ResState(
                 dict_keys=(jax.device_put(dict_dev, repl)
                            if dict_dev is not None else live.dict_keys),
@@ -1018,54 +829,13 @@ class ShardedConflictSet(TPUConflictSet):
                     np.minimum(hi_ranks, INT32_MAX).astype(np.int32), shard
                 ),
             )
-            if self._is_hist:
-                # base_st is still the table of the rows that left. A
-                # rebase by 0 moves no version and rebuilds every shard's
-                # table from its base as it stands (ck.rebase_hist): the
-                # program is compiled since the warm-up.
-                self.state = self._rebase_fn(self.state, np.int32(0))
+            # base_st is still the table of the rows that left. A rebase
+            # by 0 moves no version and rebuilds every shard's table from
+            # its base as it stands (ck.rebase_hist): the program is
+            # compiled since the warm-up.
+            self.state = self._rebase_fn(self.state, np.int32(0))
             self._interior_splits = list(splits)
             self._lo = np.ascontiguousarray(bounds[:-1])
-            self._hi = np.ascontiguousarray(bounds[1:])
-
-
-def _redistribute_history(
-    keys: np.ndarray, vers: np.ndarray, n_used: np.ndarray,
-    lo: np.ndarray, hi: np.ndarray, capacity: int,
-) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """Re-clip a sharded step-function history to new shard bounds.
-
-    keys/vers: [D, C, W]/[D, C] per-shard histories whose live prefixes
-    concatenate to the GLOBAL sorted boundary list (shards own disjoint,
-    ordered key ranges). Returns (keys', vers', n_used', overflow') for
-    the new bounds lo/hi — pure host numpy, used between dispatch windows.
-    """
-    d_n, cap, w = keys.shape
-    glob_k = np.concatenate([keys[d, : n_used[d]] for d in range(d_n)])
-    glob_v = np.concatenate([vers[d, : n_used[d]] for d in range(d_n)])
-    gsort = _row_sort_keys(glob_k)
-
-    new_keys = np.full_like(keys, ck.INT32_MAX)
-    new_vers = np.full_like(vers, ck.NEG_VERSION)
-    new_used = np.zeros(d_n, np.int32)
-    new_over = np.zeros(d_n, bool)
-    for d in range(d_n):
-        lo_sk = _row_sort_keys(lo[d : d + 1])[0]
-        hi_sk = _row_sort_keys(hi[d : d + 1])[0]
-        i0 = np.searchsorted(gsort, lo_sk, side="right") - 1
-        i1 = np.searchsorted(gsort, hi_sk, side="left")
-        seg_k = glob_k[i0:i1].copy()
-        seg_v = glob_v[i0:i1].copy()
-        seg_k[0] = lo[d]  # boundary exactly at shard lo; version of the
-        # segment containing lo carries over (step function semantics)
-        n = len(seg_k)
-        if n > capacity:
-            new_over[d] = True
-            seg_k, seg_v, n = seg_k[:capacity], seg_v[:capacity], capacity
-        new_keys[d, :n] = seg_k
-        new_vers[d, :n] = seg_v
-        new_used[d] = n
-    return new_keys, new_vers, new_used, new_over
 
 
 __all__ = [

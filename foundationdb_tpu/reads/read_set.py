@@ -1,7 +1,7 @@
 """TPUReadSet: resident key-universe mirror + one probe per dispatch.
 
 The read-plane analogue of ``TPUConflictSet``'s resident dictionary
-(models/conflict_set.py, FDB_TPU_RESIDENT): the versioned map's sorted key
+(models/conflict_set.py): the versioned map's sorted key
 universe is packed ONCE into ``[n, W]`` int32 rows (core/keypack.py) and
 stays resident — in HBM on the device arm, as the u64-column host mirror
 otherwise — across dispatches. A dispatch packs only its queries and runs

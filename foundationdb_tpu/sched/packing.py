@@ -1,9 +1,8 @@
 """Double-buffered host packing for the wire dispatch path.
 
-PR 2 moved the batch dictionary build (dedup + memcmp sort of every
-endpoint key, ``TPUConflictSet._pack_dict``) onto the host — serial with
-device execution in the plain loop. This runner puts the pack half
-(``pack_wire_window``) on ONE worker thread so window N+1 packs while the
+The rank pack of every endpoint key (``TPUConflictSet._pack_resident``)
+runs on the host — serial with device execution in the plain loop. This
+runner puts the pack half (``pack_wire_window``) on ONE worker thread so window N+1 packs while the
 device executes window N; the dispatch half (``dispatch_window``, which
 threads device state) stays on the submitting thread, in order.
 
@@ -22,8 +21,8 @@ no structural change: ``dispatch_window`` on a speculative engine routes
 through the engine's reconcile ring (dispatch N+1 runs against the
 optimistically advanced state while N's verdicts are unconfirmed; the
 collector reconciles in FIFO order), so the runner's three stages become a
-genuine three-deep pipeline — pack N+2 on the worker thread (the fused
-native kp_pack_window pass), speculatively resolve N+1 on the device,
+genuine three-deep pipeline — pack N+2 on the worker thread,
+speculatively resolve N+1 on the device,
 reconcile N at collect. The reconcile ring lives in the ENGINE, not the
 runner, because it must also guard the serial entry points (rebase,
 resident repack, object-path resolves) that never pass through a runner.

@@ -373,18 +373,15 @@ class TestResidentEngineIntegration:
         )
 
     def test_feed_and_eviction_interaction(self):
-        from foundationdb_tpu.models import conflict_kernel as ck
         from foundationdb_tpu.models.conflict_set import TPUConflictSet
 
-        if not ck._PACKED:
-            pytest.skip("resident engine requires the packed kernel")
         # Short MVCC window: churned keys expire as versions advance, so
         # the tiny dictionary recycles by EVICTION/repack (the
         # interaction under test) instead of hard-overflowing on live
         # keys.
         cs = TPUConflictSet(capacity=1 << 10, batch_size=16,
-                            resident=True, dict_capacity=96,
-                            dict_delta_slots=16, window_versions=40)
+                            dict_capacity=96, dict_delta_slots=16,
+                            window_versions=40)
         f = RecentWritesFilter(bits_log2=12, banks=4,
                                window_versions=10_000, backend="jax")
         cs.attach_admission_filter(f)
@@ -411,12 +408,9 @@ class TestResidentEngineIntegration:
         """Only ACCEPTED write sets feed the filter: a conflicted txn's
         write fingerprint must not poison admission."""
         from foundationdb_tpu.core.types import TxnConflictInfo
-        from foundationdb_tpu.models import conflict_kernel as ck
         from foundationdb_tpu.models.conflict_set import TPUConflictSet
 
-        if not ck._PACKED:
-            pytest.skip("resident engine requires the packed kernel")
-        cs = TPUConflictSet(capacity=1 << 10, batch_size=16, resident=True)
+        cs = TPUConflictSet(capacity=1 << 10, batch_size=16)
         f = RecentWritesFilter(bits_log2=12, banks=4,
                                window_versions=10_000)
         cs.attach_admission_filter(f)

@@ -293,6 +293,28 @@ def test_resolver_counts_failed_resolves_and_names_no_device_for_host_engines():
     assert m["device"] is None
 
 
+def test_graft_entry_is_the_program_the_cells_run():
+    """entry() hands out the body `ck._resolve_res_jit` jits, with a small
+    engine's state and the example transactions as its packer ships them:
+    jitted here, its verdicts are the oracle's on the same transactions."""
+    import __graft_entry__
+    from foundationdb_tpu.models import conflict_kernel as ck
+    from foundationdb_tpu.sim.oracle import OracleConflictSet
+
+    fn, args = __graft_entry__.entry()
+    assert fn is ck.resolve_batch_res
+    assert isinstance(args[0], ck.ResState)
+    assert isinstance(args[1], ck.ResidentBatch)
+    verdicts, new_state = jax.jit(fn)(*args)
+    assert isinstance(new_state, ck.ResState)
+    txns = __graft_entry__._example_txns()
+    want = OracleConflictSet().resolve(
+        txns, __graft_entry__.EXAMPLE_COMMIT, __graft_entry__.EXAMPLE_OLDEST)
+    assert [int(v) for v in np.asarray(verdicts)[: len(txns)]] == [
+        int(v) for v in want]
+    assert len({int(v) for v in want}) == 3  # commits, conflicts, too old
+
+
 def test_dryrun_multichip_refuses_devices_that_are_not_there():
     import __graft_entry__
 

@@ -245,7 +245,8 @@ def test_block_accept_variants_agree():
         jnp.asarray(read_live), jnp.asarray(wb), jnp.asarray(we),
         jnp.asarray(write_live)))
 
-    # Python sequential oracle: the reference acceptance order.
+    # The reference acceptance order as a fixed B-step sequential loop
+    # (what the kernel's `seq` arm ran on the device until ROADMAP C1).
     acc = np.zeros(b, bool)
     for i in range(b):
         if not base[i]:
@@ -254,55 +255,3 @@ def test_block_accept_variants_agree():
     assert (wave == acc).all()
     assert (blk == acc).all()
     assert (fused == acc).all()
-
-    # The FDB_TPU_ACCEPT=seq within-block design (a fixed G-step
-    # fori_loop) must agree too — here driven directly on the full tile.
-    seq = np.asarray(ck._seq_accept(jnp.asarray(base), jnp.asarray(m)))
-    assert (seq == acc).all()
-
-
-def test_accept_seq_env_full_kernel_parity():
-    """FDB_TPU_ACCEPT=seq (read at import) must produce byte-identical
-    verdicts through the full TPUConflictSet path — run in a subprocess so
-    the env snapshot and jit caches are clean."""
-    import os
-    import subprocess
-    import sys
-
-    code = r"""
-import os
-os.environ["JAX_PLATFORMS"] = "cpu"
-from foundationdb_tpu.utils import enable_compilation_cache
-enable_compilation_cache()
-import numpy as np
-from foundationdb_tpu.models.conflict_set import TPUConflictSet
-from tests.test_conflict_oracle import rand_txn
-from foundationdb_tpu.models import conflict_kernel as ck
-assert ck._ACCEPT_DESIGN == os.environ.get("FDB_TPU_ACCEPT", "wave")
-rng = np.random.default_rng(99)
-cs = TPUConflictSet(capacity=4096, batch_size=1024, max_read_ranges=2,
-                    max_write_ranges=2, max_key_bytes=8)
-out = []
-cv = 1000
-for _ in range(2):
-    cv += 25
-    txns = [rand_txn(rng, read_version=cv - int(rng.integers(1, 100)),
-                     n_ranges=2, alphabet=3, max_len=2)
-            for _ in range(1024)]
-    out.extend(int(v) for v in cs.resolve(txns, cv))
-print("".join(map(str, out)))
-"""
-    def run(accept_env):
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        env.pop("FDB_TPU_ACCEPT", None)
-        if accept_env:
-            env["FDB_TPU_ACCEPT"] = accept_env
-        r = subprocess.run(
-            [sys.executable, "-c", code], env=env, capture_output=True,
-            text=True, timeout=600, cwd=os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))),
-        )
-        assert r.returncode == 0, r.stderr[-2000:]
-        return r.stdout.strip().splitlines()[-1]
-
-    assert run("seq") == run(None)

@@ -528,13 +528,10 @@ import foundationdb_tpu.models.conflict_kernel as ck  # defaults import fine
 # The flags are read at import, so each case re-executes the module via
 # importlib.reload — one subprocess covers the whole rejection matrix
 # (spawning a fresh interpreter per bogus value would pay the jax import
-# five more times for the same assertion).
+# again for the same assertion).
 for flag, bogus, accepted in [
-    ("FDB_TPU_ACCEPT", "Seq", "wave, seq"),
     ("FDB_TPU_WAVE_COMMIT", "yes", "0, 1"),
-    ("FDB_TPU_RMQ", "dense", "sparse, blocked"),
-    ("FDB_TPU_HISTORY", "windowed", "window, batch"),
-    ("FDB_TPU_PACKED", "true", "0, 1"),
+    ("FDB_TPU_SPEC_RESOLVE", "On", "0, 1"),
 ]:
     os.environ[flag] = bogus
     try:
@@ -548,9 +545,9 @@ for flag, bogus, accepted in [
         del os.environ[flag]
 # Valid non-default values import clean and land in the snapshot.
 os.environ["FDB_TPU_WAVE_COMMIT"] = "1"
-os.environ["FDB_TPU_ACCEPT"] = "seq"
+os.environ["FDB_TPU_SPEC_RESOLVE"] = "1"
 importlib.reload(ck)
-assert ck._WAVE_COMMIT is True and ck._ACCEPT_DESIGN == "seq"
+assert ck._WAVE_COMMIT is True and ck._SPEC_RESOLVE is True
 print("FLAGS-OK")
 """
 
@@ -558,8 +555,7 @@ print("FLAGS-OK")
 class TestEnvFlagValidation:
     def test_unknown_values_raise_with_accepted_list(self):
         env = dict(os.environ, JAX_PLATFORMS="cpu")
-        for k in ("FDB_TPU_ACCEPT", "FDB_TPU_WAVE_COMMIT", "FDB_TPU_RMQ",
-                  "FDB_TPU_HISTORY", "FDB_TPU_PACKED"):
+        for k in ("FDB_TPU_WAVE_COMMIT", "FDB_TPU_SPEC_RESOLVE"):
             env.pop(k, None)
         r = subprocess.run(
             [sys.executable, "-c", _FLAG_PROBE], env=env,
@@ -579,7 +575,7 @@ class TestEnvFlagValidation:
 
 
 # ---------------------------------------------------------------------------
-# Env-default parity: wave commit composed with the other kernel knobs
+# Env-default parity: the wave default taken from the environment
 # ---------------------------------------------------------------------------
 
 
@@ -630,19 +626,12 @@ print("WAVE-MATRIX-OK")
 # each); the fast battery proves the same parity in-process (chain/clique/
 # ring above) and the env→engine default via the oracle path
 # (test_deployed_factory_refuses_wave_multi_resolver), so these children
-# only add the ENV path on the DEVICE engine per kernel design.
-@pytest.mark.parametrize("extra", [
-    {},                          # packed window-history defaults
-    pytest.param({"FDB_TPU_PACKED": "0"}),
-    # seq block-accept coexisting with wave mode
-    pytest.param({"FDB_TPU_ACCEPT": "seq"}),
-], ids=lambda f: ",".join(f"{k[8:]}={v}" for k, v in f.items()) or "defaults")
-def test_wave_env_default_parity(extra):
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               FDB_TPU_WAVE_COMMIT="1", **extra)
+# only add the ENV path on the DEVICE engine.
+def test_wave_env_default_parity():
+    env = dict(os.environ, JAX_PLATFORMS="cpu", FDB_TPU_WAVE_COMMIT="1")
     r = subprocess.run(
         [sys.executable, "-c", _WAVE_CHILD], env=env, capture_output=True,
         text=True, timeout=600, cwd=_REPO,
     )
-    assert r.returncode == 0, f"{extra}: {r.stderr[-2000:]}"
+    assert r.returncode == 0, r.stderr[-2000:]
     assert r.stdout.strip().splitlines()[-1] == "WAVE-MATRIX-OK"

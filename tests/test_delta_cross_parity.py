@@ -35,10 +35,6 @@ from tests.test_conflict_oracle import rand_txn
 from tests.test_dict_insert import reference as numpy_merge
 from tests.test_tiered_dict import TIER, _hotspot_steps
 
-pytestmark = pytest.mark.skipif(
-    not ck._RESIDENT, reason="the delta exists only in the resident engine"
-)
-
 KW = dict(capacity=512, batch_size=32, max_read_ranges=4,
           max_write_ranges=4, max_key_bytes=8)
 
@@ -101,7 +97,7 @@ def drive(cs, steps):
 
 
 def single():
-    cs = TPUConflictSet(resident=True, **KW)
+    cs = TPUConflictSet(**KW)
     spy = Spy(cs, "_resolve_fn")
     drive(cs, oracle_stream(np.random.default_rng(27), 10, n_txns=(8, 32)))
     assert len(spy.seen) == 10 and min(spy.seen) > 0, spy.seen
@@ -116,7 +112,7 @@ def window():
 
     rng = np.random.default_rng(28)
     kw = dict(KW, batch_size=16)
-    cs = TPUConflictSet(resident=True, **kw)
+    cs = TPUConflictSet(**kw)
     spy = Spy(cs, "_resolve_many_fn")
     runner = PipelinedWindowRunner(cs, threaded=True)
     oracle = OracleConflictSet()
@@ -162,7 +158,7 @@ def after_repack():
     """Four delta slots: any dispatch with more new keys repacks in full,
     ships them inside the table and an EMPTY delta (all D + 1) beside it; the
     next delta is ranked against the rebuilt mirror."""
-    cs = TPUConflictSet(resident=True, dict_delta_slots=4, **KW)
+    cs = TPUConflictSet(dict_delta_slots=4, **KW)
     spy = Spy(cs, "_resolve_fn")
     rng = np.random.default_rng(29)
     few = [(

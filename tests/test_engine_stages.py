@@ -401,6 +401,27 @@ def lowered_scopes(jitted, *args) -> set:
             for part in path.split("/")}
 
 
+def test_the_programs_the_benchmark_reads_are_the_engines_own():
+    """What benchmark/lib/trace_reduce.py counts by name
+    (`jit__resolve_res_jit`, `jit__capacity_reading_jit`: `jit_` and the
+    jitted function's name) and tests/benchmark/
+    test_benchmark_rehearsal_mesh.py's fixtures spell for the mesh
+    (`jit__sharded_resolve_res`), read off live engines, not fixtures."""
+    from foundationdb_tpu.parallel.sharded_resolver import ShardedConflictSet
+
+    kw = dict(capacity=256, batch_size=8, max_key_bytes=8)
+    cs = TPUConflictSet(**kw)
+    assert not (cs.wave_commit or cs.spec or cs.tiered)
+    assert cs._resolve_fn is ck._resolve_res_jit
+    assert cs._resolve_report_fn is ck._resolve_report_res_jit
+    assert cs._repack_fn is ck._repack_res_jit
+    assert cs._resolve_fn.__name__ == "_resolve_res_jit"
+    assert ck._capacity_reading_jit.__name__ == "_capacity_reading_jit"
+    mesh = ShardedConflictSet(n_shards=2, **kw)
+    assert mesh._resolve_fn.__name__ == "_sharded_resolve_res"
+    assert mesh._repack_fn is ck._repack_res_jit
+
+
 @pytest.fixture(scope="module")
 def resident_args():
     from foundationdb_tpu.core.keypack import INT32_MAX

@@ -27,7 +27,12 @@ from foundationdb_tpu.core.types import KeyRange, TxnConflictInfo
 from foundationdb_tpu.models import conflict_kernel as ck
 from foundationdb_tpu.models.conflict_kernel import NEG_VERSION
 from foundationdb_tpu.models.conflict_set import TPUConflictSet
-from foundationdb_tpu.ops.lex import searchsorted_words, searchsorted_words_fp
+from foundationdb_tpu.ops.lex import (
+    lex_lt,
+    searchsorted_words,
+    searchsorted_words_fp,
+    sort_keys_with_payload,
+)
 from tests.test_dict_insert import encode
 
 
@@ -96,10 +101,7 @@ def old_merge_delta(base, delta, floor):
     c, w = base.keys.shape
     cd = delta.keys.shape[0]
     n = c + cd
-    # The packed design's fingerprint search also serves the merge (both
-    # operands are step-function key arrays); unpacked keeps the r5
-    # full-width search so the A/B baseline is untouched.
-    _ss = searchsorted_words_fp if ck._PACKED else searchsorted_words
+    _ss = searchsorted_words_fp
     cross_d = _ss(base.keys, delta.keys, side="right")  # [Cd]
     seg_b_for_d = jnp.maximum(cross_d - 1, 0)
     cross_b = _ss(delta.keys, base.keys, side="right")  # [C]
@@ -319,11 +321,34 @@ def test_dedup_compact_equals_the_searching_compaction(w, n, runs, room):
 # -- the paint through it -----------------------------------------------------
 
 
+def paint_full_keys(state, batch, accepted, commit_version, new_oldest):
+    """ck._paint_tail fed W-word keys, as the kernel's full-key paint fed
+    it until ROADMAP C1 (the kernel now feeds it rank rows, W = 1): the
+    batch's endpoints sorted on the device, one history search for both
+    the containing segment and the merge path's cross-rank."""
+    c, w = state.keys.shape
+    b, q, _ = batch.write_begin.shape
+    e2 = b * q
+    valid = (accepted[:, None] & batch.write_mask
+             & lex_lt(batch.write_begin, batch.write_end))
+    inf_row = jnp.full((w,), INT32_MAX, jnp.int32)
+    wb = jnp.where(valid[..., None], batch.write_begin, inf_row).reshape(e2, w)
+    we = jnp.where(valid[..., None], batch.write_end, inf_row).reshape(e2, w)
+    new_keys = jnp.concatenate([wb, we])
+    flat = valid.reshape(e2).astype(jnp.int32)
+    cross_rank = searchsorted_words(state.keys, new_keys, side="right")
+    new_oldv = state.versions[jnp.maximum(cross_rank - 1, 0)]
+    return ck._paint_tail(
+        state, *sort_keys_with_payload(
+            new_keys, jnp.concatenate([flat, -flat]), new_oldv, cross_rank),
+        commit_version, new_oldest)
+
+
 def paint(dedup_compact, monkeypatch, st, wb, we, wm, accepted, cv, floor):
-    """_paint_and_compact (so _paint_tail) with the given compaction."""
+    """_paint_tail with the given compaction."""
     monkeypatch.setattr(ck, "_dedup_compact", dedup_compact)
     batch = types.SimpleNamespace(write_begin=wb, write_end=we, write_mask=wm)
-    return jax.jit(lambda st, acc: ck._paint_and_compact(
+    return jax.jit(lambda st, acc: paint_full_keys(
         st, batch, acc, cv, floor))(st, accepted)
 
 
@@ -424,7 +449,7 @@ def test_hist_merges_counts_the_dispatches_that_merged_and_every_advance():
                         batch_size=batch, max_read_ranges=2,
                         max_write_ranges=q, max_key_bytes=16)
     cd = cs.delta_capacity
-    assert cd == 2 * batch * q + 2 and cs._is_hist and cs.hist_merges == 0
+    assert cd == 2 * batch * q + 2 and cs.hist_merges == 0
     rng = np.random.default_rng(41)
     expected, version = 0, 0
 
@@ -568,7 +593,7 @@ def test_the_reading_without_a_shard_axis_is_what_it_was(seed):
         (x["base_n"][0],), (x["base_over"][0],), np.int32(0))
     assert [int(a) for a in np.asarray(plain)] == [
         int(x["base_n"][0]), int(x["base_over"][0]), 0]
-    # the stacked plain history (the mesh under FDB_TPU_HISTORY=batch)
+    # the stacked plain history (one level a shard)
     many = stacked_leaves(seed, shards=4)
     plain = ck._capacity_reading_jit(
         (many["base_n"],), (many["base_over"],), np.int32(0))

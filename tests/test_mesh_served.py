@@ -436,7 +436,7 @@ def test_the_roles_headroom_and_merges_are_the_window_historys_a_shard():
     them through the collector or, collected at once, through headroom()."""
     loop, cs, res = mesh_role(capacity=512, batch_size=16,
                               max_write_ranges=2)
-    assert cs._is_hist and cs.delta_capacity == 66
+    assert cs.delta_capacity == 66
     run = Compared(loop, res, point_judge())
     rng = np.random.default_rng(8)
     for b in range(30):

@@ -20,10 +20,6 @@ from foundationdb_tpu.models.conflict_set import TPUConflictSet
 from foundationdb_tpu.sim.oracle import OracleConflictSet
 from tests.test_engine_stages import point_txns
 
-pytestmark = pytest.mark.skipif(
-    not ck._RESIDENT, reason="the dictionary exists only in the resident engine"
-)
-
 BATCH = 32
 STEP = 100  # versions a batch
 BEHIND = 4  # batches between the newest commit and the MVCC floor
@@ -38,7 +34,7 @@ COUNTERS = ("full_repacks", "repacks_frag_due", "repacks_dict_full",
 
 
 def untiered():
-    return TPUConflictSet(resident=True, **KW)
+    return TPUConflictSet(**KW)
 
 
 def mesh():

@@ -1,8 +1,6 @@
-"""Device-resident dictionary & rank-space history (FDB_TPU_RESIDENT).
+"""Device-resident dictionary & rank-space history.
 
-The resident mode is a PER-ENGINE override (like wave_commit), so one
-process can A/B resident vs per-dispatch-repack engines byte-for-byte on
-the same stream, with the brute-force oracle as the third witness. The
+The engine against the brute-force oracle on the same stream. The
 eviction / overflow / full-repack / reshard paths are forced with tiny
 dictionary capacities — randomized parity must hold across all of them,
 including keys that are evicted and then reappear.
@@ -25,18 +23,13 @@ from tests.test_conflict_oracle import rand_txn
 KW = dict(capacity=512, batch_size=32, max_read_ranges=4,
           max_write_ranges=4, max_key_bytes=8)
 
-pytestmark = pytest.mark.skipif(
-    not ck._PACKED, reason="resident requires the packed kernel"
-)
-
-
 def pt(k: bytes) -> KeyRange:
     return KeyRange(k, k + b"\x00")
 
 
-def drive_parity(rng, cs_res, cs_base, n_batches=10, n_txns=(1, 40),
+def drive_parity(rng, cs_res, n_batches=10, n_txns=(1, 40),
                  report_some=False):
-    """Same stream through both engines + the oracle; assert 3-way parity.
+    """Same stream through the engine and the oracle; assert parity.
     Returns the oracle (for follow-on assertions)."""
     oracle = OracleConflictSet()
     cv = 1000
@@ -51,11 +44,9 @@ def drive_parity(rng, cs_res, cs_base, n_batches=10, n_txns=(1, 40),
                 object.__setattr__(t, "report_conflicting_keys", True)
         oldest = cv - 200
         got_r = cs_res.resolve(txns, cv, oldest_version=oldest)
-        got_b = cs_base.resolve(txns, cv, oldest_version=oldest)
         oracle.oldest_version = max(oracle.oldest_version, oldest)
         want = oracle.resolve(txns, cv)
         assert got_r == want, f"resident vs oracle, batch {batch_i}"
-        assert got_b == want, f"baseline vs oracle, batch {batch_i}"
         if report_some:
             for i, ranges in oracle.last_conflicting.items():
                 kernel = cs_res.last_conflicting.get(i)
@@ -70,18 +61,16 @@ def drive_parity(rng, cs_res, cs_base, n_batches=10, n_txns=(1, 40),
 @pytest.mark.parametrize("seed", [1, 2])
 def test_parity_vs_oracle_and_packed(seed):
     rng = np.random.default_rng(seed)
-    cs_res = TPUConflictSet(resident=True, **KW)
-    cs_base = TPUConflictSet(resident=False, **KW)
+    cs_res = TPUConflictSet(**KW)
     assert isinstance(cs_res.state, ck.ResState)
-    drive_parity(rng, cs_res, cs_base, report_some=(seed == 1))
+    drive_parity(rng, cs_res, report_some=(seed == 1))
     assert not cs_res.overflowed
     stats = cs_res.dict_stats
     assert stats["dispatches"] > 0 and stats["resident_keys"] > 1
-    assert cs_base.dict_stats is None
 
 
 def test_duplicate_keys_straddling_dispatches_hit_the_mirror():
-    cs = TPUConflictSet(resident=True, **KW)
+    cs = TPUConflictSet(**KW)
     keys = [f"k{i}".encode() for i in range(24)]
     txns = [TxnConflictInfo(99, [pt(k)], [pt(k)]) for k in keys]
     cs.resolve(txns, 100)
@@ -100,9 +89,7 @@ def test_eviction_then_reappearance_stays_exact():
     re-enter the dictionary and still resolve exactly (the history that
     referenced it was remapped, never corrupted)."""
     kw = dict(KW, window_versions=120)
-    cs = TPUConflictSet(resident=True, dict_capacity=96, dict_delta_slots=48,
-                        **kw)
-    base = TPUConflictSet(resident=False, **kw)
+    cs = TPUConflictSet(dict_capacity=96, dict_delta_slots=48, **kw)
     oracle = OracleConflictSet()
     hot = b"evict-me"
     cv = 1000
@@ -115,10 +102,9 @@ def test_eviction_then_reappearance_stays_exact():
             for j in range(8)
         ]
         got = cs.resolve(txns, cv, oldest_version=cv - 100)
-        want_b = base.resolve(txns, cv, oldest_version=cv - 100)
         oracle.oldest_version = max(oracle.oldest_version, cv - 100)
         want = oracle.resolve(txns, cv)
-        assert got == want == want_b, f"round {i}"
+        assert got == want, f"round {i}"
     stats = cs.dict_stats
     assert stats["full_repacks"] > 0, stats
     assert stats["evictions"] > 0, stats
@@ -127,16 +113,15 @@ def test_eviction_then_reappearance_stays_exact():
 
 def test_overflow_fallback_tiny_delta_forces_full_repack():
     rng = np.random.default_rng(9)
-    cs = TPUConflictSet(resident=True, dict_delta_slots=4, **KW)
-    base = TPUConflictSet(resident=False, **KW)
-    drive_parity(rng, cs, base, n_batches=6, n_txns=(8, 24))
+    cs = TPUConflictSet(dict_delta_slots=4, **KW)
+    drive_parity(rng, cs, n_batches=6, n_txns=(8, 24))
     stats = cs.dict_stats
     # >4 new keys per dispatch: every early dispatch takes the fallback.
     assert stats["full_repacks"] >= 2, stats
 
 
 def test_dict_capacity_too_small_raises_actionable_error():
-    cs = TPUConflictSet(resident=True, dict_capacity=8, dict_delta_slots=4,
+    cs = TPUConflictSet(dict_capacity=8, dict_delta_slots=4,
                         **KW)
     txns = [TxnConflictInfo(99, [], [pt(f"k{i}".encode())]) for i in range(32)]
     with pytest.raises(ValueError, match="dict_capacity"):
@@ -144,12 +129,12 @@ def test_dict_capacity_too_small_raises_actionable_error():
 
 
 def test_wave_levels_parity_resident():
-    """FDB_TPU_RESIDENT=1 × wave commit: verdicts AND wave levels match
-    the per-dispatch-dictionary wave engine on RMW chains + cycles."""
+    """Wave commit in rank space: verdicts AND wave levels match the wave
+    oracle on RMW chains + cycles."""
     rng = np.random.default_rng(21)
     kw = dict(KW, batch_size=64)
-    cs_r = TPUConflictSet(resident=True, wave_commit=True, **kw)
-    cs_b = TPUConflictSet(resident=False, wave_commit=True, **kw)
+    cs_r = TPUConflictSet(wave_commit=True, **kw)
+    cs_b = OracleConflictSet(wave_commit=True)
     cv = 500
     for i in range(6):
         cv += 10
@@ -169,13 +154,13 @@ def test_window_path_parity_and_deferred_repack_threaded():
     """The pipelined window path with a DEFERRED repack: a tiny delta
     budget makes the pack worker emit _RepackPlans; the mirror gate must
     serialize the worker against dispatch-side repacks and verdicts must
-    equal the baseline engine's byte-for-byte."""
+    equal the oracle's byte-for-byte."""
     from foundationdb_tpu.sched.packing import PipelinedWindowRunner
 
     rng = np.random.default_rng(13)
     kw = dict(KW, batch_size=16)
-    cs_r = TPUConflictSet(resident=True, dict_delta_slots=8, **kw)
-    cs_b = TPUConflictSet(resident=False, **kw)
+    cs_r = TPUConflictSet(dict_delta_slots=8, **kw)
+    cs_b = OracleConflictSet()
     runner = PipelinedWindowRunner(cs_r, threaded=True)
     k, count = 2, 16
     outs_b = []
@@ -190,7 +175,10 @@ def test_window_path_parity_and_deferred_repack_threaded():
         wire = encode_resolve_batch(txns)
         cvs = list(range(cv, cv + k))
         wires.append((wire, cvs))
-        outs_b.append(cs_b.resolve_wire_window(wire, cvs, count))
+        outs_b.append([
+            [int(v) for v in cs_b.resolve(
+                txns[b * count:(b + 1) * count], cvs[b])]
+            for b in range(k)])
         cv += k
     for wire, cvs in wires:
         runner.submit(wire, cvs, count)
@@ -208,7 +196,7 @@ def test_gc_and_headroom_recover_under_resident():
     """advance()/headroom/clear_overflow drive the ResState wrapper: the
     fail-safe contract (headroom recovers as the window slides) must hold
     with the rank-space history."""
-    cs = TPUConflictSet(resident=True, capacity=256, batch_size=16,
+    cs = TPUConflictSet(capacity=256, batch_size=16,
                         max_key_bytes=8, window_versions=100)
     cv = 1000
     for i in range(30):
@@ -240,10 +228,9 @@ class TestResidentMesh:
 
     def test_mesh_parity_vs_oracle(self):
         rng = np.random.default_rng(31)
-        cs = self._mk(resident=True)
+        cs = self._mk()
         assert isinstance(cs.state, ck.ResState)
-        base = self._mk(resident=False)
-        drive_parity(rng, cs, base, n_batches=8)
+        drive_parity(rng, cs, n_batches=8)
 
     def test_reshard_scoped_repack_preserves_verdicts(self):
         """Explicit reshard mid-stream: per-shard rank histories are
@@ -251,7 +238,7 @@ class TestResidentMesh:
         scoped counter proves the economy), bound keys are pinned, and
         verdicts stay oracle-exact across the move."""
         rng = np.random.default_rng(33)
-        cs = self._mk(resident=True, n_shards=4)
+        cs = self._mk(n_shards=4)
         oracle = OracleConflictSet()
         cv = 1000
         keys_seen = []
@@ -283,7 +270,7 @@ class TestResidentMesh:
         (already resident → no dictionary insert) and keeps verdicts
         oracle-exact."""
         rng = np.random.default_rng(35)
-        cs = self._mk(resident=True, n_shards=2, auto_reshard=True,
+        cs = self._mk(n_shards=2, auto_reshard=True,
                       reshard_interval=3, reshard_skew=1.5)
         oracle = OracleConflictSet()
         cv = 1000
@@ -304,7 +291,7 @@ class TestResidentMesh:
 def test_mirror_gate_serializes_concurrent_pack():
     """The deferred-repack gate: while a plan is pending, a concurrent
     pack blocks until the dispatch thread executes the repack."""
-    cs = TPUConflictSet(resident=True, dict_delta_slots=4, **KW)
+    cs = TPUConflictSet(dict_delta_slots=4, **KW)
     mir = cs._mirror
     mir.gate.clear()
     seen = []

@@ -271,7 +271,7 @@ class TestDensitySplits:
 
 # -- the window history a shard (PR 45) --------------------------------------
 #
-# A shard keeps what one chip keeps (ck._HIST_DESIGN): a base frozen between
+# A shard keeps what one chip keeps: a base frozen between
 # merges, its table, a delta that every dispatch probes and paints. The delta
 # here holds 2 x 16 x 2 + 2 = 66 rows, so a shard folds it into its base
 # every few batches, on the demand of ITS slice of the batch alone.
@@ -344,7 +344,7 @@ def test_the_window_history_a_shard_is_one_historys(resplit):
     mesh = ShardedConflictSet(n_shards=4, **small_window())
     one = TPUConflictSet(capacity=2048, batch_size=16, max_read_ranges=4,
                          max_write_ranges=2, max_key_bytes=8)
-    assert mesh._is_hist and mesh.delta_capacity == 66
+    assert mesh.delta_capacity == 66
     oracle = OracleConflictSet()
     cv, moved, before, written = 1000, 0, merges_a_shard(mesh), []
     for step in range(48):

@@ -25,17 +25,12 @@ import numpy as np
 import pytest
 
 from foundationdb_tpu.core.types import KeyRange, TxnConflictInfo
-from foundationdb_tpu.models import conflict_kernel as ck
 from foundationdb_tpu.models.conflict_set import (
     TPUConflictSet,
     encode_resolve_batch,
 )
 from foundationdb_tpu.sim.oracle import OracleConflictSet
 from tests.test_conflict_oracle import rand_txn
-
-pytestmark = pytest.mark.skipif(
-    not ck._RESIDENT, reason="tiering rides the resident rank-space engine"
-)
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

@@ -204,10 +204,10 @@ class TestTwoPhaseOracle:
 
 
 class TestTwoPhaseDevice:
-    @pytest.mark.parametrize("resident", [True, False])
-    def test_sharded_matches_single_and_oracle(self, resident):
-        rng = np.random.default_rng(11)
-        kw = eng_kw(resident=resident, wave_commit=True)
+    @pytest.mark.parametrize("seed", [11, 12])
+    def test_sharded_matches_single_and_oracle(self, seed):
+        rng = np.random.default_rng(seed)
+        kw = eng_kw(wave_commit=True)
         single = TPUConflictSet(**kw)
         shards = [TPUConflictSet(**kw) for _ in range(2)]
         oracle = OracleConflictSet(wave_commit=True)

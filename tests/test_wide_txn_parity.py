@@ -203,13 +203,13 @@ def test_too_old_is_the_transactions_and_write_only_never_is():
 def kernel_conts(cs: TPUConflictSet) -> list:
     """Record the `cont` of every batch `cs` hands its kernel from now on
     (True where it had one)."""
-    seen, inner = [], cs._dev_batch
+    seen, inner = [], cs._pack_resident
 
-    def spy(bt):
+    def spy(bt, **kw):
         seen.append(bt.cont is not None)
-        return inner(bt)
+        return inner(bt, **kw)
 
-    cs._dev_batch = spy
+    cs._pack_resident = spy
     return seen
 
 
@@ -449,10 +449,7 @@ def test_the_loser_report_names_the_reads_that_lost(n_reads, n_writes):
 
 
 @pytest.mark.parametrize("kw", [
-    pytest.param(dict(resident=False), id="per-dispatch-dictionary"),
     pytest.param(dict(wave_commit=True), id="wave-commit"),
-    pytest.param(dict(wave_commit=True, resident=False),
-                 id="wave-commit-per-dispatch-dictionary"),
 ])
 def test_the_other_engine_designs_judge_wide_transactions_exactly(kw):
     cs = engine(**kw)
@@ -497,14 +494,12 @@ def two_phase(shards, txns, cv, oldest):
     return [sh.resolve_apply(graph) for sh in shards]
 
 
-@pytest.mark.parametrize("resident", [True, False],
-                         ids=["resident", "per-dispatch-dictionary"])
 @pytest.mark.parametrize("n_reads,n_writes", [(17, 9), (40, 2)])
 def test_the_two_phase_wave_exchange_judges_wide_transactions_exactly(
-        n_reads, n_writes, resident):
+        n_reads, n_writes):
     """Every shard is sent every transaction, clipped to its keys, so a
     wide one takes other rows on each: the exchange goes by transaction."""
-    kw = dict(wave_commit=True, resident=resident)
+    kw = dict(wave_commit=True)
     single, shards = engine(**kw), [engine(**kw) for _ in SHARDS]
     oracle = OracleConflictSet(wave_commit=True)
     rows_differ = wide_on_a_shard = False
